@@ -10,7 +10,7 @@ kernels where fusion matters.
 Mirrors apex/__init__.py:1-20 eager subpackage imports.
 """
 
-from . import compat  # noqa: F401  (jax-version shims; polyfills jax.shard_map)
+from . import compat  # noqa: F401  (names jax's shard_map / axis_size)
 from . import ops  # noqa: F401  (kernel substrate; the "amp_C" equivalent)
 from . import multi_tensor_apply  # noqa: F401
 

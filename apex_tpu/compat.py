@@ -1,89 +1,12 @@
-"""jax version compatibility shims.
+"""The one module that names jax's ``shard_map`` and ``axis_size``.
 
-The codebase targets the modern jax surface (``jax.shard_map`` with the
-``check_vma`` knob, ``jax.lax.axis_size``); the oldest supported runtime is
-jax 0.4.x, where ``shard_map`` still lives in ``jax.experimental.shard_map``
-(with the knob spelled ``check_rep``) and ``axis_size`` does not exist.
-Everything in apex_tpu goes through this module — ``tests/test_compat.py``
-lints that no source file calls ``jax.shard_map`` directly — and
-:func:`install` additionally polyfills the modern names onto the ``jax``
-module itself so user code (and the test suite) written against the modern
-API runs unchanged on 0.4.x.
+Package code imports both from here (the COMPAT-SHIM lint rule and
+``tests/test_compat.py`` keep it so); under the installed jax they are
+the native entry points, passed through.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 
-_SENTINEL = object()
-
-try:
-    _NATIVE_SHARD_MAP = jax.shard_map   # jax >= 0.5
-except AttributeError:
-    _NATIVE_SHARD_MAP = None
-
-#: True when this jax exposes jax.shard_map natively (>= 0.5)
-HAS_NATIVE_SHARD_MAP = _NATIVE_SHARD_MAP is not None
-
-
-def _legacy_shard_map():
-    from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=_SENTINEL, **kw):
-    """``jax.shard_map`` on every supported jax.
-
-    Accepts the modern keyword surface; on jax 0.4.x the call is forwarded
-    to ``jax.experimental.shard_map.shard_map`` with ``check_vma``
-    translated to its old spelling ``check_rep`` (same meaning: verify the
-    per-device values are consistent with the declared replication).
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        if check_vma is not _SENTINEL:
-            kw["check_vma"] = check_vma
-        return _NATIVE_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-    if check_vma is not _SENTINEL:
-        kw["check_rep"] = check_vma
-    return _legacy_shard_map()(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **kw)
-
-
-_NATIVE_AXIS_SIZE = getattr(jax.lax, "axis_size", None)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` on every supported jax.
-
-    On 0.4.x the idiom is ``lax.psum(1, axis)``: psum of the literal 1 is
-    constant-folded to the mapped axis size without emitting a collective.
-    """
-    if _NATIVE_AXIS_SIZE is not None:
-        return _NATIVE_AXIS_SIZE(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _polyfill_shard_map(f=None, **kw):
-    """The function installed AS ``jax.shard_map`` on 0.4.x: the compat
-    wrapper above, usable both directly and (defensively) curried."""
-    if f is None:
-        return functools.partial(_polyfill_shard_map, **kw)
-    return shard_map(f, **kw)
-
-
-def install():
-    """Polyfill the modern names onto ``jax`` where missing (idempotent).
-
-    Called from ``apex_tpu.__init__`` so that importing apex_tpu is enough
-    to make ``jax.shard_map(..., check_vma=False)`` and
-    ``jax.lax.axis_size`` work on jax 0.4.x.  No-op on modern jax.
-    """
-    if not HAS_NATIVE_SHARD_MAP:
-        jax.shard_map = _polyfill_shard_map
-    if _NATIVE_AXIS_SIZE is None:
-        jax.lax.axis_size = axis_size
-
-
-install()
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size

@@ -1,7 +1,7 @@
 """Chunked LM-head + cross-entropy: the program-level vocab-chain
 attack.
 
-The round-4 GPT profile (BENCH_HISTORY, docs/performance.md) attributes
+The round-4 GPT profile (unledgered runs, docs/performance.md) attributes
 ~34 ms of the 69.5 ms seq-128 step to the vocab chain — tied-head
 matmul, f32 casts of the (N, V) logits, loss, and backward — while the
 same chain costs 15.9 ms in isolation; two Pallas kernel attacks on the
@@ -27,7 +27,7 @@ under ``jax.checkpoint``, so
 The models' ``output_hidden=True`` option pairs with this: forward
 returns ``(hidden, head_table)`` and the loss owns the chain.
 
-Measured on v5e (BENCH_HISTORY round 5): see the ``--loss-mode`` A/B
+Measured on v5e (unledgered run, round 5): see the ``--loss-mode`` A/B
 rows; this path ships as an option, with the winner of the in-step A/B
 promoted to the bench default.
 """
@@ -48,7 +48,7 @@ from .softmax_xentropy import softmax_cross_entropy_loss
 def _chunk_rows(n, v, requested):
     """Rows per chunk.  Default: balanced chunks capped at 1024 rows
     (and ~64M logits elements for very wide heads) — the v5e-measured
-    optimum for both LM vocabs (BENCH_HISTORY round 5: GPT 50257 swept
+    optimum for both LM vocabs (unledgered run, round 5: GPT 50257 swept
     127..4064 rows, peak at 1016; Llama 32000 likewise) — big enough to
     keep the (chunk, V) @ (V, E) matmuls MXU-shaped, small enough that
     casts/loss fuse block-locally.  Balanced like

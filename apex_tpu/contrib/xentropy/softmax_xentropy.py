@@ -33,8 +33,8 @@ where a (B·S, 50257) f32 temporary is gigabytes —
 * above ``_AUTO_ELEMS`` elements (or always, when ``APEX_TPU_XENT_BLOCK_ROWS``
   is set) both passes run row-blocked under ``lax.map(batch_size=...)`` so
   only one block of f32 temporaries is live at a time.  The GPT seq-1024
-  loss shape (16384, 50257) — the on-chip OOM-crash signature this guards
-  against (diagnose_gpt1024.jsonl round 4) — chunks into two blocks; the
+  loss shape (16384, 50257) — the on-chip out-of-memory shape this guards
+  against — chunks into two blocks; the
   seq-128 headline shape stays on the single-shot path.
 """
 from __future__ import annotations
@@ -62,7 +62,7 @@ def _use_kernel(mode):
     XLA's own fusion of the jnp expression at both LM loss shapes
     (0.38x at (8192, 50257), 0.74x at (16384, 50257) fwd+bwd — the
     online-softmax block sweep is VPU-bound while XLA's reduce kernels
-    are tuned; BENCH_HISTORY round 4), and the GPT seq-128 headline ran
+    are tuned; unledgered run, round 4), and the GPT seq-128 headline ran
     8% slower with it engaged.  The kernel stays for parity coverage
     (interpret mode always exercises it — that mode exists to test
     kernels) and as the starting point for a future fused
@@ -163,7 +163,7 @@ def _bwd_row(lf_row, lse, label, g, smoothing, padding_idx, out_dtype):
     # scatter: the compare fuses into this elementwise chain, while a
     # vmapped scatter-add lowered to an XLA scatter that serialized the
     # whole (rows, vocab) grad — measured 1.6x step-time regression on
-    # the seq-128 LM headlines (BENCH_HISTORY round 4).  For a padding
+    # the seq-128 LM headlines (unledgered run, round 4).  For a padding
     # label of -1 no column compares equal, and gm is 0 anyway.
     onehot = (jax.lax.broadcasted_iota(jnp.int32, (c,), 0) == label)
     if smoothing:

@@ -525,7 +525,7 @@ def flash_min_sk() -> int:
 
     Measured on v5e (bench --kernels-timing, fwd+bwd).  Round 3, before
     causal block skipping: S=256 ran 0.82x XLA.  Round 4, with skipping
-    (BENCH_HISTORY round-4 A/B table): S=256 1.06x, S=512 0.96x (both
+    (unledgered run, round 4 A/B table): S=256 1.06x, S=512 0.96x (both
     noise-level), S=1024 causal 1.24x, S=2048/D=128 1.19x, banded
     S=2048/w=256 1.82x — flash decisively wins the shapes it exists
     for, and the 256-512 boundary is a wash.  APEX_TPU_FLASH_MIN_SK

@@ -37,12 +37,14 @@ _VERSION = 1
 
 
 def default_path() -> str:
-    """``$APEX_TPU_LEDGER`` or ``~/.cache/apex_tpu/kernel_ledger.json``."""
+    """``$APEX_TPU_LEDGER`` or ``<cache_root>/kernel_ledger.json`` (the
+    checkout's own git-ignored cache directory,
+    :func:`apex_tpu.compile_cache.cache_root`)."""
     env = os.environ.get(_ENV_PATH)
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "apex_tpu",
-                        "kernel_ledger.json")
+    from ..compile_cache import cache_root
+    return os.path.join(cache_root(), "kernel_ledger.json")
 
 
 def chip_name(devices=None) -> str:

@@ -19,7 +19,7 @@ backward: dlogits = gm·(exp(logit - lse) - onehot) is recomputed
           logits traffic per step.
 
 Status: NOT wired into any model/loss path.  VERDICT (round-4 on-chip
-A/B, BENCH_HISTORY): **0.69x** — the kernel LOSES to XLA's lowering of
+A/B, unledgered runs): **0.69x** — the kernel LOSES to XLA's lowering of
 the plain matmul + fused-xentropy chain at (8192, 50257, 768) fwd+bwd
 (23.0 vs 15.9 ms).  XLA's isolated vocab-chain cost is already close to
 the matmul roofline; the backward's +33% recompute FLOPs and this

@@ -8,7 +8,7 @@ backward is a single elementwise pass reconstructing probabilities from
 the saved logsumexp.  The jnp expression of the same math
 (contrib/xentropy/softmax_xentropy.py) can materialize f32 casts of the
 whole (rows, vocab) logits in unfavorable fusion contexts — measured
-~14 ms of convert_element_type per GPT seq-128 step (BENCH_HISTORY
+~14 ms of convert_element_type per GPT seq-128 step (unledgered run,
 round 4); this kernel was built to fuse that away (see VERDICT below
 for how that bet measured out).
 
@@ -19,7 +19,7 @@ backward needs no scratch: ``p = exp(x - lse)`` is elementwise given
 the saved per-row lse, and the label column folds in as an iota
 compare.
 
-VERDICT (round-4 on-chip A/B, BENCH_HISTORY): the kernel LOSES to
+VERDICT (round-4 on-chip A/B, unledgered runs): the kernel LOSES to
 XLA's fused lowering of the jnp expression in isolation — 0.38x at
 (8192, 50257), 0.74x at (16384, 50257) fwd+bwd — the online-softmax
 column sweep is VPU-bound where XLA's reduce kernels are tuned, and

@@ -630,17 +630,15 @@ class DonatedReuse(Rule):
 class CompatShim(Rule):
     """Direct ``jax.shard_map`` / ``lax.axis_size`` in package code.
 
-    Both are jax>=0.5 spellings: on the 0.4.x runtimes this repo
-    supports they are AttributeErrors (the PR 3 satellite that fixed
-    ~120 tier-1 failures).  Package code goes through
-    ``apex_tpu.compat``; user code may use the modern names because
-    ``compat.install()`` polyfills them — so this rule only applies
-    inside the apex_tpu package.
+    Package code reaches both through ``apex_tpu.compat`` so the
+    spelling lives in one module (under the installed jax it is a
+    pass-through to the native names); user code may use the jax names
+    directly — so this rule only applies inside the apex_tpu package.
     """
     id = "COMPAT-SHIM"
-    summary = "direct jax.shard_map / lax.axis_size (breaks on jax 0.4.x)"
-    hint = ("use apex_tpu.compat.shard_map / compat.axis_size — the shim "
-            "translates check_vma<->check_rep and polyfills 0.4.x")
+    summary = "direct jax.shard_map / lax.axis_size (bypasses compat)"
+    hint = ("use apex_tpu.compat.shard_map / compat.axis_size — the one "
+            "module that names the jax entry points")
 
     def check(self, module, ctx):
         if not module.in_apex_package or \
@@ -652,28 +650,27 @@ class CompatShim(Rule):
                 if d == "jax.shard_map":
                     yield self.finding(
                         module, node,
-                        "direct jax.shard_map — AttributeError on "
-                        "jax 0.4.x (compat.shard_map translates the "
-                        "check_vma knob)")
+                        "direct jax.shard_map — package code goes "
+                        "through compat.shard_map")
                 elif d in ("jax.lax.axis_size", "lax.axis_size"):
                     yield self.finding(
                         module, node,
-                        "direct lax.axis_size — does not exist on "
-                        "jax 0.4.x (compat.axis_size uses the psum(1) "
-                        "idiom there)")
+                        "direct lax.axis_size — package code goes "
+                        "through compat.axis_size")
                 elif d and d.startswith("jax.experimental.shard_map"):
                     yield self.finding(
                         module, node,
                         "jax.experimental.shard_map referenced directly "
-                        "— removed on modern jax; the shim owns version "
-                        "dispatch")
+                        "— deprecated in favour of jax.shard_map; route "
+                        "through apex_tpu.compat")
             elif isinstance(node, ast.ImportFrom) and \
                     (node.module or "").startswith(
                         "jax.experimental.shard_map"):
                 yield self.finding(
                     module, node,
-                    "import from jax.experimental.shard_map — removed "
-                    "on modern jax; route through apex_tpu.compat")
+                    "import from jax.experimental.shard_map — "
+                    "deprecated in favour of jax.shard_map; route "
+                    "through apex_tpu.compat")
 
 
 # ---------------------------------------------------------------------------

@@ -333,7 +333,7 @@ class GptModel(nn.Module):
         # the -1e30 pads into the loss; slice logits[..., :vocab_size]
         # before such a loss.  Pad table rows are
         # never looked up and receive zero gradient through the masked
-        # columns.  Measured on v5e (BENCH_HISTORY round 4): a WASH on
+        # columns.  Measured on v5e (unledgered run, round 4): a WASH on
         # the GPT headlines (912 vs 921 seq/s at seq-128) — XLA pads
         # unaligned contraction dims internally — so this is a
         # divisibility/parity convenience (e.g. for tp sharding), not a

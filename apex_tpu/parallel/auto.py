@@ -4,7 +4,7 @@ Every parallelism primitive in this framework is a manual knob on
 :func:`~apex_tpu.training.make_train_step` (``axis_name``, ``tp_axis``,
 ``zero_sharding``/``zero_stage``, ``accum_steps``) or a model build option
 (``tp_axis=``, ``sp_axis=``, the chunked LM loss).  Picking the
-configuration is worth double-digit throughput (BENCH_HISTORY round 5:
+configuration is worth double-digit throughput (unledgered run, round 5:
 +13–15% from the chunked vocab chain alone, batch-size plateaus that
 invert per model), and the AMP (arXiv:2210.07297) / Galvatron
 (arXiv:2504.03662) line of work shows an analytical cost model over
@@ -133,19 +133,27 @@ CHIPS = {
 }
 
 
+#: ``device_kind`` substrings -> CHIPS key.  The v5e chip reports
+#: itself as "TPU v5 lite".
+_KIND_TO_CHIP = (("v6", "v6"), ("v5p", "v5p"), ("v5e", "v5e"),
+                 ("v5 lite", "v5e"), ("v4", "v4"), ("v3", "v3"))
+
+
 def chip_spec(devices=None) -> ChipSpec:
-    """Match the running device kind to the constants table (cpu
-    fallback; unknown accelerators borrow the v4 numbers)."""
+    """Match the running device kind to the constants table.  The CPU
+    backend gets the "cpu" entry; an accelerator the table does not
+    list is an error, never priced as some other chip."""
     devices = list(devices) if devices is not None else jax.devices()
     kind = (getattr(devices[0], "device_kind", "") or
             devices[0].platform or "").lower()
     if "cpu" in kind or devices[0].platform == "cpu":
         return CHIPS["cpu"]
-    for key in ("v6", "v5p", "v5e", "v5 lite", "v4", "v3"):
-        if key in kind:
-            return CHIPS.get(key, CHIPS["v5e"]) if key != "v5 lite" \
-                else CHIPS["v5e"]
-    return CHIPS["v4"]
+    for needle, key in _KIND_TO_CHIP:
+        if needle in kind:
+            return CHIPS[key]
+    raise ValueError(
+        f"chip_spec: no constants for device_kind "
+        f"{devices[0].device_kind!r} — add it to auto.CHIPS")
 
 
 # ---------------------------------------------------------------------------
@@ -521,8 +529,6 @@ def profile_model(model, optimizer, loss_fn: Callable, example_batch, *,
         lowered = jax.jit(jax.value_and_grad(fwd)).lower(
             vals_struct, *batch)
         ca = lowered.cost_analysis()
-        if not isinstance(ca, dict):        # older jax returns [dict]
-            ca = ca[0]
         ma = lowered.compile().memory_analysis()
         return (float(ca.get("flops", 0.0)),
                 float(ca.get("bytes accessed", 0.0)),
@@ -2314,33 +2320,7 @@ def build_planned_step(model, optimizer, loss_fn, parallel, *,
 def measured_step_memory(compiled) -> int:
     """Per-device footprint of a compiled step program, donation-aware:
     arguments + outputs + temps − aliased (donated buffers counted
-    once).  The validation target for :func:`predict_memory`.
-
-    Compile the program with :func:`compile_uncached`: when jax 0.4.x's
-    persistent compilation cache is enabled, executables that pass
-    through its (de)serialization layer report ``alias_size_in_bytes=0``
-    — a donated program then measures its outputs double, and whether a
-    given compile passes through the layer depends on the
-    ``min_compile_time_secs`` threshold, i.e. on machine load.
-    """
+    once).  The validation target for :func:`predict_memory`."""
     ma = compiled.memory_analysis()
     return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
                + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-
-
-def compile_uncached(lowered):
-    """``lowered.compile()`` with the persistent compilation cache
-    disabled for the duration — the donation-aware companion of
-    :func:`measured_step_memory` (see its note on alias metadata)."""
-    try:
-        prev = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        prev = None
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:       # knob absent on this jax: nothing to bypass
-        return lowered.compile()
-    try:
-        return lowered.compile()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)

@@ -149,30 +149,6 @@ def _merge(out, lse, o_r, lse_r):
 UNROLL_LIMIT = int(os.environ.get("APEX_TPU_RING_UNROLL_LIMIT", "8"))
 
 
-def _jaxlib_version():
-    try:
-        import jaxlib.version
-        return tuple(int(p) for p in
-                     jaxlib.version.__version__.split(".")[:2])
-    except Exception:
-        return (0, 0)
-
-
-_JAXLIB = _jaxlib_version()
-
-
-def _must_unroll(causal: bool, dropout_p: float) -> bool:
-    """jaxlib 0.4.x workaround: with ``causal=False`` and no dropout,
-    nothing in the ring body consumes ``lax.axis_index`` — but the
-    fori_loop lowering still materializes it as a PartitionId
-    instruction, which that jaxlib's SPMD partitioner rejects inside the
-    loop body ("PartitionId is not supported").  The unrolled path
-    computes the identical math (the fori body is the same ``step``
-    closure), so route these cases there regardless of ring size; fixed
-    upstream in jaxlib >= 0.5."""
-    return (not causal) and dropout_p == 0.0 and _JAXLIB < (0, 5)
-
-
 def _expand_kv(kv3, groups, batch):
     """(B*KVH, Sk, D) -> (B*H, Sk, D): repeat each KV head over its
     query group (kv-major, groups consecutive — the GQA head order the
@@ -221,7 +197,7 @@ def _ring_fwd_math(q3, k3, v3, seed, axis_name, causal, scale, mode,
             v_cur = lax.ppermute(v_cur, axis_name, perm)
         return out, lse, k_cur, v_cur
 
-    if n <= UNROLL_LIMIT or _must_unroll(causal, dropout_p):
+    if n <= UNROLL_LIMIT:
         k_cur, v_cur = k3, v3
         for r in range(n):
             out, lse, k_cur, v_cur = step(r, out, lse, k_cur, v_cur,
@@ -288,7 +264,7 @@ def _ring_vjp_bwd(axis_name, causal, scale, mode, groups, batch, dropout_p,
         dv_cur = lax.ppermute(dv_cur, axis_name, perm)
         return dq, dk_cur, dv_cur, k_cur, v_cur
 
-    if n <= UNROLL_LIMIT or _must_unroll(causal, dropout_p):
+    if n <= UNROLL_LIMIT:
         k_cur, v_cur = k3, v3
         for r in range(n):
             dq, dk_cur, dv_cur, k_cur, v_cur = step(

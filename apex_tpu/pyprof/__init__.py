@@ -87,7 +87,7 @@ def thunk_events_available() -> bool:
     """One-shot runtime probe: does ``jax.profiler.trace`` on THIS
     backend/jaxlib emit per-thunk duration events?
 
-    CPU jaxlib (0.4.x) writes the trace plugin's metadata but no thunk
+    The CPU backend writes the trace plugin's metadata but no thunk
     timings, which left the measured-profile pipeline dead behind two
     xfail'd tests.  The probe runs one trivial jitted function under a
     trace into a tempdir and checks whether ``parse.trace`` can extract

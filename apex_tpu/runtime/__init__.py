@@ -5,9 +5,10 @@ flatten/unflatten, csrc/flatten_unflatten.cpp; the examples' side-stream
 prefetcher byte-work, examples/imagenet/main_amp.py:264-302).  This package
 is the TPU-native equivalent: a small C++ library (csrc/runtime.cpp) built
 on first use with the system toolchain and bound over ctypes — no torch, no
-pybind11.  Degrades to numpy fallbacks when no compiler is present,
-mirroring the reference's Python-only install path (setup.py extensions
-optional, README.md:130-139).
+pybind11.  Where the build fails it says so once, with the compiler's
+stderr, and the numpy fallbacks take over, mirroring the reference's
+Python-only install path (setup.py extensions optional,
+README.md:130-139).
 
 Public surface:
   flatten(arrays) / unflatten(flat, like)   — bucket coalescing (apex_C)
@@ -30,12 +31,16 @@ Public surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
 import threading
+import warnings
 
 import numpy as np
+
+from ..compile_cache import cache_root
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc", "runtime.cpp")
@@ -44,28 +49,43 @@ _lib = None
 
 
 def _build_and_load():
-    """Compile csrc/runtime.cpp into a cached .so and dlopen it."""
-    cache = os.environ.get("APEX_TPU_CACHE",
-                           os.path.join(tempfile.gettempdir(),
-                                        "apex_tpu_runtime"))
-    os.makedirs(cache, exist_ok=True)
+    """Compile csrc/runtime.cpp into ``<cache_root>/native`` (named by
+    the source's content hash, so an edited source rebuilds) and dlopen
+    it.  A failure is reported once — ``_get`` caches the verdict — with
+    the compiler's stderr, and the numpy fallbacks take over."""
     try:
-        src_mtime = int(os.path.getmtime(_SRC))
-    except OSError:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError as e:
+        warnings.warn(f"apex_tpu native runtime: cannot read {_SRC}: {e}; "
+                      "using the numpy fallbacks")
         return None
-    so = os.path.join(cache, f"libapex_runtime_{src_mtime}.so")
+    cache = os.path.join(cache_root(), "native")
+    so = os.path.join(cache, f"libapex_runtime_{digest}.so")
     if not os.path.exists(so):
-        tmp = so + f".build{os.getpid()}"
+        os.makedirs(cache, exist_ok=True)
+        # build beside the target, then rename: atomic vs concurrent
+        # builders (two test processes racing on a cold checkout)
+        fd, tmp = tempfile.mkstemp(suffix=".so.partial", dir=cache)
+        os.close(fd)
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
                _SRC, "-o", tmp]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
+            os.unlink(tmp)
+            stderr = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                f"apex_tpu native runtime: build failed ({e}); using the "
+                f"numpy fallbacks.  Compiler stderr:\n"
+                f"{stderr.decode(errors='replace')}")
             return None
-        os.replace(tmp, so)  # atomic vs concurrent builders
+        os.replace(tmp, so)
     try:
         return ctypes.CDLL(so)
-    except OSError:
+    except OSError as e:
+        warnings.warn(f"apex_tpu native runtime: cannot load {so}: {e}; "
+                      "using the numpy fallbacks")
         return None
 
 

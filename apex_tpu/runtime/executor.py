@@ -81,10 +81,8 @@ class DonationPolicy:
     ``"auto"`` donates on backends with real input→output buffer
     aliasing (tpu/gpu) and skips donation on cpu, where XLA accepts
     ``donate_argnums`` but degrades it to defensive copies (measured 2×
-    eager FusedAdam step time at 10M params — and jax 0.4.x's
-    persistently-cached CPU executables resolve the aliasing of
-    deserialized donated programs incorrectly, returning stale
-    outputs).  The resolved flag is part of every program cache key.
+    eager FusedAdam step time at 10M params).  The resolved flag is
+    part of every program cache key.
     """
 
     def __init__(self, mode="auto"):

@@ -103,8 +103,7 @@ def make_gan_train_step(netD, netG, optD, optG,
     """
     from ..runtime import executor as _executor
     # the executor's donation policy: donate on tpu/gpu, skip on cpu
-    # (defensive copies + the jax-0.4.x cached-executable aliasing
-    # hazard — see make_train_step's donate_state doc)
+    # (defensive copies — see make_train_step's donate_state doc)
     donate_state = _executor.donation.resolve(donate_state)
     d_parts = _net_parts(netD, optD, half_dtype, keep_batchnorm_fp32,
                          "make_gan_train_step(netD)")

@@ -800,7 +800,7 @@ def make_train_step(model, optimizer, loss_fn: Callable,
     collectives are per-tensor, pre-stack) and grad_accum; excludes
     zero_sharding (its per-param shardings are the point there).
 
-    MEASURED VERDICT (v5e, BENCH_HISTORY round 5): a NEGATIVE result,
+    MEASURED VERDICT (v5e, unledgered run, round 5): a NEGATIVE result,
     kept as the reference design's receipt.  ResNet-50 b128: 2256
     img/s stacked vs 2355 per-tensor (a truly flat 1-D layout was far
     worse, 1806 — the 1-D→tiled relayouts cost ~17 ms/step).  The
@@ -867,10 +867,8 @@ def make_train_step(model, optimizer, loss_fn: Callable,
     ``donate_state``: "auto" (default) follows the executor's
     :class:`~apex_tpu.runtime.executor.DonationPolicy` — donate on
     tpu/gpu (in-place buffer reuse), skip on cpu, where XLA degrades
-    donation to defensive copies (measured 2x step time, and jax 0.4.x's
-    persistently-cached CPU executables resolve the input→output
-    aliasing of deserialized donated programs incorrectly — stale
-    outputs on cache hits).  Pass True/False to force.
+    donation to defensive copies (measured 2x step time).  Pass
+    True/False to force.
     """
     from ..runtime import executor as _executor
 

@@ -7,13 +7,13 @@ into the fused step's optimized loss).
 The canonical Switch layout: experts ride the SAME mesh axis the batch
 shards over, so expert-parallel capacity grows with data parallelism and
 the ordinary psum-mean of the step yields exact expert gradients.  The
-reference has no MoE (SURVEY.md §2.3).  Runs anywhere: with fewer real
-devices than ``--devices`` it builds a virtual CPU mesh.
+reference has no MoE (SURVEY.md §2.3).  Uses the first ``--devices``
+devices JAX finds and exits with a message when there are fewer
+(examples/README.md has the virtual-mesh recipe).
 
 Run: ``python main_moe.py --devices 4 --steps 20 --top-k 1``
 """
 import argparse
-import os
 import sys
 import time
 
@@ -42,14 +42,6 @@ def main():
     args = parse_args()
 
     import jax
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P

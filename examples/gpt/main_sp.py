@@ -5,14 +5,13 @@ per-device activation memory is O(S / n_devices) at block boundaries, so
 global context length scales linearly with the ring size.
 
 The reference has no long-context story (SURVEY.md §5); this is the
-TPU-native recipe.  Runs anywhere: with fewer real devices than
-``--devices`` it builds a virtual CPU mesh (the same trick the test
-harness uses).
+TPU-native recipe.  Uses the first ``--devices`` devices JAX finds and
+exits with a message when there are fewer (examples/README.md has the
+virtual-mesh recipe for a host without that many).
 
 Run: ``python main_sp.py --devices 8 --seq-len 1024 --steps 20``
 """
 import argparse
-import os
 import sys
 import time
 
@@ -39,17 +38,7 @@ def parse_args():
 def main():
     args = parse_args()
 
-    # pin a virtual CPU mesh when the attached platform cannot provide
-    # the requested ring (single-chip or laptop runs)
     import jax
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P

@@ -8,13 +8,13 @@ are shard-count-independent.
 
 The reference has no model parallelism (SURVEY.md §2.3 — its distributed
 scope is DDP); this is the TPU-native equivalent of what Megatron-LM
-layers on top of it.  Runs anywhere: with fewer real devices than
-``--dp * --tp`` it builds a virtual CPU mesh (the test harness trick).
+layers on top of it.  Uses the first ``--dp * --tp`` devices JAX finds and
+exits with a message when there are fewer (examples/README.md has the
+virtual-mesh recipe for a host without that many).
 
 Run: ``python main_tp.py --dp 2 --tp 4 --steps 20``
 """
 import argparse
-import os
 import sys
 import time
 
@@ -43,14 +43,6 @@ def main():
     n_dev = args.dp * args.tp
 
     import jax
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n_dev}"
-        ).strip()
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P

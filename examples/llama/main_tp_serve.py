@@ -12,7 +12,8 @@ Demonstrates, on the same weights:
   4. beam search under the same mesh (``beam_generate(..., mesh=...)``),
      bit-identical to single-shard beam search.
 
-Run (any host; uses a virtual CPU mesh unless real devices exist):
+Run (needs ``--tp`` devices; examples/README.md has the virtual-mesh
+recipe for a host without that many):
     python main_tp_serve.py --tp 2 --new-tokens 32
 
 The reference repo has no inference path (SURVEY.md §2 — it is a
@@ -20,7 +21,6 @@ training-side library); this example exercises the framework's own
 serving story end to end.
 """
 import argparse
-import os
 import sys
 
 
@@ -38,14 +38,6 @@ def parse_args():
 
 def main():
     args = parse_args()
-    # a virtual device mesh when the host lacks args.tp real devices
-    # (set BEFORE jax import; harmless if real devices exist)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={args.tp}"
-        ).strip()
-
     import jax
     import jax.numpy as jnp
     import numpy as np

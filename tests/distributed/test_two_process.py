@@ -22,9 +22,9 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 def test_two_process_dp_step_grads_agree(tmp_path):
     env = dict(os.environ, PYTHONPATH=REPO,
                APEX_TPU_COORD_PORT="12517")
-    # children pin their own platform/devices
+    # the children pin the CPU backend themselves; without the suite's
+    # virtual-device flag each owns exactly one device
     env.pop("XLA_FLAGS", None)
-    env.pop("JAX_PLATFORMS", None)
     worker = os.path.join(REPO, "tests", "distributed",
                           "two_process_worker.py")
     out = subprocess.run(

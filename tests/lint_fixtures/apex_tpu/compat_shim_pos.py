@@ -6,13 +6,13 @@ from jax.sharding import PartitionSpec as P
 
 
 def wrap(f, mesh):
-    # BAD: jax.shard_map is an AttributeError on jax 0.4.x
+    # BAD: package code spells this compat.shard_map
     return jax.shard_map(f, mesh=mesh, in_specs=P("dp"),
                          out_specs=P("dp"))
 
 
 def world(axis):
-    # BAD: jax.lax.axis_size does not exist on jax 0.4.x
+    # BAD: package code spells this compat.axis_size
     return jax.lax.axis_size(axis)
 
 

@@ -1,0 +1,155 @@
+"""The main path's kernels and whole programs, compiled for a described
+TPU v5e at GPT-2-small widths.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described and not attached (``on-chip-measurement`` guide,
+section 2).  Nothing runs, so these say nothing about results or times:
+they catch what interpret mode cannot — a tile the chip refuses, a kernel
+over its fast-memory budget, a program over the chip's 16 GB — before a
+PR spends chip time on it.  Skipped where the topology cannot be
+described.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so each
+case steers it *in the test*: ``force_mode("compiled")`` for the kernel
+mode, ``donate_state=True`` / ``donate_argnums`` for donation.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu.kernels.dispatch import force_mode
+
+pytestmark = pytest.mark.kernels
+
+HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
+VOCAB, HIDDEN, LAYERS, HEADS, HEAD_DIM = 50257, 768, 12, 12, 64
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device, with the persistent compile cache off
+    around the module: a compile for an unattached chip is written to
+    the cache but cannot be read back, and the next one would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"TPU topology cannot be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, chip):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _on(chip, tree):
+    return jax.tree.map(lambda a: _sds(a.shape, a.dtype, chip), tree)
+
+
+def _check(compiled, min_custom_calls):
+    """Kernel present where one is expected, and the program fits."""
+    assert compiled.as_text().count("tpu_custom_call") >= min_custom_calls
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 2**30:.1f} GiB does not fit 16 GB"
+    return ma
+
+
+def _flash(window=None):
+    from apex_tpu.contrib.multihead_attn.attn_funcs import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, sliding_window=window)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+    return fwd, jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("which,batch,seq,window,calls", [
+    ("fwd", 8, 1024, None, 1),
+    ("bwd", 8, 1024, None, 3),          # forward + dq + dkv
+    ("bwd", 4, 2048, 256, 3),
+], ids=["flash_fwd", "flash_bwd", "flash_windowed"])
+def test_flash_attention_compiles(chip, which, batch, seq, window, calls):
+    fwd, bwd = _flash(window)
+    q = _sds((batch, HEADS, seq, HEAD_DIM), jnp.bfloat16, chip)
+    with force_mode("compiled"):
+        compiled = jax.jit(fwd if which == "fwd" else bwd).lower(
+            q, q, q).compile()
+    _check(compiled, calls)
+
+
+def test_fused_adam_compiles(chip):
+    from apex_tpu.kernels.multi_tensor import fused_adam
+
+    shapes = [(VOCAB, HIDDEN), (HIDDEN, 3 * HIDDEN), (HIDDEN,), (257,)]
+    lists = [[_sds(s, jnp.float32, chip) for s in shapes]] * 4
+    flag = _sds((), jnp.int32, chip)
+    with force_mode("compiled"):
+        compiled = jax.jit(lambda f, t: fused_adam(
+            f, t, 1e-3, 0.9, 0.999, 1e-8, 7, 1, True, 0.01)).lower(
+                flag, lists).compile()
+    _check(compiled, 1)
+
+
+def _gpt2_small(**kw):
+    import apex_tpu.nn as nn
+    from apex_tpu.models import gpt2_small
+    nn.manual_seed(0)
+    return gpt2_small(vocab_size=VOCAB, max_positions=1024, **kw)
+
+
+def test_decode_program_compiles(chip):
+    """The paged decode tick at the pool ``chip_smoke.py`` serves from
+    (2048 blocks of 16): batch bucket 8, table bucket 64, pool donated."""
+    from apex_tpu.serve import kernels as serve_kernels
+
+    num_blocks, block_size, batch, table = 2048, 16, 8, 64
+    model = _gpt2_small(dropout=0.0, attn_dropout=0.0).bfloat16()
+    model.eval()
+    params = list(model.parameters()) + list(model.buffers())
+    fn = serve_kernels.build_decode_fn(model, params, block_size,
+                                       num_blocks)
+    vals = _on(chip, [p.data for p in params])
+    pool = _sds((LAYERS, 2, num_blocks, HEADS, block_size, HEAD_DIM),
+                jnp.bfloat16, chip)
+    i32 = jnp.int32
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        vals, pool, _sds((batch,), i32, chip), _sds((batch,), i32, chip),
+        _sds((batch, table), i32, chip)).compile()
+    ma = _check(compiled, 0)
+    assert ma.alias_size_in_bytes >= pool.size * 2      # pool updated in place
+
+
+def test_fused_train_step_compiles(chip):
+    """The whole GPT-2-small fused step at 8 x 1024 — bf16, FusedAdam,
+    chunked LM-head loss, state donated — with the flash kernel in."""
+    from apex_tpu.contrib.xentropy import make_chunked_lm_loss
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.training import make_train_step
+
+    model = _gpt2_small(attn_dropout=0.0, output_hidden=True)
+    opt = FusedAdam(list(model.parameters()), lr=6e-4, weight_decay=0.1)
+    step = make_train_step(
+        model, opt, make_chunked_lm_loss(vocab_size=VOCAB, padding_idx=-1),
+        half_dtype=jnp.bfloat16, loss_scale=1.0, donate_state=True)
+    ids = _sds((8, 1024), jnp.int32, chip)
+    with force_mode("compiled"):
+        compiled = jax.jit(step._raw_step_fn, donate_argnums=(0,)).lower(
+            _on(chip, step.state), ids, ids).compile()
+    # 12 layers x (forward + dq + dkv)
+    _check(compiled, 3 * LAYERS)

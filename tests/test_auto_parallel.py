@@ -188,13 +188,11 @@ def test_memory_model_within_15pct_of_xla(plan):
     step(x, y)
     if plan.dp > 1:
         shs = step._batch_shardings((x, y))
-        comp = auto.compile_uncached(
-            step._jitted(shs).lower(step.state, x, y))
+        comp = step._jitted(shs).lower(step.state, x, y).compile()
     else:
         ent = [e for e in step_cache.step_cache.entries()
                if e["kind"] == "train_step"][-1]
-        comp = auto.compile_uncached(
-            ent["fn"].lower(*ent["example"]))
+        comp = ent["fn"].lower(*ent["example"]).compile()
     measured = auto.measured_step_memory(comp)
     assert measured > 0
     assert abs(predicted - measured) / measured < 0.15, \

@@ -157,14 +157,12 @@ def test_scenario_memory_order_replicated_vs_zero3(profiled):
         step(x, y)
         if plan.dp > 1:
             shs = step._batch_shardings((x, y))
-            comp = auto.compile_uncached(
-            step._jitted(shs).lower(step.state, x, y))
+            comp = step._jitted(shs).lower(step.state, x, y).compile()
         else:
             from apex_tpu.runtime.step_cache import step_cache
             ent = [e for e in step_cache.entries()
                    if e["kind"] == "train_step"][-1]
-            comp = auto.compile_uncached(
-            ent["fn"].lower(*ent["example"]))
+            comp = ent["fn"].lower(*ent["example"]).compile()
         return auto.measured_step_memory(comp)
 
     meas_rep, meas_z3 = measured(rep_plan), measured(z3_plan)

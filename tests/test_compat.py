@@ -1,20 +1,15 @@
-"""jax-version compat shim (apex_tpu/compat.py).
+"""apex_tpu/compat.py: the one module that names jax's ``shard_map`` and
+``axis_size``.
 
-The repository targets the modern jax surface (``jax.shard_map`` with
-``check_vma``, ``jax.lax.axis_size``) but must run on jax 0.4.x, where
-``shard_map`` lives in ``jax.experimental.shard_map`` (knob spelled
-``check_rep``) and ``axis_size`` does not exist.  Everything goes through
-the shim — the lint below enforces that no apex_tpu source file calls
-``jax.shard_map`` directly — and ``compat.install()`` polyfills the
-modern names onto the ``jax`` module so user code written against them
-runs unchanged.
+Everything in the package goes through it — the lint below enforces that
+no apex_tpu source file calls ``jax.shard_map`` directly — and under the
+installed jax it passes both straight through to the native entry points.
 """
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import compat
@@ -32,8 +27,7 @@ def _compat_findings():
 
 
 def test_lint_no_direct_jax_shard_map_references():
-    """Every shard_map call site goes through apex_tpu.compat — a direct
-    ``jax.shard_map`` reference is an AttributeError on jax 0.4.x."""
+    """Every shard_map call site goes through apex_tpu.compat."""
     bad = [f for f in _compat_findings().active()
            if "shard_map" in f.message]
     assert not bad, (
@@ -51,8 +45,8 @@ def test_lint_no_direct_lax_axis_size_references():
 
 def test_lint_walk_covers_auto_planner():
     """The engine must actually SCAN the parallelism planner
-    (parallel/auto.py drives shard_map through the compat shim; a lint
-    that silently skipped it could not enforce the jax-0.4.37 invariant
+    (parallel/auto.py drives shard_map through the compat module; a
+    lint that silently skipped it could not enforce the invariant
     there)."""
     files = {os.path.relpath(p, PKG_ROOT)
              for p in _compat_findings().files}
@@ -78,8 +72,8 @@ def _mesh():
 
 
 def test_compat_shard_map_runs_with_check_vma():
-    """The modern keyword surface works on this jax (0.4.x translates
-    check_vma → check_rep; >= 0.5 forwards natively)."""
+    """The keyword surface call sites use (``check_vma`` included)
+    reaches jax.shard_map unchanged."""
     mesh = _mesh()
     n = len(jax.devices())
 
@@ -103,36 +97,4 @@ def test_compat_axis_size_inside_shard_map():
     fn = compat.shard_map(body, mesh=mesh, in_specs=P("data"),
                           out_specs=P("data"), check_vma=False)
     out = np.asarray(jax.jit(fn)(jnp.ones((n,), jnp.float32)))
-    np.testing.assert_allclose(out, np.full((n,), float(n)))
-
-
-def test_install_polyfills_modern_names():
-    """Importing apex_tpu is enough for user code written against the
-    modern jax API: jax.shard_map and jax.lax.axis_size both resolve
-    (natively on >= 0.5, via the polyfill on 0.4.x)."""
-    compat.install()        # idempotent
-    assert callable(jax.shard_map)
-    assert callable(jax.lax.axis_size)
-    mesh = _mesh()
-    n = len(jax.devices())
-
-    fn = jax.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
-                       in_specs=P("data"), out_specs=P("data"),
-                       check_vma=False)
-    out = np.asarray(jax.jit(fn)(jnp.ones((n,), jnp.float32)))
-    np.testing.assert_allclose(out, np.full((n,), float(n)))
-
-
-def test_polyfill_supports_curried_use():
-    """The polyfilled jax.shard_map also works curried —
-    ``jax.shard_map(mesh=..., ...) (f)`` — matching the functools.partial
-    idiom some user code uses."""
-    if compat.HAS_NATIVE_SHARD_MAP:
-        pytest.skip("native jax.shard_map: currying is jax's own surface")
-    mesh = _mesh()
-    n = len(jax.devices())
-    deco = jax.shard_map(mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"), check_vma=False)
-    fn = deco(lambda x: x + compat.axis_size("data"))
-    out = np.asarray(jax.jit(fn)(jnp.zeros((n,), jnp.float32)))
     np.testing.assert_allclose(out, np.full((n,), float(n)))

@@ -195,7 +195,7 @@ def test_analyze_in_process(rng):
     not pyprof.thunk_events_available(),
     reason="backend capability: jax.profiler on this backend emits no "
            "XLA thunk-duration events (pyprof.thunk_events_available() "
-           "probed false — CPU jaxlib 0.4.x), so the trace<->HLO join "
+           "probed false — the CPU backend), so the trace<->HLO join "
            "has nothing to measure; runs on real TPU")
 def test_profile_step_measured_durations(rng, tmp_path):
     """The measured pipeline (VERDICT round 1 #5): profile a tiny jitted

@@ -196,14 +196,8 @@ def test_ring_pallas_interpret_grads(rng, causal):
 def test_ring_fori_loop_path(rng, causal, monkeypatch):
     """Large-ring fallback: with UNROLL_LIMIT forced to 0 the fwd and bwd
     ring loops run as lax.fori_loop (O(1) HLO per pass) and must match the
-    reference exactly like the unrolled path does.
-
-    causal=False (+ no dropout) exercises ``_must_unroll``: on jaxlib
-    0.4.x the SPMD partitioner rejects the PartitionId instruction the
-    fori lowering leaves in the ring body when causal masking (the only
-    live axis-index consumer) is off, so production routes those cases
-    to the unrolled path — identical math, and this parametrization
-    proves the routing keeps the case working rather than xfailing."""
+    reference exactly like the unrolled path does — with causal masking
+    (the only live axis-index consumer in the ring body) on and off."""
     import importlib
     ra_mod = importlib.import_module("apex_tpu.parallel.ring_attention")
     monkeypatch.setattr(ra_mod, "UNROLL_LIMIT", 0)

@@ -240,7 +240,7 @@ def test_forced_preemption_on_decode_engine_parity(model, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# load_kv_handoff error taxonomy
+# load_kv_handoff error classes
 # ---------------------------------------------------------------------------
 
 
