@@ -413,6 +413,7 @@ def flash_attention_fwd(q3, k3, v3, bias, scale, causal, interpret=False,
             pltpu.VMEM((bq, d), _f32),
         ],
         interpret=interpret,
+        name="flash_attn_fwd",
     )(*args)
     return out[:, :sq], lse[:, :sq, 0]
 
@@ -473,6 +474,7 @@ def flash_attention_bwd(q3, k3, v3, bias, out, lse, g, scale, causal,
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), _f32)],
         interpret=interpret,
+        name="flash_attn_bwd_dq",
     )(*args)
 
     # dk/dv: swap loop order — k blocks in the middle, q innermost
@@ -503,6 +505,7 @@ def flash_attention_bwd(q3, k3, v3, bias, out, lse, g, scale, causal,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), _f32)] * 2,
         interpret=interpret,
+        name="flash_attn_bwd_dkv",
     )(*args2)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 

@@ -102,6 +102,7 @@ def ln_forward(x2d, weight, bias, eps, interpret=False):
             jax.ShapeDtypeStruct((rows_p, 1), _f32),
         ],
         interpret=interpret,
+        name="layer_norm_fwd",
     )(*args)
     return y[:rows], mean[:rows], rstd[:rows]
 
@@ -137,6 +138,7 @@ def ln_backward(g2d, x2d, mean, rstd, weight, interpret=False):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="layer_norm_bwd",
     )(*args)
     if affine:
         dx, dw, db = outs

@@ -197,6 +197,7 @@ def _fwd_impl(x, emb, labels, interpret=False):
         out_shape=[jax.ShapeDtypeStruct((n_p, 1), _f32)] * 2,
         scratch_shapes=[pltpu.VMEM((bn, 1), _f32)] * 3,
         interpret=interpret,
+        name="lm_head_xent_fwd",
     )(xp, ep, lab2d)
     return losses[:n, 0], lse[:n, 0]
 
@@ -235,6 +236,7 @@ def _bwd(res, g):
         out_shape=jax.ShapeDtypeStruct((n_p, e), x.dtype),
         scratch_shapes=[pltpu.VMEM((bn, e), _f32)],
         interpret=interpret,
+        name="lm_head_xent_bwd_dx",
     )(xp, ep, lab2d, lse2d, gm2d)
 
     # swapped grid: vocab blocks outer, row blocks inner
@@ -249,6 +251,7 @@ def _bwd(res, g):
         out_shape=jax.ShapeDtypeStruct((v_p, e), emb.dtype),
         scratch_shapes=[pltpu.VMEM((bv, e), _f32)],
         interpret=interpret,
+        name="lm_head_xent_bwd_demb",
     )(xp, ep, lab2d, lse2d, gm2d)
     import numpy as _np
 

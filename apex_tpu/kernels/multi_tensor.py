@@ -166,6 +166,7 @@ def fused_sgd(noop_flag, tensor_lists, wd, momentum, dampening, lr,
         out_specs=[blk, blk],
         out_shape=[jax.ShapeDtypeStruct(g_pack.shape, _f32)] * 2,
         interpret=_dispatch.pallas_mode() == "interpret",
+        name="fused_sgd",
     )(g_pack, p_pack, m_pack, scal)
 
     skip = noop_flag > 0
@@ -248,6 +249,7 @@ def fused_adam(noop_flag, tensor_lists, lr, beta1, beta2, eps, step,
         out_specs=[blk, blk, blk],
         out_shape=[jax.ShapeDtypeStruct(g_pack.shape, _f32)] * 3,
         interpret=_dispatch.pallas_mode() == "interpret",
+        name="fused_adam",
     )(g_pack, p_pack, m_pack, v_pack, scal)
 
     new_ps = [pf.astype(p.dtype) for p, pf in zip(ps, _unpack(new_p, ps))]

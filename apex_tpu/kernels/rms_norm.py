@@ -84,6 +84,7 @@ def rms_forward(x2d, weight, eps, interpret=False):
             jax.ShapeDtypeStruct((rows_p, 1), _f32),
         ],
         interpret=interpret,
+        name="rms_norm_fwd",
     )(*args)
     return y[:rows], rstd[:rows]
 
@@ -119,6 +120,7 @@ def rms_backward(g2d, x2d, rstd, weight, interpret=False):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="rms_norm_bwd",
     )(*args)
     if affine:
         dx, dw = outs

@@ -153,6 +153,7 @@ def xent_forward(logits2d, labels, smoothing, padding_idx, interpret=False):
         out_shape=[jax.ShapeDtypeStruct((rows_p, 1), _f32)] * 2,
         scratch_shapes=[pltpu.VMEM((bm, 1), _f32)] * 5,
         interpret=interpret,
+        name="xent_fwd",
     )(logits2d, lab2d)
     return losses[:rows, 0], lse[:rows, 0]
 
@@ -189,5 +190,6 @@ def xent_backward(logits2d, labels, lse, gmask, smoothing, interpret=False):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((rows_p, c_p), logits2d.dtype),
         interpret=interpret,
+        name="xent_bwd",
     )(logits2d, lab2d, lse2d, gm2d, nv2d)
     return dx[:rows, :c]

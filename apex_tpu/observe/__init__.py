@@ -16,7 +16,6 @@ Everything except :mod:`telemetry` is host-side only; calls reachable
 from jit-traced code are flagged by the OBS-IN-JIT lint rule.
 """
 from .catalog import CATALOG, describe
-from .catalog import names as catalog_names
 from .registry import (SCHEMA_VERSION, Counter, Gauge, Histogram,
                        MetricsRegistry, counter, event, events, gauge,
                        get_registry, histogram)
@@ -30,5 +29,5 @@ __all__ = [
     "span", "last_span",
     "StepTelemetry", "init_telemetry", "accumulate",
     "StallWatchdog", "heartbeat", "last_heartbeat", "STALL_HINT",
-    "CATALOG", "describe", "catalog_names",
+    "CATALOG", "describe",
 ]

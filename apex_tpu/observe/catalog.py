@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-__all__ = ["CATALOG", "describe", "names"]
+__all__ = ["CATALOG", "describe"]
 
 #: name -> {kind, unit, description}.  ``kind`` is one of
 #: "counter" | "gauge" | "histogram" | "event".
@@ -150,11 +150,6 @@ CATALOG: Dict[str, Dict[str, str]] = {
                        "of the chosen plan; set only when the winner "
                        "pipelines (pp > 1)."},
 }
-
-
-def names(prefix: str = "") -> list:
-    """Cataloged metric names, optionally filtered by prefix."""
-    return sorted(n for n in CATALOG if n.startswith(prefix))
 
 
 def describe(name: str) -> Optional[Dict[str, str]]:

@@ -19,17 +19,25 @@ Design constraints:
 - **Monotonic timestamps.** Event records carry `ts_ms` from
   `time.monotonic()` so ordering survives wall-clock steps (NTP slew
   on long runs); sinks that need wall time can add their own.
+- **One ring per event name.** A frequent event (``span``: several a
+  serve tick) cannot push a rare one (``watchdog.stall``,
+  ``serve.request``) out of memory, and reading one name never scans
+  another's records.
 """
 from __future__ import annotations
 
 import collections
+import heapq
 import json
+import operator
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
 SCHEMA_VERSION = 1
 
+#: records kept in memory per event name, unless
+#: :meth:`MetricsRegistry.set_event_capacity` asked for another bound
 _EVENT_BUFFER_MAX = 4096
 
 
@@ -113,9 +121,9 @@ class MetricsRegistry:
     """Named metrics plus a bounded structured event log.
 
     Events are dicts `{"schema": 1, "ts_ms": <monotonic ms>,
-    "event": <name>, ...fields}`; the newest `_EVENT_BUFFER_MAX` are
-    kept in memory and every event is appended to any attached JSONL
-    sinks as one line.
+    "event": <name>, ...fields}`; the newest `_EVENT_BUFFER_MAX` *of
+    each event name* are kept in memory (a ring per name) and every
+    event is appended to any attached JSONL sinks as one line.
     """
 
     def __init__(self):
@@ -123,8 +131,10 @@ class MetricsRegistry:
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
-        self._events: collections.deque = collections.deque(
-            maxlen=_EVENT_BUFFER_MAX)
+        #: event name -> ring of (arrival number, record)
+        self._events: Dict[str, collections.deque] = {}
+        self._capacity: Dict[str, int] = {}
+        self._arrivals = 0
         self._sinks: Dict[str, Any] = {}   # path -> open file handle
 
     # -- metric accessors (create-on-first-use) ---------------------------
@@ -158,7 +168,12 @@ class MetricsRegistry:
                "event": name}
         rec.update(fields)
         with self._lock:
-            self._events.append(rec)
+            ring = self._events.get(name)
+            if ring is None:
+                ring = self._events[name] = collections.deque(
+                    maxlen=self._capacity.get(name, _EVENT_BUFFER_MAX))
+            self._arrivals += 1
+            ring.append((self._arrivals, rec))
             sinks = list(self._sinks.values())
         for fh in sinks:
             try:
@@ -169,11 +184,26 @@ class MetricsRegistry:
         return rec
 
     def events(self, name: Optional[str] = None) -> List[Dict[str, Any]]:
+        """The records in memory, oldest first: of one event name, or
+        of every name merged in arrival order."""
         with self._lock:
-            evs = list(self._events)
-        if name is None:
-            return evs
-        return [e for e in evs if e["event"] == name]
+            if name is not None:
+                return [rec for _, rec in self._events.get(name, ())]
+            rings = [list(ring) for ring in self._events.values()]
+        return [rec for _, rec in heapq.merge(*rings,
+                                             key=operator.itemgetter(0))]
+
+    def set_event_capacity(self, name: str, capacity: int) -> None:
+        """Keep the newest ``capacity`` records of event ``name`` (the
+        default is ``_EVENT_BUFFER_MAX`` for each name)."""
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        with self._lock:
+            self._capacity[name] = int(capacity)
+            ring = self._events.get(name)
+            if ring is not None and ring.maxlen != capacity:
+                self._events[name] = collections.deque(ring,
+                                                       maxlen=capacity)
 
     def add_jsonl_sink(self, path: str) -> None:
         with self._lock:

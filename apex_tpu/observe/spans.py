@@ -1,27 +1,47 @@
-"""Trace spans: one context manager, two outputs.
+"""Trace spans: one context manager, two outputs, one record.
 
-``span("ckpt.save")`` emits (a) a structured event + latency histogram
-into the metrics registry and (b) a ``jax.profiler.TraceAnnotation`` so
-the same region shows up in device profiles — host events and XLA
-timelines line up by name.
+``span("ckpt.save")`` emits (a) a structured ``span`` event + latency
+histogram into the metrics registry and (b) a
+``jax.profiler.TraceAnnotation`` so the same region shows up in device
+profiles — host events and XLA timelines line up by name.
+
+The record is what a tree is built from: a process-unique ``id``, the
+``parent`` (the span that was open *on the same thread* when this one
+started, else None), ``t0_ns`` / ``t1_ns`` from
+``time.perf_counter_ns()``, and the caller's fields (``kind``,
+``tick``, ``rid``, ``step``, ``n``, ...).  A span opened inside one
+that has a ``tick`` is of the same tick: it takes its parent's unless
+it gives its own.  The annotation is named
+``<name>.<kind>`` where the span has a ``kind`` and carries ``id`` (and
+``tick``) as arguments, so a profiler host event joins to its record.
+:func:`recorded` reads the records back.
 
 Spans are host-side instrumentation; entering one from jit-traced code
 is a host round-trip and is flagged by the OBS-IN-JIT lint rule.
 Thread-safe: the prefetch worker and async-checkpoint writer open spans
-on their own threads, and the watchdog reads ``last_span()`` from its
-heartbeat thread.
+on their own threads (each thread has its own stack of open spans), and
+the watchdog reads ``last_span()`` from its heartbeat thread.
 """
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from . import registry as _registry
 
+#: ``span`` records kept in memory: about eight a serve tick, eleven
+#: ticks a second, so six minutes of serving (a benchmark window is 45 s)
+SPAN_RING = 32768
+
+_registry.get_registry().set_event_capacity("span", SPAN_RING)
+
 _state_lock = threading.Lock()
 _last_span: Optional[Dict[str, Any]] = None
+_ids = itertools.count(1)           # next() is one bytecode: thread-safe
+_open = threading.local()           # .stack: this thread's open records
 
 _trace_annotation = None
 _trace_annotation_probed = False
@@ -48,21 +68,60 @@ def last_span() -> Optional[Dict[str, Any]]:
         return dict(_last_span) if _last_span else None
 
 
+def annotation_name(name: str, fields: Dict[str, Any]) -> str:
+    """The span's name on the profiler's timeline: ``dispatch`` of kind
+    ``decode_step`` is ``dispatch.decode_step``."""
+    kind = fields.get("kind")
+    return f"{name}.{kind}" if kind is not None else name
+
+
 @contextlib.contextmanager
 def span(name: str, **fields: Any):
     """Time a region; emit a ``span`` event and a ``span.<name>_ms``
-    histogram sample on exit, wrapped in a profiler TraceAnnotation."""
+    histogram sample on exit, wrapped in a profiler TraceAnnotation.
+
+    Yields the record being built (the caller's fields, ``span``,
+    ``id``, ``parent``, ``t0_ns``): the caller may add fields that
+    are known only at the end (``rec["n_finished"] = 3``), and reads
+    ``rec["dur_ms"]`` after the block."""
     global _last_span
-    t0 = time.monotonic()
-    with _state_lock:
-        _last_span = {"span": name, "started_ms": t0 * 1e3, **fields}
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    sid = next(_ids)
+    parent = stack[-1] if stack else None
+    # the record's own keys win over a caller's field of the same name
+    rec = {**fields, "span": name, "id": sid,
+           "parent": parent["id"] if parent else None}
+    if parent and "tick" in parent:
+        rec.setdefault("tick", parent["tick"])
     annotation = _get_trace_annotation()
-    cm = annotation(name) if annotation is not None \
-        else contextlib.nullcontext()
+    if annotation is None:
+        cm = contextlib.nullcontext()
+    else:
+        args = {"id": sid, "tick": rec["tick"]} if "tick" in rec \
+            else {"id": sid}
+        cm = annotation(annotation_name(name, fields), **args)
+    stack.append(rec)
+    t0 = rec["t0_ns"] = time.perf_counter_ns()
+    with _state_lock:
+        _last_span = {"span": name, "started_ms": t0 / 1e6, **fields}
     try:
         with cm:
-            yield
+            yield rec
     finally:
-        dur_ms = (time.monotonic() - t0) * 1e3
+        t1 = rec["t1_ns"] = time.perf_counter_ns()
+        stack.pop()
+        dur_ms = rec["dur_ms"] = (t1 - t0) / 1e6
         _registry.histogram(f"span.{name}_ms").observe(dur_ms)
-        _registry.event("span", span=name, dur_ms=dur_ms, **fields)
+        _registry.event("span", **rec)
+
+
+def recorded(since_ns: Optional[int] = None) -> List[Dict[str, Any]]:
+    """The ``span`` records in memory (the newest ``SPAN_RING``) that
+    started at or after ``since_ns`` on the ``time.perf_counter_ns()``
+    clock, oldest first by start: a parent comes before its children."""
+    recs = _registry.events("span")
+    if since_ns is not None:
+        recs = [r for r in recs if r["t0_ns"] >= since_ns]
+    return sorted(recs, key=lambda r: r["t0_ns"])

@@ -17,6 +17,8 @@ import time
 
 import numpy as np
 
+from ..observe import spans as _spans
+
 
 class DataPrefetcher:
     """Wrap a batch iterable; yields device-resident (input, target) pairs
@@ -102,8 +104,6 @@ class DataPrefetcher:
 
     def _run(self):
         import jax
-
-        from ..observe import spans as _spans
         try:
             window = []
             for images, target in self.loader:
@@ -151,7 +151,10 @@ class DataPrefetcher:
         # (None, None) like the reference prefetcher, no deadlock
         if self._done:
             return None, None
-        item = self._q.get()
+        # how long the step loop waited for its batch; the worker's
+        # ``h2d`` spans are on its own thread
+        with _spans.span("data.wait"):
+            item = self._q.get()
         if item is None:
             self._done = True
             return None, None

@@ -31,6 +31,7 @@ the old per-surface ``step_cache`` call sites.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -47,8 +48,7 @@ _f32 = jnp.float32
 
 #: program kinds that are whole-training-window dispatches: these always
 #: get a ``span("dispatch")`` and a watchdog heartbeat.  Eager kinds
-#: (optimizer/amp programs) span only under
-#: ``step_cache.set_dispatch_spans(True)`` — the eager hot path is
+#: (optimizer/amp programs) do not span — the eager hot path is
 #: microbenchmarked and a per-step span event is a measurable fraction
 #: of a small fused step — and never heartbeat (many eager dispatches
 #: compose into one logical step; the *step* is the liveness unit).
@@ -59,9 +59,11 @@ TRAIN_KINDS = frozenset({"train_step", "zero_train_step",
 #: unit of forward progress — every tick spans and heartbeats, so the
 #: stall watchdog guards the decode loop the same way it guards the
 #: train loop.  Unlike eager kinds there is no microbenchmarked
-#: hot path concern: a serve dispatch covers a whole batched tick.
+#: hot path concern: a serve dispatch covers a whole batched tick
+#: (``block_copy``, admission's copy-on-write fork, rides in a tick).
 SERVE_KINDS = frozenset({"prefill_step", "decode_step",
-                         "draft_prefill_step", "spec_verify_step"})
+                         "draft_prefill_step", "spec_verify_step",
+                         "block_copy"})
 
 #: rollout-loop kinds (apex_tpu.rollout): the generate-then-train
 #: runtime's own dispatches.  ``weight_publish`` is the one fused
@@ -299,21 +301,25 @@ class Executor:
         ``step``: the caller's 1-based step count for the watchdog
         heartbeat (train and serve kinds; dispatch returning means the
         host made forward progress — execution is async, a wedged
-        backend blocks the dispatch itself).  Eager kinds pass None:
-        they span only under ``step_cache.set_dispatch_spans(True)``
-        and never heartbeat.
+        backend blocks the dispatch itself); the ``dispatch`` span
+        carries it.  Eager kinds pass None: they neither span nor
+        heartbeat.
         """
-        fn = self.compile(program, args)
-        self._cache._bump("dispatches", program.kind)
         beat = (program.kind in TRAIN_KINDS or program.kind in SERVE_KINDS
                 or program.kind in ROLLOUT_KINDS)
-        if beat or _sc._DISPATCH_SPANS:
+        span = contextlib.nullcontext()
+        if beat:
             tags = {"kind": program.kind}
+            if step is not None:
+                tags["step"] = step
             if _CLUSTER_EPOCH is not None:
                 tags["cluster_epoch"] = _CLUSTER_EPOCH
-            with _spans.span("dispatch", **tags):
-                out = fn(*args)
-        else:
+            span = _spans.span("dispatch", **tags)
+        # the span covers all of what enqueuing costs the host: the
+        # cache lookup (a signature over every argument leaf) too
+        with span:
+            fn = self.compile(program, args)
+            self._cache._bump("dispatches", program.kind)
             out = fn(*args)
         if beat and step is not None:
             _obs_watchdog.heartbeat(step=step)

@@ -41,20 +41,6 @@ import jax.numpy as jnp
 
 from ..observe import registry as _obs
 
-#: opt-in ``span("dispatch")`` around every eager step-cache dispatch.
-#: Off by default: the eager optimizer hot path is microbenchmarked
-#: (``bench.py --opt-microbench``) and a per-step span event would be a
-#: measurable fraction of a small fused step; the dispatch *counters*
-#: always flow through the observe registry regardless.
-_DISPATCH_SPANS = False
-
-
-def set_dispatch_spans(enable: bool) -> None:
-    """Enable/disable ``span("dispatch")`` around eager cache dispatches."""
-    global _DISPATCH_SPANS
-    _DISPATCH_SPANS = bool(enable)
-
-
 def _leaf_sig(leaf):
     return (tuple(leaf.shape), jnp.dtype(leaf.dtype).name)
 

@@ -49,6 +49,7 @@ from ..kernels.dispatch import decide as _decide
 from ..kernels.spec_verify import spec_verify_fp
 from ..models.gpt import _sharded_decode_axes
 from ..observe import registry as _obs
+from ..observe import spans as _spans
 from ..observe import watchdog as _watchdog
 from ..runtime import executor as _executor
 from . import kernels as _kernels
@@ -430,8 +431,46 @@ class ServeEngine:
         the full tick (its prefill stage serves recompute-mode
         re-admissions after local preemption)."""
         self._tick += 1
-        t0 = time.monotonic()
-        for s in self.scheduler.admit():
+        # the root of the tick's span tree (docs/observability.md lists
+        # the children); spans opened below take its ``tick``
+        with _spans.span("serve.step", tick=self._tick, decode_batch=0,
+                         prefill_rid=None) as tick:
+            more = self._run_tick(tick)
+        if tick["decode_batch"]:
+            _obs.histogram("serve.decode_tick_ms").observe(tick["dur_ms"])
+        return more
+
+    def _run_tick(self, tick: dict) -> bool:
+        if self.scheduler.queue:
+            with _spans.span("serve.admit") as rec:
+                rec["n"] = self._admit()
+        ps = self.scheduler.next_prefill()
+        if ps is not None:
+            tick["prefill_rid"] = ps.rid
+            self._prefill_chunk(ps)
+        elif self.spec:
+            cs = self._next_draft_catchup()
+            if cs is not None:
+                self._draft_catchup_chunk(cs)
+        if self._phase != "prefill":
+            with _spans.span("serve.ensure_blocks"):
+                self._ensure_decode_blocks()
+            ds = self._decode_ready()
+            if ds:
+                tick["decode_batch"] = len(ds)
+                if self.spec and self._spec_pays(ds):
+                    self._spec_tick(ds)
+                else:
+                    self._decode_tick(ds)
+        _obs.gauge("serve.queue_depth").set(len(self.scheduler.queue))
+        _obs.gauge("serve.active_sessions").set(
+            len(self.scheduler.sessions))
+        return self.scheduler.has_work()
+
+    def _admit(self) -> int:
+        """Admission with its bookkeeping; returns the number admitted."""
+        admitted = self.scheduler.admit()
+        for s in admitted:
             s.weight_epoch = self.weight_epochs["target"]
             self._dispatch_cow(s)
             self._prefill_tokens_saved += s.prefix_hit_tokens
@@ -447,32 +486,7 @@ class ServeEngine:
                        tick=self._tick, blocks=len(s.table),
                        prefix_hit=s.prefix_hit_tokens,
                        weight_epoch=s.weight_epoch)
-        ps = self.scheduler.next_prefill()
-        if ps is not None:
-            self._prefill_chunk(ps)
-        elif self.spec:
-            cs = self._next_draft_catchup()
-            if cs is not None:
-                self._draft_catchup_chunk(cs)
-        if self._phase == "prefill":
-            _obs.gauge("serve.queue_depth").set(
-                len(self.scheduler.queue))
-            _obs.gauge("serve.active_sessions").set(
-                len(self.scheduler.sessions))
-            return self.scheduler.has_work()
-        self._ensure_decode_blocks()
-        ds = self._decode_ready()
-        if ds:
-            if self.spec and self._spec_pays(ds):
-                self._spec_tick(ds)
-            else:
-                self._decode_tick(ds)
-            _obs.histogram("serve.decode_tick_ms").observe(
-                (time.monotonic() - t0) * 1e3)
-        _obs.gauge("serve.queue_depth").set(len(self.scheduler.queue))
-        _obs.gauge("serve.active_sessions").set(
-            len(self.scheduler.sessions))
-        return self.scheduler.has_work()
+        return len(admitted)
 
     def run(self, requests: Sequence[Request], arrivals=None,
             watchdog_deadline_s=None, max_ticks=None):
@@ -510,9 +524,13 @@ class ServeEngine:
     # -- internals ---------------------------------------------------------
 
     def _prefill_chunk(self, s: Session) -> None:
-        prefill_prog, _ = self._programs()
         chunk = self.scheduler.prefill_chunk
         n = min(chunk, s.prefill_remaining)
+        with _spans.span("serve.prefill_chunk", rid=s.rid, n_real=n):
+            self._prefill(s, chunk, n)
+
+    def _prefill(self, s: Session, chunk: int, n: int) -> None:
+        prefill_prog, _ = self._programs()
         t0 = s.position
         toks = list(s.prefill_src[t0:t0 + n])
         toks += [0] * (chunk - n)
@@ -550,7 +568,8 @@ class ServeEngine:
             return
         s.state = DECODE
         if s.emit_on_prefill:
-            tok = int(jnp.argmax(last[0]))
+            with _spans.span("serve.fetch", what="first_token"):
+                tok = int(jnp.argmax(last[0]))
             s.out.append(tok)
             s.pending_tok = tok
             s.t_first = time.monotonic()
@@ -642,25 +661,29 @@ class ServeEngine:
 
     def _decode_tick(self, sessions: List[Session]) -> None:
         _, decode_prog = self._programs()
-        b, nb, tokens, positions, tables = \
-            self.scheduler.pack_decode(sessions)
+        with _spans.span("serve.pack"):
+            b, nb, tokens, positions, tables = \
+                self.scheduler.pack_decode(sessions)
         nxt, _logits, self.pool = _executor.executor.submit(
             decode_prog,
             (self._vals(), self.pool,
              np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
              np.asarray(tables, np.int32)),
             step=next(self._dispatch_no))
-        nxt = np.asarray(nxt)
-        for i, s in enumerate(sessions):
-            s.position += 1
-            tok = int(nxt[i])
-            s.out.append(tok)
-            s.pending_tok = tok
-            if self.window is not None:
-                self.scheduler.retire_window_blocks(s, self.window)
-            self._note_commit(s)
-            if s.finished():
-                self._finish(s)
+        with _spans.span("serve.fetch", what="tokens"):
+            nxt = np.asarray(nxt)
+        with _spans.span("serve.commit", n_finished=0) as rec:
+            for i, s in enumerate(sessions):
+                s.position += 1
+                tok = int(nxt[i])
+                s.out.append(tok)
+                s.pending_tok = tok
+                if self.window is not None:
+                    self.scheduler.retire_window_blocks(s, self.window)
+                self._note_commit(s)
+                if s.finished():
+                    self._finish(s)
+                    rec["n_finished"] += 1
 
     def _spec_tick(self, sessions: List[Session]) -> None:
         """One batched speculative tick: a single ``spec_verify_step``
@@ -674,8 +697,9 @@ class ServeEngine:
         pattern, eos/max_new truncation, or preemption does to tick
         boundaries."""
         _, spec_prog = self._spec_programs()
-        b, nbt, nbd, tokens, positions, t_tables, d_tables = \
-            self.scheduler.pack_spec(sessions)
+        with _spans.span("serve.pack"):
+            b, nbt, nbd, tokens, positions, t_tables, d_tables = \
+                self.scheduler.pack_spec(sessions)
         emitted, n_acc, self.pool, self.dpool = _executor.executor.submit(
             spec_prog,
             (self._vals(), self._d_vals(), self.pool, self.dpool,
@@ -683,33 +707,36 @@ class ServeEngine:
              np.asarray(t_tables, np.int32),
              np.asarray(d_tables, np.int32)),
             step=next(self._dispatch_no))
-        emitted = np.asarray(emitted)
-        n_acc = np.asarray(n_acc)
-        committed_total = 0
-        for i, s in enumerate(sessions):
-            m = 0
-            for j in range(int(n_acc[i])):
-                tok = int(emitted[i, j])
-                s.out.append(tok)
-                s.pending_tok = tok
-                s.position += 1
-                m += 1
+        with _spans.span("serve.fetch", what="spec_tokens"):
+            emitted = np.asarray(emitted)
+            n_acc = np.asarray(n_acc)
+        with _spans.span("serve.commit", n_finished=0) as rec:
+            committed_total = 0
+            for i, s in enumerate(sessions):
+                m = 0
+                for j in range(int(n_acc[i])):
+                    tok = int(emitted[i, j])
+                    s.out.append(tok)
+                    s.pending_tok = tok
+                    s.position += 1
+                    m += 1
+                    if s.finished():
+                        break
+                # rows p..p+m-1 of the draft cache hold exactly the
+                # committed tokens (the rejected tail past them is rewritten
+                # by the next tick's chunk before any mask can read it)
+                s.draft_position = s.position
+                # chain-commit only blocks the committed position has fully
+                # crossed — every row of such a block holds committed-token
+                # KV (any rejected-tail rows were overwritten by later
+                # ticks before position could pass them)
+                self._note_commit(s)
+                committed_total += m
+                self._spec_offered += self.spec_k
+                self._spec_accepted += max(0, m - 1)
                 if s.finished():
-                    break
-            # rows p..p+m-1 of the draft cache hold exactly the
-            # committed tokens (the rejected tail past them is rewritten
-            # by the next tick's chunk before any mask can read it)
-            s.draft_position = s.position
-            # chain-commit only blocks the committed position has fully
-            # crossed — every row of such a block holds committed-token
-            # KV (any rejected-tail rows were overwritten by later
-            # ticks before position could pass them)
-            self._note_commit(s)
-            committed_total += m
-            self._spec_offered += self.spec_k
-            self._spec_accepted += max(0, m - 1)
-            if s.finished():
-                self._finish(s)
+                    self._finish(s)
+                    rec["n_finished"] += 1
         self._spec_ticks += 1
         self._spec_committed += committed_total
         _obs.histogram("serve.spec.accepted_tokens").observe(

@@ -153,3 +153,10 @@ def test_fused_train_step_compiles(chip):
             _on(chip, step.state), ids, ids).compile()
     # 12 layers x (forward + dq + dkv)
     _check(compiled, 3 * LAYERS)
+    # the kernels go by their own names in the device trace: the custom
+    # calls' instruction names come from ``pallas_call(name=...)``
+    calls = [ln.split("=")[0] for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    for kernel in ("flash_attn_fwd", "flash_attn_bwd_dq",
+                   "flash_attn_bwd_dkv"):
+        assert sum(kernel in c for c in calls) == LAYERS, (kernel, calls)

@@ -89,6 +89,34 @@ def test_submit_counts_compiles_and_dispatches():
     spans = [e for e in get_registry().events("span")
              if e["span"] == "dispatch" and e["kind"] == "train_step"]
     assert len(spans) == 2
+    assert [e["step"] for e in spans] == [1, 2]     # the caller's count
+
+
+@pytest.mark.parametrize("kind,spans", [
+    ("block_copy", True),           # rides in a serve tick
+    ("decode_step", True),
+    ("weight_publish", True),
+    ("fused_adam_step", False),     # an eager kind: counted, no span
+], ids=lambda v: str(v))
+def test_which_kinds_span(kind, spans):
+    """Serve kinds (the copy-on-write block copy among them), train and
+    rollout kinds open a ``dispatch`` span inside the enclosing one;
+    eager kinds only count, and nothing switches that."""
+    from apex_tpu.observe import span
+    assert not hasattr(step_cache, "set_dispatch_spans")
+    prog = rex.Program(kind, ("t-span", kind), lambda a: a + 1)
+    with span("serve.step", tick=4) as root:
+        rex.executor.submit(prog, (jnp.ones((2,)),),
+                            step=3 if spans else None)
+    assert rex.executor.stats()["by_kind"][kind]["dispatches"] == 1
+    recs = [e for e in get_registry().events("span")
+            if e["span"] == "dispatch"]
+    assert len(recs) == (1 if spans else 0)
+    if spans:
+        (rec,) = recs
+        assert rec["kind"] == kind and rec["step"] == 3
+        assert rec["parent"] == root["id"] and rec["tick"] == 4
+        assert root["t0_ns"] <= rec["t0_ns"] <= rec["t1_ns"] <= root["t1_ns"]
 
 
 def test_donation_policy_resolution():
@@ -341,6 +369,13 @@ def test_drive_one_h2d_per_window(rng):
     h2d = [e for e in get_registry().events("span") if e["span"] == "h2d"]
     assert len(h2d) == 5                              # ONE transfer per window
     assert all(e["accum_steps"] == 4 for e in h2d)
+    # the consumer's side of the queue: one wait per window and one for
+    # the end of the data, on the step loop's thread, none inside a
+    # worker's span
+    waits = [e for e in get_registry().events("span")
+             if e["span"] == "data.wait"]
+    assert len(waits) == 6
+    assert all(e["parent"] is None for e in waits + h2d)
     st = rex.executor.stats()["by_kind"]["train_step"]
     assert st["compiles"] == 1 and st["dispatches"] == 5
 

@@ -16,8 +16,12 @@ pytestmark = pytest.mark.lint
 @pytest.fixture(scope="module")
 def audit():
     """One audited run for the whole module, with stats reset first so
-    kind_stats cross-checks count exactly the audit's own workloads."""
+    kind_stats cross-checks count exactly the audit's own workloads, and
+    the cache emptied: the audit slices the cache's entries by index, so
+    a worker that ran other files first and holds the LRU at its cap
+    would hand it empty slices."""
     sc.reset_stats()
+    sc.clear()
     return jaxpr_audit.run(force=True)
 
 

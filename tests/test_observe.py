@@ -90,6 +90,138 @@ def test_span_emits_event_histogram_and_last_span():
     assert reg.histogram("span.test.region_ms").count >= 1
 
 
+def test_nested_spans_record_a_tree():
+    """A span's record names its parent (the span open on the same
+    thread), lies inside it on the perf_counter_ns clock, and is of its
+    parent's tick unless it gives its own."""
+    reg = get_registry()
+    reg.clear_events()
+    before = time.perf_counter_ns()
+    with span("t.root", tick=7) as root:
+        with span("t.child", kind="k") as child:
+            with span("t.leaf", tick=8):
+                pass
+        with span("t.second"):
+            pass
+        root["n_done"] = 2              # a field known only at the end
+    recs = {e["span"]: e for e in reg.events("span")}
+    assert recs["t.root"]["parent"] is None and recs["t.root"]["n_done"] == 2
+    assert recs["t.child"]["parent"] == recs["t.root"]["id"] == root["id"]
+    assert recs["t.leaf"]["parent"] == child["id"]
+    assert recs["t.second"]["parent"] == root["id"]
+    assert len({e["id"] for e in recs.values()}) == 4
+    assert [recs[n]["tick"] for n in
+            ("t.root", "t.child", "t.leaf", "t.second")] == [7, 7, 8, 7]
+    for name in ("t.child", "t.leaf", "t.second"):
+        kid = recs[name]
+        assert recs["t.root"]["t0_ns"] <= kid["t0_ns"] <= kid["t1_ns"] \
+            <= recs["t.root"]["t1_ns"]
+        assert kid["dur_ms"] == (kid["t1_ns"] - kid["t0_ns"]) / 1e6
+    assert before <= recs["t.root"]["t0_ns"] <= time.perf_counter_ns()
+    # after the block nothing is open: the next span is a root again
+    with span("t.after") as after:
+        pass
+    assert after["parent"] is None and "tick" not in after
+
+
+def test_span_on_a_second_thread_has_no_parent_from_the_first():
+    import threading
+    get_registry().clear_events()
+    seen = {}
+
+    def worker():
+        with span("t.worker") as rec:
+            with span("t.worker.inner") as inner:
+                pass
+        seen["rec"], seen["inner"] = rec, inner
+
+    with span("t.main", tick=3) as main:
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    assert seen["rec"]["parent"] is None and "tick" not in seen["rec"]
+    assert seen["inner"]["parent"] == seen["rec"]["id"]
+    assert main["t0_ns"] <= seen["rec"]["t0_ns"] <= main["t1_ns"]
+
+
+def test_each_event_name_has_its_own_ring():
+    """A frequent event cannot push a rare one out of memory, and the
+    rings merge back in arrival order."""
+    reg = MetricsRegistry()
+    reg.set_event_capacity("span", 8)
+    reg.event("rare", n=0)
+    for i in range(20):
+        reg.event("span", i=i)
+        if i == 10:
+            reg.event("rare", n=1)
+    assert [e["i"] for e in reg.events("span")] == list(range(12, 20))
+    assert [e["n"] for e in reg.events("rare")] == [0, 1]
+    merged = [(e["event"], e.get("i", e.get("n"))) for e in reg.events()]
+    assert merged == [("rare", 0), ("rare", 1)] + \
+        [("span", i) for i in range(12, 20)]
+    reg.set_event_capacity("span", 4)       # shrinking keeps the newest
+    assert [e["i"] for e in reg.events("span")] == [16, 17, 18, 19]
+    reg.clear_events()
+    assert reg.events() == []
+    for i in range(6):
+        reg.event("span", i=i)
+    assert len(reg.events("span")) == 4     # the bound outlives a clear
+    with pytest.raises(ValueError, match="capacity"):
+        reg.set_event_capacity("span", 0)
+    # the process-wide registry keeps SPAN_RING span records
+    from apex_tpu.observe import spans
+    assert spans.SPAN_RING == 32768
+    get_registry().clear_events()
+    with span("t.ring"):
+        pass
+    assert get_registry()._events["span"].maxlen == spans.SPAN_RING
+
+
+def test_recorded_returns_records_oldest_first_since_a_time():
+    from apex_tpu.observe import spans
+    get_registry().clear_events()
+    with span("t.old"):
+        pass
+    cut = time.perf_counter_ns()
+    with span("t.outer"):
+        with span("t.inner"):
+            pass
+    # the ring is in order of exit (inner first); recorded() is by start
+    assert [e["span"] for e in get_registry().events("span")] == \
+        ["t.old", "t.inner", "t.outer"]
+    assert [r["span"] for r in spans.recorded()] == \
+        ["t.old", "t.outer", "t.inner"]
+    assert [r["span"] for r in spans.recorded(since_ns=cut)] == \
+        ["t.outer", "t.inner"]
+    assert spans.recorded(since_ns=time.perf_counter_ns()) == []
+
+
+@pytest.mark.parametrize("fields,name,args", [
+    ({"kind": "decode_step", "tick": 5}, "dispatch.decode_step",
+     {"tick": 5}),
+    ({"kind": "train_step", "step": 9}, "dispatch.train_step", {}),
+    ({"what": "tokens"}, "dispatch", {}),
+], ids=["kind_and_tick", "kind", "plain"])
+def test_annotation_name_carries_the_kind(monkeypatch, fields, name, args):
+    """The profiler's event is ``<name>.<kind>`` and carries the
+    record's id (and tick), so an xplane host event joins to it."""
+    import contextlib
+
+    from apex_tpu.observe import spans
+    made = []
+
+    def fake(label, **kw):
+        made.append((label, kw))
+        return contextlib.nullcontext()
+    spans._get_trace_annotation()           # probe before patching
+    monkeypatch.setattr(spans, "_trace_annotation", fake)
+    with span("dispatch", **fields) as rec:
+        pass
+    assert made == [(name, dict(args, id=rec["id"]))]
+    assert spans.annotation_name("dispatch", fields) == name
+
+
 # ---------------------------------------------------------------------------
 # the on-device telemetry carry
 # ---------------------------------------------------------------------------

@@ -426,3 +426,83 @@ def test_close_returns_all_live_blocks(model):
         eng2.step()
         assert eng2.block_pool.in_use > 0
     assert eng2.block_pool.in_use == 0
+
+
+# ---------------------------------------------------------------------------
+# the span tree of a tick
+# ---------------------------------------------------------------------------
+
+#: what may open directly under ``serve.step`` (docs/observability.md)
+TICK_CHILDREN = {"serve.admit", "serve.prefill_chunk", "serve.ensure_blocks",
+                 "serve.pack", "dispatch", "serve.fetch", "serve.commit"}
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "speculative"])
+def test_every_tick_records_a_span_tree(model, spec):
+    from apex_tpu.inference import make_self_draft
+    from apex_tpu.observe import spans
+    obs.get_registry().clear_events()
+    obs.get_registry().remove("serve.decode_tick_ms")
+    eng = ServeEngine(model, num_blocks=64, block_size=8, max_batch=4,
+                      prefill_chunk=4,
+                      draft=make_self_draft(model) if spec else None)
+    reqs = [Request(f"s{i}", [2 + i, 5, 7, 11, 3, 8][:3 + i], 5)
+            for i in range(4)]
+    out = eng.run(reqs, arrivals=[0, 0, 1, 3])
+    assert all(len(out[r.rid]) == 5 for r in reqs)
+    recs = spans.recorded()
+    by_id = {r["id"]: r for r in recs}
+    steps = [r for r in recs if r["span"] == "serve.step"]
+    # one root a tick, in order, none nested in another span
+    assert [r["tick"] for r in steps] == list(range(1, eng.tick + 1))
+    assert all(r["parent"] is None for r in steps)
+    kids = {}
+    for r in recs:
+        if r["parent"] is not None:
+            kids.setdefault(r["parent"], []).append(r)
+    for root in steps:
+        mine = kids.get(root["id"], [])
+        assert {k["span"] for k in mine} <= TICK_CHILDREN
+        last_end = root["t0_ns"]
+        for k in mine:                  # inside the tick, one after another
+            assert last_end <= k["t0_ns"] <= k["t1_ns"] <= root["t1_ns"]
+            last_end = k["t1_ns"]
+    # every span below a tick is of that tick
+    for r in recs:
+        top = r
+        while top["parent"] is not None:
+            top = by_id[top["parent"]]
+        assert r["tick"] == top["tick"] and top["span"] == "serve.step"
+    # every blocking read follows, under the same parent, the dispatch
+    # whose result it reads
+    fetches = [r for r in recs if r["span"] == "serve.fetch"]
+    assert {r["what"] for r in fetches} == \
+        {"first_token", "spec_tokens" if spec else "tokens"}
+    for f in fetches:
+        before = [k for k in kids[f["parent"]]
+                  if k["span"] == "dispatch" and k["t1_ns"] <= f["t0_ns"]]
+        assert before, f
+    # a request's prefill chunks carry its rid, and cover its prompt
+    for r in reqs:
+        chunks = [c for c in recs if c["span"] == "serve.prefill_chunk"
+                  and c["rid"] == r.rid]
+        assert sum(c["n_real"] for c in chunks) == len(r.prompt)
+        admitted = [e["tick"] for e in obs.events("serve.request")
+                    if e["rid"] == r.rid and e["phase"] == "prefill"]
+        assert chunks[0]["tick"] >= admitted[0]
+    # what the root says of its tick
+    decoding = [r for r in steps if r["decode_batch"]]
+    assert decoding and max(r["decode_batch"] for r in steps) <= 4
+    assert {r["prefill_rid"] for r in steps} - {None} == \
+        {r.rid for r in reqs}
+    commits = [r for r in recs if r["span"] == "serve.commit"]
+    assert len(commits) == len(decoding)
+    assert sum(c["n_finished"] for c in commits) == len(reqs)
+    admits = [r for r in recs if r["span"] == "serve.admit"]
+    assert sum(a["n"] for a in admits) == len(reqs)
+    # serve.decode_tick_ms is the root span's own duration
+    hist = obs.get_registry().histogram("serve.decode_tick_ms")
+    assert hist.count == len(decoding)
+    assert hist.last == decoding[-1]["dur_ms"]
+    assert hist.total == pytest.approx(sum(r["dur_ms"] for r in decoding))
+    eng.block_pool.check_no_leaks()
