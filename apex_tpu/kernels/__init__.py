@@ -56,6 +56,7 @@ from . import lm_head_xent  # noqa: F401
 from . import multi_tensor  # noqa: F401
 from . import vocab_chain  # noqa: F401
 from . import spec_verify  # noqa: F401
+from . import paged_attention  # noqa: F401
 
 from .multi_tensor import (  # noqa: F401
     fused_adam,

@@ -1,0 +1,361 @@
+"""paged_attention: reading the serve engine's KV pool through block
+tables.
+
+The pool (``serve/pool.py::init_pool_buffer``) is
+``(layers, 2, num_blocks, block_size, heads*head_dim)``: one token's K
+(or V) of one layer is one contiguous row of ``heads*head_dim``
+elements, a whole number of lane rows, so the device stores it
+row-major and a block is one contiguous piece.  Every reader here takes
+the pool where it lies — no program needs it in another layout, so none
+copies it.
+
+* :func:`gather_kv` — the blocks of a table, one layer, in the pool's
+  own dtype, heads side by side as in the pool: the gathered view the
+  prefill and speculative-verify programs attend (many query rows, few
+  sessions), and what the XLA tier of the decode reader reads.
+* :func:`attend` — the score / ``-1e30`` mask / fp32 softmax / combine
+  of ``GptBlock.decode_chunk`` over such a view, for chunks of query
+  rows: K and V stay in the pool's dtype in memory, products accumulate
+  in fp32, the softmax and the probabilities are fp32.
+* :func:`paged_decode_attention` — one query row a session, B sessions
+  (the decode tick): the registered ``paged_attention`` kernel, which
+  takes the block tables by scalar prefetch and DMAs each live block
+  from the pool in HBM, with a gather per layer as its XLA tier.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..inference.quant import QuantKV
+from .dispatch import decide, pallas_mode, register_kernel, shape_fp
+
+_f32 = jnp.float32
+
+
+def gather_kv(pool, layer, tables):
+    """``tables (B, nb)`` physical ids -> ``(k, v)`` of one layer, each
+    ``(B, nb*block_size, heads*head_dim)``: row ``s`` holds the KV of
+    logical position ``s`` (the table is logical-block-ordered, so the
+    gather IS the logical->physical translation), heads side by side as
+    in the pool.  Null-padded entries read the zero block, which the
+    caller's position mask excludes.  A plain pool is read in its own
+    dtype; a :class:`QuantKV` pool gathers int8 payload and scales and
+    dequantizes the selected blocks to fp32."""
+    b, nb = tables.shape
+
+    def take(part, kv):
+        # (L, 2, N, bs, W) -> (L*2*N, bs, W) is a bitcast of the row-major
+        # pool, so the gather addresses blocks in the pool itself and no
+        # per-layer slice is materialised; (layer, kv, block) is row
+        # (layer*2 + kv)*N + block of that view
+        flat = part.reshape((-1,) + part.shape[3:])
+        g = flat[(2 * layer + kv) * part.shape[2] + tables]
+        return g.reshape(b, nb * g.shape[2], g.shape[3])     # (B, S, W)
+
+    def read(kv):
+        if not isinstance(pool, QuantKV):
+            return take(pool, kv)
+        q8, scale = take(pool.q, kv), take(pool.scale, kv)   # scale (B, S, H)
+        return q8.astype(_f32) * jnp.repeat(
+            scale, q8.shape[-1] // scale.shape[-1], axis=-1)
+    return read(0), read(1)
+
+
+def _valid(n_slots, positions, window):
+    """``(..., n_slots)`` mask: slot ``s`` is valid for a query at
+    position ``p`` where ``s <= p`` (and ``s > p - window``: rolling.py's
+    band, over block tables)."""
+    slots = jnp.arange(n_slots, dtype=jnp.int32)
+    valid = slots <= positions[..., None]
+    if window is not None:
+        valid = valid & (slots > positions[..., None] - window)
+    return valid
+
+
+def attend(q, k, v, positions, scaling, window=None):
+    """``q (B, H, Q, D)`` at per-row query positions ``positions
+    (B, Q)`` against a gathered view ``k, v (B, S, H*D)`` ->
+    ``(B, Q, H*D)`` fp32: the score / mask / softmax / combine of
+    ``GptBlock.decode_chunk`` for chunks of query rows."""
+    b, h, s_q, d = q.shape
+    k = k.reshape(b, -1, h, d)
+    v = v.reshape(b, -1, h, d)
+    scores = jnp.einsum("bhqd,bshd->bhqs", q, k,
+                        preferred_element_type=_f32) * scaling
+    valid = _valid(k.shape[1], positions, window)            # (B, Q, S)
+    scores = jnp.where(valid[:, None, :, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("bhqs,bshd->bqhd", probs, v,
+                   preferred_element_type=_f32)
+    return o.reshape(b, s_q, h * d)
+
+
+def _own_columns(heads, width):
+    """``(H, H*D)`` mask: the columns of a pool row that are head h's."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+    return lane // (width // heads) == row
+
+
+def _probs_times(p, v, dot, in_kernel=False):
+    """``dot(p, v)`` with the fp32 probabilities ``p`` at full
+    precision: against a bf16 ``v`` the MXU takes bf16 operands, so ``p``
+    goes in as three bf16 pieces (8 + 8 + 8 mantissa bits: every product
+    exact, fp32 accumulation) and ``v`` is never upcast in memory; any
+    other ``v`` is upcast and multiplied at ``HIGHEST``.  XLA cuts the
+    pieces with ``reduce_precision`` (a cast to bf16 and back is an
+    identity it may remove, which leaves one piece of 8 bits: read on
+    the chip as an error of 1.5e-3); Mosaic has no such primitive and
+    keeps the casts."""
+    if v.dtype != jnp.bfloat16:
+        return dot(p, v.astype(_f32), jax.lax.Precision.HIGHEST)
+    out = None
+    for _ in range(3):
+        if in_kernel:
+            piece = p.astype(jnp.bfloat16).astype(_f32)
+        else:
+            piece = jax.lax.reduce_precision(p, exponent_bits=8,
+                                             mantissa_bits=7)
+        p = p - piece
+        part = dot(piece.astype(jnp.bfloat16), v, None)
+        out = part if out is None else out + part
+    return out
+
+
+def _decode_xla(q, pool, layer, tables, positions, scaling, window):
+    """The XLA tier of the decode reader (the declared fallback): the
+    tables' blocks of one layer gathered in the pool's dtype, every
+    entry read, null padding included.
+
+    One query row a session, so the heads are not split out of the rows
+    (on the chip a minor dimension of ``head_dim`` is a relayout of the
+    whole view): the scores of all heads come from one product of a
+    block-diagonal ``(H, H*D)`` query with the ``(S, H*D)`` keys — the
+    off-diagonal zeros add nothing — and the combine from one ``(H, S) x
+    (S, H*D)`` product of which head ``h`` keeps its own columns."""
+    b, h, d = q.shape
+    k, v = gather_kv(pool, layer, tables)                    # (B, S, H*D)
+    own = _own_columns(h, h * d)
+    q_bd = jnp.where(own[None], q.reshape(b, 1, h * d),
+                     jnp.zeros((), q.dtype))                 # (B, H, H*D)
+    exact = q.dtype == jnp.bfloat16 and k.dtype == jnp.bfloat16
+    scores = jnp.einsum(
+        "bhx,bsx->bhs", q_bd, k, preferred_element_type=_f32,
+        precision=None if exact else jax.lax.Precision.HIGHEST) * scaling
+    valid = _valid(k.shape[1], positions, window)            # (B, S)
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = _probs_times(probs, v, lambda p, v_, prec: jnp.einsum(
+        "bhs,bsx->bhx", p, v_, preferred_element_type=_f32,
+        precision=prec))
+    return jnp.sum(jnp.where(own[None], o, 0.0), axis=1)
+
+
+# ---------------------------------------------------------------------------
+# The Pallas tier: block tables by scalar prefetch, live blocks by DMA
+# ---------------------------------------------------------------------------
+
+#: blocks fetched and attended per loop iteration (two slots of K and V
+#: each: 2 x 2 x 8 x 32 KiB = 1 MiB of VMEM at 16 x 1024 bf16 blocks)
+CHUNK_BLOCKS = 8
+
+
+def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
+                   buf, sem, *, heads, scaling, window, chunk):
+    """One session (grid step ``b``): walk its live blocks ``chunk`` at
+    a time, two slots deep, and attend them with an online softmax.
+
+    Heads lie side by side in a row of the pool: the block-diagonal
+    query and the combine are :func:`_decode_xla`'s."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    nb = tables_ref.shape[1]
+    bs, hd = buf.shape[3], buf.shape[4]
+    t = chunk * bs
+    pos = pos_ref[b]
+    # live blocks: through the query's own (just written) row; under a
+    # window the blocks wholly before the band were retired to null
+    n_chunks = (pos // bs + chunk) // chunk             # 0 for a dead row
+    first = 0 if window is None else \
+        jnp.maximum(pos - window + 1, 0) // (bs * chunk)
+
+    def fetch(c, slot, start):
+        for j in range(chunk):
+            i = c * chunk + j
+            # past the table's width: the null block (zeros), as the
+            # table's own padding past the session's length is
+            blk = jnp.where(i < nb, tables_ref[b, jnp.minimum(i, nb - 1)],
+                            0)
+            dma = pltpu.make_async_copy(
+                pool_ref.at[layer, :, blk], buf.at[slot, :, j],
+                sem.at[slot])
+            dma.start() if start else dma.wait()
+
+    own = _own_columns(heads, hd)
+    q = q_ref[0]                                        # (1, H*D)
+    # (selected in fp32: the mask has the 32-bit tiling)
+    q_bd = jnp.where(own, jnp.broadcast_to(q.astype(_f32), (heads, hd)),
+                     0.0).astype(q.dtype)
+    exact = q.dtype == jnp.bfloat16 and buf.dtype == jnp.bfloat16
+
+    @pl.when(first < n_chunks)
+    def _():
+        fetch(first, first % 2, True)
+
+    def body(c, carry):
+        m, l, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            fetch(c + 1, 1 - slot, True)
+        fetch(c, slot, False)
+        k = buf[slot, 0].reshape(t, hd)
+        v = buf[slot, 1].reshape(t, hd)
+        if exact:       # bf16 x bf16 products are exact in fp32
+            s = jax.lax.dot_general(q_bd, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=_f32)
+        else:
+            s = jax.lax.dot_general(
+                q_bd.astype(_f32), k.astype(_f32),
+                (((1,), (1,)), ((), ())), preferred_element_type=_f32,
+                precision=jax.lax.Precision.HIGHEST)
+        s = s * scaling                                 # (H, T)
+        slots = c * t + jax.lax.broadcasted_iota(jnp.int32, (heads, t), 1)
+        valid = slots <= pos
+        if window is not None:
+            valid = valid & (slots > pos - window)
+        s = jnp.where(valid, s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = alpha * acc + _probs_times(
+            p, v, lambda p_, v_, prec: jnp.dot(
+                p_, v_, preferred_element_type=_f32, precision=prec),
+            in_kernel=True)
+        return m_new, l, acc
+
+    m0 = jnp.full((heads, 1), -1e30, _f32)
+    l0 = jnp.zeros((heads, 1), _f32)
+    acc0 = jnp.zeros((heads, hd), _f32)
+    _, l, acc = jax.lax.fori_loop(first, n_chunks, body, (m0, l0, acc0))
+    out = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
+    o_ref[0] = jnp.sum(out, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("scaling", "window",
+                                             "interpret"))
+def _decode_call(layer, tables, positions, q, pool, *, scaling, window,
+                 interpret):
+    """The kernel's call, jitted with the layer as an operand: a
+    program's per-layer calls then share one trace and one Mosaic
+    lowering (lowering 24 of them took 7-10 s a program, which the
+    compile cache does not spare a warm start)."""
+    b, heads, d = q.shape
+    _, _, _, bs, hd = pool.shape
+    chunk = min(CHUNK_BLOCKS, tables.shape[1])
+    kernel = functools.partial(
+        _decode_kernel, heads=heads, scaling=scaling, window=window,
+        chunk=chunk)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, 2, chunk, bs, hd), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), _f32),
+        interpret=interpret,
+        name="paged_attention_decode",
+    )(layer, tables, positions, q.reshape(b, 1, hd), pool)
+    return out[:, 0]
+
+
+def _decode_pallas(q, pool, layer, tables, positions, scaling, window,
+                   interpret):
+    return _decode_call(jnp.full((1,), layer, jnp.int32), tables, positions,
+                        q, pool, scaling=float(scaling), window=window,
+                        interpret=interpret)
+
+
+def paged_attention_fp(b, nb, h, d, bs, dtype) -> str:
+    return shape_fp(b=int(b), nb=int(nb), h=int(h), d=int(d), bs=int(bs),
+                    dtype=str(dtype))
+
+
+def paged_decode_attention(q, pool, layer, tables, positions, scaling,
+                           window=None):
+    """The decode tick's attention: ``q (B, H, D)``, one query row a
+    session at ``positions (B,)`` (``-1`` = dead pad row, whose output
+    the caller discards), against the session's blocks of ``layer``
+    -> ``(B, H*D)`` fp32.
+
+    Two tiers behind :func:`~apex_tpu.kernels.dispatch.decide`: the
+    Pallas kernel takes the tables by scalar prefetch and DMAs each live
+    block from the pool in HBM (a session at depth 300 reads 19 blocks,
+    not its table's 64); the XLA tier gathers the whole table.  An int8
+    pool, or rows that are not whole tiles, are the XLA tier's."""
+    mode = pallas_mode()
+    if mode is not None and _kernel_takes(q, pool):
+        fp = paged_attention_fp(q.shape[0], tables.shape[1], q.shape[1],
+                                q.shape[2], pool.shape[3], pool.dtype)
+        if decide("paged_attention", fp).tier == "pallas":
+            return _decode_pallas(q, pool, layer, tables, positions,
+                                  scaling, window, mode == "interpret")
+    return _decode_xla(q, pool, layer, tables, positions, scaling, window)
+
+
+def _kernel_takes(q, pool) -> bool:
+    """What the kernel's tiles allow: a plain pool whose rows are whole
+    lane rows and whose blocks are whole sublane tiles of its dtype."""
+    if isinstance(pool, QuantKV):
+        return False
+    rows = 8 * 4 // jnp.dtype(pool.dtype).itemsize
+    return pool.shape[4] % 128 == 0 and pool.shape[3] % rows == 0
+
+
+def _paged_probe(dims):
+    """No-ledger prior: the kernel reads the live blocks once where the
+    XLA tier writes and re-reads a gathered copy of the whole table, so
+    it is taken wherever its tiles fit (PERF.md section 6, PR 27, has
+    the chip's reading at gpt2-medium's widths)."""
+    return 1, True
+
+
+def _audit_programs():
+    """Both tiers on one abstract shape for the jaxpr verifier."""
+    sds = jax.ShapeDtypeStruct
+    q = sds((2, 2, 64), jnp.bfloat16)
+    pool = sds((1, 2, 8, 16, 128), jnp.bfloat16)
+    i32 = jnp.int32
+    ex = (q, pool, sds((2, 4), i32), sds((2,), i32))
+
+    def _pallas(q, pool, tables, positions):
+        return _decode_pallas(q, pool, 0, tables, positions, 0.125, None,
+                              False)
+
+    def _xla(q, pool, tables, positions):
+        return _decode_xla(q, pool, 0, tables, positions, 0.125, None)
+
+    return [("pallas", _pallas, ex), ("xla", _xla, ex)]
+
+
+register_kernel(
+    "paged_attention",
+    xla_fallback="apex_tpu.kernels.paged_attention._decode_xla",
+    threshold_probe=_paged_probe,
+    doc="Decode attention through block tables: live KV blocks by DMA",
+    audit_programs=_audit_programs)

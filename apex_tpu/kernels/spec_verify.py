@@ -39,7 +39,7 @@ from .dispatch import measured_threshold, register_kernel, shape_fp
 
 def spec_verify_fp(*, b, k, s_t, s_d, dtype) -> str:
     """Ledger fingerprint for one spec-verify dispatch shape: batch
-    bucket ``b``, draft depth ``k``, gathered target/draft linear cache
+    bucket ``b``, draft depth ``k``, target/draft table
     widths ``s_t``/``s_d`` (table bucket x block_size), pool dtype.
     Built by the SAME helper at probe time (bench) and decision time
     (the engine's ``spec="auto"`` path)."""
@@ -81,7 +81,7 @@ def _audit_programs():
     bs, nblk, k, b, nb = 4, 6, 2, 2, 2
     t_vals = [sds(p.data.shape, p.data.dtype) for p in t_params]
     d_vals = [sds(p.data.shape, p.data.dtype) for p in d_params]
-    pool = sds((1, 2, nblk, 2, bs, 8), jnp.float32)   # (L,2,NB,H,bs,D)
+    pool = sds((1, 2, nblk, bs, 2 * 8), jnp.float32)  # (L,2,NB,bs,H*D)
     toks = sds((b,), i32)
     pos = sds((b,), i32)
     tab = sds((b, nb), i32)

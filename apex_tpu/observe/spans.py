@@ -32,9 +32,11 @@ from typing import Any, Dict, List, Optional
 
 from . import registry as _registry
 
-#: ``span`` records kept in memory: about eight a serve tick, eleven
-#: ticks a second, so six minutes of serving (a benchmark window is 45 s)
-SPAN_RING = 32768
+#: ``span`` records kept in memory: about nine a serve tick and 60-150
+#: ticks a second since the pool is no longer copied every tick, so two
+#: to four minutes of serving (a benchmark window is 45 s, and its
+#: readers look at the ring after the run)
+SPAN_RING = 131072
 
 _registry.get_registry().set_event_capacity("span", SPAN_RING)
 

@@ -13,18 +13,36 @@ step-cache keying discipline, enforced by the SERVE-SHAPE lint rule.
 The attention math deliberately reuses the model's own decode pieces —
 ``GptBlock._chunk_qkv`` (LN1 + interleaved QKV projection),
 ``GptBlock._attn_mlp_tail`` (out-proj + residual + FFN), the fp32
-score einsum + ``-1e30`` mask + softmax of ``GptBlock.decode_chunk``,
+score product + ``-1e30`` mask + softmax of ``GptBlock.decode_chunk``,
 and the int8-aware ``gather_rows`` embedding lookup — so the paged
 path cannot drift numerically from the contiguous-cache path it is
-parity-tested against (tests/test_serve.py).  The only new math is the
-index plumbing: block-table gathers into a per-tick linear cache view,
-and position→(block, offset) scatters of fresh KV.
+parity-tested against (tests/test_serve.py, tests/test_serve_paged.py).
+The only new math is the index plumbing, and it keeps the pool where it
+lies (``serve/pool.py``: ``(layers, 2, num_blocks, block_size,
+heads*head_dim)``, row-major on the device):
+
+* **write** — each layer sets its fresh K and V rows in the donated
+  pool in place, position -> (physical block, offset), one contiguous
+  row each (:func:`write_rows`), BEFORE that layer attends: the
+  write-then-read of ``decode_chunk``, so a query finds its own key
+  where every other key is;
+* **read, decode** — one query row a session: through the block table
+  (:func:`~apex_tpu.kernels.paged_attention.paged_decode_attention`: a
+  Pallas kernel that DMAs the live blocks, with a per-layer gather as
+  its XLA tier);
+* **read, prefill and speculative verify** — many query rows, few
+  sessions: a gathered view of those sessions' blocks, one layer at a
+  time, in the pool's dtype.
+
+No program holds a view of all layers, an fp32 copy of the pool's rows,
+or the pool in another layout (tests/test_aot_tpu_compile.py pins it on
+the compiled v5e programs).
 
 Dead batch rows (bucket padding) are encoded as ``position == -1``:
-their tables are all-null (gathers read zeros the mask excludes), their
-embedding lookups clip to row 0 (outputs discarded), and their KV
-scatter targets are redirected past the pool so ``mode="drop"``
-discards the write — padding never touches the null block's zeros.
+their tables are all-null (reads see zeros the mask excludes), their
+embedding lookups clip to row 0 (outputs discarded), and their KV write
+targets are redirected past the pool so ``mode="drop"`` discards the
+write — padding never touches the null block's zeros.
 """
 from __future__ import annotations
 
@@ -32,6 +50,8 @@ import jax
 import jax.numpy as jnp
 
 from ..inference import QuantKV, absmax_int8, gather_rows
+from ..kernels.paged_attention import (attend, gather_kv,
+                                       paged_decode_attention)
 from ..nn.modules import Ctx
 
 _f32 = jnp.float32
@@ -43,72 +63,45 @@ def _ctx(params, vals):
 
 
 # ---------------------------------------------------------------------------
-# Pool indexing: block-table gather / position scatter
+# Pool writes: position -> (physical block, offset), rows set in place
 # ---------------------------------------------------------------------------
 
 
-def gather_pool(pool, tables):
-    """Gather each session's blocks into a LINEAR cache view.
+def row_targets(tables, positions, live, block_size, num_blocks):
+    """Where the K and V rows of logical ``positions (B, Q)`` live in
+    one layer of the pool: index arrays ``(kv, block, offset)``, each
+    ``(2*B*Q,)`` — the K rows, then the V rows.  Rows that are not
+    ``live (B, Q)`` (bucket padding, a chunk's zero-padded tail) point
+    past the pool, so :func:`write_rows` drops them — padding never
+    touches the null block's zeros."""
+    p = jnp.clip(positions, 0)
+    tgt = jnp.take_along_axis(
+        tables, jnp.minimum(p // block_size, tables.shape[1] - 1), axis=1)
+    tgt = jnp.where(live, tgt, num_blocks).reshape(-1)
+    kv = jnp.repeat(jnp.arange(2, dtype=tgt.dtype), tgt.shape[0])
+    return kv, jnp.tile(tgt, 2), jnp.tile((p % block_size).reshape(-1), 2)
 
-    ``tables (B, nb)`` physical ids -> per-layer reader ``read(l)``
-    returning ``(k, v)`` of shape ``(B, H, nb*block_size, D)`` fp32,
-    where linear slot ``s`` holds the KV of logical position ``s`` (the
-    table is logical-block-ordered, so the gather IS the
-    logical→physical translation).  Null-padded table entries read the
-    zero block — masked out by the caller's position-validity mask.
-    QuantKV pools gather int8 payload + scales and dequantize after the
-    gather (only the selected blocks' bytes move)."""
-    def lin(g):
-        # (B, nb, H, bs, D) -> (B, H, nb*bs, D)
-        b, nb, h, bs, d = g.shape
-        return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(b, h, nb * bs, d)
 
+def write_rows(pool, layer, targets, k_new, v_new):
+    """Set the fresh K and V rows of one layer in the (donated) pool, in
+    place: ``k_new, v_new (B, H, Q, D)`` go to ``pool[layer, kv, block,
+    offset, :]`` (``targets`` from :func:`row_targets`), each a
+    contiguous row of ``H*D`` elements.  Rows whose block id points past
+    the pool are dropped.  QuantKV pools quantize per position and head
+    (absmax over D — identical stored bytes to the contiguous int8
+    cache's write path)."""
+    b, h, s_q, d = k_new.shape
+    rows = jnp.concatenate(
+        [jnp.swapaxes(k_new, 1, 2).reshape(b * s_q, h, d),
+         jnp.swapaxes(v_new, 1, 2).reshape(b * s_q, h, d)])  # (2R, H, D)
+    at = (layer,) + tuple(targets)
     if isinstance(pool, QuantKV):
-        q = pool.q[:, :, tables]          # (L, 2, B, nb, H, bs, D)
-        s = pool.scale[:, :, tables]      # (L, 2, B, nb, H, bs, 1)
-
-        def read(layer):
-            return (lin(q[layer, 0]).astype(_f32) * lin(s[layer, 0]),
-                    lin(q[layer, 1]).astype(_f32) * lin(s[layer, 1]))
-        return read
-    g = pool[:, :, tables]                # (L, 2, B, nb, H, bs, D)
-
-    def read(layer):
-        return lin(g[layer, 0]).astype(_f32), lin(g[layer, 1]).astype(_f32)
-    return read
-
-
-def scatter_pool(pool, layer, kv, blk_ids, offs, vals):
-    """Write ``vals (R, H, D)`` into ``pool[layer, kv]`` at physical
-    block ``blk_ids (R,)``, in-block offset ``offs (R,)``.  Rows whose
-    ``blk_ids`` point past the pool are dropped (``mode="drop"``) —
-    the caller encodes dead/pad rows that way.  QuantKV pools quantize
-    per position (absmax over D — identical stored bytes to the
-    contiguous int8 cache's write path)."""
-    if isinstance(pool, QuantKV):
-        q, scale = absmax_int8(vals.astype(_f32), -1, pool.scale.dtype)
+        q, scale = absmax_int8(rows.astype(_f32), -1, pool.scale.dtype)
         return QuantKV(
-            pool.q.at[layer, kv, blk_ids, :, offs, :].set(
-                q, mode="drop"),
-            pool.scale.at[layer, kv, blk_ids, :, offs, :].set(
-                scale, mode="drop"))
-    return pool.at[layer, kv, blk_ids, :, offs, :].set(
-        vals.astype(pool.dtype), mode="drop")
-
-
-def insert_row(pool, k_lin, v_lin, k_new, v_new, own):
-    """Splice the just-projected KV row(s) into the gathered linear
-    view so the current query attends its own fresh keys (the paged
-    analogue of decode_chunk's write-then-read).  Through an int8 pool
-    the inserted rows take the quantize→dequantize round trip FIRST, so
-    attention reads exactly the bytes the scatter will store."""
-    if isinstance(pool, QuantKV):
-        kq, ks = absmax_int8(k_new.astype(_f32), -1, pool.scale.dtype)
-        vq, vs = absmax_int8(v_new.astype(_f32), -1, pool.scale.dtype)
-        k_new = kq.astype(_f32) * ks
-        v_new = vq.astype(_f32) * vs
-    return (jnp.where(own, k_new.astype(_f32), k_lin),
-            jnp.where(own, v_new.astype(_f32), v_lin))
+            pool.q.at[at].set(q.reshape(-1, h * d), mode="drop"),
+            pool.scale.at[at].set(scale[..., 0], mode="drop"))
+    return pool.at[at].set(
+        rows.reshape(-1, h * d).astype(pool.dtype), mode="drop")
 
 
 def build_block_copy_fn():
@@ -137,24 +130,6 @@ def build_block_copy_fn():
 # ---------------------------------------------------------------------------
 
 
-def _paged_attend(blk, x, q, k_lin, v_lin, positions, slots, window):
-    """decode_chunk's score/mask/softmax/combine against a gathered
-    linear cache: ``q (B, H, Q, D)``, per-row query positions
-    ``positions (B, Q)``.  ``window`` adds the sliding-window band term
-    (rolling.py's mask, generalized to block tables)."""
-    scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(_f32),
-                        k_lin) * blk.attn.scaling
-    valid = slots[None, None, :] <= positions[:, :, None]   # (B, Q, S)
-    if window is not None:
-        valid = valid & (slots[None, None, :]
-                         > positions[:, :, None] - window)
-    scores = jnp.where(valid[:, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhqs,bhsd->bhqd", probs, v_lin).astype(x.dtype)
-    b, h, s_q, d = q.shape
-    return jnp.swapaxes(o, 1, 2).reshape(b, s_q, h * d)
-
-
 def _embed(ctx, model, toks, positions):
     """Token + position embedding with int8-aware row gathers;
     ``positions`` clip to the table (pad rows only — real positions are
@@ -172,6 +147,47 @@ def _head(ctx, model, x):
         jnp.matmul(x, jnp.swapaxes(emb, 0, 1).astype(x.dtype)))
 
 
+def _through_blocks(ctx, model, pool, x, q_pos, live, tables, block_size,
+                    num_blocks, read):
+    """``x (B, Q, E)`` at positions ``q_pos (B, Q)``, of which ``live
+    (B, Q)`` are real, through every block.  Each layer writes the live
+    rows' KV into the pool and then attends it through ``read(q, pool,
+    layer, scaling) -> (B, Q, H*D)`` — the write-then-read of
+    ``GptBlock.decode_chunk``, so a query finds its own key where every
+    other key is (through an int8 pool: exactly the bytes stored)."""
+    targets = row_targets(tables, q_pos, live, block_size, num_blocks)
+    for layer, blk in enumerate(model.blocks):
+        q, k_new, v_new = blk._chunk_qkv(ctx, x)          # (B, H, Q, D)
+        pool = write_rows(pool, layer, targets, k_new, v_new)
+        o = read(q, pool, layer, blk.attn.scaling)
+        x = blk._attn_mlp_tail(ctx, x, o.astype(x.dtype))
+    return x, pool
+
+
+def _decode_layers(ctx, model, pool, x, positions, tables, block_size,
+                   num_blocks, window):
+    """One token a session, ``x (B, 1, E)`` at ``positions (B,)``: the
+    reader goes through the block table."""
+    def read(q, pool, layer, scaling):
+        return paged_decode_attention(q[:, :, 0], pool, layer, tables,
+                                      positions, scaling, window)[:, None]
+    return _through_blocks(ctx, model, pool, x, positions[:, None],
+                           positions[:, None] >= 0, tables, block_size,
+                           num_blocks, read)
+
+
+def _chunk_layers(ctx, model, pool, x, q_pos, live, tables, block_size,
+                  num_blocks, window):
+    """A chunk of query rows a session: the reader gathers the tables'
+    blocks of one layer — these sessions' blocks only, in the pool's
+    dtype — and attends the view."""
+    def read(q, pool, layer, scaling):
+        k, v = gather_kv(pool, layer, tables)
+        return attend(q, k, v, q_pos, scaling, window)
+    return _through_blocks(ctx, model, pool, x, q_pos, live, tables,
+                           block_size, num_blocks, read)
+
+
 def build_decode_fn(model, params, block_size, num_blocks, window=None):
     """The decode-tick program body: one token per live session.
 
@@ -184,40 +200,14 @@ def build_decode_fn(model, params, block_size, num_blocks, window=None):
     round-trip per tick is one small int array; the logits ride along
     as an un-fetched device array for clients (PagedSession) that
     continue from them."""
-    bs = block_size
-
     def fn(vals, pool, tokens, positions, tables):
         ctx = _ctx(params, vals)
         x = _embed(ctx, model, tokens[:, None], positions[:, None])
-        read = gather_pool(pool, tables)
-        slots = jnp.arange(tables.shape[1] * bs, dtype=jnp.int32)
-        fresh = []
-        for layer, blk in enumerate(model.blocks):
-            q, k_new, v_new = blk._chunk_qkv(ctx, x)      # (B, H, 1, D)
-            k_lin, v_lin = read(layer)
-            own = (slots[None, :]
-                   == positions[:, None])[:, None, :, None]
-            k_lin, v_lin = insert_row(pool, k_lin, v_lin, k_new, v_new,
-                                      own)
-            o = _paged_attend(blk, x, q, k_lin, v_lin,
-                              positions[:, None], slots, window)
-            x = blk._attn_mlp_tail(ctx, x, o)
-            fresh.append((k_new, v_new))
+        x, pool = _decode_layers(ctx, model, pool, x, positions, tables,
+                                 block_size, num_blocks, window)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)[:, 0]               # (B, V)
         nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
-        # position -> (physical block, offset); dead rows drop
-        p = jnp.clip(positions, 0)
-        tgt = jnp.take_along_axis(
-            tables, jnp.minimum(p // bs, tables.shape[1] - 1)[:, None],
-            axis=1)[:, 0]
-        tgt = jnp.where(positions >= 0, tgt, num_blocks)
-        offs = p % bs
-        for layer, (k_new, v_new) in enumerate(fresh):
-            pool = scatter_pool(pool, layer, 0, tgt, offs,
-                                k_new[:, :, 0, :])
-            pool = scatter_pool(pool, layer, 1, tgt, offs,
-                                v_new[:, :, 0, :])
         return nxt, logits, pool
     return fn
 
@@ -236,75 +226,21 @@ def build_prefill_fn(model, params, block_size, num_blocks,
     prompt length, keys compilation).  ``last_logits (1, V)`` is row
     ``n_real - 1`` — the next-token distribution once the final chunk
     lands."""
-    bs = block_size
-
     def fn(vals, pool, toks, table, t0, n_real):
         ctx = _ctx(params, vals)
-        chunk = toks.shape[1]
-        rows = jnp.arange(chunk, dtype=jnp.int32)
-        pos = t0 + rows                                   # (chunk,)
-        x = _embed(ctx, model, toks, pos[None, :])
-        read = gather_pool(pool, table)
-        nb = table.shape[1]
-        slots = jnp.arange(nb * bs, dtype=jnp.int32)
-        # chunk row d lands in linear slot t0 + d; live rows only
-        # (the rolling_kv_write masked-select technique, block-tabled)
-        d = slots - t0                                    # (S,)
-        own = ((d >= 0) & (d < n_real))[None, None, :, None]
-        src = jnp.clip(d, 0, chunk - 1)
-        fresh = []
-        for layer, blk in enumerate(model.blocks):
-            q, k_new, v_new = blk._chunk_qkv(ctx, x)   # (B, H, chunk, D)
-            k_lin, v_lin = read(layer)
-            k_ins = jnp.take(k_new, src, axis=2)       # (B, H, S, D)
-            v_ins = jnp.take(v_new, src, axis=2)
-            k_lin, v_lin = insert_row(pool, k_lin, v_lin, k_ins, v_ins,
-                                      own)
-            o = _paged_attend(blk, x, q, k_lin, v_lin, pos[None, :],
-                              slots, window)
-            x = blk._attn_mlp_tail(ctx, x, o)
-            fresh.append((k_new, v_new))
+        rows = jnp.arange(toks.shape[1], dtype=jnp.int32)
+        pos = (t0 + rows)[None, :]                        # (1, chunk)
+        x = _embed(ctx, model, toks, pos)
+        # chunk row d lands at position t0 + d; live rows only
+        x, pool = _chunk_layers(ctx, model, pool, x, pos,
+                                (rows < n_real)[None, :], table,
+                                block_size, num_blocks, window)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)                  # (1, chunk, V)
         last = jax.lax.dynamic_index_in_dim(
             logits, jnp.clip(n_real - 1, 0), axis=1, keepdims=False)
-        live = rows < n_real
-        tgt = table[0, jnp.minimum(pos // bs, nb - 1)]  # (chunk,)
-        tgt = jnp.where(live, tgt, num_blocks)
-        offs = pos % bs
-        for layer, (k_new, v_new) in enumerate(fresh):
-            pool = scatter_pool(pool, layer, 0, tgt, offs,
-                                jnp.swapaxes(k_new[0], 0, 1))
-            pool = scatter_pool(pool, layer, 1, tgt, offs,
-                                jnp.swapaxes(v_new[0], 0, 1))
         return last, pool
     return fn
-
-
-def _scatter_chunk(pool, fresh, tables, positions, block_size,
-                   num_blocks, width):
-    """Batched multi-position scatter: write ``fresh`` — per-layer
-    ``(k_new, v_new)`` of shape ``(B, H, width, D)`` — so chunk row
-    ``j`` of batch row ``b`` lands at logical position
-    ``positions[b] + j``.  Dead rows (``positions == -1``) redirect past
-    the pool and drop; live rows are distinct (position, table) pairs,
-    so the scatter has no write conflicts."""
-    bs = block_size
-    nb = tables.shape[1]
-    p = positions[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
-    pc = jnp.clip(p, 0)
-    tgt = jnp.take_along_axis(tables, jnp.minimum(pc // bs, nb - 1),
-                              axis=1)
-    tgt = jnp.where(positions[:, None] >= 0, tgt,
-                    num_blocks).reshape(-1)
-    offs = (pc % bs).reshape(-1)
-    for layer, (k_new, v_new) in enumerate(fresh):
-        _, h, _, d_ = k_new.shape
-        pool = scatter_pool(pool, layer, 0, tgt, offs,
-                            jnp.swapaxes(k_new, 1, 2).reshape(-1, h, d_))
-        pool = scatter_pool(pool, layer, 1, tgt, offs,
-                            jnp.swapaxes(v_new, 1, 2).reshape(-1, h, d_))
-    return pool
 
 
 def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
@@ -342,7 +278,6 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
     expressed in block tables.  Shape-static like every serve body: the
     batch bucket, the two table buckets and ``k`` key compilation;
     acceptance lengths are DATA (`n_acc`), never shapes."""
-    bs = block_size
     kp1 = k + 1
 
     def fn(t_vals, d_vals, t_pool, d_pool, tokens, positions,
@@ -350,65 +285,30 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
         t_ctx = _ctx(t_params, t_vals)
         d_ctx = _ctx(d_params, d_vals)
         live = positions >= 0
-        # ---- draft proposes: kp1 sequential paged single-token steps
-        # against one up-front gather; fresh rows accumulate in the
-        # linear view and scatter back once at the end.
-        d_read = gather_pool(d_pool, d_tables)
-        d_slots = jnp.arange(d_tables.shape[1] * bs, dtype=jnp.int32)
-        d_lins = [list(d_read(layer))
-                  for layer in range(len(draft.blocks))]
-        d_fresh = [[] for _ in draft.blocks]
+        # ---- draft proposes: kp1 sequential paged single-token steps,
+        # each the decode program's own layer loop (its fresh row goes
+        # into the draft pool before it attends)
         chunk_toks = [tokens]
         tok = tokens
         for j in range(kp1):
             pos_j = jnp.where(live, positions + j, -1)
             x = _embed(d_ctx, draft, tok[:, None], pos_j[:, None])
-            for layer, blk in enumerate(draft.blocks):
-                q, k_new, v_new = blk._chunk_qkv(d_ctx, x)
-                own = (d_slots[None, :]
-                       == pos_j[:, None])[:, None, :, None]
-                k_lin, v_lin = insert_row(
-                    d_pool, d_lins[layer][0], d_lins[layer][1],
-                    k_new, v_new, own)
-                d_lins[layer] = [k_lin, v_lin]
-                o = _paged_attend(blk, x, q, k_lin, v_lin,
-                                  pos_j[:, None], d_slots, None)
-                x = blk._attn_mlp_tail(d_ctx, x, o)
-                d_fresh[layer].append((k_new, v_new))
+            x, d_pool = _decode_layers(d_ctx, draft, d_pool, x, pos_j,
+                                       d_tables, block_size, num_blocks,
+                                       None)
             if j < k:                  # step k only writes its KV row
                 x = draft.ln_f.forward(d_ctx, x)
                 logits = _head(d_ctx, draft, x)[:, 0]
                 tok = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
                 chunk_toks.append(tok)
         chunk = jnp.stack(chunk_toks, axis=1)       # (B, kp1)
-        d_pool = _scatter_chunk(
-            d_pool,
-            [(jnp.concatenate([f[0] for f in per], axis=2),
-              jnp.concatenate([f[1] for f in per], axis=2))
-             for per in d_fresh],
-            d_tables, positions, bs, num_blocks, kp1)
         # ---- target verifies the whole chunk in one paged pass
-        t_read = gather_pool(t_pool, t_tables)
-        slots = jnp.arange(t_tables.shape[1] * bs, dtype=jnp.int32)
         offs_q = jnp.arange(kp1, dtype=jnp.int32)[None, :]
-        q_pos = jnp.where(live[:, None], positions[:, None] + offs_q, -1)
+        q_live = jnp.broadcast_to(live[:, None], chunk.shape)
+        q_pos = jnp.where(q_live, positions[:, None] + offs_q, -1)
         x = _embed(t_ctx, target, chunk, q_pos)
-        d = slots[None, :] - positions[:, None]             # (B, S)
-        own = ((d >= 0) & (d < kp1)
-               & live[:, None])[:, None, :, None]
-        src = jnp.clip(d, 0, k)[:, None, :, None]
-        t_fresh = []
-        for layer, blk in enumerate(target.blocks):
-            q, k_new, v_new = blk._chunk_qkv(t_ctx, x)   # (B, H, kp1, D)
-            k_lin, v_lin = t_read(layer)
-            k_ins = jnp.take_along_axis(k_new, src, axis=2)
-            v_ins = jnp.take_along_axis(v_new, src, axis=2)
-            k_lin, v_lin = insert_row(t_pool, k_lin, v_lin, k_ins,
-                                      v_ins, own)
-            o = _paged_attend(blk, x, q, k_lin, v_lin, q_pos, slots,
-                              None)
-            x = blk._attn_mlp_tail(t_ctx, x, o)
-            t_fresh.append((k_new, v_new))
+        x, t_pool = _chunk_layers(t_ctx, target, t_pool, x, q_pos, q_live,
+                                  t_tables, block_size, num_blocks, None)
         x = target.ln_f.forward(t_ctx, x)
         logits = _head(t_ctx, target, x)                # (B, kp1, V)
         emitted = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
@@ -416,7 +316,5 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
         stop = jnp.concatenate(
             [agree, jnp.zeros((agree.shape[0], 1), bool)], axis=1)
         n_acc = jnp.argmin(stop.astype(jnp.int32), axis=1) + 1
-        t_pool = _scatter_chunk(t_pool, t_fresh, t_tables, positions,
-                                bs, num_blocks, kp1)
         return emitted, n_acc, t_pool, d_pool
     return fn

@@ -14,9 +14,11 @@ built around — the decode program's operand shapes depend only on the
 POOL geometry and the bucket dims, never on which sessions are resident,
 so session churn cannot force a recompile.
 
-Layout: ``(layers, 2, num_blocks, heads, block_size, head_dim)`` —
-k/v interleaved on axis 1 so one gather serves both, block id on axis 2
-so a session's table indexes one axis.  **Physical block 0 is the null
+Layout: ``(layers, 2, num_blocks, block_size, heads*head_dim)`` — k/v
+on axis 1, block id on axis 2 so a session's table indexes one axis, and
+one token's row of all heads contiguous and minor-most (a whole number
+of lane rows, so the device keeps the array row-major and the serve
+programs read and write it where it lies).  **Physical block 0 is the null
 block**: it is never allocated, stays all-zeros, and pads every block
 table out to its bucket width — gathers through it read zeros that the
 position-validity mask already excludes, so padding is free instead of
@@ -79,13 +81,21 @@ def blocks_for(n_positions: int, block_size: int) -> int:
 def init_pool_buffer(layers, heads, head_dim, num_blocks, block_size,
                      dtype=jnp.float32):
     """The device-side pool array
-    ``(layers, 2, num_blocks, heads, block_size, head_dim)`` — zeros, so
-    the null block is born valid.  ``dtype="int8"``/``jnp.int8`` builds
-    the :class:`QuantKV` pair (scales fp32, one per position)."""
-    shape = (layers, 2, num_blocks, heads, block_size, head_dim)
+    ``(layers, 2, num_blocks, block_size, heads*head_dim)`` — zeros, so
+    the null block is born valid.  One token's K (or V) of one layer is
+    one contiguous row of ``heads*head_dim`` elements (head ``h`` at
+    ``[h*head_dim, (h+1)*head_dim)``) and one block is one contiguous
+    piece: with a minor dimension that is a whole number of lane rows
+    the device stores the array row-major, which is the layout both the
+    row writes and the block-table reads of serve/kernels.py want, so no
+    program relayouts it.  ``dtype="int8"``/``jnp.int8`` builds the
+    :class:`QuantKV` pair: int8 payload in the same shape, scales fp32
+    ``(layers, 2, num_blocks, block_size, heads)`` — one per position
+    and head."""
+    shape = (layers, 2, num_blocks, block_size, heads * head_dim)
     if jnp.dtype(dtype) == jnp.dtype("int8"):
         return QuantKV(jnp.zeros(shape, jnp.int8),
-                       jnp.zeros(shape[:-1] + (1,), jnp.float32))
+                       jnp.zeros(shape[:-1] + (heads,), jnp.float32))
     return jnp.zeros(shape, dtype)
 
 

@@ -922,6 +922,36 @@ def kernel_probe_records(iters=2, reps=3):
         (sp_vals, sp_vals, sp_pool, sp_dpool, sp_toks, sp_positions,
          sp_tabs, sp_tabs)))
 
+    # --- paged_attention: the decode tick's attention through block
+    # tables, live blocks by DMA against the gathered XLA tier; sessions
+    # at staggered depths in a table bucketed to 8 blocks.  Both arms
+    # bypass decide(), as above ---
+    from apex_tpu.kernels import paged_attention as kpa
+    pa_b, pa_h, pa_d, pa_bs, pa_nb = 4, 2, 64, 16, 8
+    pa_pool = jnp.asarray(
+        rng.standard_normal((1, 2, 1 + pa_b * pa_nb, pa_bs, pa_h * pa_d)),
+        jnp.bfloat16).at[:, :, 0].set(0)
+    pa_pos = np.asarray([5, 40, 77, 127], np.int32)
+    pa_tabs = np.zeros((pa_b, pa_nb), np.int32)
+    for i, p_ in enumerate(pa_pos):
+        n_live = int(p_) // pa_bs + 1
+        pa_tabs[i, :n_live] = 1 + i * pa_nb + np.arange(n_live)
+    pa_q = jnp.asarray(rng.standard_normal((pa_b, pa_h, pa_d)),
+                       jnp.bfloat16)
+
+    def build_pa(arm):
+        if arm == "pallas":
+            interp = kdispatch.pallas_mode() == "interpret"
+            return jax.jit(lambda q, pool, tabs, pos: kpa._decode_pallas(
+                q, pool, 0, tabs, pos, pa_d ** -0.5, None, interp))
+        return jax.jit(lambda q, pool, tabs, pos: kpa._decode_xla(
+            q, pool, 0, tabs, pos, pa_d ** -0.5, None))
+    probes.append((
+        "paged_attention",
+        kpa.paged_attention_fp(pa_b, pa_nb, pa_h, pa_d, pa_bs, "bfloat16"),
+        build_pa, (pa_q, pa_pool, jnp.asarray(pa_tabs),
+                   jnp.asarray(pa_pos))))
+
     write_ledger = mode == "compiled"
     led = kledger.get_ledger() if write_ledger else None
     records = []

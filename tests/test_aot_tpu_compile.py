@@ -13,7 +13,9 @@ Code that asks ``jax.default_backend()`` still sees the CPU here, so each
 case steers it *in the test*: ``force_mode("compiled")`` for the kernel
 mode, ``donate_state=True`` / ``donate_argnums`` for donation.
 """
+import json
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -113,10 +115,19 @@ def _gpt2_small(**kw):
     return gpt2_small(vocab_size=VOCAB, max_positions=1024, **kw)
 
 
+def _kernel_calls(compiled):
+    """Names of the program's Pallas custom calls: they come from
+    ``pallas_call(name=...)`` and are what the device trace shows."""
+    return [ln.split("=")[0] for ln in compiled.as_text().splitlines()
+            if "tpu_custom_call" in ln and " custom-call(" in ln]
+
+
 def test_decode_program_compiles(chip):
     """The paged decode tick at the pool ``chip_smoke.py`` serves from
-    (2048 blocks of 16): batch bucket 8, table bucket 64, pool donated."""
+    (2048 blocks of 16): batch bucket 8, table bucket 64, pool donated,
+    the table-reading kernel in every layer."""
     from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import init_pool_buffer
 
     num_blocks, block_size, batch, table = 2048, 16, 8, 64
     model = _gpt2_small(dropout=0.0, attn_dropout=0.0).bfloat16()
@@ -125,14 +136,144 @@ def test_decode_program_compiles(chip):
     fn = serve_kernels.build_decode_fn(model, params, block_size,
                                        num_blocks)
     vals = _on(chip, [p.data for p in params])
-    pool = _sds((LAYERS, 2, num_blocks, HEADS, block_size, HEAD_DIM),
-                jnp.bfloat16, chip)
+    pool = _on(chip, jax.eval_shape(lambda: init_pool_buffer(
+        LAYERS, HEADS, HEAD_DIM, num_blocks, block_size, jnp.bfloat16)))
     i32 = jnp.int32
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        vals, pool, _sds((batch,), i32, chip), _sds((batch,), i32, chip),
-        _sds((batch, table), i32, chip)).compile()
-    ma = _check(compiled, 0)
+    with force_mode("compiled"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            vals, pool, _sds((batch,), i32, chip),
+            _sds((batch,), i32, chip),
+            _sds((batch, table), i32, chip)).compile()
+    ma = _check(compiled, LAYERS)
     assert ma.alias_size_in_bytes >= pool.size * 2      # pool updated in place
+    assert sum("paged_attention_decode" in c
+               for c in _kernel_calls(compiled)) == LAYERS
+
+
+# -- the serve programs of the benchmark's gpt2-medium cell ----------------
+# (perfbench/configs/gpt2-medium.json: the engine settings of a
+# deployment).  What these pin: the programs take the KV pool where it
+# lies.  Its format is the program's (``init_pool_buffer``), it is
+# aliased input to output, and nothing but the in-place row writes
+# produces a buffer of its size: no copy, transpose, gather or convert
+# of the pool, which is what made a decode step 89 ms (PERF.md, PR 27).
+
+USABLE_BYTES = int(15.75 * 2 ** 30)     # the compiler's own limit on v5e
+
+
+@pytest.fixture(scope="module")
+def medium():
+    """gpt2-medium and the cell's engine settings."""
+    import apex_tpu.nn as nn
+    from apex_tpu.models import GptModel
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "perfbench", "configs",
+                           "gpt2-medium.json")) as f:
+        cfg = json.load(f)
+    nn.manual_seed(0)
+    model = GptModel(
+        vocab_size=cfg["vocab_size"], hidden=cfg["n_embd"],
+        layers=cfg["n_layer"], heads=cfg["n_head"],
+        max_positions=cfg["n_positions"], dropout=0.0, attn_dropout=0.0,
+        attn_bias=cfg["attn_bias"]).to(
+            jnp.dtype(cfg["serve"]["weights_dtype"]))
+    model.eval()
+    return cfg, model
+
+
+def _serve_program(chip, medium, which, batch):
+    from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import init_pool_buffer
+    cfg, model = medium
+    sv = cfg["serve"]
+    heads = cfg["n_head"]
+    params = list(model.parameters()) + list(model.buffers())
+    vals = _on(chip, [p.data for p in params])
+    pool = _on(chip, jax.eval_shape(lambda: init_pool_buffer(
+        cfg["n_layer"], heads, cfg["n_embd"] // heads, sv["num_blocks"],
+        sv["block_size"], jnp.dtype(sv["cache_dtype"]))))
+    nb = cfg["n_positions"] // sv["block_size"]         # a full context
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, chip)
+    build = {"decode": serve_kernels.build_decode_fn,
+             "prefill": serve_kernels.build_prefill_fn}[which]
+    fn = build(model, params, sv["block_size"], sv["num_blocks"])
+    args = (i32(batch), i32(batch), i32(batch, nb)) if which == "decode" \
+        else (i32(1, sv["prefill_chunk"]), i32(1, nb), i32(), i32())
+    with force_mode("compiled"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            vals, pool, *args).compile()
+    return compiled, pool
+
+
+def _pool_sized_results(compiled, pool):
+    """``[(opcode, line)]`` of every instruction whose result is an
+    array at least as large as the pool."""
+    head = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                      r"([\w\-]+)\(")
+    out = []
+    for ln in compiled.as_text().splitlines():
+        m = head.match(ln)
+        if not m:
+            continue
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d)
+        if n >= pool.size:
+            out.append((m.group(2), ln))
+    return out
+
+
+@pytest.mark.parametrize("which,batch", [
+    ("decode", 16), ("decode", 32), ("prefill", 1)],
+    ids=["decode_b16", "decode_b32", "prefill_chunk512"])
+def test_serve_programs_take_the_pool_where_it_lies(chip, medium, which,
+                                                    batch):
+    compiled, pool = _serve_program(chip, medium, which, batch)
+    ma = _check(compiled, 0)
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert ma.alias_size_in_bytes >= pool_bytes         # updated in place
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    # max_batch 32 fits (it was refused over 2048 blocks at PR 25)
+    assert total < USABLE_BYTES
+    # the parent's decode program kept 9.02 GiB of temporaries at B=16
+    assert ma.temp_size_in_bytes < 2 ** 30, ma.temp_size_in_bytes / 2 ** 30
+    text = compiled.as_text()
+    for op, ln in _pool_sized_results(compiled, pool):
+        # a pool-sized result is the pool itself (a parameter, a view of
+        # it) or a row write: a scatter on the aliased buffer, alone or
+        # as a fusion's body (out of place it would be 3 GiB of
+        # temporaries, refused above)
+        assert op in ("parameter", "bitcast", "scatter", "fusion"), ln[:300]
+        if op == "fusion":
+            body = _called_computation(text, ln)
+            assert " scatter(" in body, ln[:300]
+
+
+def _called_computation(text, fusion_line):
+    """The body of the computation a fusion instruction calls."""
+    name = re.search(r"calls=(%[\w.\-]+)", fusion_line).group(1)
+    start = text.index(f"\n{name} ")
+    return text[start:text.index("\n}", start)]
+
+
+def test_decode_program_reads_through_the_block_table(chip, medium):
+    """The table-reading kernel is in every layer of the cell's decode
+    program under its own name (one trace and one lowering shared by
+    the layers: the layer is an operand), and no gather of the tables'
+    blocks is left beside it."""
+    compiled, pool = _serve_program(chip, medium, "decode", 16)
+    cfg, _ = medium
+    calls = _kernel_calls(compiled)
+    assert sum("paged_attention_decode" in c for c in calls) \
+        == cfg["n_layer"], calls
+    # gather_kv reads through a flat (layers*2*num_blocks, ...) view of
+    # the pool: with the kernel in, nothing takes that view
+    flat = pool.shape[0] * pool.shape[1] * pool.shape[2]
+    assert f"[{flat},{pool.shape[3]},{pool.shape[4]}]" \
+        not in compiled.as_text()
 
 
 def test_fused_train_step_compiles(chip):
@@ -155,8 +296,7 @@ def test_fused_train_step_compiles(chip):
     _check(compiled, 3 * LAYERS)
     # the kernels go by their own names in the device trace: the custom
     # calls' instruction names come from ``pallas_call(name=...)``
-    calls = [ln.split("=")[0] for ln in compiled.as_text().splitlines()
-             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    calls = _kernel_calls(compiled)
     for kernel in ("flash_attn_fwd", "flash_attn_bwd_dq",
                    "flash_attn_bwd_dkv"):
         assert sum(kernel in c for c in calls) == LAYERS, (kernel, calls)

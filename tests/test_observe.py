@@ -171,7 +171,7 @@ def test_each_event_name_has_its_own_ring():
         reg.set_event_capacity("span", 0)
     # the process-wide registry keeps SPAN_RING span records
     from apex_tpu.observe import spans
-    assert spans.SPAN_RING == 32768
+    assert spans.SPAN_RING == 131072
     get_registry().clear_events()
     with span("t.ring"):
         pass
