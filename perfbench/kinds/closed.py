@@ -6,6 +6,17 @@ from __future__ import annotations
 from pb import serve_common, serve_loop, traffic
 
 
+def tiny(mix: dict, limits: dict) -> tuple:
+    """A mix of this kind and its cell's limits at sizes a CPU test can
+    hold (``tests/perfbench/pb_tiny.py``): float32 serving picks the
+    reference's own token everywhere there."""
+    mix = dict(mix, prompt={"dist": "uniform", "lo": 6, "hi": 40},
+               output={"dist": "uniform", "lo": 4, "hi": 12},
+               max_total=96, warm_prompt_lens=[9, 30],
+               warm_prefill_lens=[], cycle=8)
+    return mix, dict(limits, served_sq_gap_per_close_call=1e-9)
+
+
 class _Source:
     """Requests in cycles of stratified lengths, drawn as needed."""
 
@@ -25,7 +36,7 @@ class _Source:
 
 def run(cell, args, env, fault=None, eng=None):
     cfg, mix = cell.config, cell.traffic
-    vocab = cfg["vocab_size"]
+    vocab = cell.family.vocab(cfg)
     max_batch = cfg["serve"]["max_batch"]
     clients = int(mix["clients_per_slot"] * max_batch)
     source = _Source(mix, args.seed, vocab)

@@ -9,9 +9,22 @@ from __future__ import annotations
 
 import collections
 
-from pb import correct, reference, sut, traffic, weights
+from pb import correct, sut, traffic, weights
 
 CHECK_STEPS = 3
+
+
+def tiny(mix: dict, limits: dict) -> tuple:
+    """A mix of this kind and its cell's limits at sizes a CPU test can
+    hold (``tests/perfbench/pb_tiny.py``).  The committed limits are the
+    chip's, set from readings at the cells' own sizes; at these the
+    bf16 step on the CPU reads losses to 2e-5, the first gradient to
+    3e-3 (median leaf 4e-4) and the change to 1e-2 (median leaf 4e-4)."""
+    mix = dict(mix, global_batch=8, seq_len=32, reference_block_rows=4)
+    limits = {"loss1_gap": 5e-4, "loss2_gap": 5e-4, "loss3_gap": 5e-4,
+              "grad1_gap": 0.02, "grad1_median_gap": 1e-3,
+              "delta3_gap": 0.05, "delta3_median_gap": 1e-3}
+    return mix, limits
 
 
 def _leaf_norm_fn():
@@ -25,25 +38,26 @@ def _leaf_norm_fn():
     return norms
 
 
-def _delta_norm_fn(cfg, seed, dtype="float32"):
+def _delta_norm_fn(family, cfg, seed, dtype="float32"):
     """Per-leaf ``||p - p0||`` with ``p0`` redrawn from the seed inside
     the same program, so no second copy of the weights is kept."""
     import jax
     import jax.numpy as jnp
-    convert = sut.layout_converter(cfg["n_layer"], cfg["n_head"])
+    convert = family.to_program(cfg)
 
     @jax.jit
     def norms(key, leaves):
-        p0 = convert(weights._draw(cfg, key, jnp.dtype(dtype)))
+        p0 = convert(family.draw(cfg, key, jnp.dtype(dtype)))
         return [jnp.sqrt(jnp.sum(jnp.square(a - b)))
                 for a, b in zip(leaves, p0)]
     return lambda leaves: norms(weights.seed_key(seed), leaves)
 
 
-def drive_first_steps(cfg, seed, step, feed_iter, step_call=None):
+def drive_first_steps(cell, seed, step, feed_iter, step_call=None):
     """The program's readings over its first CHECK_STEPS steps."""
     import jax
-    names = sut.program_leaf_names(cfg)
+    cfg, family = cell.config, cell.family
+    names = family.program_leaf_names(cfg)
     b1 = cfg["train"]["betas"][0]
     norm_fn = _leaf_norm_fn()
     call = step_call or (lambda x, y: step(x, y))
@@ -54,17 +68,21 @@ def drive_first_steps(cfg, seed, step, feed_iter, step_call=None):
         if i == 0:
             m = jax.device_get(norm_fn(sut.adam_first_moment(step)))
             g1 = {n: float(v) / (1.0 - b1) for n, v in zip(names, m)}
-    d = jax.device_get(_delta_norm_fn(cfg, seed)(sut.master_params(step)))
+    d = jax.device_get(
+        _delta_norm_fn(family, cfg, seed)(sut.master_params(step)))
     return {"losses": losses, "grad1_norms": g1,
             "delta_norms": {n: float(v) for n, v in zip(names, d)}}
 
 
-def reference_readings(cfg, mix, seed, quant=None):
+def reference_readings(cell, seed, quant=None):
+    """The same steps by the configuration's plain reference (with
+    ``quant``, by the control: one precision down)."""
     import jax.numpy as jnp
-    w0 = weights.make_weights(cfg, seed, "float32")
+    cfg, mix, family = cell.config, cell.traffic, cell.family
+    w0 = weights.make_weights(family, cfg, seed, "float32")
     batches = [jnp.asarray(b) for b in traffic.first_train_batches(
-        mix, seed, cfg["vocab_size"], CHECK_STEPS)]
-    return reference.train_reference(
+        mix, seed, family.vocab(cfg), CHECK_STEPS)]
+    return cell.reference.train_reference(
         cfg, w0, batches, block_rows=mix.get("reference_block_rows", 4),
         quant=quant)
 
@@ -98,15 +116,16 @@ def run(cell, args, env, step_call_wrapper=None):
     env.say(f"train: {cell.config_name} {mix['global_batch']} x "
             f"{mix['seq_len']} tokens a step, parallel={parallel} over "
             f"{len(devices)} chip(s), in flight {mix['in_flight']}")
-    step, mesh = sut.build_train_step(cfg, args.seed, parallel, devices)
+    step, mesh = sut.build_train_step(cell.family, cfg, args.seed, parallel,
+                                      devices)
     where = NamedSharding(mesh, P("data")) if mesh is not None \
         else devices[0]
     feed = sut.train_feed(
-        traffic.train_batches(mix, args.seed, cfg["vocab_size"]), where)
+        traffic.train_batches(mix, args.seed, cell.family.vocab(cfg)), where)
     feed_iter = iter(feed)
     call = step_call_wrapper(step) if step_call_wrapper else None
     try:
-        prog = drive_first_steps(cfg, args.seed, step, feed_iter, call)
+        prog = drive_first_steps(cell, args.seed, step, feed_iter, call)
         env.say("train: first losses " + " ".join(
             f"{l:.5f}" for l in prog["losses"]))
         call = call or step
@@ -142,7 +161,7 @@ def run(cell, args, env, step_call_wrapper=None):
             f"{peak['in_use']} (peak {peak['peak_in_use']}) + reserved "
             f"peak {peak['reserved']}")
     del step, call, feed, feed_iter            # free the program's state
-    ref = reference_readings(cfg, mix, args.seed)
+    ref = reference_readings(cell, args.seed)
     numbers = correct.train_numbers(prog, ref)
     env.say(f"correct: reference losses " + " ".join(
         f"{l:.5f}" for l in ref["losses"]) + f"; worst gradient leaf "

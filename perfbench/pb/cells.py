@@ -9,10 +9,14 @@ per-layer metric is a file of its own:
     perfbench/workloads/<cell>.json    the cell's limits for ``correct``
     perfbench/metrics/<metric>.json    which reader computes the metric
 
-so a later PR adds a cell or a metric by adding files and entries.
-Traffic kinds (``perfbench/kinds/<kind>.py``) and metric readers
-(``perfbench/readers/<module>.py``) are looked up by the name the data
-file gives, never switched on a cell's name.
+so a later PR adds a cell, a metric or a model by adding files and
+entries.  Traffic kinds (``perfbench/kinds/<kind>.py``), metric readers
+(``perfbench/readers/<module>.py``) and model families
+(``perfbench/families/<builder>.py``) are looked up by the name the
+data file gives, never switched on a cell's name.  A configuration file
+names its family (``builder``) and its plain reference (``reference``,
+a path from the root of the checkout); this module is the one place
+that reads either key.
 """
 from __future__ import annotations
 
@@ -58,6 +62,25 @@ class Cell:
             bdir, "workloads", name + ".json"))
         self.kind = self.traffic["kind"]
 
+    @property
+    def family(self):
+        """What knows this configuration's model: the module
+        ``perfbench/families/<builder>.py`` (sizes, weights, the
+        program's model, counts)."""
+        return family_module(self.config.get("builder"), self.repo)
+
+    @property
+    def reference(self):
+        """The configuration's plain reference, the file its
+        ``reference`` key names: ``train_reference`` and
+        ``served_token_gaps``.  It imports nothing of the program."""
+        path = self.config.get("reference")
+        if not path or not os.path.isfile(os.path.join(self.repo, path)):
+            raise SystemExit(
+                f"perfbench: configuration {self.config_name} names the "
+                f"reference {path!r}, which is no file of the checkout")
+        return _module_from(os.path.join(self.repo, path), "reference")
+
     def _reports(self, metric: dict) -> bool:
         return "workloads" not in metric or self.name in metric["workloads"]
 
@@ -75,14 +98,9 @@ class Cell:
 _MODULES = {}
 
 
-def _module_at(repo: str, folder: str, name: str):
-    """Load ``<repo>/perfbench/<folder>/<name>.py`` by its path, so that
-    a file a later PR adds is found with no edit to a list."""
-    path = os.path.join(repo, "perfbench", folder, name + ".py")
+def _module_from(path: str, what: str):
     if path not in _MODULES:
-        if not os.path.exists(path):
-            raise SystemExit(f"perfbench: no {folder[:-1]} file {path}")
-        tag = f"perfbench_{folder}_{name}_{len(_MODULES)}"
+        tag = f"perfbench_{what}_{len(_MODULES)}"
         spec = importlib.util.spec_from_file_location(tag, path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
@@ -90,10 +108,34 @@ def _module_at(repo: str, folder: str, name: str):
     return _MODULES[path]
 
 
+def _module_at(repo: str, folder: str, name: str):
+    """Load ``<repo>/perfbench/<folder>/<name>.py`` by its path, so that
+    a file a later PR adds is found with no edit to a list."""
+    path = os.path.join(repo, "perfbench", folder, name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"perfbench: no {folder[:-1]} file {path}")
+    return _module_from(path, f"{folder}_{name}")
+
+
 def kind_module(kind: str, repo: str = REPO):
     """``perfbench/kinds/<kind>.py``, which has
     ``run(cell, args, env) -> dict``."""
     return _module_at(repo, "kinds", kind)
+
+
+def family_module(builder, repo: str = REPO):
+    """``perfbench/families/<builder>.py``: one model family's sizes,
+    seeded weights, program model and counts (``families/gpt.py`` says
+    what a family file provides)."""
+    fdir = os.path.join(repo, "perfbench", "families")
+    if not builder or not os.path.exists(
+            os.path.join(fdir, f"{builder}.py")):
+        have = sorted(f[:-3] for f in os.listdir(fdir) if f.endswith(".py"))
+        raise SystemExit(
+            f"perfbench: a configuration's `builder` has to name a file "
+            f"of perfbench/families/; it says {builder!r} and the "
+            f"families there are {have}")
+    return _module_at(repo, "families", builder)
 
 
 def metric_reader(name: str, repo: str = REPO):

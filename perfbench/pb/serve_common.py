@@ -3,7 +3,7 @@ the configuration's settings, warming the shapes the mix reaches, the
 window, its numbers, and the comparison with the reference."""
 from __future__ import annotations
 
-from . import correct, reference, serve_loop, sut, traffic, weights
+from . import cells, correct, serve_loop, sut, traffic, weights
 from .runenv import percentile
 
 
@@ -11,20 +11,38 @@ from .runenv import percentile
 REF_ROWS = 4
 
 
-def served_gaps(cfg, seed, samples, control=None):
+def reference_sample(samples, n, seed):
+    """The requests the reference goes over: all of them, or, where the
+    cell's settings say ``reference_sample: n``, the longest and
+    ``n - 1`` others drawn from the seed."""
+    if n is None or n >= len(samples):
+        return samples
+    longest = max(range(len(samples)),
+                  key=lambda i: len(samples[i][0]) + len(samples[i][1]))
+    others = [i for i in range(len(samples)) if i != longest]
+    rng = traffic.rng_for(seed, "reference_sample")
+    keep = sorted([longest] + [others[i] for i in rng.permutation(
+        len(others))[:max(0, n - 1)]])
+    return [samples[i] for i in keep]
+
+
+def served_gaps(cell, seed, samples, control=None):
     """``(gaps, margins)``, one entry for every served token of every
-    sampled request ``(prompt, served)``: the gap by which the served
-    token's float32 reference logit lies below the reference's best at
-    that position (0 where it is the reference's own choice), and the
-    margin of the reference's best over its second best there.  With
-    ``control`` the tokens judged are the lower-precision reference's
-    own first choices at the same positions of the same prompts and
-    tokens."""
+    request ``(prompt, served)`` of ``samples``: the gap by which the
+    served token's float32 reference logit lies below the reference's
+    best at that position (0 where it is the reference's own choice),
+    and the margin of the reference's best over its second best there.
+    With ``control`` the tokens judged are the lower-precision
+    reference's own first choices at the same positions of the same
+    prompts and tokens."""
     import jax.numpy as jnp
     import numpy as np
-    w = weights.make_weights(cfg, seed, cfg["serve"]["weights_dtype"])
-    w = {k: v.astype(jnp.float32) for k, v in w.items()}
-    s_max = cfg["n_positions"]
+    cfg, family = cell.config, cell.family
+    # the benchmark's leaves as they are served; the reference widens
+    # them itself, all at once or layer by layer
+    w = weights.make_weights(family, cfg, seed, cfg["serve"]["weights_dtype"])
+    served_token_gaps = cell.reference.served_token_gaps
+    s_max = family.max_positions(cfg)
     gaps, margins = [], []
     for r in range(0, len(samples), REF_ROWS):
         block = samples[r:r + REF_ROWS]
@@ -37,7 +55,7 @@ def served_gaps(cfg, seed, samples, control=None):
             padded[i, :len(ids)] = ids
             picked[i, n_p - 1:n_p - 1 + n_out] = ids[n_p:]
             spans.append((n_p - 1, n_p - 1 + n_out))
-        g, m = (np.asarray(x) for x in reference.served_token_gaps(
+        g, m = (np.asarray(x) for x in served_token_gaps(
             cfg, w, jnp.asarray(padded), jnp.asarray(picked), control))
         gaps += [g[i, a:b] for i, (a, b) in enumerate(spans)]
         margins += [m[i, a:b] for i, (a, b) in enumerate(spans)]
@@ -112,7 +130,7 @@ def run(cell, args, env, lead_in, drive, fault, eng=None):
             f"{k}={sv[k]}" for k in (
                 "weights_dtype", "cache_dtype", "block_size", "num_blocks",
                 "max_batch", "prefill_chunk", "prefix_cache", "draft")))
-        eng = sut.build_engine(cfg, args.seed)
+        eng = sut.build_engine(cell.family, cfg, args.seed)
     else:
         eng.results.clear()             # request ids repeat from seed to seed
     loop = serve_loop.Loop(eng, env)
@@ -121,7 +139,7 @@ def run(cell, args, env, lead_in, drive, fault, eng=None):
     if own:
         t = env.now()
         ticks = serve_loop.warm_waves(
-            loop, mix, cfg["vocab_size"], sv["max_batch"],
+            loop, mix, cell.family.vocab(cfg), sv["max_batch"],
             traffic.rng_for(args.seed, "warm"))
         env.say(f"warm-up: prompt lengths {mix['warm_prompt_lens']} in "
                 f"waves, {mix.get('warm_prefill_lens', [])} prefill only; "
@@ -202,6 +220,11 @@ def run(cell, args, env, lead_in, drive, fault, eng=None):
     env.say(tick_report(ticks_w, window) + f"; collector pauses "
             f"{len(pauses)}, {sum(d for _, d in pauses) * 1e3:.1f} ms, "
             f"longest {max([d for _, d in pauses] or [0.0]) * 1e3:.1f} ms")
+    if not args.trace:
+        # a traced run's span readers print this themselves
+        tree = cells._module_at(cell.repo, "readers", "spans") \
+            .longest_tick_line(ticks_w)
+        env.say(f"longest tick by the program's spans: {tree}")
     out["counters"].update({
         "chips": cell.chips, "ticks": ticks_w,
         "compiles_in_window": sum(tk["compiles"] for tk in ticks_w),
@@ -209,10 +232,13 @@ def run(cell, args, env, lead_in, drive, fault, eng=None):
         "module_prefixes": ["jit_fn("],
         "tokens_per_s": tokens / window,
     })
-    # every token served to the window's requests is compared: the
-    # finished answers whole, the others as far as they got
-    samples = [(tr.prompt, loop.served(tr)) for tr in counted
-               if tr.token_times]
+    # every token served to the window's requests is compared, or to a
+    # seeded sample of them: the finished answers whole, the others as
+    # far as they got
+    served = [(tr.prompt, loop.served(tr)) for tr in counted
+              if tr.token_times]
+    samples = reference_sample(served, st.get("reference_sample"),
+                               args.seed)
     out["samples"] = samples
     if own:
         eng.close()
@@ -222,11 +248,12 @@ def run(cell, args, env, lead_in, drive, fault, eng=None):
     else:
         eng.close()         # drops what is live and queued; stays usable
     t = env.now()
-    gaps, margins = served_gaps(cfg, args.seed, samples)
+    gaps, margins = served_gaps(cell, args.seed, samples)
     out["gaps"], out["margins"] = gaps, margins
-    env.say(f"correct: {len(samples)} request(s), {len(finished)} of them "
-            f"finished, {len(gaps)} served tokens against the float32 "
-            f"reference in {env.now() - t:.1f} s; {len(wrong)} of "
+    env.say(f"correct: {len(samples)} of {len(served)} request(s), "
+            f"{len(finished)} finished in all, {len(gaps)} served tokens "
+            f"against the float32 reference in {env.now() - t:.1f} s; "
+            f"{len(wrong)} of "
             f"{len(finished)} answers of the wrong length; "
             + gap_report(gaps, margins))
     numbers = dict(gap_numbers(gaps, margins),

@@ -1,13 +1,13 @@
-"""The system under test: the one file of the benchmark that imports
-``apex_tpu``.  It builds the program's own objects (``GptModel``,
-``make_train_step``, ``ServeEngine``) through their public entries,
-puts the benchmark's seeded weights into them, and reads the program's
-counters.  Everything it measures with lives elsewhere under
-``perfbench/``.
+"""The system under test: with the families' ``model`` functions
+(``perfbench/families/``), the one place of the benchmark that imports
+``apex_tpu``.  What is the same for every family is here: the compile
+cache, ``make_train_step`` with the configuration's recipe,
+``ServeEngine`` with the configuration's ``serve`` block, the feed, the
+seeded weights put into the family's model, the program's counters.
+Everything it measures with lives elsewhere under ``perfbench/``.
 """
 from __future__ import annotations
 
-import functools
 import os
 import sys
 
@@ -28,65 +28,20 @@ def enable_compile_cache():
     return compile_cache.enable()
 
 
-# -- weights: benchmark layout -> program layout ---------------------------
+# -- the family's model with the seeded weights in it --------------------------
 
 
-def program_leaf_names(cfg) -> list:
-    """The benchmark's leaf name for each entry of
-    ``list(model.parameters())``, in the program's order."""
-    names = ["wte", "wpe"]
-    for i in range(cfg["n_layer"]):
-        h = f"h.{i}."
-        names += [h + "ln_1.g", h + "ln_1.b", h + "attn.c_attn.w",
-                  h + "attn.c_proj.w", h + "ln_2.g", h + "ln_2.b",
-                  h + "mlp.c_fc.w", h + "mlp.c_fc.b",
-                  h + "mlp.c_proj.w", h + "mlp.c_proj.b"]
-    return names + ["ln_f.g", "ln_f.b"]
-
-
-@functools.lru_cache(maxsize=None)
-def layout_converter(n_layer: int, n_head: int):
-    cfg = {"n_layer": n_layer}
-    names = program_leaf_names(cfg)
-
-    def convert(leaves):
-        out = []
-        for name in names:
-            x = leaves[name]
-            if name.endswith("c_attn.w"):
-                # (E, 3E) q|k|v, heads major -> the program's rows
-                # interleaved [head, (q, k, v), d]: (3E, E)
-                e = x.shape[0]
-                d = e // n_head
-                x = x.reshape(e, 3, n_head, d).transpose(2, 1, 3, 0) \
-                     .reshape(3 * e, e)
-            elif name.endswith(".w"):
-                x = x.T                # Conv1D (in, out) -> Linear (out, in)
-            out.append(x)
-        return out
-    return convert
-
-
-def program_weights(cfg, seed: int, dtype):
+def program_weights(family, cfg, seed: int, dtype):
     """The seeded weights as the program's parameter list, one jitted
     call on the device."""
-    return _weights.make_weights(
-        cfg, seed, dtype, convert=layout_converter(cfg["n_layer"], cfg["n_head"]))
+    return _weights.make_weights(family, cfg, seed, dtype,
+                                 convert=family.to_program(cfg))
 
 
-def build_model(cfg, seed: int, dtype, **kw):
-    from apex_tpu.models import GptModel
-    if cfg["embd_pdrop"] != cfg["resid_pdrop"]:
-        raise ValueError("the program has one dropout rate for embeddings "
-                         "and residuals")
-    model = GptModel(
-        vocab_size=cfg["vocab_size"], hidden=cfg["n_embd"],
-        layers=cfg["n_layer"], heads=cfg["n_head"],
-        intermediate=cfg.get("n_inner"),
-        max_positions=cfg["n_positions"], dropout=cfg["resid_pdrop"],
-        attn_dropout=cfg["attn_pdrop"], attn_bias=cfg["attn_bias"], **kw)
+def build_model(family, cfg, seed: int, dtype, **kw):
+    model = family.model(cfg, **kw)
     params = list(model.parameters())
-    vals = program_weights(cfg, seed, dtype)
+    vals = program_weights(family, cfg, seed, dtype)
     if len(vals) != len(params):
         raise RuntimeError(
             f"the program's model has {len(params)} parameters, the "
@@ -102,7 +57,7 @@ def build_model(cfg, seed: int, dtype, **kw):
 # -- training --------------------------------------------------------------
 
 
-def build_train_step(cfg, seed: int, parallel: str, devices):
+def build_train_step(family, cfg, seed: int, parallel: str, devices):
     """The program's fused step as the configuration's recipe states it
     (``chip_smoke._lm_step``): bf16 compute, FusedAdam, chunked LM
     loss.  ``parallel``: "single", or "dp" for pure data parallelism
@@ -118,11 +73,11 @@ def build_train_step(cfg, seed: int, parallel: str, devices):
     from apex_tpu.training import make_train_step
 
     tr = cfg["train"]
-    model = build_model(cfg, seed, jnp.float32, output_hidden=True)
+    model = build_model(family, cfg, seed, jnp.float32, output_hidden=True)
     opt = FusedAdam(list(model.parameters()), lr=tr["lr"],
                     betas=tuple(tr["betas"]), eps=tr["eps"],
                     weight_decay=tr["weight_decay"])
-    loss_fn = make_chunked_lm_loss(vocab_size=cfg["vocab_size"],
+    loss_fn = make_chunked_lm_loss(vocab_size=family.vocab(cfg),
                                    padding_idx=-1)
     kw = {}
     mesh = None
@@ -165,7 +120,7 @@ def adam_first_moment(step):
 # -- serving ---------------------------------------------------------------
 
 
-def build_engine(cfg, seed: int):
+def build_engine(family, cfg, seed: int):
     import jax.numpy as jnp
 
     from apex_tpu.serve import ServeEngine
@@ -174,7 +129,7 @@ def build_engine(cfg, seed: int):
     if sv.get("draft") is not None:
         raise ValueError("the benchmark serves without a draft model")
     dtype = jnp.dtype(sv["weights_dtype"])
-    model = build_model(cfg, seed, dtype)
+    model = build_model(family, cfg, seed, dtype)
     model.eval()
     return ServeEngine(
         model, num_blocks=sv["num_blocks"], block_size=sv["block_size"],
@@ -183,12 +138,13 @@ def build_engine(cfg, seed: int):
         prefix_cache=sv["prefix_cache"])
 
 
-def publish_weights(eng, cfg, seed: int) -> None:
+def publish_weights(eng, family, cfg, seed: int) -> None:
     """Swap another seed's weights into a running engine through its
     own hot-swap entry (no program is rebuilt; the prefix cache is
     flushed)."""
     import jax.numpy as jnp
-    vals = program_weights(cfg, seed, jnp.dtype(cfg["serve"]["weights_dtype"]))
+    vals = program_weights(family, cfg, seed,
+                           jnp.dtype(cfg["serve"]["weights_dtype"]))
     eng.publish_weights(vals)
 
 

@@ -1,6 +1,6 @@
 """Whole-step shares of the chip's peak: model FLOPs the algorithm
-needs (``pb.counts``) over the time the step had, over the peak."""
-from pb import counts
+needs (the cell's family counts them) over the time the step had, over
+the peak."""
 
 
 def train_mfu(ctx):
@@ -9,7 +9,8 @@ def train_mfu(ctx):
     c = ctx["counters"]
     if not ctx["peaks"]:
         return None
-    flops = counts.train_flops_per_token(ctx["cfg"], ctx["mix"]["seq_len"])
+    flops = ctx["family"].train_flops_per_token(ctx["cfg"],
+                                                ctx["mix"]["seq_len"])
     return 100.0 * flops * c["tokens_per_s"] / (
         c["chips"] * ctx["peaks"]["bf16_flops_per_s"])
 
@@ -22,6 +23,6 @@ def decode_step_mfu(ctx):
              if "decode_step" in tk["dispatches"]]
     if not ticks or not ctx["peaks"]:
         return None
-    flops = sum(counts.forward_flops(ctx["cfg"], tk["decode_batch"],
-                                     tk["kv_tokens"]) for tk in ticks)
+    flops = sum(ctx["family"].decode_step_flops(ctx["cfg"], tk)
+                for tk in ticks)
     return 100.0 * flops / ctx["window_s"] / ctx["peaks"]["bf16_flops_per_s"]

@@ -108,6 +108,25 @@ def step_span_ms(ctx, span, kind=None):
     return percentile([_ms(r) for r in recs[-n:]], 50)
 
 
+def longest_tick_line(ticks):
+    """For an untraced run: the span tree of the longest of ``ticks``
+    (the harness's tick records), so that a run which stood still names
+    the span it stood in.  ``None`` where the program keeps no records
+    or the ring has lost that tick."""
+    from apex_tpu.observe import spans
+    recorded = getattr(spans, "recorded", None)
+    if not ticks or recorded is None:
+        return None
+    tk = max(ticks, key=lambda t: t["t1"] - t["t0"])
+    lo, hi = tk["t0"] * 1e9, tk["t1"] * 1e9
+    recs = [r for r in recorded(int(lo))
+            if lo <= r["t0_ns"] and r["t1_ns"] <= hi]
+    root = next((r for r in recs if r["span"] == "serve.step"), None)
+    if root is None:
+        return None
+    return tree_line(root, children_of(recs))
+
+
 def _say(msg: str) -> None:
     print(f"[perfbench spans] {msg}", flush=True)
 

@@ -55,7 +55,7 @@ def metrics_of(cell, args, result, env) -> dict:
         return {m["name"]: {"value": result["end_to_end"][m["name"]],
                             "unit": m["unit"]}
                 for m in cell.end_to_end}
-    ctx = {"cfg": cell.config, "mix": cell.traffic,
+    ctx = {"cfg": cell.config, "family": cell.family, "mix": cell.traffic,
            # main() has refused an unlisted TPU already; off the chip
            # (tests) there are no peaks and the readers of shares of a
            # peak find nothing to read

@@ -46,30 +46,30 @@ def train_readings(cell, seeds, n_faults, env):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from pb import traffic
     train = cells.kind_module("train", cell.repo)
-    cfg, mix = cell.config, cell.traffic
+    cfg, mix, family = cell.config, cell.traffic, cell.family
     devices = list(env.devices[:cell.chips])
     rows = []
     for k, seed in enumerate(seeds):
-        ref = train.reference_readings(cfg, mix, seed)
+        ref = train.reference_readings(cell, seed)
         row = {"seed": seed}
 
         def program(wrapper=None):
-            step, mesh = sut.build_train_step(cfg, seed, mix["parallel"],
-                                              devices)
+            step, mesh = sut.build_train_step(family, cfg, seed,
+                                              mix["parallel"], devices)
             where = NamedSharding(mesh, P("data")) if mesh is not None \
                 else devices[0]
             feed = sut.train_feed(traffic.train_batches(
-                mix, seed, cfg["vocab_size"]), where)
+                mix, seed, family.vocab(cfg)), where)
             try:
                 return train.drive_first_steps(
-                    cfg, seed, step, iter(feed),
+                    cell, seed, step, iter(feed),
                     wrapper(step) if wrapper else None)
             finally:
                 feed.close()
         row["program"] = correct.train_numbers(program(), ref)
         for q in ("int8", "fp8"):
             row["control_" + q] = correct.train_numbers(
-                train.reference_readings(cfg, mix, seed, quant=q), ref)
+                train.reference_readings(cell, seed, quant=q), ref)
         if k < n_faults:
             row["fault_half_batch"] = correct.train_numbers(
                 program(_rows(0.5)), ref)
@@ -105,19 +105,20 @@ def _serve_seeds(cell, seeds, seconds, env, controls, tag=""):
     ``chiprun_out/gaps_<cell><tag>_<seed>.npz``."""
     import numpy as np
     kind = cells.kind_module(cell.kind, cell.repo)
-    cfg, limits = cell.config, cell.settings["limits"]
+    cfg, family = cell.config, cell.family
+    limits = cell.settings["limits"]
     eng = None
     rows = []
     for seed in seeds:
         args = types.SimpleNamespace(seed=seed, seconds=seconds, trace=0)
         if eng is None:
-            eng = sut.build_engine(cfg, seed)
+            eng = sut.build_engine(family, cfg, seed)
             from pb import serve_loop, traffic
             serve_loop.warm_waves(
-                serve_loop.Loop(eng, env), cell.traffic, cfg["vocab_size"],
+                serve_loop.Loop(eng, env), cell.traffic, family.vocab(cfg),
                 cfg["serve"]["max_batch"], traffic.rng_for(seed, "warm"))
         else:
-            sut.publish_weights(eng, cfg, seed)
+            sut.publish_weights(eng, family, cfg, seed)
         result = kind.run(cell, args, env, eng=eng)
         arrays = {"margins": result["margins"], "program": result["gaps"]}
         row = {"seed": seed, "failed": result["failed"],
@@ -126,7 +127,7 @@ def _serve_seeds(cell, seeds, seconds, env, controls, tag=""):
                                    limits)}
         for q in controls:
             gaps, margins = serve_common.served_gaps(
-                cfg, seed, result["samples"], control=q)
+                cell, seed, result["samples"], control=q)
             arrays[q] = gaps
             row["control_" + q] = _gap_row(gaps, margins, limits)
         rows.append(row)

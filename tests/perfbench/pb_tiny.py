@@ -13,8 +13,9 @@ BENCH = os.path.join(REPO, "perfbench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-TINY_MODEL = dict(n_embd=64, n_layer=2, n_head=4, vocab_size=211,
-                  n_positions=128, n_ctx=128)
+#: the engine's settings at sizes a CPU test can hold: the same keys of
+#: the ``serve`` block whatever the family
+TINY_SERVE = dict(num_blocks=96, block_size=4, max_batch=4, prefill_chunk=32)
 
 
 def _load(path):
@@ -28,53 +29,61 @@ def _dump(path, data):
         json.dump(data, f, indent=1)
 
 
-def make_repo(tmp, float32=True):
-    """Copy BENCHMARK.json and the data files into ``tmp`` with every
-    size cut down; code is not copied (kinds and readers are copied so
-    that a test can add one beside them)."""
+def tiny_config(cfg, repo, float32=True):
+    """``cfg`` at the sizes its own family gives for a CPU test."""
+    from pb import cells
+    cfg = dict(cfg)
+    cfg.update(cells.family_module(cfg.get("builder"), repo).tiny(cfg))
+    if "serve" in cfg:
+        cfg["serve"] = dict(cfg["serve"], **TINY_SERVE)
+        if float32:
+            cfg["serve"].update(weights_dtype="float32",
+                                cache_dtype="float32")
+    return cfg
+
+
+def make_repo(tmp, float32=True, source=REPO):
+    """Copy ``source``'s BENCHMARK.json and data files into ``tmp`` with
+    every size cut down: each configuration by its family's ``tiny()``,
+    each mix and each cell's limits by their kind's.  Code is not
+    copied (kinds, readers, families and the references the
+    configurations name are, so that a test can add one beside them)."""
+    from pb import cells
     tmp = str(tmp)
-    bench = _load(os.path.join(REPO, "BENCHMARK.json"))
+    bdir = os.path.join(source, "perfbench")
+    bench = _load(os.path.join(source, "BENCHMARK.json"))
     _dump(os.path.join(tmp, "BENCHMARK.json"), bench)
-    for folder in ("kinds", "readers", "metrics", "workloads"):
-        shutil.copytree(os.path.join(BENCH, folder),
-                        os.path.join(tmp, "perfbench", folder))
-    # limits for the tiny sizes (the committed ones are the chip's, set
-    # from readings at the cells' own sizes): float32 serving picks the
-    # reference's own token everywhere here, and the bf16 step on the CPU reads losses to 2e-5, the
-    # first gradient to 3e-3 (median leaf 4e-4) and the change to 1e-2
-    # (median leaf 4e-4)
-    wdir = os.path.join(tmp, "perfbench", "workloads")
-    for name in os.listdir(wdir):
-        st = _load(os.path.join(wdir, name))
-        if "served_sq_gap_per_close_call" in st["limits"]:
-            st["limits"]["served_sq_gap_per_close_call"] = 1e-9
-        else:
-            st["limits"] = {"loss1_gap": 5e-4, "loss2_gap": 5e-4,
-                            "loss3_gap": 5e-4, "grad1_gap": 0.02,
-                            "grad1_median_gap": 1e-3, "delta3_gap": 0.05,
-                            "delta3_median_gap": 1e-3}
-        st["trace_seconds"] = 1.0
-        _dump(os.path.join(wdir, name), st)
+    for folder in ("kinds", "readers", "families", "metrics", "workloads"):
+        shutil.copytree(os.path.join(bdir, folder),
+                        os.path.join(tmp, "perfbench", folder),
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for conf in bench["configs"]:
-        cfg = _load(os.path.join(REPO, conf["file"]))
-        cfg.update(TINY_MODEL)
-        if "serve" in cfg:
-            cfg["serve"].update(num_blocks=96, block_size=4, max_batch=4,
-                                prefill_chunk=32)
-            if float32:
-                cfg["serve"].update(weights_dtype="float32",
-                                    cache_dtype="float32")
-        _dump(os.path.join(tmp, conf["file"]), cfg)
-    for name in os.listdir(os.path.join(BENCH, "traffic")):
-        mix = _load(os.path.join(BENCH, "traffic", name))
-        if mix["kind"] == "train":
-            mix.update(global_batch=8, seq_len=32, reference_block_rows=4)
-        else:
-            mix.update(prompt={"dist": "uniform", "lo": 6, "hi": 40},
-                       output={"dist": "uniform", "lo": 4, "hi": 12},
-                       max_total=96, warm_prompt_lens=[9, 30],
-                       warm_prefill_lens=[], cycle=8)
-        _dump(os.path.join(tmp, "perfbench", "traffic", name), mix)
+        cfg = _load(os.path.join(source, conf["file"]))
+        os.makedirs(os.path.dirname(os.path.join(tmp, cfg["reference"])),
+                    exist_ok=True)
+        shutil.copy(os.path.join(source, cfg["reference"]),
+                    os.path.join(tmp, cfg["reference"]))
+        _dump(os.path.join(tmp, conf["file"]), tiny_config(cfg, tmp, float32))
+    mixes = {name[:-len(".json")]: _load(os.path.join(bdir, "traffic", name))
+             for name in os.listdir(os.path.join(bdir, "traffic"))}
+    builders = {c["name"]: _load(os.path.join(tmp, c["file"]))["builder"]
+                for c in bench["configs"]}
+    for cell in bench["workloads"]:
+        mix = mixes[cell["traffic"]]
+        wfile = os.path.join(tmp, "perfbench", "workloads",
+                             cell["name"] + ".json")
+        st = _load(wfile)
+        tiny_mix, st["limits"] = cells.kind_module(mix["kind"], tmp).tiny(
+            mix, st["limits"])
+        # a family whose tiny model reads otherwise than the kind's
+        # limits allow says so itself
+        family = cells.family_module(builders[cell["config"]], tmp)
+        if hasattr(family, "tiny_limits"):
+            st["limits"] = family.tiny_limits(mix["kind"], st["limits"])
+        st["trace_seconds"] = 1.0
+        _dump(wfile, st)
+        _dump(os.path.join(tmp, "perfbench", "traffic",
+                           cell["traffic"] + ".json"), tiny_mix)
     return tmp
 
 

@@ -76,7 +76,8 @@ def _compiled_mode():
 def test_train_step_of_gpt2s_train_fits(one_chip):
     cell = _cell("gpt2s-train")
     mix = cell.traffic
-    step, _ = sut.build_train_step(cell.config, 0, "single", None)
+    step, _ = sut.build_train_step(cell.family, cell.config, 0, "single",
+                                   None)
     ids = jax.ShapeDtypeStruct((mix["global_batch"], mix["seq_len"]),
                                jnp.int32, sharding=one_chip)
     with _compiled_mode():
@@ -93,32 +94,38 @@ def test_train_step_of_gpt2s_train_fits(one_chip):
     assert total > 0.25 * 16e9
 
 
-def _serve_programs(cfg, one_chip):
+def _serve_programs(cell, cfg, one_chip):
+    """The engine's decode and prefill programs lowered at their largest
+    buckets, and the pool as the engine makes it: the pool's format is
+    the program's own (``init_pool_buffer``, as ``ServeEngine.__init__``
+    calls it), never spelled out here."""
     from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import init_pool_buffer
+    family = cell.family
     sv = cfg["serve"]
-    model = sut.build_model(cfg, 0, jnp.dtype(sv["weights_dtype"]))
+    model = sut.build_model(family, cfg, 0, jnp.dtype(sv["weights_dtype"]))
     model.eval()
     params = list(model.parameters()) + list(model.buffers())
     vals = _shaped([p.data for p in params], one_chip)
-    heads = cfg["n_head"]
-    pool = jax.ShapeDtypeStruct(
-        (cfg["n_layer"], 2, sv["num_blocks"], heads, sv["block_size"],
-         cfg["n_embd"] // heads), jnp.dtype(sv["cache_dtype"]),
-        sharding=one_chip)
+    attn = model.blocks[0].attn
+    pool = _shaped(jax.eval_shape(lambda: init_pool_buffer(
+        len(model.blocks), attn.num_heads, attn.head_dim, sv["num_blocks"],
+        sv["block_size"], jnp.dtype(sv["cache_dtype"]))), one_chip)
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    nb = cfg["n_positions"] // sv["block_size"]         # a full context
-    decode = jax.jit(serve_kernels.build_decode_fn(
-        model, params, sv["block_size"], sv["num_blocks"]),
-        donate_argnums=(1,)).lower(
-            vals, pool, i32(sv["max_batch"]), i32(sv["max_batch"]),
-            i32(sv["max_batch"], nb))
-    prefill = jax.jit(serve_kernels.build_prefill_fn(
-        model, params, sv["block_size"], sv["num_blocks"]),
-        donate_argnums=(1,)).lower(
-            vals, pool, i32(1, sv["prefill_chunk"]), i32(1, nb), i32(),
-            i32())
+    nb = family.max_positions(cfg) // sv["block_size"]  # a full context
+    with _compiled_mode():          # the kernels the chip would take
+        decode = jax.jit(serve_kernels.build_decode_fn(
+            model, params, sv["block_size"], sv["num_blocks"]),
+            donate_argnums=(1,)).lower(
+                vals, pool, i32(sv["max_batch"]), i32(sv["max_batch"]),
+                i32(sv["max_batch"], nb))
+        prefill = jax.jit(serve_kernels.build_prefill_fn(
+            model, params, sv["block_size"], sv["num_blocks"]),
+            donate_argnums=(1,)).lower(
+                vals, pool, i32(1, sv["prefill_chunk"]), i32(1, nb), i32(),
+                i32())
     return decode, prefill, pool
 
 
@@ -127,28 +134,41 @@ def test_serve_programs_of_gpt2_medium_fit(one_chip, which):
     """The engine settings in ``gpt2-medium.json`` (every gpt2-medium
     cell shares them): the largest bucket of each program, pool
     donated."""
-    cfg = _cell("gpt2m-serve-decode").config
-    decode, prefill, pool = _serve_programs(cfg, one_chip)
+    cell = _cell("gpt2m-serve-decode")
+    cfg = cell.config
+    decode, prefill, pool = _serve_programs(cell, cfg, one_chip)
     compiled = (decode if which == "decode" else prefill).compile()
     total, ma = _total(compiled)
     assert total < USABLE_BYTES
-    pool_bytes = int(np.prod(pool.shape)) * 2
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
     assert ma.alias_size_in_bytes >= pool_bytes         # updated in place
     sv = cfg["serve"]
+    assert sv["kv_bytes_per_token"] == cell.family.kv_bytes_per_token(cfg)
     assert pool_bytes == sv["num_blocks"] * sv["block_size"] \
         * sv["kv_bytes_per_token"]
     assert sv["sessions_of_1024_tokens_in_pool"] == \
         sv["num_blocks"] * sv["block_size"] // 1024
+    # the program works on the pool where it lies: its temporaries are a
+    # small part of it (0.015 GiB decode, 0.064 prefill: the file's
+    # memory_reckoning), where the program before PR 27 kept three
+    # copies
+    assert ma.temp_size_in_bytes < pool_bytes // 8
 
 
 def test_max_batch_32_is_refused_as_the_configuration_says(one_chip):
-    """Why ``max_batch`` is 16: at 32 the decode program alone is over
-    the chip."""
-    cfg = json.loads(json.dumps(_cell("gpt2m-serve-decode").config))
+    """Turned round at PR 28: since PR 27 the decode program takes the
+    pool where it lies, and batch 32 over 2048 blocks is not refused
+    but fits with room to spare (3.68 GiB by the configuration's
+    ``memory_reckoning``).  ``max_batch`` 16 is a choice of the cell and
+    no limit of the chip."""
+    cell = _cell("gpt2m-serve-decode")
+    cfg = json.loads(json.dumps(cell.config))
     cfg["serve"]["max_batch"] = 32
-    decode, _, _ = _serve_programs(cfg, one_chip)
-    with pytest.raises(Exception, match="(?i)memory|RESOURCE_EXHAUSTED"):
-        decode.compile()
+    decode, _, pool = _serve_programs(cell, cfg, one_chip)
+    total, ma = _total(decode.compile())
+    assert total < 4 * 2 ** 30 < USABLE_BYTES
+    assert ma.alias_size_in_bytes >= \
+        int(np.prod(pool.shape)) * pool.dtype.itemsize
 
 
 def test_data_parallel_step_over_the_four_chips(topo, monkeypatch):
@@ -167,7 +187,8 @@ def test_data_parallel_step_over_the_four_chips(topo, monkeypatch):
     # its state is skipped, nothing else of it is
     with monkeypatch.context() as m:
         m.setattr(jax, "device_put", lambda x, *a, **k: x)
-        step, mesh = sut.build_train_step(cfg, 0, "dp", topo.devices)
+        step, mesh = sut.build_train_step(_cell("gpt2s-train").family, cfg, 0,
+                                          "dp", topo.devices)
     assert type(step).__name__ == "ZeroTrainStep" and mesh.size == 4
     rows = NamedSharding(mesh, P("data"))
     ids = jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32,

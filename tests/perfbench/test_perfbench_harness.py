@@ -2,8 +2,10 @@
 traffic kind end to end, a cell and a metric added as new files, the
 control that has to come out as not correct, and the timed path broken
 underneath."""
+import ast
 import json
 import os
+import shutil
 
 import numpy as np
 import pytest
@@ -39,6 +41,15 @@ def _run(repo, name, trace=0, seed=SEEDS[0], seconds=1.0, **fault):
 CELLS = [w["name"] for w in cells.load_benchmark()["workloads"]]
 
 
+def _files_under(path):
+    out = {}
+    for root, _, files in os.walk(path):
+        for f in files:
+            with open(os.path.join(root, f), "rb") as fh:
+                out[os.path.join(root, f)] = fh.read()
+    return out
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_runs_on_the_cpu_and_reports_end_to_end(repo, name):
     cell, line = _run(repo, name)
@@ -66,11 +77,7 @@ def test_traced_cell_prints_no_device_metric_off_the_chip(repo, name):
 
 def test_new_cell_and_metric_are_found_as_new_files(repo):
     """A later PR adds entries and files and edits none that is there."""
-    before = {}
-    for root, _, files in os.walk(os.path.join(repo, "perfbench")):
-        for f in files:
-            p = os.path.join(root, f)
-            before[p] = open(p, "rb").read()
+    before = _files_under(os.path.join(repo, "perfbench"))
     bench = cells.load_benchmark(repo)
     bench["workloads"].append({
         "name": "added-cell", "config": "gpt2-small",
@@ -126,6 +133,272 @@ def test_four_chip_cell_is_added_as_files_and_agrees_on_the_cpu(repo):
     assert line["metrics"]["train_tokens_per_s"]["value"] > 0
 
 
+# -- a model family is a file ---------------------------------------------------
+
+ADDED = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                     "added_family")
+#: the added cells: configuration, mix, the cell whose limits file and
+#: metrics they take, their end-to-end metric
+ADDED_CELLS = {
+    "biasgpt-train": ("biasgpt-mini", "train-16x1024", "gpt2s-train",
+                      "train_tokens_per_s"),
+    "biasgpt-serve": ("biasgpt-mini", "short-in-long-out-closed",
+                      "gpt2m-serve-decode", "serve_tokens_per_s"),
+    "ropedec-train": ("ropedec-mini", "train-16x1024", "gpt2s-train",
+                      "train_tokens_per_s")}
+
+
+@pytest.fixture(scope="module")
+def added_family(tmp_path_factory):
+    """A checkout's benchmark as a later ``model_config`` PR leaves it:
+    everything that was there, and beside it two families of other keys
+    and other blocks (``data/added_family/``: ``biasgpt``, q/k/v/out
+    biases, a sliced vocabulary, (out, in) matrices, trained and
+    served; ``ropedec``, RMSNorm, rotary positions, grouped keys and
+    values, a gated feed-forward and an untied head, trained only: the
+    engine refuses rotary blocks), their references, their
+    configurations, cells of both kinds on the mixes that are there,
+    and two per-layer metrics fed by the families' counts.  Returns the
+    checkout, the tiny copy the cells run from, and what the files held
+    before."""
+    src = str(tmp_path_factory.mktemp("src"))
+    shutil.copy(os.path.join(pb_tiny.REPO, "BENCHMARK.json"), src)
+    shutil.copytree(pb_tiny.BENCH, os.path.join(src, "perfbench"),
+                    ignore=shutil.ignore_patterns("__pycache__", ".trace"))
+    before = _files_under(os.path.join(src, "perfbench"))
+    pb = os.path.join(src, "perfbench")
+    os.makedirs(os.path.join(pb, "references"))
+    for fam in ("biasgpt", "ropedec"):
+        for name, to in [(f"{fam}.py", f"families/{fam}.py"),
+                         (f"{fam}_reference.py", f"references/{fam}.py"),
+                         (f"{fam}-mini.json", f"configs/{fam}-mini.json")]:
+            shutil.copy(os.path.join(ADDED, name), os.path.join(pb, to))
+    with open(os.path.join(pb, "readers", "added_counts.py"), "w") as f:
+        f.write("def train_flops_per_token(ctx):\n"
+                "    return ctx['family'].train_flops_per_token(\n"
+                "        ctx['cfg'], ctx['mix']['seq_len'])\n\n\n"
+                "def decode_flops(ctx):\n"
+                "    return sum(ctx['family'].decode_step_flops(ctx['cfg'], "
+                "tk)\n"
+                "               for tk in ctx['counters']['ticks']\n"
+                "               if 'decode_step' in tk['dispatches'])\n")
+    bench = cells.load_benchmark(src)
+    for fam in ("biasgpt", "ropedec"):
+        bench["configs"].append({
+            "name": f"{fam}-mini",
+            "source": "tests/perfbench/data/added_family",
+            "file": f"perfbench/configs/{fam}-mini.json", "reduced": [],
+            "why": "added by a test"})
+    for name, (config, mix, like, e2e) in ADDED_CELLS.items():
+        bench["workloads"].append({
+            "name": name, "config": config, "traffic": mix,
+            "chips": 1, "why": "added by a test"})
+        shutil.copy(os.path.join(pb, "workloads", like + ".json"),
+                    os.path.join(pb, "workloads", name + ".json"))
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if like in m.get("workloads", []) and \
+                    m["name"] != "paged_attn_decode_roofline":
+                m["workloads"].append(name)
+    for reader, e2e in [("train_flops_per_token", "train_tokens_per_s"),
+                        ("decode_flops", "serve_tokens_per_s")]:
+        bench["per_layer"].append({
+            "name": "added_" + reader, "unit": "flops", "better": "lower",
+            "source": "program_counter", "layer": "step programs",
+            "moves": e2e,
+            "workloads": [n for n, c in ADDED_CELLS.items() if c[3] == e2e]})
+        pb_tiny._dump(os.path.join(pb, "metrics", f"added_{reader}.json"),
+                      {"reader": "added_counts." + reader, "args": {}})
+    with open(os.path.join(src, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    tiny = pb_tiny.make_repo(tmp_path_factory.mktemp("tiny"), source=src)
+    yield src, tiny, before
+    from apex_tpu.runtime import step_cache
+    step_cache.clear()
+
+
+#: by hand, at the families' tiny sizes: the parameters that sit in a
+#: matrix product for every token
+HAND_MATMUL = {
+    # 2 layers x (qkv 3 x 48 x 48 + out 48 x 48 + 2 x 48 x 80) + head 302 x 48
+    "biasgpt": 2 * (4 * 48 * 48 + 2 * 48 * 80) + 302 * 48,
+    # 2 layers x (q, o 32 x 32; k, v 16 x 32; gate, up, down 32 x 56) + 173 x 32
+    "ropedec": 2 * (2 * 32 * 32 + 2 * 16 * 32 + 3 * 32 * 56) + 173 * 32}
+
+
+@pytest.mark.parametrize("name", sorted(ADDED_CELLS))
+def test_a_family_of_other_keys_comes_in_as_files_alone(added_family, name):
+    """A ``train`` and a ``closed`` cell of an added family, plain and
+    traced on the CPU, at the family's tiny sizes and not at its file's;
+    ``correct`` by the family's own reference; a per-layer metric fed by
+    the family's own counts; and no file that was there edited."""
+    src, tiny, before = added_family
+    fam = ADDED_CELLS[name][0][:-len("-mini")]
+    cell, line = _run(tiny, name)
+    family, cfg = cell.family, cell.config
+    assert family.__file__ == os.path.join(
+        tiny, "perfbench", "families", fam + ".py")
+    assert cell.reference.__file__.endswith(f"references/{fam}.py")
+    # the family's tiny sizes, not its file's
+    at_file = pb_tiny._load(os.path.join(ADDED, fam + "-mini.json"))
+    for key, value in family.tiny(at_file).items():
+        assert cfg[key] == value != at_file[key], key
+    if fam == "biasgpt":            # a sliced vocabulary
+        assert family.vocab(cfg) == 1208 // 4 == 302
+    assert not set(cfg) & {"n_embd", "n_layer", "n_head", "n_positions",
+                           "vocab_size"}
+    assert line["correct"] is True, line["compared"]
+    assert line["failed"] == 0 and line["attempted"] > 0
+    assert set(line["metrics"]) == {ADDED_CELLS[name][3], "setup_s"}
+    assert all(v["value"] > 0 for v in line["metrics"].values())
+    cell, line = _run(tiny, name, trace=1, seconds=3.0)
+    assert line["correct"] is True, line["compared"]
+    assert not [m for m in line["metrics"] if m.endswith(DEVICE_METRIC)]
+    matmul = HAND_MATMUL[fam]
+    assert family.matmul_params(cfg) == matmul
+    if cell.kind == "train":
+        seq, width = cell.traffic["seq_len"], {"biasgpt": 48, "ropedec": 32}
+        assert line["metrics"]["added_train_flops_per_token"]["value"] == \
+            6 * matmul + 3 * 4 * 2 * width[fam] * (seq + 1) / 2
+        assert line["metrics"]["train_compiles_in_window"]["value"] == 0
+    else:
+        # every decode tick: 2 x matmul parameters a session at least
+        got = line["metrics"]["added_decode_flops"]["value"]
+        assert got > 2 * matmul * line["metrics"]["decode_batch_mean"]["value"]
+    assert _files_under(os.path.join(src, "perfbench")).items() >= \
+        before.items(), "a file that was there was edited"
+    untouched = cells.load_benchmark()
+    now = cells.load_benchmark(src)
+    assert now["configs"][:2] == untouched["configs"]
+    assert now["workloads"][:2] == untouched["workloads"]
+
+
+def test_the_added_familys_control_and_fault_are_not_correct(added_family):
+    """The seam carries the rest of what decides ``correct``: the added
+    family's reference one precision down fails its train cell, and a
+    token altered where it is produced fails its serve cell."""
+    _, tiny, _ = added_family
+    cell = cells.Cell("biasgpt-train", repo=tiny)
+    train = cells.kind_module("train", tiny)
+    ref = train.reference_readings(cell, SEEDS[1])
+    ctl = train.reference_readings(cell, SEEDS[1], quant="int8")
+    ok, compared = correct.judge(correct.train_numbers(ctl, ref),
+                                 cell.settings["limits"])
+    assert not ok, compared
+
+    def fault(loop):
+        def alter(tr, s):
+            if len(s.out) == 2 and not getattr(s, "_altered", False):
+                s._altered = True
+                s.out[-1] = s.pending_tok = (s.out[-1] + 1) % 302
+        loop.on_token = alter
+    _, line = _run(tiny, "biasgpt-serve", fault=fault)
+    assert line["correct"] is False
+
+
+def test_a_builder_that_names_no_file_is_a_clear_error(repo):
+    path = os.path.join(repo, "perfbench", "configs", "gpt2-small.json")
+    cfg = pb_tiny._load(path)
+    try:
+        for builder in ("gpt3", None):
+            pb_tiny._dump(path, dict(cfg, builder=builder))
+            with pytest.raises(SystemExit) as e:
+                cells.Cell("gpt2s-train", repo=repo).family
+            assert "perfbench/families/" in str(e.value)
+            assert repr(builder) in str(e.value) and "'gpt'" in str(e.value)
+        pb_tiny._dump(path, dict(cfg, reference="perfbench/pb/nowhere.py"))
+        with pytest.raises(SystemExit, match="nowhere.py"):
+            cells.Cell("gpt2s-train", repo=repo).reference
+    finally:
+        pb_tiny._dump(path, cfg)
+
+
+def test_the_reference_key_is_what_finds_the_reference(repo):
+    """Pointed at a file whose losses are wrong, the train cell is not
+    ``correct``: the key is read, and nothing else decides which
+    reference a cell is compared with."""
+    path = os.path.join(repo, "perfbench", "configs", "gpt2-small.json")
+    cfg = pb_tiny._load(path)
+    wrong = os.path.join(repo, "perfbench", "pb", "wrong_reference.py")
+    with open(wrong, "w") as f:
+        f.write(
+            "import importlib.util, os\n"
+            "_spec = importlib.util.spec_from_file_location(\n"
+            "    'right_reference', os.path.join(os.path.dirname(__file__), "
+            "'reference.py'))\n"
+            "_right = importlib.util.module_from_spec(_spec)\n"
+            "_spec.loader.exec_module(_right)\n\n\n"
+            "def train_reference(*a, **kw):\n"
+            "    out = _right.train_reference(*a, **kw)\n"
+            "    out['losses'] = [1.01 * l for l in out['losses']]\n"
+            "    return out\n")
+    try:
+        pb_tiny._dump(path, dict(
+            cfg, reference="perfbench/pb/wrong_reference.py"))
+        _, line = _run(repo, "gpt2s-train")
+        assert line["correct"] is False
+        value, limit = line["compared"]["loss2_gap"]
+        assert value == pytest.approx(0.01, rel=0.05) and value > limit
+    finally:
+        pb_tiny._dump(path, cfg)
+        os.remove(wrong)
+
+
+def _imports_of(path):
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            yield "." * node.level + (node.module or "")
+
+
+@pytest.mark.parametrize("conf", cells.load_benchmark()["configs"],
+                         ids=lambda c: c["name"])
+def test_the_reference_imports_nothing_of_the_program(conf):
+    """By its source: the configuration's reference file, and what it
+    shares with every other reference, import jax, the standard library
+    and ``pb.refmath`` alone."""
+    cfg = pb_tiny._load(os.path.join(pb_tiny.REPO, conf["file"]))
+    allowed = {"__future__", "functools", "json", "math", "jax",
+               "jax.numpy", "pb.refmath"}
+    for path in (os.path.join(pb_tiny.REPO, cfg["reference"]),
+                 os.path.join(pb_tiny.BENCH, "pb", "refmath.py"),
+                 os.path.join(ADDED, "biasgpt_reference.py"),
+                 os.path.join(ADDED, "ropedec_reference.py")):
+        assert set(_imports_of(path)) <= allowed, path
+
+
+def test_only_the_family_and_the_reference_know_the_model():
+    """Outside the family's file and the reference it is compared with,
+    nothing under ``perfbench/`` and nothing in ``pb_tiny.py`` reads a
+    GPT-2 size, names a GPT-2 leaf or imports the program's models; and
+    ``builder`` and ``reference`` are read in one place."""
+    import re
+    knows = re.compile(r"n_embd|n_layer|n_head|n_positions|n_inner|c_attn|"
+                       r"c_proj|c_fc|\bwte\b|\bwpe\b|apex_tpu\.models|"
+                       r"GptModel")
+    keys = re.compile(r"""["'](builder|reference)["']""")
+    may_know = {os.path.join(pb_tiny.BENCH, "families", "gpt.py"),
+                os.path.join(pb_tiny.BENCH, "pb", "reference.py")}
+    files = [os.path.join(root, f)
+             for root, _, fs in os.walk(pb_tiny.BENCH) for f in fs
+             if f.endswith(".py")] + [pb_tiny.__file__]
+    readers_of_the_keys = []
+    for path in files:
+        with open(path) as f:
+            code = f.read()
+        if path not in may_know:
+            assert not knows.search(code), (path, knows.search(code).group())
+        if keys.search(code):
+            readers_of_the_keys.append(os.path.relpath(path, pb_tiny.REPO))
+    # cells.py finds the family and the reference; pb_tiny.py copies the
+    # reference file beside the tiny configuration and asks cells for
+    # the family
+    assert sorted(readers_of_the_keys) == [
+        "perfbench/pb/cells.py", "tests/perfbench/pb_tiny.py"]
+
+
 # -- the control: the reference one precision down has to fail -------------
 
 
@@ -136,9 +409,8 @@ def test_train_control_is_not_correct(repo, seed, quant):
     place: the comparison has to refuse it."""
     cell = cells.Cell("gpt2s-train", repo=repo)
     train = cells.kind_module("train", repo)
-    ref = train.reference_readings(cell.config, cell.traffic, seed)
-    ctl = train.reference_readings(cell.config, cell.traffic, seed,
-                                   quant=quant)
+    ref = train.reference_readings(cell, seed)
+    ctl = train.reference_readings(cell, seed, quant=quant)
     numbers = correct.train_numbers(ctl, ref)
     ok, compared = correct.judge(numbers, cell.settings["limits"])
     assert not ok, compared
@@ -150,12 +422,12 @@ def test_serve_control_is_not_correct(repo, seed, quant):
     """The lower-precision reference's own first choices, at the
     positions of served prompts and tokens, judged as a run's are."""
     cell = cells.Cell("gpt2m-serve-decode", repo=repo)
-    cfg = cell.config
+    vocab = cell.family.vocab(cell.config)
     rng = np.random.default_rng(seed)
     def toks(n):
-        return [int(t) for t in rng.integers(1, cfg["vocab_size"], n)]
+        return [int(t) for t in rng.integers(1, vocab, n)]
     samples = [(toks(30 + i), toks(80)) for i in range(6)]
-    gaps, margins = serve_common.served_gaps(cfg, seed, samples,
+    gaps, margins = serve_common.served_gaps(cell, seed, samples,
                                              control=quant)
     # every token of every request, and no gap without a flip
     assert len(gaps) == len(margins) == 480
@@ -199,11 +471,14 @@ def test_broken_train_step_is_not_correct(repo, fault, why):
 
 @pytest.mark.parametrize("name", ["gpt2m-serve-decode"])
 def test_altered_token_is_not_correct(repo, name):
+    cell = cells.Cell(name, repo=repo)
+    vocab = cell.family.vocab(cell.config)
+
     def fault(loop):
         def alter(tr, s):
             if len(s.out) == 2 and not getattr(s, "_altered", False):
                 s._altered = True
-                s.out[-1] = s.pending_tok = (s.out[-1] + 1) % 211
+                s.out[-1] = s.pending_tok = (s.out[-1] + 1) % vocab
         loop.on_token = alter
     _, line = _run(repo, name, fault=fault)
     assert line["correct"] is False

@@ -109,6 +109,22 @@ def test_decode_tick_metrics_split_the_tick_at_the_fetch(serve_ctx, capsys):
     assert longest.endswith("serve.commit 0.400, self 0.100)")
 
 
+def test_an_untraced_run_names_where_its_longest_tick_stood(serve_ctx,
+                                                            monkeypatch):
+    spans = cells._module_at(cells.REPO, "readers", "spans")
+    ticks = serve_ctx["counters"]["ticks"]
+    line = spans.longest_tick_line(ticks)
+    assert line.startswith("serve.step 154.800 (serve.prefill_chunk 64.000 ")
+    assert "serve.fetch.tokens 89.000" in line
+    assert spans.longest_tick_line([]) is None
+    # the ring has lost the tick, or the program keeps no records
+    assert spans.longest_tick_line(
+        [{"t0": 9.0, "t1": 9.5, "dispatches": []}]) is None
+    from apex_tpu.observe import spans as program
+    monkeypatch.delattr(program, "recorded")
+    assert spans.longest_tick_line(ticks) is None
+
+
 def test_train_metrics_are_medians_of_the_windows_last_steps(monkeypatch):
     from apex_tpu.observe import spans
     records = []

@@ -1,13 +1,21 @@
 """The yardstick's own arithmetic: the traffic generator, the FLOP and
-byte counts, the trace reduction.  Nothing here needs a device."""
+byte counts, the trace reduction, and what the seed gives.  Nothing here
+needs a device."""
+import hashlib
 import json
 import os
+import re
 
 import numpy as np
 import pytest
 
 import pb_tiny  # noqa: F401  (puts perfbench/ on sys.path)
-from pb import counts, peaks, trace, traffic
+from pb import cells, peaks, trace, traffic
+from pb.counts import roofline_seconds
+
+#: the GPT-2 family's counts: the hand counts below were written against
+#: ``pb/counts.py``, whose functions moved there unchanged (PR 28)
+counts = cells.family_module("gpt")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BIG = 2 ** 31 + 12345           # the driver's seeds pass 32 signed bits
@@ -60,7 +68,6 @@ def test_stratified_lengths_are_the_distributions_quantiles():
 
 def test_every_traffic_key_is_read_by_the_generator_or_its_kind():
     """A key in a mix that nothing reads is a parameter in name only."""
-    import re
     code = ""
     for folder in ("pb", "kinds"):
         d = os.path.join(pb_tiny.BENCH, folder)
@@ -74,6 +81,108 @@ def test_every_traffic_key_is_read_by_the_generator_or_its_kind():
         for key in set(mix) - notes:
             assert re.search(r"""["']%s["']""" % re.escape(key), code), \
                 (name, key)
+
+
+def _reads_key(code, key):
+    return re.search(r"""["']%s["']""" % re.escape(key), code)
+
+
+@pytest.mark.parametrize("conf", cells.load_benchmark()["configs"],
+                         ids=lambda c: c["name"])
+def test_every_size_of_a_configuration_is_read_by_its_family(conf):
+    """The sibling for configurations: a family declares the keys it
+    reads (``READS``) and reads each; every number at the top of a
+    configuration file is one of them, or the reference's, or says the
+    same as one (GPT-2's ``n_ctx``); and what is listed as ``reduced``
+    is a key that some code reads."""
+    cfg = _cfg(os.path.basename(conf["file"])[:-len(".json")])
+    family = cells.family_module(cfg["builder"])
+    code = open(family.__file__).read()
+    for key in family.READS:
+        assert _reads_key(code, key), key
+    ref_code = open(os.path.join(pb_tiny.REPO, cfg["reference"])).read()
+    same_as = {"n_ctx": "n_positions"}
+    for key, value in cfg.items():
+        if isinstance(value, (int, float)) and not isinstance(value, bool):
+            if key in same_as:
+                assert value == cfg[same_as[key]]
+                continue
+            assert key in family.READS or _reads_key(ref_code, key), key
+    for key in conf["reduced"]:
+        assert key in family.READS, key
+    # the serve and train blocks are the harness's: the same keys for
+    # every family, read in pb/ and the kinds
+    harness = "".join(
+        open(os.path.join(pb_tiny.BENCH, folder, f)).read()
+        for folder in ("pb", "kinds")
+        for f in sorted(os.listdir(os.path.join(pb_tiny.BENCH, folder)))
+        if f.endswith(".py"))
+    noted = {"note", "kv_bytes_per_token_how", "memory_reckoning",
+             "precision", "optimizer", "weight_decay_mode", "loss",
+             # pinned against the family's count and the pool's size in
+             # test_perfbench_aot_fit.py
+             "kv_bytes_per_token", "sessions_of_1024_tokens_in_pool"}
+    for block in ("serve", "train"):
+        for key in set(cfg.get(block, {})) - noted:
+            assert _reads_key(harness, key), (block, key)
+
+
+# -- what the seed gives: pinned at the parent of PR 28 ----------------------
+
+
+def _arrays_digest(named):
+    h = hashlib.sha256()
+    for name, a in named:
+        a = np.asarray(a)
+        h.update(f"{name}:{a.dtype}:{a.shape};".encode())
+        h.update(a.view(f"u{a.dtype.itemsize}").tobytes())
+    return h.hexdigest()
+
+
+with open(os.path.join(HERE, "data", "seeded_digests.json")) as _f:
+    PINNED = json.load(_f)
+
+
+@pytest.mark.parametrize("cell_name", ["gpt2s-train", "gpt2m-serve-decode"])
+def test_the_seed_gives_what_it_gave_before_the_families(cell_name):
+    """Weights (in the program's order and layout, at the cell's own
+    size and in the type they are trained or served in), train batches
+    and serve requests of one seed, against digests taken with the
+    harness of PR 27 (``tests/perfbench/data/seeded_digests.json``): the
+    move into ``families/gpt.py`` changed no bit of them."""
+    from pb import sut
+    cell = cells.Cell(cell_name)
+    cfg, mix, family = cell.config, cell.traffic, cell.family
+    seed = PINNED["seed"]
+    pinned = PINNED[cell.config_name]
+    vals = sut.program_weights(family, cfg, seed, pinned["dtype"])
+    assert _arrays_digest((str(i), a) for i, a in enumerate(vals)) == \
+        pinned["program_order"]
+    del vals
+    if cell.kind == "train":
+        got = _arrays_digest(
+            (str(i), a) for i, a in enumerate(traffic.first_train_batches(
+                mix, seed, family.vocab(cfg), 3)))
+    else:
+        reqs = [traffic.serve_requests(mix, seed, family.vocab(cfg),
+                                       mix["cycle"], cycle=c)
+                for c in (0, 1)]
+        got = hashlib.sha256(json.dumps(reqs).encode()).hexdigest()
+    assert got == PINNED[cell.traffic_name]
+
+
+@pytest.mark.parametrize("name", ["gpt2-small", "gpt2-medium"])
+def test_the_benchmarks_own_leaves_are_what_they_were(name):
+    """The same for the leaves the reference reads, at the family's tiny
+    sizes (the layout at full size is pinned through the program's
+    order above)."""
+    from pb import weights
+    cfg = pb_tiny.tiny_config(_cfg(name), pb_tiny.REPO)
+    leaves = weights.make_weights(counts, cfg, PINNED["seed"],
+                                  PINNED[name]["dtype"])
+    assert set(leaves) == set(counts.leaf_shapes(cfg))
+    assert _arrays_digest(sorted(leaves.items())) == \
+        PINNED[name]["tiny_leaves"]
 
 
 def test_train_batches_differ_row_by_row_and_repeat_with_the_seed():
@@ -124,8 +233,8 @@ def test_flops_and_bytes_match_hand_counts():
     v5e = peaks.peaks_for("TPU v5 lite")
     assert v5e["bf16_flops_per_s"] == 197e12
     assert v5e["hbm_bytes_per_s"] == 819e9
-    assert counts.roofline_seconds(197e12, 1.0, v5e) == (1.0, "compute")
-    assert counts.roofline_seconds(1.0, 819e9, v5e) == (1.0, "memory")
+    assert roofline_seconds(197e12, 1.0, v5e) == (1.0, "compute")
+    assert roofline_seconds(1.0, 819e9, v5e) == (1.0, "memory")
     with pytest.raises(KeyError):
         peaks.peaks_for("TPU v9")
 
@@ -191,6 +300,89 @@ def test_reduction_of_the_recorded_chip_trace():
     assert kinds["decode_step"]["n"] == 6
     assert kinds["decode_step"]["seconds"] * 1e3 / 6 == pytest.approx(
         88.854, abs=0.01)
+
+
+def test_a_kernel_is_read_by_its_name_in_the_trace():
+    """``paged_attn_decode_roofline`` through its metric file: the
+    decode ticks' live K and V rows once, queries and outputs, over the
+    bandwidth, over the device time of the operations named
+    ``paged_attention_decode`` (hand-made trace: 24 calls of 50 us in
+    each of two decode ticks; a prefill tick and other operations do
+    not count)."""
+    medium = _cfg("gpt2-medium")
+    reader, kw = cells.metric_reader("paged_attn_decode_roofline")
+    ops = []
+    for i in range(48):
+        ops.append((f"%paged_attention_decode.{i % 24} = bf16[16,1024] "
+                    f"custom-call()", 1000 * i, 50_000))
+        ops.append((f"%fusion.{i} = bf16[16,1024] fusion()", 1000 * i, 9_000))
+    ticks = [{"dispatches": ["decode_step"], "decode_batch": 16,
+              "kv_tokens": 6400},
+             {"dispatches": ["prefill_step"], "decode_batch": 0,
+              "kv_tokens": 0},
+             {"dispatches": ["prefill_step", "decode_step"],
+              "decode_batch": 15, "kv_tokens": 6000}]
+    ctx = {"cfg": medium, "family": counts, "trace": {"ops": ops},
+           "peaks": peaks.peaks_for("TPU v5 lite"),
+           "counters": {"ticks": ticks}}
+    nbytes = 98_304 * (6400 + 6000) + 2 * 24 * 1024 * 2 * (16 + 15)
+    assert counts.paged_attn_decode_bytes(medium, ticks[0]) \
+        + counts.paged_attn_decode_bytes(medium, ticks[2]) == nbytes
+    assert counts.paged_attn_decode_flops(medium, ticks[0]) == \
+        4 * 24 * 1024 * 6400
+    # memory-bound: bytes over 819 GB/s, against 48 x 50 us
+    assert reader(ctx, **kw) == pytest.approx(
+        100.0 * (nbytes / 819e9) / (48 * 50e-6))
+    # nothing of that name in the trace, no trace, no decode tick, no
+    # peaks (off the chip): nothing is read, never 0
+    for gone in ({"trace": {"ops": [o for o in ops if "fusion" in o[0]]}},
+                 {"trace": None}, {"counters": {"ticks": ticks[1:2]}},
+                 {"peaks": None}):
+        assert reader(dict(ctx, **gone), **kw) is None
+
+
+def test_whole_step_shares_take_their_counts_from_the_family():
+    """``train_mfu`` and ``decode_step_mfu`` through their metric files
+    with a family that is not GPT-2's: the counts are asked of
+    ``ctx["family"]``, with the tick's whole record."""
+    import types
+    seen = []
+
+    def decode_step_flops(cfg, tick):
+        seen.append(tick)
+        return 1e9 * tick["experts_hit"]
+    family = types.SimpleNamespace(
+        train_flops_per_token=lambda cfg, seq_len: 2e9 + seq_len,
+        decode_step_flops=decode_step_flops)
+    v5e = peaks.peaks_for("TPU v5 lite")
+    ctx = {"cfg": {}, "family": family, "mix": {"seq_len": 1000},
+           "peaks": v5e, "window_s": 2.0,
+           "counters": {"tokens_per_s": 1e4, "chips": 1, "ticks": [
+               {"dispatches": ["decode_step"], "experts_hit": 3},
+               {"dispatches": ["prefill_step"], "experts_hit": 9},
+               {"dispatches": ["decode_step"], "experts_hit": 5}]}}
+    reader, kw = cells.metric_reader("train_mfu")
+    assert reader(ctx, **kw) == pytest.approx(
+        100.0 * (2e9 + 1000) * 1e4 / 197e12)
+    reader, kw = cells.metric_reader("decode_step_mfu")
+    assert reader(ctx, **kw) == pytest.approx(100.0 * 8e9 / 2.0 / 197e12)
+    assert [tk["experts_hit"] for tk in seen] == [3, 5]
+    assert reader(dict(ctx, peaks=None), **kw) is None
+
+
+def test_reference_sample_is_seeded_and_holds_the_longest():
+    from pb import serve_common
+    samples = [([1] * (5 + i % 7), [2] * (3 + (i * 5) % 11))
+               for i in range(40)]
+    assert serve_common.reference_sample(samples, None, BIG) is samples
+    assert serve_common.reference_sample(samples, 40, BIG) is samples
+    a = serve_common.reference_sample(samples, 9, BIG)
+    assert a == serve_common.reference_sample(samples, 9, BIG)
+    assert a != serve_common.reference_sample(samples, 9, BIG + 1)
+    assert len(a) == 9 and all(x in samples for x in a)
+    longest = max(len(p) + len(o) for p, o in samples)
+    assert max(len(p) + len(o) for p, o in a) == longest
+    assert len(serve_common.reference_sample(samples, 1, BIG)) == 1
 
 
 # -- what a run reads of itself ------------------------------------------------
