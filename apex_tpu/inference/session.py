@@ -375,7 +375,7 @@ class PagedSession:
             table = np.asarray(
                 [self._table + [0] * (nb - len(self._table))], np.int32)
             from ..runtime import executor as _executor
-            last, eng.pool = _executor.executor.submit(
+            last, eng.pool, _ = _executor.executor.submit(
                 prefill_prog,
                 (eng._vals(), eng.pool, padded, table,
                  np.int32(self.position), np.int32(n)),
@@ -410,7 +410,7 @@ class PagedSession:
             nb = bucket(len(self._table))
             table = np.asarray(
                 [self._table + [0] * (nb - len(self._table))], np.int32)
-            nxt, logits, eng.pool = _executor.executor.submit(
+            nxt, logits, eng.pool, _ = _executor.executor.submit(
                 decode_prog,
                 (eng._vals(), eng.pool,
                  np.asarray([out[-1]], np.int32),

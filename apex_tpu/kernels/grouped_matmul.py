@@ -1,0 +1,216 @@
+"""grouped_matmul: the matrix products of routed experts.
+
+Rows sorted by the expert they go to, each expert's rows starting at a
+tile boundary (:func:`tile_layout`): a tile of rows then belongs to one
+expert, and the product is a tiled matmul whose right-hand block is
+chosen per row tile — the expert's matrix is streamed once for the
+tiles that use it, and tiles past the last used one are skipped without
+moving anything.
+
+* :func:`tile_layout` — from each pair's group to the rows' layout.
+* :func:`grouped_matmul` — ``lhs (M, K)`` x ``rhs (G, K, N)`` by that
+  layout: the registered ``routed_experts`` kernel (its name in a device
+  trace), with ``jax.lax.ragged_dot`` as its XLA tier.
+"""
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .dispatch import decide, pallas_mode, register_kernel, shape_fp
+
+_f32 = jnp.float32
+
+#: rows of a tile of the Pallas tier (the MXU's height); the XLA tier
+#: needs no alignment and lays the rows out with tiles of one row
+TILE_ROWS = 128
+#: the widest K and N tiles tried (2 MiB of bf16 a block, two in flight)
+_TILE_KN = (1024, 512, 256, 128)
+
+
+class TileLayout(NamedTuple):
+    """Where the pairs sorted by group lie, ``tile`` rows a tile."""
+    tile: int               # rows a tile (static)
+    sizes: jax.Array        # (G,) pairs of each group
+    padded: jax.Array       # (G,) the same, rounded up to whole tiles
+    tile_group: jax.Array   # (n_tiles,) the group a row tile belongs to
+    n_active: jax.Array     # () tiles in use (a prefix of the tiles)
+    pair_of_row: jax.Array  # (M,) index of the pair a row holds, -1: none
+    row_of_pair: jax.Array  # (P,) row of each pair, -1: its group is not held
+
+
+def tile_layout(group, n_groups: int, max_rows: int, tile: int) -> TileLayout:
+    """``group (P,)``: the held group each pair goes to, ``n_groups`` for
+    a pair that goes elsewhere.  At most ``max_rows`` pairs go to held
+    groups (the caller's bound); the layout has
+    ``ceil(max_rows / tile) + n_groups`` tiles, enough for every group to
+    end in a partly filled one."""
+    p = group.shape[0]
+    g_ids = jnp.arange(n_groups, dtype=jnp.int32)
+    sizes = jnp.sum(group[:, None] == g_ids[None, :], axis=0,
+                    dtype=jnp.int32)                         # (G,)
+    tiles = (sizes + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tile                    # (G,)
+    sorted_start = jnp.cumsum(sizes) - sizes
+    n_tiles = -(-max_rows // tile) + n_groups
+    tile_group = jnp.minimum(
+        jnp.sum(jnp.arange(n_tiles, dtype=jnp.int32)[:, None]
+                >= tile_end[None, :], axis=1, dtype=jnp.int32),
+        n_groups - 1)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)  # sorted pairs
+    rank = jnp.zeros((p,), jnp.int32).at[order].set(
+        jnp.arange(p, dtype=jnp.int32))                  # place when sorted
+    held = group < n_groups
+    g_safe = jnp.minimum(group, n_groups - 1)
+    row_of_pair = jnp.where(
+        held, row_start[g_safe] + rank - sorted_start[g_safe], -1)
+    row = jnp.arange(n_tiles * tile, dtype=jnp.int32)
+    g_row = tile_group[row // tile]
+    k = row - row_start[g_row]
+    filled = (row // tile < tile_end[-1]) & (k < sizes[g_row])
+    pair_of_row = jnp.where(
+        filled, order[jnp.clip(sorted_start[g_row] + k, 0, p - 1)], -1)
+    return TileLayout(tile, sizes, tiles * tile, tile_group, tile_end[-1],
+                      pair_of_row, row_of_pair)
+
+
+def _tile_of(n: int):
+    return next((t for t in _TILE_KN if n % t == 0), None)
+
+
+def _gmm_kernel(tile_group_ref, n_active_ref, lhs_ref, rhs_ref, out_ref,
+                acc_ref, *, nk):
+    i, k = pl.program_id(0), pl.program_id(2)
+    active = i < n_active_ref[0]
+
+    @pl.when(active & (k == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(active)
+    def _():
+        acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+                                preferred_element_type=_f32)
+
+    @pl.when(active & (k == nk - 1))
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _gmm_call(tile_group, n_active, lhs, rhs, *, tile, interpret):
+    m, kdim = lhs.shape
+    _, _, n = rhs.shape
+    tk, tn = _tile_of(kdim), _tile_of(n)
+    nj, nk = n // tn, kdim // tk
+
+    # a tile past the last one in use keeps every block index of the
+    # last step that did work, so that nothing is fetched or written for it
+    def at(i, j, k, na):
+        act = i < na[0]
+        return (jnp.maximum(jnp.minimum(i, na[0] - 1), 0),
+                jnp.where(act, j, nj - 1), jnp.where(act, k, nk - 1))
+
+    def lhs_at(i, j, k, tg, na):
+        ic, _, kc = at(i, j, k, na)
+        return ic, kc
+
+    def rhs_at(i, j, k, tg, na):
+        ic, jc, kc = at(i, j, k, na)
+        return tg[ic], kc, jc
+
+    def out_at(i, j, k, tg, na):
+        ic, jc, _ = at(i, j, k, na)
+        return ic, jc
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(m // tile, nj, nk),
+            in_specs=[pl.BlockSpec((tile, tk), lhs_at),
+                      pl.BlockSpec((None, tk, tn), rhs_at)],
+            out_specs=pl.BlockSpec((tile, tn), out_at),
+            scratch_shapes=[pltpu.VMEM((tile, tn), _f32)]),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="routed_experts",
+    )(tile_group, n_active.reshape(1), lhs, rhs)
+
+
+def grouped_matmul_fp(m, k, n, g, dtype) -> str:
+    return shape_fp(m=int(m), k=int(k), n=int(n), g=int(g), dtype=str(dtype))
+
+
+def takes_tiles(kdim: int, n: int, dtype) -> bool:
+    """Whether the Pallas tier's tiles fit these widths: the caller lays
+    its rows out with :data:`TILE_ROWS` then, else with tiles of one."""
+    return pallas_mode() is not None and _tile_of(kdim) is not None \
+        and _tile_of(n) is not None \
+        and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(_f32))
+
+
+def grouped_matmul(lhs, rhs, layout: TileLayout):
+    """``lhs (M, K)`` rows laid out by ``layout`` times ``rhs (G, K, N)``
+    -> ``(M, N)``: each row with its own group's matrix.  Rows no pair
+    holds come back undefined in the Pallas tier and zero in the XLA
+    tier: the caller selects by ``layout.row_of_pair``."""
+    if layout.tile == TILE_ROWS and \
+            takes_tiles(lhs.shape[1], rhs.shape[2], lhs.dtype):
+        fp = grouped_matmul_fp(lhs.shape[0], lhs.shape[1], rhs.shape[2],
+                               rhs.shape[0], lhs.dtype)
+        if decide("routed_experts", fp).tier == "pallas":
+            return _gmm_call(layout.tile_group, layout.n_active, lhs,
+                             rhs.astype(lhs.dtype), tile=layout.tile,
+                             interpret=pallas_mode() == "interpret")
+    return _gmm_xla(lhs, rhs, layout.padded)
+
+
+def _gmm_xla(lhs, rhs, padded):
+    """The XLA tier (the declared fallback)."""
+    exact = lhs.dtype == jnp.bfloat16
+    return jax.lax.ragged_dot(
+        lhs, rhs.astype(lhs.dtype), padded,
+        precision=None if exact else jax.lax.Precision.HIGHEST,
+        preferred_element_type=_f32).astype(lhs.dtype)
+
+
+def _gmm_probe(dims):
+    """No-ledger prior: the kernel streams each used expert's matrix
+    once and skips the unused tiles."""
+    return 1, True
+
+
+def _audit_programs():
+    sds = jax.ShapeDtypeStruct
+    lhs = sds((3 * TILE_ROWS, 128), jnp.bfloat16)
+    rhs = sds((2, 128, 256), jnp.bfloat16)
+    group = sds((16,), jnp.int32)
+
+    def _pallas(lhs, rhs, group):
+        lay = tile_layout(group, 2, TILE_ROWS, TILE_ROWS)
+        return _gmm_call(lay.tile_group, lay.n_active, lhs, rhs,
+                         tile=TILE_ROWS, interpret=False)
+
+    def _xla(lhs, rhs, group):
+        lay = tile_layout(group, 2, TILE_ROWS, TILE_ROWS)
+        return _gmm_xla(lhs, rhs, lay.padded)
+
+    return [("pallas", _pallas, (lhs, rhs, group)),
+            ("xla", _xla, (lhs, rhs, group))]
+
+
+register_kernel(
+    "routed_experts",
+    xla_fallback="apex_tpu.kernels.grouped_matmul._gmm_xla",
+    threshold_probe=_gmm_probe,
+    doc="Grouped matmul of routed experts: rows sorted by expert",
+    audit_programs=_audit_programs)

@@ -2,10 +2,11 @@
 tables.
 
 The pool (``serve/pool.py::init_pool_buffer``) is
-``(layers, 2, num_blocks, block_size, heads*head_dim)``: one token's K
-(or V) of one layer is one contiguous row of ``heads*head_dim``
-elements, a whole number of lane rows, so the device stores it
-row-major and a block is one contiguous piece.  Every reader here takes
+``(layers, streams, num_blocks, block_size, width)``: what a layer keeps
+of one token is ``streams`` contiguous rows (a GPT block's K and V, each
+``heads*head_dim`` wide; a latent block's one row), each a whole number
+of lane rows, so the device stores the pool row-major and a block is one
+contiguous piece.  Every reader here takes
 the pool where it lies — no program needs it in another layout, so none
 copies it.
 
@@ -38,23 +39,24 @@ _f32 = jnp.float32
 
 
 def gather_kv(pool, layer, tables):
-    """``tables (B, nb)`` physical ids -> ``(k, v)`` of one layer, each
-    ``(B, nb*block_size, heads*head_dim)``: row ``s`` holds the KV of
-    logical position ``s`` (the table is logical-block-ordered, so the
-    gather IS the logical->physical translation), heads side by side as
-    in the pool.  Null-padded entries read the zero block, which the
+    """``tables (B, nb)`` physical ids -> the streams of one layer (a
+    GPT block's ``(k, v)``; a latent block's one stream), each
+    ``(B, nb*block_size, width)``: row ``s`` holds what the layer keeps
+    of logical position ``s`` (the table is logical-block-ordered, so
+    the gather IS the logical->physical translation), heads side by
+    side as in the pool.  Null-padded entries read the zero block, which the
     caller's position mask excludes.  A plain pool is read in its own
     dtype; a :class:`QuantKV` pool gathers int8 payload and scales and
     dequantizes the selected blocks to fp32."""
     b, nb = tables.shape
 
     def take(part, kv):
-        # (L, 2, N, bs, W) -> (L*2*N, bs, W) is a bitcast of the row-major
+        # (L, S, N, bs, W) -> (L*S*N, bs, W) is a bitcast of the row-major
         # pool, so the gather addresses blocks in the pool itself and no
         # per-layer slice is materialised; (layer, kv, block) is row
-        # (layer*2 + kv)*N + block of that view
+        # (layer*S + kv)*N + block of that view
         flat = part.reshape((-1,) + part.shape[3:])
-        g = flat[(2 * layer + kv) * part.shape[2] + tables]
+        g = flat[(part.shape[1] * layer + kv) * part.shape[2] + tables]
         return g.reshape(b, nb * g.shape[2], g.shape[3])     # (B, S, W)
 
     def read(kv):
@@ -63,7 +65,8 @@ def gather_kv(pool, layer, tables):
         q8, scale = take(pool.q, kv), take(pool.scale, kv)   # scale (B, S, H)
         return q8.astype(_f32) * jnp.repeat(
             scale, q8.shape[-1] // scale.shape[-1], axis=-1)
-    return read(0), read(1)
+    streams = (pool.q if isinstance(pool, QuantKV) else pool).shape[1]
+    return tuple(read(kv) for kv in range(streams))
 
 
 def _valid(n_slots, positions, window):
@@ -165,17 +168,21 @@ def _decode_xla(q, pool, layer, tables, positions, scaling, window):
 CHUNK_BLOCKS = 8
 
 
-def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
-                   buf, sem, *, heads, scaling, window, chunk):
-    """One session (grid step ``b``): walk its live blocks ``chunk`` at
-    a time, two slots deep, and attend them with an online softmax.
+def _attend_live_blocks(layer, tables_ref, pos_ref, pool_ref, buf, sem, q,
+                        *, scaling, window, chunk, rank):
+    """One session (grid step ``b``) of a decode kernel: walk its live
+    blocks ``chunk`` at a time, two slots deep, and attend them with an
+    online softmax -> ``(H, rank)`` fp32.
 
-    Heads lie side by side in a row of the pool: the block-diagonal
-    query and the combine are :func:`_decode_xla`'s."""
+    ``q (H, W)`` against the pool's rows ``(layers, streams, blocks,
+    block_size, W)`` of ``layer``, by way of ``buf (2, streams, chunk,
+    block_size, W)``: the keys are the first stream, the values the first
+    ``rank`` columns of the last (a GPT block's K and V; a latent block's
+    one row, which is both)."""
     b = pl.program_id(0)
-    layer = layer_ref[0]
     nb = tables_ref.shape[1]
-    bs, hd = buf.shape[3], buf.shape[4]
+    bs, w = buf.shape[3], buf.shape[4]
+    heads = q.shape[0]
     t = chunk * bs
     pos = pos_ref[b]
     # live blocks: through the query's own (just written) row; under a
@@ -196,11 +203,6 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
                 sem.at[slot])
             dma.start() if start else dma.wait()
 
-    own = _own_columns(heads, hd)
-    q = q_ref[0]                                        # (1, H*D)
-    # (selected in fp32: the mask has the 32-bit tiling)
-    q_bd = jnp.where(own, jnp.broadcast_to(q.astype(_f32), (heads, hd)),
-                     0.0).astype(q.dtype)
     exact = q.dtype == jnp.bfloat16 and buf.dtype == jnp.bfloat16
 
     @pl.when(first < n_chunks)
@@ -215,14 +217,16 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
         def _():
             fetch(c + 1, 1 - slot, True)
         fetch(c, slot, False)
-        k = buf[slot, 0].reshape(t, hd)
-        v = buf[slot, 1].reshape(t, hd)
+        k = buf[slot, 0].reshape(t, w)
+        v = buf[slot, buf.shape[1] - 1].reshape(t, w)
+        if rank != w:
+            v = v[:, :rank]
         if exact:       # bf16 x bf16 products are exact in fp32
-            s = jax.lax.dot_general(q_bd, k, (((1,), (1,)), ((), ())),
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=_f32)
         else:
             s = jax.lax.dot_general(
-                q_bd.astype(_f32), k.astype(_f32),
+                q.astype(_f32), k.astype(_f32),
                 (((1,), (1,)), ((), ())), preferred_element_type=_f32,
                 precision=jax.lax.Precision.HIGHEST)
         s = s * scaling                                 # (H, T)
@@ -243,10 +247,26 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
 
     m0 = jnp.full((heads, 1), -1e30, _f32)
     l0 = jnp.zeros((heads, 1), _f32)
-    acc0 = jnp.zeros((heads, hd), _f32)
+    acc0 = jnp.zeros((heads, rank), _f32)
     _, l, acc = jax.lax.fori_loop(first, n_chunks, body, (m0, l0, acc0))
-    out = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
-    o_ref[0] = jnp.sum(out, axis=0, keepdims=True)
+    return acc / jnp.where(l > 0, l, 1.0)
+
+
+def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
+                   buf, sem, *, heads, scaling, window, chunk):
+    """Heads lie side by side in a row of the pool: the block-diagonal
+    query and the combine are :func:`_decode_xla`'s, the walk between
+    them :func:`_attend_live_blocks`'s."""
+    hd = buf.shape[4]
+    own = _own_columns(heads, hd)
+    q = q_ref[0]                                        # (1, H*D)
+    # (selected in fp32: the mask has the 32-bit tiling)
+    q_bd = jnp.where(own, jnp.broadcast_to(q.astype(_f32), (heads, hd)),
+                     0.0).astype(q.dtype)
+    o = _attend_live_blocks(layer_ref[0], tables_ref, pos_ref, pool_ref, buf,
+                            sem, q_bd, scaling=scaling, window=window,
+                            chunk=chunk, rank=hd)
+    o_ref[0] = jnp.sum(jnp.where(own, o, 0.0), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("scaling", "window",

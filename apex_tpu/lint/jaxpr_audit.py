@@ -302,7 +302,8 @@ def _optimizer_workload():
 
 def _serve_workload():
     """Prefill + decode through the continuous-batching engine on a
-    2-layer toy LM — populates the prefill_step/decode_step kinds."""
+    2-layer toy LM — populates the prefill_step/decode_step kinds.
+    Returns the engine: its programs leave the step cache with it."""
     import apex_tpu.nn as nn
     from apex_tpu.models.gpt import GptModel
     from apex_tpu.serve import Request, ServeEngine
@@ -313,6 +314,7 @@ def _serve_workload():
     model.eval()
     eng = ServeEngine(model, num_blocks=24, block_size=4, max_batch=2)
     eng.run([Request("a", [3, 7, 5], 3), Request("b", [9, 2], 3)])
+    return eng
 
 
 def _trace_entry(entry):
@@ -438,8 +440,9 @@ def _run_into(res: AuditResult) -> None:
         rex.donation.set("auto")
 
     n3 = _n_entries()
-    _serve_workload()
+    serve_engine = _serve_workload()
     serve_entries = _entries_after(n3)
+    del serve_engine
 
     for e in train_off:
         res.programs.append(_audit_entry(

@@ -151,6 +151,44 @@ class GptBlock(nn.Module):
             x = x + o
         return x + self._ffn(ctx, self.ln2.forward(ctx, x))
 
+    # -- the serve engine's layer protocol (serve/kernels.py) --------------
+
+    @property
+    def cache_rows(self):
+        """What the block keeps of a token in a paged pool:
+        ``(streams, heads, head_dim)`` — a K and a V row of all heads."""
+        return 2, self.attn.num_heads, self.attn.head_dim
+
+    def chunk_rows(self, ctx, x, positions):
+        """``x (B, Q, E)`` at ``positions (B, Q)`` -> the queries ``(B,
+        H, Q, D)`` and the rows to store, ``(B, Q, H*D)`` each.  The
+        positions are unused: a learned position is added at the
+        embedding."""
+        q, k_new, v_new = self._chunk_qkv(ctx, x)
+        b, h, s_q, d = k_new.shape
+        rows = tuple(jnp.swapaxes(r, 1, 2).reshape(b, s_q, h * d)
+                     for r in (k_new, v_new))
+        return q, rows
+
+    def read_decode(self, q, pool, layer, tables, positions, window):
+        """One query row a session through the block table."""
+        from ..kernels.paged_attention import paged_decode_attention
+        return paged_decode_attention(q[:, :, 0], pool, layer, tables,
+                                      positions, self.attn.scaling,
+                                      window)[:, None]
+
+    def read_chunk(self, q, pool, layer, tables, positions, window):
+        """A chunk of query rows against a gathered view of the tables'
+        blocks of one layer, in the pool's dtype."""
+        from ..kernels.paged_attention import attend, gather_kv
+        k, v = gather_kv(pool, layer, tables)
+        return attend(q, k, v, positions, self.attn.scaling, window)
+
+    def finish(self, ctx, x, o, live):
+        """The block's output from its attention's, and what the block
+        counted on the way over the ``live`` rows (nothing here)."""
+        return self._attn_mlp_tail(ctx, x, o.astype(x.dtype)), None
+
     def prefill(self, ctx, x, kcache, vcache):
         """Cache-filling forward from position 0: flash causal attention
         over the chunk (the caches are empty) + KV writes — one pass for

@@ -80,3 +80,13 @@ def param_values(params) -> list[jax.Array]:
 
 def param_grads(params) -> list:
     return [p.grad for p in params]
+
+
+def abstract_parameter(shape, dtype, name: str | None = None) -> Parameter:
+    """A parameter with a shape and a type and no value yet
+    (``data`` is a ``jax.ShapeDtypeStruct``): for a model whose weights
+    are loaded or published after it is built, and too large to draw
+    first.  Nothing can be computed from it until ``data`` is set."""
+    p = Parameter(jnp.zeros((), dtype), name)
+    p.data = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+    return p

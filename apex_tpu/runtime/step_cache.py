@@ -156,6 +156,18 @@ class StepCache:
         with self._lock:
             self._programs.clear()
 
+    def discard(self, match) -> int:
+        """Drop every entry whose ``(kind, static_key)`` satisfies
+        ``match``; returns how many.  For programs that can never be hit
+        again (their static key names an owner that is gone): an entry
+        holds its program's closure, and with it whatever that closes
+        over, until the LRU turns it out."""
+        with self._lock:
+            dead = [k for k in self._programs if match(k[0], k[1])]
+            for k in dead:
+                del self._programs[k]
+        return len(dead)
+
 
 #: process-global cache shared by every optimizer / amp hook
 step_cache = StepCache()

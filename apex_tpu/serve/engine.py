@@ -37,11 +37,12 @@ PR's satellite, not this one's.
 """
 from __future__ import annotations
 
-import inspect
 import itertools
 import time
+import weakref
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -61,6 +62,18 @@ from .scheduler import DECODE, Request, Scheduler, Session, bucket
 #: program closures hold different parameter objects)
 _SERVE_TOKENS = itertools.count()
 
+
+def _forget_programs(token: int) -> None:
+    """Drop a dead engine's programs from the step cache.  Their static
+    keys start with the engine's token, so nothing can hit them again,
+    and their closures hold the model: until they go, so do its
+    weights (8.6 GB for the latent-MoE cell of the benchmark, whose
+    reference needs that room once the engine is gone)."""
+    from ..runtime import step_cache as _sc
+    _sc.step_cache.discard(
+        lambda kind, key: isinstance(key, tuple) and key[:1] == (token,))
+
+
 #: engine roles in a disaggregated deployment (serve/disagg.py): the
 #: phase joins every serve program's static key, so a prefill engine
 #: and a decode engine over the same weights never collide in the step
@@ -69,11 +82,15 @@ PHASES = ("unified", "prefill", "decode")
 
 
 class ServeEngine:
-    """Continuous-batching paged-KV serving over a GPT-protocol model.
+    """Continuous-batching paged-KV serving over a model whose blocks
+    follow the layer protocol of ``serve/kernels.py`` (a GPT block: a K
+    and a V row a token, learned positions; a latent block: one latent
+    row, rotary positions, routed experts).  What differs between them
+    is read off the model's layers, never set here.
 
-    ``num_blocks`` sizes the shared pool (one block =
-    ``block_size × layers × 2 × heads × head_dim`` KV rows; block 0 is
-    the reserved null block).  ``cache_dtype`` follows the session
+    ``num_blocks`` sizes the shared pool (one block = ``block_size ×
+    layers`` tokens' rows, whose streams and width are the layers'
+    ``cache_rows``; block 0 is the reserved null block).  ``cache_dtype`` follows the session
     convention — default the token-embedding dtype, ``"int8"`` for the
     quantized pool.  ``window`` enables sliding-window attention with
     block-table retirement (rolling.py's band, generalized).
@@ -93,15 +110,12 @@ class ServeEngine:
         self.num_blocks = int(num_blocks)
         self.window = window
         self._phase = phase
-        blk0 = model.blocks[0]
         self._params = list(model.parameters()) + list(model.buffers())
         dtype = cache_dtype if cache_dtype is not None \
             else model.tok_emb.weight.data.dtype
         self._dtype_name = dtype if isinstance(dtype, str) \
             else jnp.dtype(dtype).name
-        self.pool = init_pool_buffer(
-            len(model.blocks), blk0.attn.num_heads, blk0.attn.head_dim,
-            self.num_blocks, self.block_size, dtype)
+        self.pool = self._pool_for(model, dtype)
         self.block_pool = BlockPool(self.num_blocks, self.block_size)
         # -- speculative mode: a draft model served from its OWN pool
         # buffer (int8 by default — weight-only drafts are bandwidth
@@ -116,17 +130,13 @@ class ServeEngine:
         if self.spec:
             self._validate_spec(model, draft, window, self.spec_k,
                                 spec_policy)
-            dblk0 = draft.blocks[0]
             self._d_params = list(draft.parameters()) \
                 + list(draft.buffers())
             d_dtype = draft_cache_dtype if draft_cache_dtype is not None \
                 else draft.tok_emb.weight.data.dtype
             self._d_dtype_name = d_dtype if isinstance(d_dtype, str) \
                 else jnp.dtype(d_dtype).name
-            self.dpool = init_pool_buffer(
-                len(draft.blocks), dblk0.attn.num_heads,
-                dblk0.attn.head_dim, self.num_blocks, self.block_size,
-                d_dtype)
+            self.dpool = self._pool_for(draft, d_dtype)
         if max_prefill_backlog is None:
             max_prefill_backlog = 4 * prefill_chunk
         self.scheduler = Scheduler(
@@ -139,6 +149,7 @@ class ServeEngine:
             prefix_cache=prefix_cache,
             cache_tag=self._cache_tag(epoch=0))
         self._token = next(_SERVE_TOKENS)
+        weakref.finalize(self, _forget_programs, self._token)
         self._donate = _executor.donation.enabled
         self._decode_prog = None
         self._prefill_prog = None
@@ -147,6 +158,10 @@ class ServeEngine:
         self._spec_prog = None
         self._dispatch_no = itertools.count(1)
         self._tick = 0
+        # what this tick's programs counted (routed layers' pairs a held
+        # expert), device arrays until the tick's fetch
+        self._counted: List = []
+        self._fetched: List = []
         # prefix-cache telemetry (admission-weighted; the pool keeps
         # its own eviction counter)
         self._prefill_tokens_saved = 0
@@ -164,28 +179,33 @@ class ServeEngine:
         self.weight_epochs: Dict[str, int] = {"target": 0, "draft": 0}
         self.result_meta: Dict[str, dict] = {}
 
+    def _pool_for(self, model, dtype):
+        """The pool buffer whose geometry is the model's layers'."""
+        streams, heads, head_dim = model.blocks[0].cache_rows
+        return init_pool_buffer(len(model.blocks), heads, head_dim,
+                                self.num_blocks, self.block_size, dtype,
+                                streams=streams)
+
     @staticmethod
     def _validate_model(model):
-        for a in ("blocks", "tok_emb", "pos_emb", "ln_f",
-                  "_mask_pad_logits", "max_positions"):
+        for a in ("blocks", "tok_emb", "ln_f", "_mask_pad_logits",
+                  "max_positions"):
             if not hasattr(model, a):
                 raise ValueError(
-                    f"ServeEngine needs model.{a} (the GPT decode "
-                    f"protocol)")
-        blk = model.blocks[0]
-        for a in ("_chunk_qkv", "_attn_mlp_tail"):
-            if not hasattr(blk, a):
-                raise ValueError(
-                    f"ServeEngine needs block.{a} — paged attention "
-                    f"reuses the model's own decode projections")
-        # Llama's _chunk_qkv(ctx, x, pos) applies RoPE inside the
-        # projection — the paged bodies would silently skip it
-        if len(inspect.signature(blk._chunk_qkv).parameters) != 2:
+                    f"ServeEngine needs model.{a} (the decode protocol)")
+        for blk in model.blocks:
+            for a in ("cache_rows", "chunk_rows", "read_decode",
+                      "read_chunk", "finish"):
+                if not hasattr(blk, a):
+                    raise ValueError(
+                        f"ServeEngine needs block.{a} — the layer "
+                        f"protocol of serve/kernels.py "
+                        f"({type(blk).__name__} does not follow it)")
+        rows = {tuple(blk.cache_rows) for blk in model.blocks}
+        if len(rows) != 1:
             raise NotImplementedError(
-                "ServeEngine supports the GPT-family cache protocol "
-                "(_chunk_qkv(ctx, x)); rotary-position families need "
-                "position-aware paged projections — use the "
-                "single-request decode paths for now")
+                f"ServeEngine keeps one pool: every layer has to store "
+                f"the same rows a token, the model's store {sorted(rows)}")
         axes = _sharded_decode_axes(model)
         if axes:
             names = ", ".join(f"{a}='{v}'" for a, v in axes)
@@ -436,6 +456,11 @@ class ServeEngine:
         with _spans.span("serve.step", tick=self._tick, decode_batch=0,
                          prefill_rid=None) as tick:
             more = self._run_tick(tick)
+            if self._counted:       # what no decode fetch took with it
+                self._fetched += jax.device_get(self._counted)
+                self._counted = []
+            if self._fetched:
+                self._publish_moe(tick)
         if tick["decode_batch"]:
             _obs.histogram("serve.decode_tick_ms").observe(tick["dur_ms"])
         return more
@@ -536,12 +561,14 @@ class ServeEngine:
         toks += [0] * (chunk - n)
         nb = bucket(len(s.table))
         table = s.table + [0] * (nb - len(s.table))
-        last, self.pool = _executor.executor.submit(
+        last, self.pool, counted = _executor.executor.submit(
             prefill_prog,
             (self._vals(), self.pool,
              np.asarray([toks], np.int32), np.asarray([table], np.int32),
              np.int32(t0), np.int32(n)),
             step=next(self._dispatch_no))
+        if counted is not None:
+            self._counted.append(counted)
         if self.spec and s.draft_position == t0:
             # lockstep draft ingest: the draft's cache tracks the
             # target's row for row through prefill (and recompute
@@ -552,7 +579,7 @@ class ServeEngine:
             draft_prog, _ = self._spec_programs()
             nbd = bucket(len(s.draft_table))
             d_table = s.draft_table + [0] * (nbd - len(s.draft_table))
-            _dl, self.dpool = _executor.executor.submit(
+            _dl, self.dpool, _ = _executor.executor.submit(
                 draft_prog,
                 (self._d_vals(), self.dpool,
                  np.asarray([toks], np.int32),
@@ -599,7 +626,7 @@ class ServeEngine:
         toks = list(fed[d0:d0 + n]) + [0] * (chunk - n)
         nbd = bucket(len(s.draft_table))
         d_table = s.draft_table + [0] * (nbd - len(s.draft_table))
-        _dl, self.dpool = _executor.executor.submit(
+        _dl, self.dpool, _ = _executor.executor.submit(
             draft_prog,
             (self._d_vals(), self.dpool,
              np.asarray([toks], np.int32), np.asarray([d_table], np.int32),
@@ -664,14 +691,23 @@ class ServeEngine:
         with _spans.span("serve.pack"):
             b, nb, tokens, positions, tables = \
                 self.scheduler.pack_decode(sessions)
-        nxt, _logits, self.pool = _executor.executor.submit(
+        nxt, _logits, self.pool, counted = _executor.executor.submit(
             decode_prog,
             (self._vals(), self.pool,
              np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
              np.asarray(tables, np.int32)),
             step=next(self._dispatch_no))
+        if counted is not None:
+            self._counted.append(counted)
         with _spans.span("serve.fetch", what="tokens"):
-            nxt = np.asarray(nxt)
+            if self._counted:
+                # the layers' counts of this tick's programs (a prefill
+                # chunk's too) come over in the same fetch as the tokens
+                nxt, fetched = jax.device_get((nxt, self._counted))
+                self._fetched += fetched
+                self._counted = []
+            else:
+                nxt = np.asarray(nxt)
         with _spans.span("serve.commit", n_finished=0) as rec:
             for i, s in enumerate(sessions):
                 s.position += 1
@@ -684,6 +720,23 @@ class ServeEngine:
                 if s.finished():
                     self._finish(s)
                     rec["n_finished"] += 1
+
+    def _publish_moe(self, tick: dict) -> None:
+        """What the tick's programs counted in their routed layers —
+        each ``(routed layers, held experts)`` token-expert pairs —
+        as ``serve.moe.*`` counters and on the tick's ``serve.step``
+        record (docs/observability.md)."""
+        per_program = np.stack(self._fetched)       # (programs, layers, held)
+        self._fetched = []
+        tick["moe_pairs"] = int(per_program.sum())
+        # (program, layer, expert) cells with a pair: how often an
+        # expert's matrices had to be streamed in this tick
+        tick["moe_experts_hit"] = int((per_program > 0).sum())
+        tick["moe_pairs_max"] = int(per_program.max())
+        tick["moe_layers"], tick["moe_held"] = per_program.shape[1:]
+        _obs.counter("serve.moe.pairs").inc(tick["moe_pairs"])
+        _obs.counter("serve.moe.experts_hit").inc(tick["moe_experts_hit"])
+        _obs.gauge("serve.moe.pairs_max").set(tick["moe_pairs_max"])
 
     def _spec_tick(self, sessions: List[Session]) -> None:
         """One batched speculative tick: a single ``spec_verify_step``

@@ -10,26 +10,32 @@ positions, block tables), so session churn re-dispatches the same
 compiled program instead of retracing — the serving analogue of the
 step-cache keying discipline, enforced by the SERVE-SHAPE lint rule.
 
-The attention math deliberately reuses the model's own decode pieces —
-``GptBlock._chunk_qkv`` (LN1 + interleaved QKV projection),
-``GptBlock._attn_mlp_tail`` (out-proj + residual + FFN), the fp32
-score product + ``-1e30`` mask + softmax of ``GptBlock.decode_chunk``,
-and the int8-aware ``gather_rows`` embedding lookup — so the paged
-path cannot drift numerically from the contiguous-cache path it is
-parity-tested against (tests/test_serve.py, tests/test_serve_paged.py).
-The only new math is the index plumbing, and it keeps the pool where it
-lies (``serve/pool.py``: ``(layers, 2, num_blocks, block_size,
-heads*head_dim)``, row-major on the device):
+Every layer of a servable model follows ONE protocol
+(:func:`_through_blocks`): the body hands it the chunk and its positions
+and gets back its queries and the row(s) to store; the layer says what
+it keeps of a token and how a query reads it.  ``GptBlock`` keeps a K
+and a V row of all heads (its learned positions were added at the
+embedding) and reuses the model's own decode pieces —
+``_chunk_qkv`` (LN1 + interleaved QKV projection), ``_attn_mlp_tail``
+(out-proj + residual + FFN), the fp32 score product + ``-1e30`` mask +
+softmax of ``decode_chunk``, the int8-aware ``gather_rows`` embedding
+lookup — so the paged path cannot drift numerically from the
+contiguous-cache path it is parity-tested against (tests/test_serve.py,
+tests/test_serve_paged.py); a latent block (``models/latent_moe.py``)
+rotates by the positions and keeps one latent row a token, read by all
+heads alike.  What is here is the index plumbing, and it keeps the pool
+where it lies (``serve/pool.py``: ``(layers, streams, num_blocks,
+block_size, width)``, row-major on the device):
 
-* **write** — each layer sets its fresh K and V rows in the donated
-  pool in place, position -> (physical block, offset), one contiguous
-  row each (:func:`write_rows`), BEFORE that layer attends: the
-  write-then-read of ``decode_chunk``, so a query finds its own key
-  where every other key is;
+* **write** — each layer sets its fresh rows in the donated pool in
+  place, position -> (physical block, offset), one contiguous row each
+  (:func:`write_rows`), BEFORE that layer attends: the write-then-read
+  of ``decode_chunk``, so a query finds its own key where every other
+  key is;
 * **read, decode** — one query row a session: through the block table
-  (:func:`~apex_tpu.kernels.paged_attention.paged_decode_attention`: a
-  Pallas kernel that DMAs the live blocks, with a per-layer gather as
-  its XLA tier);
+  (``paged_decode_attention`` / ``latent_decode_attention``: Pallas
+  kernels that DMA the live blocks, each with a per-layer gather as its
+  XLA tier);
 * **read, prefill and speculative verify** — many query rows, few
   sessions: a gathered view of those sessions' blocks, one layer at a
   time, in the pool's dtype.
@@ -50,8 +56,6 @@ import jax
 import jax.numpy as jnp
 
 from ..inference import QuantKV, absmax_int8, gather_rows
-from ..kernels.paged_attention import (attend, gather_kv,
-                                       paged_decode_attention)
 from ..nn.modules import Ctx
 
 _f32 = jnp.float32
@@ -67,41 +71,42 @@ def _ctx(params, vals):
 # ---------------------------------------------------------------------------
 
 
-def row_targets(tables, positions, live, block_size, num_blocks):
-    """Where the K and V rows of logical ``positions (B, Q)`` live in
-    one layer of the pool: index arrays ``(kv, block, offset)``, each
-    ``(2*B*Q,)`` — the K rows, then the V rows.  Rows that are not
-    ``live (B, Q)`` (bucket padding, a chunk's zero-padded tail) point
-    past the pool, so :func:`write_rows` drops them — padding never
-    touches the null block's zeros."""
+def row_targets(tables, positions, live, block_size, num_blocks, streams):
+    """Where the rows of logical ``positions (B, Q)`` live in one layer
+    of the pool: index arrays ``(stream, block, offset)``, each
+    ``(streams*B*Q,)`` — the first stream's rows, then the next's.  Rows
+    that are not ``live (B, Q)`` (bucket padding, a chunk's zero-padded
+    tail) point past the pool, so :func:`write_rows` drops them —
+    padding never touches the null block's zeros."""
     p = jnp.clip(positions, 0)
     tgt = jnp.take_along_axis(
         tables, jnp.minimum(p // block_size, tables.shape[1] - 1), axis=1)
     tgt = jnp.where(live, tgt, num_blocks).reshape(-1)
-    kv = jnp.repeat(jnp.arange(2, dtype=tgt.dtype), tgt.shape[0])
-    return kv, jnp.tile(tgt, 2), jnp.tile((p % block_size).reshape(-1), 2)
+    stream = jnp.repeat(jnp.arange(streams, dtype=tgt.dtype), tgt.shape[0])
+    return stream, jnp.tile(tgt, streams), \
+        jnp.tile((p % block_size).reshape(-1), streams)
 
 
-def write_rows(pool, layer, targets, k_new, v_new):
-    """Set the fresh K and V rows of one layer in the (donated) pool, in
-    place: ``k_new, v_new (B, H, Q, D)`` go to ``pool[layer, kv, block,
+def write_rows(pool, layer, targets, rows):
+    """Set the fresh rows of one layer in the (donated) pool, in place:
+    ``rows``, one ``(B, Q, width)`` array a stream (a GPT block's K and
+    V; a latent block's one row), go to ``pool[layer, stream, block,
     offset, :]`` (``targets`` from :func:`row_targets`), each a
-    contiguous row of ``H*D`` elements.  Rows whose block id points past
-    the pool are dropped.  QuantKV pools quantize per position and head
-    (absmax over D — identical stored bytes to the contiguous int8
+    contiguous row.  Rows whose block id points past the pool are
+    dropped.  QuantKV pools quantize per position and head (absmax over
+    the head's columns — identical stored bytes to the contiguous int8
     cache's write path)."""
-    b, h, s_q, d = k_new.shape
-    rows = jnp.concatenate(
-        [jnp.swapaxes(k_new, 1, 2).reshape(b * s_q, h, d),
-         jnp.swapaxes(v_new, 1, 2).reshape(b * s_q, h, d)])  # (2R, H, D)
+    width = rows[0].shape[-1]
+    rows = jnp.concatenate([r.reshape(-1, width) for r in rows])
     at = (layer,) + tuple(targets)
     if isinstance(pool, QuantKV):
-        q, scale = absmax_int8(rows.astype(_f32), -1, pool.scale.dtype)
+        h = pool.scale.shape[-1]
+        q, scale = absmax_int8(rows.reshape(-1, h, width // h).astype(_f32),
+                               -1, pool.scale.dtype)
         return QuantKV(
-            pool.q.at[at].set(q.reshape(-1, h * d), mode="drop"),
+            pool.q.at[at].set(q.reshape(-1, width), mode="drop"),
             pool.scale.at[at].set(scale[..., 0], mode="drop"))
-    return pool.at[at].set(
-        rows.reshape(-1, h * d).astype(pool.dtype), mode="drop")
+    return pool.at[at].set(rows.astype(pool.dtype), mode="drop")
 
 
 def build_block_copy_fn():
@@ -131,84 +136,95 @@ def build_block_copy_fn():
 
 
 def _embed(ctx, model, toks, positions):
-    """Token + position embedding with int8-aware row gathers;
-    ``positions`` clip to the table (pad rows only — real positions are
-    range-checked at admission, where the bound is a host decision, not
-    here where a clamp would silently corrupt)."""
-    n_pos = model.pos_emb.weight.shape[0]
-    pos = jnp.clip(positions, 0, n_pos - 1)
-    return gather_rows(ctx, model.tok_emb.weight, toks) \
-        + gather_rows(ctx, model.pos_emb.weight, pos)
+    """Token embedding (int8-aware row gather), plus the learned
+    position's where the model has a table of them (a rotary model's
+    positions reach its layers instead); ``positions`` clip to the table
+    (pad rows only — real positions are range-checked at admission,
+    where the bound is a host decision, not here where a clamp would
+    silently corrupt)."""
+    x = gather_rows(ctx, model.tok_emb.weight, toks)
+    pos_emb = getattr(model, "pos_emb", None)
+    if pos_emb is None:
+        return x
+    pos = jnp.clip(positions, 0, pos_emb.weight.shape[0] - 1)
+    return x + gather_rows(ctx, pos_emb.weight, pos)
 
 
 def _head(ctx, model, x):
-    emb = ctx.value(model.tok_emb.weight)
-    return model._mask_pad_logits(
-        jnp.matmul(x, jnp.swapaxes(emb, 0, 1).astype(x.dtype)))
+    """Logits through the model's own head, else the tied embedding."""
+    table = getattr(model, "lm_head", model.tok_emb).weight
+    return model._mask_pad_logits(jnp.matmul(
+        x, jnp.swapaxes(ctx.value(table), 0, 1).astype(x.dtype)))
 
 
 def _through_blocks(ctx, model, pool, x, q_pos, live, tables, block_size,
-                    num_blocks, read):
+                    num_blocks, window, *, decode):
     """``x (B, Q, E)`` at positions ``q_pos (B, Q)``, of which ``live
-    (B, Q)`` are real, through every block.  Each layer writes the live
-    rows' KV into the pool and then attends it through ``read(q, pool,
-    layer, scaling) -> (B, Q, H*D)`` — the write-then-read of
-    ``GptBlock.decode_chunk``, so a query finds its own key where every
-    other key is (through an int8 pool: exactly the bytes stored)."""
-    targets = row_targets(tables, q_pos, live, block_size, num_blocks)
+    (B, Q)`` are real, through every block (``decode``: the tick's one
+    row a session, ``Q == 1``), by the one layer protocol every servable
+    block follows:
+
+    * ``blk.cache_rows`` — ``(streams, heads, head_dim)``: what the block
+      keeps of a token (the pool's geometry is read off it);
+    * ``blk.chunk_rows(ctx, x, positions) -> (q, rows)`` — its queries
+      and the rows to store, positions in hand (rotary or unused);
+    * ``blk.read_decode`` / ``blk.read_chunk(q, pool, layer, tables,
+      positions, window)`` — how a query reads the stored rows: one
+      query row a session through the block table (the decode tick), or
+      a chunk of rows against a gathered view of these sessions' blocks,
+      one layer at a time, in the pool's dtype;
+    * ``blk.finish(ctx, x, o, live) -> (x, counted)`` — the rest of the
+      block, and what it counted on the way over the ``live`` rows (a
+      routed layer's token-expert pairs a held expert; None).
+
+    Each layer writes the live rows into the pool and then attends —
+    the write-then-read of ``GptBlock.decode_chunk``, so a query finds
+    its own key where every other key is (through an int8 pool: exactly
+    the bytes stored).  Returns ``(x, pool, counted)``: ``counted`` the
+    layers' counts stacked ``(layers that count, ...)``, None if none
+    does."""
+    streams = model.blocks[0].cache_rows[0]
+    targets = row_targets(tables, q_pos, live, block_size, num_blocks,
+                          streams)
+    pos = q_pos[:, 0] if decode else q_pos
+    counted = []
     for layer, blk in enumerate(model.blocks):
-        q, k_new, v_new = blk._chunk_qkv(ctx, x)          # (B, H, Q, D)
-        pool = write_rows(pool, layer, targets, k_new, v_new)
-        o = read(q, pool, layer, blk.attn.scaling)
-        x = blk._attn_mlp_tail(ctx, x, o.astype(x.dtype))
-    return x, pool
-
-
-def _decode_layers(ctx, model, pool, x, positions, tables, block_size,
-                   num_blocks, window):
-    """One token a session, ``x (B, 1, E)`` at ``positions (B,)``: the
-    reader goes through the block table."""
-    def read(q, pool, layer, scaling):
-        return paged_decode_attention(q[:, :, 0], pool, layer, tables,
-                                      positions, scaling, window)[:, None]
-    return _through_blocks(ctx, model, pool, x, positions[:, None],
-                           positions[:, None] >= 0, tables, block_size,
-                           num_blocks, read)
-
-
-def _chunk_layers(ctx, model, pool, x, q_pos, live, tables, block_size,
-                  num_blocks, window):
-    """A chunk of query rows a session: the reader gathers the tables'
-    blocks of one layer — these sessions' blocks only, in the pool's
-    dtype — and attends the view."""
-    def read(q, pool, layer, scaling):
-        k, v = gather_kv(pool, layer, tables)
-        return attend(q, k, v, q_pos, scaling, window)
-    return _through_blocks(ctx, model, pool, x, q_pos, live, tables,
-                           block_size, num_blocks, read)
+        q, rows = blk.chunk_rows(ctx, x, q_pos)
+        pool = write_rows(pool, layer, targets, rows)
+        read = blk.read_decode if decode else blk.read_chunk
+        x, n = blk.finish(ctx, x, read(q, pool, layer, tables, pos, window),
+                          live)
+        if n is not None:
+            counted.append(n)
+    return x, pool, jnp.stack(counted) if counted else None
 
 
 def build_decode_fn(model, params, block_size, num_blocks, window=None):
     """The decode-tick program body: one token per live session.
 
     ``fn(vals, pool, tokens, positions, tables) ->
-    (next_tokens, logits, pool)`` with ``tokens (B,)`` the last emitted
+    (next_tokens, logits, pool, counted)`` with ``tokens (B,)`` the last emitted
     token per session, ``positions (B,)`` its ingest position (``-1`` =
     dead pad row), ``tables (B, nb)``.  Greedy sampling happens
     in-program (argmax over the masked logits — the same reduction the
     session path's ``make_sampler(0, ...)`` runs), so the engine's host
     round-trip per tick is one small int array; the logits ride along
     as an un-fetched device array for clients (PagedSession) that
-    continue from them."""
+    continue from them.  ``counted`` is what the layers counted on the
+    way (:func:`_through_blocks`: a routed layer's token-expert pairs a
+    held expert, ``(routed layers, held experts)`` i32), None for a
+    model whose layers count nothing; the engine fetches it with the
+    tokens."""
     def fn(vals, pool, tokens, positions, tables):
         ctx = _ctx(params, vals)
         x = _embed(ctx, model, tokens[:, None], positions[:, None])
-        x, pool = _decode_layers(ctx, model, pool, x, positions, tables,
-                                 block_size, num_blocks, window)
+        x, pool, counted = _through_blocks(
+            ctx, model, pool, x, positions[:, None], positions[:, None] >= 0,
+            tables, block_size, num_blocks, window, decode=True)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)[:, 0]               # (B, V)
         nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
-        return nxt, logits, pool
+        return nxt, logits, pool, counted
     return fn
 
 
@@ -219,27 +235,28 @@ def build_prefill_fn(model, params, block_size, num_blocks,
     chunks, interleaved with decode ticks so they never stall the
     batch).
 
-    ``fn(vals, pool, toks, table, t0, n_real) -> (last_logits, pool)``
+    ``fn(vals, pool, toks, table, t0, n_real) -> (last_logits, pool,
+    counted)``
     with ``toks (1, chunk)`` zero-padded past ``n_real``, ``table
     (1, nb)``, ``t0`` the chunk's first position, ``n_real`` the live
     prefix length (both traced i32 — the bucketed chunk width, not the
     prompt length, keys compilation).  ``last_logits (1, V)`` is row
     ``n_real - 1`` — the next-token distribution once the final chunk
-    lands."""
+    lands; ``counted`` as the decode body's."""
     def fn(vals, pool, toks, table, t0, n_real):
         ctx = _ctx(params, vals)
         rows = jnp.arange(toks.shape[1], dtype=jnp.int32)
         pos = (t0 + rows)[None, :]                        # (1, chunk)
         x = _embed(ctx, model, toks, pos)
         # chunk row d lands at position t0 + d; live rows only
-        x, pool = _chunk_layers(ctx, model, pool, x, pos,
-                                (rows < n_real)[None, :], table,
-                                block_size, num_blocks, window)
+        x, pool, counted = _through_blocks(
+            ctx, model, pool, x, pos, (rows < n_real)[None, :], table,
+            block_size, num_blocks, window, decode=False)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)                  # (1, chunk, V)
         last = jax.lax.dynamic_index_in_dim(
             logits, jnp.clip(n_real - 1, 0), axis=1, keepdims=False)
-        return last, pool
+        return last, pool, counted
     return fn
 
 
@@ -293,9 +310,9 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
         for j in range(kp1):
             pos_j = jnp.where(live, positions + j, -1)
             x = _embed(d_ctx, draft, tok[:, None], pos_j[:, None])
-            x, d_pool = _decode_layers(d_ctx, draft, d_pool, x, pos_j,
-                                       d_tables, block_size, num_blocks,
-                                       None)
+            x, d_pool, _ = _through_blocks(
+                d_ctx, draft, d_pool, x, pos_j[:, None], pos_j[:, None] >= 0,
+                d_tables, block_size, num_blocks, None, decode=True)
             if j < k:                  # step k only writes its KV row
                 x = draft.ln_f.forward(d_ctx, x)
                 logits = _head(d_ctx, draft, x)[:, 0]
@@ -307,8 +324,9 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
         q_live = jnp.broadcast_to(live[:, None], chunk.shape)
         q_pos = jnp.where(q_live, positions[:, None] + offs_q, -1)
         x = _embed(t_ctx, target, chunk, q_pos)
-        x, t_pool = _chunk_layers(t_ctx, target, t_pool, x, q_pos, q_live,
-                                  t_tables, block_size, num_blocks, None)
+        x, t_pool, _ = _through_blocks(
+            t_ctx, target, t_pool, x, q_pos, q_live, t_tables, block_size,
+            num_blocks, None, decode=False)
         x = target.ln_f.forward(t_ctx, x)
         logits = _head(t_ctx, target, x)                # (B, kp1, V)
         emitted = jnp.argmax(logits, axis=-1).astype(tokens.dtype)

@@ -14,11 +14,14 @@ built around — the decode program's operand shapes depend only on the
 POOL geometry and the bucket dims, never on which sessions are resident,
 so session churn cannot force a recompile.
 
-Layout: ``(layers, 2, num_blocks, block_size, heads*head_dim)`` — k/v
-on axis 1, block id on axis 2 so a session's table indexes one axis, and
-one token's row of all heads contiguous and minor-most (a whole number
-of lane rows, so the device keeps the array row-major and the serve
-programs read and write it where it lies).  **Physical block 0 is the null
+Layout: ``(layers, streams, num_blocks, block_size, heads*head_dim)`` —
+the geometry is the model's layers' (``block.cache_rows``): a GPT block
+keeps two streams, k and v on axis 1, each a row of all heads; a latent
+(MLA) block keeps one stream of one "head", the token's latent row.
+Block id on axis 2 so a session's table indexes one axis, and one
+token's row contiguous and minor-most (a whole number of lane rows, so
+the device keeps the array row-major and the serve programs read and
+write it where it lies).  **Physical block 0 is the null
 block**: it is never allocated, stays all-zeros, and pads every block
 table out to its bucket width — gathers through it read zeros that the
 position-validity mask already excludes, so padding is free instead of
@@ -79,20 +82,35 @@ def blocks_for(n_positions: int, block_size: int) -> int:
 
 
 def init_pool_buffer(layers, heads, head_dim, num_blocks, block_size,
-                     dtype=jnp.float32):
+                     dtype=jnp.float32, streams=2):
     """The device-side pool array
-    ``(layers, 2, num_blocks, block_size, heads*head_dim)`` — zeros, so
-    the null block is born valid.  One token's K (or V) of one layer is
-    one contiguous row of ``heads*head_dim`` elements (head ``h`` at
-    ``[h*head_dim, (h+1)*head_dim)``) and one block is one contiguous
-    piece: with a minor dimension that is a whole number of lane rows
-    the device stores the array row-major, which is the layout both the
-    row writes and the block-table reads of serve/kernels.py want, so no
-    program relayouts it.  ``dtype="int8"``/``jnp.int8`` builds the
-    :class:`QuantKV` pair: int8 payload in the same shape, scales fp32
-    ``(layers, 2, num_blocks, block_size, heads)`` — one per position
-    and head."""
-    shape = (layers, 2, num_blocks, block_size, heads * head_dim)
+    ``(layers, streams, num_blocks, block_size, heads*head_dim)`` —
+    zeros, so the null block is born valid.  ``streams, heads, head_dim``
+    are a layer's ``cache_rows``: what it keeps of one token is
+    ``streams`` contiguous rows of ``heads*head_dim`` elements (head
+    ``h`` at ``[h*head_dim, (h+1)*head_dim)``), and one block is one
+    contiguous piece: with a minor dimension that is a whole number of
+    lane rows the device stores the array row-major, which is the layout
+    both the row writes and the block-table reads of serve/kernels.py
+    want, so no program relayouts it.
+
+    **A latent row is stored 640 wide, not 576.**  A latent layer keeps
+    ``c_kv`` (512) and the rotated ``k_rope`` (64) of a token: 576
+    elements, 4.5 lane rows.  Stored as ``(1, 1, 640)`` — one stream,
+    one row read by every head alike, zeros in the last 64 — a row is
+    five whole lane rows, so the device keeps the pool row-major as it
+    does a GPT pool (a minor dimension of 576 it would pad to 640 in its
+    tiles anyway, and a block would no longer be one contiguous piece
+    for the table reader's DMA), and the absorbed query ``[q_lat |
+    q_rope | 0]`` meets a whole row in ONE product, with no split of the
+    row at a half lane row.  512 + 128 as two streams costs the same
+    bytes and two DMAs a block.  The price is 64 / 576 = 11% more bytes
+    read than the algorithm needs; rooflines count 576.
+
+    ``dtype="int8"``/``jnp.int8`` builds the :class:`QuantKV` pair: int8
+    payload in the same shape, scales fp32 ``(layers, streams,
+    num_blocks, block_size, heads)`` — one per position and head."""
+    shape = (layers, streams, num_blocks, block_size, heads * head_dim)
     if jnp.dtype(dtype) == jnp.dtype("int8"):
         return QuantKV(jnp.zeros(shape, jnp.int8),
                        jnp.zeros(shape[:-1] + (heads,), jnp.float32))

@@ -20,7 +20,9 @@ serve/kernels.py are policy-free):
   deadlock), and prefill backlog (``max_prefill_backlog`` tokens not
   yet ingested across admitted sessions — the queue-depth/token-budget
   backpressure that keeps time-to-first-token bounded under load:
-  admitting a 30th long prompt helps nobody's SLO).
+  admitting a 30th long prompt helps nobody's SLO; a prompt longer than
+  the whole budget is admitted when no other prompt is waiting to be
+  ingested).
 * **packing** — every decode tick takes ALL decoding sessions (in
   admission order), padded to the next batch bucket; block tables pad
   to the next block bucket.  Buckets are powers of two, so the set of
@@ -296,8 +298,12 @@ class Scheduler:
                     fork = True
             else:
                 pos0 = hit
-            if self._backlog_tokens() + (len(src) - pos0) \
-                    > self.max_prefill_backlog and self.sessions:
+            # the budget is on tokens not yet ingested: a request
+            # longer than all of it enters when nothing else is waiting
+            # to be ingested (sessions that only decode are no backlog)
+            backlog = self._backlog_tokens()
+            if backlog and backlog + (len(src) - pos0) \
+                    > self.max_prefill_backlog:
                 self.pool.free(shared)
                 break
             cold = need_total - len(shared) + (1 if fork else 0)

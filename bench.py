@@ -911,7 +911,7 @@ def kernel_probe_records(iters=2, reps=3):
                   d_tab):
             tk, p = toks, t_pool
             for j in range(sp_k + 1):
-                tk, _lg, p = dec(t_vals, p, tk, pos + j, t_tab)
+                tk, _lg, p, _ = dec(t_vals, p, tk, pos + j, t_tab)
             return tk, p
         return jax.jit(chain)
     probes.append((
@@ -951,6 +951,53 @@ def kernel_probe_records(iters=2, reps=3):
         kpa.paged_attention_fp(pa_b, pa_nb, pa_h, pa_d, pa_bs, "bfloat16"),
         build_pa, (pa_q, pa_pool, jnp.asarray(pa_tabs),
                    jnp.asarray(pa_pos))))
+
+    # --- latent_attention: the same walk over a latent (one-stream) pool,
+    # absorbed queries of 8 heads against rows of 256 (128 latent) ---
+    from apex_tpu.kernels import latent_attention as kla
+    la_h, la_w, la_rank = 8, 256, 128
+    la_pool = jnp.asarray(
+        rng.standard_normal((1, 1, 1 + pa_b * pa_nb, pa_bs, la_w)),
+        jnp.bfloat16).at[:, :, 0].set(0)
+    la_q = jnp.asarray(rng.standard_normal((pa_b, la_h, la_w)), jnp.bfloat16)
+
+    def build_la(arm):
+        if arm == "pallas":
+            interp = kdispatch.pallas_mode() == "interpret"
+            return jax.jit(lambda q, pool, tabs, pos: kla._decode_pallas(
+                q, pool, 0, tabs, pos, 0.125, la_rank, None, interp))
+        return jax.jit(lambda q, pool, tabs, pos: kla._decode_xla(
+            q, pool, 0, tabs, pos, 0.125, la_rank, None))
+    probes.append((
+        "latent_attention",
+        kla.latent_attention_fp(pa_b, pa_nb, la_h, la_w, la_rank, pa_bs,
+                                "bfloat16"),
+        build_la, (la_q, la_pool, jnp.asarray(pa_tabs),
+                   jnp.asarray(pa_pos))))
+
+    # --- routed_experts: the grouped matmul of rows sorted by expert
+    # (tile-aligned groups, unused tiles skipped) against ragged_dot ---
+    from apex_tpu.kernels import grouped_matmul as kgm
+    gm_g, gm_k, gm_n, gm_p = 4, 256, 128, 64
+    gm_group = jnp.asarray(rng.integers(0, gm_g + 2, gm_p), jnp.int32)
+    gm_lay = kgm.tile_layout(gm_group, gm_g, gm_p, kgm.TILE_ROWS)
+    gm_lhs = jnp.asarray(rng.standard_normal(
+        (gm_lay.pair_of_row.shape[0], gm_k)), jnp.bfloat16)
+    gm_rhs = jnp.asarray(rng.standard_normal((gm_g, gm_k, gm_n)),
+                         jnp.bfloat16)
+
+    def build_gm(arm):
+        if arm == "pallas":
+            interp = kdispatch.pallas_mode() == "interpret"
+            return jax.jit(lambda lhs, rhs, tg, na, padded: kgm._gmm_call(
+                tg, na, lhs, rhs, tile=kgm.TILE_ROWS, interpret=interp))
+        return jax.jit(lambda lhs, rhs, tg, na, padded: kgm._gmm_xla(
+            lhs, rhs, padded))
+    probes.append((
+        "routed_experts",
+        kgm.grouped_matmul_fp(gm_lhs.shape[0], gm_k, gm_n, gm_g, "bfloat16"),
+        build_gm, (gm_lhs, gm_rhs, gm_lay.tile_group, gm_lay.n_active,
+                   gm_lay.padded)))
 
     write_ledger = mode == "compiled"
     led = kledger.get_ledger() if write_ledger else None

@@ -276,6 +276,86 @@ def test_decode_program_reads_through_the_block_table(chip, medium):
         not in compiled.as_text()
 
 
+# -- the serve programs of the benchmark's latent-attention MoE cell -----------
+# (perfbench/configs/gigachat3.1-702b-a36b-ep16.json at its published
+# widths: 8.58 GB of bf16 weights as abstract parameters, a latent pool
+# of 32769 blocks of 16 rows of 640).  The same pin as above for a pool
+# of one stream, and both new kernels under their own names.
+
+
+@pytest.fixture(scope="module")
+def latent():
+    """The cell's model with parameters that have shapes and no values,
+    and its engine settings."""
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    bench = os.path.join(here, "..", "perfbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from pb import cells
+    with open(os.path.join(bench, "configs",
+                           "gigachat3.1-702b-a36b-ep16.json")) as f:
+        cfg = json.load(f)
+    # the benchmark's own way to the program's model (abstract parameters)
+    model = cells.family_module(cfg["builder"]).model(cfg)
+    model.eval()
+    return cfg, model
+
+
+@pytest.mark.parametrize("which,batch", [("decode", 128), ("prefill", 1)],
+                         ids=["decode_b128", "prefill_chunk512"])
+def test_latent_serve_programs_take_the_pool_where_it_lies(chip, latent,
+                                                           which, batch):
+    from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import init_pool_buffer
+    cfg, model = latent
+    sv = cfg["serve"]
+    params = list(model.parameters())
+    # the bf16 weights a deployment serves (a norm's gain is built
+    # float32 and published bf16 with the rest)
+    vals = [_sds(p.shape, jnp.bfloat16, chip) for p in params]
+    streams, heads, head_dim = model.blocks[0].cache_rows
+    assert (streams, heads, head_dim) == (1, 1, 640)
+    pool = _on(chip, jax.eval_shape(lambda: init_pool_buffer(
+        len(model.blocks), heads, head_dim, sv["num_blocks"],
+        sv["block_size"], jnp.dtype(sv["cache_dtype"]), streams=streams)))
+    nb = cfg["max_position_embeddings"] // sv["block_size"]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, chip)
+    build = {"decode": serve_kernels.build_decode_fn,
+             "prefill": serve_kernels.build_prefill_fn}[which]
+    fn = build(model, params, sv["block_size"], sv["num_blocks"])
+    args = (i32(batch), i32(batch), i32(batch, nb)) if which == "decode" \
+        else (i32(1, sv["prefill_chunk"]), i32(1, nb), i32(), i32())
+    with force_mode("compiled"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            vals, pool, *args).compile()
+    ma = _check(compiled, 0)
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert ma.alias_size_in_bytes >= pool_bytes         # updated in place
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < USABLE_BYTES, total / 2 ** 30
+    assert ma.temp_size_in_bytes < 2 ** 30, ma.temp_size_in_bytes / 2 ** 30
+    text = compiled.as_text()
+    for op, ln in _pool_sized_results(compiled, pool):
+        assert op in ("parameter", "bitcast", "scatter", "fusion"), ln[:300]
+        if op == "fusion":
+            assert " scatter(" in _called_computation(text, ln), ln[:300]
+    calls = _kernel_calls(compiled)
+    layers = len(model.blocks)
+    routed = layers - cfg["first_k_dense_replace"]
+    # two grouped matmuls a routed layer (gate | up, then down)
+    assert sum("routed_experts" in c for c in calls) == 2 * routed, calls
+    assert sum("latent_attention_decode" in c for c in calls) \
+        == (layers if which == "decode" else 0), calls
+    if which == "decode":
+        # the table reader is in: nothing takes gather_kv's flat view
+        flat = pool.shape[0] * pool.shape[1] * pool.shape[2]
+        assert f"[{flat},{pool.shape[3]},{pool.shape[4]}]" not in text
+
+
 def test_fused_train_step_compiles(chip):
     """The whole GPT-2-small fused step at 8 x 1024 — bf16, FusedAdam,
     chunked LM-head loss, state donated — with the flash kernel in."""

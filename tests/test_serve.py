@@ -202,6 +202,45 @@ def test_scheduler_churn_500_requests_zero_leaks():
     assert pool.free_exact + pool.cached_count == pool.capacity
 
 
+@pytest.mark.parametrize("ahead", ["decoding", "ingesting"])
+def test_a_prompt_over_the_backlog_budget_waits_only_for_prompts(ahead):
+    """``max_prefill_backlog`` is a budget on tokens not yet ingested.
+    A prompt longer than all of it joins sessions that only decode (it
+    used to wait for an EMPTY engine, so that a batch behind one such
+    prompt drained to nothing first), and waits while another prompt is
+    still being ingested, then enters when that one has landed."""
+    pool = BlockPool(num_blocks=64, block_size=4)
+    sched = Scheduler(pool, max_batch=8, prefill_chunk=4,
+                      max_prefill_backlog=8, max_positions=96)
+    sched.submit(Request("first", [3, 5], 40))
+    assert [s.rid for s in sched.admit()] == ["first"]
+    _sim_prefill_tick(sched)
+    assert [s.rid for s in sched.decode_sessions()] == ["first"]
+    long_ = list(range(1, 21))                   # 20 tokens > the budget
+    if ahead == "decoding":
+        sched.submit(Request("long", long_, 4))
+        assert [s.rid for s in sched.admit()] == ["long"]
+        assert sched._backlog_tokens() == 20
+        return
+    sched.submit(Request("within", [7, 8, 9, 2, 4, 6], 4))
+    sched.submit(Request("long", long_, 4))
+    sched.submit(Request("short", [9, 9], 4))
+    # 6 of the 8 are taken: neither the long prompt nor, behind it in
+    # the queue, the short one gets in (FIFO holds)
+    assert [s.rid for s in sched.admit()] == ["within"]
+    assert sched.admit() == []
+    _sim_prefill_tick(sched)                     # 4 of the 6 ingested
+    assert sched._backlog_tokens() == 2 and sched.admit() == []
+    _sim_prefill_tick(sched)                     # "within" has landed
+    assert sched._backlog_tokens() == 0
+    assert len(sched.decode_sessions()) == 2
+    # the long prompt enters beside two decoding sessions, and the short
+    # one waits for it in turn
+    assert [s.rid for s in sched.admit()] == ["long"]
+    assert sched.admit() == []
+    _pool_books_balance(sched)
+
+
 # ---------------------------------------------------------------------------
 # packing determinism: a seeded Poisson trace replays to the byte
 # ---------------------------------------------------------------------------
@@ -275,6 +314,41 @@ def test_decode_recompile_free_after_warmup(model):
     assert again["dispatches"] > warm["dispatches"]
     assert again["cache_hits"] > warm["cache_hits"]
     eng.block_pool.check_no_leaks()
+
+
+def test_a_dead_engines_programs_leave_the_step_cache(model):
+    """A serve program's static key starts with its engine's token, and
+    its closure holds the model: when the engine goes, so do its
+    entries (nothing could hit them again, and they would keep the
+    weights alive until the LRU turned them out); another engine's
+    stay."""
+    import gc
+
+    def held_by(token):
+        return [k for k in sc.step_cache._programs
+                if isinstance(k[1], tuple) and k[1][:1] == (token,)]
+
+    def engine():
+        eng = ServeEngine(model, num_blocks=64, block_size=8, max_batch=4,
+                          prefill_chunk=4)
+        eng.run([Request("a", [2, 5, 7, 11], 3)])
+        return eng
+
+    sc.clear()
+    kept, gone = engine(), engine()
+    tokens = kept._token, gone._token
+    kinds = {k[0] for k in held_by(tokens[1])}
+    assert {"decode_step", "prefill_step"} <= kinds
+    assert len(held_by(tokens[0])) == len(held_by(tokens[1]))
+    del gone
+    gc.collect()
+    assert held_by(tokens[1]) == []
+    assert held_by(tokens[0]) and \
+        len(sc.step_cache._programs) == len(held_by(tokens[0]))
+    # the survivor still hits its own programs
+    before = sc.kind_stats("decode_step")["compiles"]
+    kept.run([Request("b", [3, 9, 4, 2], 3)])
+    assert sc.kind_stats("decode_step")["compiles"] == before
 
 
 def test_prefill_chunking_interleaves_decode(model):

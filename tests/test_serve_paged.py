@@ -86,9 +86,10 @@ class _Paged:
         params = _params(model)
         self.vals = [p.data for p in params]
         blk = model.blocks[0]
+        streams, heads, head_dim = blk.cache_rows
         self.pool = init_pool_buffer(
-            len(model.blocks), blk.attn.num_heads, blk.attn.head_dim,
-            NUM_BLOCKS, BS, cache_dtype)
+            len(model.blocks), heads, head_dim, NUM_BLOCKS, BS, cache_dtype,
+            streams=streams)
         self.prefill = jax.jit(sk.build_prefill_fn(
             model, params, BS, NUM_BLOCKS, window))
         self.decode = jax.jit(sk.build_decode_fn(
@@ -106,7 +107,7 @@ class _Paged:
         for a in range(0, len(toks), CHUNK):
             part = toks[a:a + CHUNK]
             padded = part + [0] * (CHUNK - len(part))
-            last, self.pool = self.prefill(
+            last, self.pool, _ = self.prefill(
                 self.vals, self.pool, jnp.asarray([padded], jnp.int32),
                 jnp.asarray([self.table(ids)], jnp.int32),
                 jnp.int32(t0 + a), jnp.int32(len(part)))
@@ -118,7 +119,7 @@ class _Paged:
         toks = [r[0] if r else 0 for r in rows]
         pos = [r[1] if r else -1 for r in rows]
         tabs = [self.table(r[2]) if r else [NULL_BLOCK] * NB for r in rows]
-        _, logits, self.pool = self.decode(
+        _, logits, self.pool, _ = self.decode(
             self.vals, self.pool, jnp.asarray(toks, jnp.int32),
             jnp.asarray(pos, jnp.int32), jnp.asarray(tabs, jnp.int32))
         return np.asarray(logits, np.float32)
