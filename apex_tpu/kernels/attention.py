@@ -14,7 +14,19 @@ innermost: TPU grids execute sequentially, so the running max / denominator /
 accumulator live in VMEM scratch across the k sweep (the canonical TPU flash
 pattern).  The backward recomputes attention blockwise from the saved
 logsumexp: one kernel accumulates dq over the k sweep, a second accumulates
-dk/dv over the q sweep.  All softmax/accumulation math in fp32.
+dk/dv over the q sweep.
+
+Precision: the nine MXU products of a block pair (forward S, P·V; dq S, dP,
+dS·K; dkv S, Pᵀ·dO, dP, dSᵀ·Q) take their operands in the dtype the tensors
+came in (``_operand_dtype``) and accumulate in fp32; scores, softmax
+statistics (m, l, lse, delta) and every accumulator are fp32.  bf16 q/k/v/dO
+therefore reach the MXU as stored — a bf16 x bf16 product is exact in the
+fp32 accumulator, so widening them first buys no bit — and P and dS are
+rounded to bf16 for their products, as the left operand of every other
+matmul of a bf16 step is.  fp32 tensors run fp32 products, bit for bit what
+they always gave.  (On a v5e Mosaic runs a default-precision fp32 product
+as one bf16 pass anyway: there the rule changes no result and no time,
+PERF.md PR 30; it is what makes the interpreter round as the chip does.)
 
 An additive ``bias`` (broadcastable (B|1, Sq|1, Sk)) carries both mask
 flavors of the reference API (key_padding_mask → 0/-inf per key,
@@ -42,31 +54,57 @@ def _ceil_div(a, b):
 _VMEM_BUDGET = 10 * 1024 * 1024  # conservative slice of the ~16 MiB/core VMEM
 
 
-def _vmem_estimate(bq, bk, d):
-    """Worst-case fp32 bytes resident per grid step across the three kernels
-    (input blocks + (bq, bk) score intermediates + scratch accumulators)."""
+def _operand_dtype(*tensors):
+    """The dtype the kernels' products take their operands in: bfloat16
+    where every tensor is bfloat16 (the MXU's native operand: no widened
+    copy is made), float32 otherwise (fp32 tensors as they are; fp16 or
+    mixed ones widened, as they always were)."""
+    if all(t.dtype == jnp.bfloat16 for t in tensors):
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(_f32)
+
+
+def _vmem_estimate(bq, bk, d, itemsize=4):
+    """Worst-case bytes resident per grid step across the three kernels:
+    input and output blocks at the operands' ``itemsize``, the (bq, bk)
+    score intermediates and the scratch accumulators at fp32."""
     f = 4
-    fwd = (2 * bq * d + 2 * bk * d) * f + 3 * bq * bk * f + 2 * bq * f
-    dkv = (3 * bq * d + 2 * bk * d) * f + 4 * bq * bk * f + 2 * bk * d * f
+    fwd = (2 * bq * d + 2 * bk * d) * itemsize + 3 * bq * bk * f + 2 * bq * f
+    dkv = (3 * bq * d + 2 * bk * d) * itemsize + 4 * bq * bk * f \
+        + 2 * bk * d * f
     return max(fwd, dkv)
 
 
-def _block_sizes(sq, sk, d):
-    bq = min(256, _round8(sq))
-    bk = min(512, _round8(sk))
+def _block_sizes(sq, sk, d, itemsize=4):
+    """(bq, bk) from what the kernels can see: the lengths, the head
+    width and the operands' itemsize.
+
+    bf16 operands take 512 x 1024.  On a v5e a grid step costs about the
+    same whatever its key width (S = 1024, D = 64, causal, ms a call of
+    192 heads, fwd + dq + dkv: 256 x 512 5.00, 512 x 512 3.91,
+    256 x 1024 3.75, 512 x 1024 3.03, 1024 x 1024 2.77, 256 x 256 7.66,
+    128 x 128 16.6: PERF.md, PR 30), so fewer, larger steps win even
+    where they compute the whole square of a causal call; 1024 x 1024
+    is not taken because its backward does not fit the 16 MiB of scoped
+    VMEM once a bias and dropout ride along.  fp32 operands keep
+    256 x 512: their blocks are twice the bytes, and the order of the
+    blocks is what their results' last bits follow."""
+    cap_q, cap_k = (256, 512) if itemsize >= 4 else (512, 1024)
+    bq = min(cap_q, _round8(sq))
+    bk = min(cap_k, _round8(sk))
     # shrink blocks until the per-step working set fits the VMEM budget
-    # (large head dims would otherwise OOM VMEM at the default 256/512)
-    while _vmem_estimate(bq, bk, d) > _VMEM_BUDGET and bk > 128:
+    # (large head dims would otherwise OOM VMEM at the default tiles)
+    while _vmem_estimate(bq, bk, d, itemsize) > _VMEM_BUDGET and bk > 128:
         bk //= 2
-    while _vmem_estimate(bq, bk, d) > _VMEM_BUDGET and bq > 128:
+    while _vmem_estimate(bq, bk, d, itemsize) > _VMEM_BUDGET and bq > 128:
         bq //= 2
     return bq, bk
 
 
-def vmem_fit(sq, sk, d):
+def vmem_fit(sq, sk, d, itemsize=4):
     """VMEM-fit report for the chosen block sizes (bench --kernels guard)."""
-    bq, bk = _block_sizes(sq, sk, d)
-    est = _vmem_estimate(bq, bk, d)
+    bq, bk = _block_sizes(sq, sk, d, itemsize)
+    est = _vmem_estimate(bq, bk, d, itemsize)
     return {"bq": bq, "bk": bk, "est_bytes": est,
             "budget_bytes": _VMEM_BUDGET, "fits": est <= _VMEM_BUDGET}
 
@@ -180,6 +218,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
         bias_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    mxu = _operand_dtype(q_ref, k_ref, v_ref)
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -189,9 +228,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _compute():
-        q = q_ref[0].astype(_f32)
-        k = k_ref[0].astype(_f32)
-        v = v_ref[0].astype(_f32)
+        q = q_ref[0].astype(mxu)
+        k = k_ref[0].astype(mxu)
+        v = v_ref[0].astype(mxu)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=_f32) * scale
         if has_bias:
@@ -209,7 +248,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
         if dropout_p > 0.0:
             p = p * _dropout_mult(i, j, b, bq, bk, seed_ref, dropout_p)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=_f32)
+            p.astype(mxu), v, preferred_element_type=_f32)
         m_scr[...] = m_new
 
     if causal:
@@ -243,6 +282,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         bias_ref, dq_ref, acc_scr = refs
     else:
         dq_ref, acc_scr = refs
+    mxu = _operand_dtype(q_ref, k_ref, v_ref, do_ref)
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -250,10 +290,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _compute():
-        q = q_ref[0].astype(_f32)
-        k = k_ref[0].astype(_f32)
-        v = v_ref[0].astype(_f32)
-        do = do_ref[0].astype(_f32)
+        q = q_ref[0].astype(mxu)
+        k = k_ref[0].astype(mxu)
+        v = v_ref[0].astype(mxu)
+        do = do_ref[0].astype(mxu)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=_f32) * scale
         if has_bias:
@@ -267,7 +307,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             # already includes it (delta = sum(do*out), out dropped)
             dp = dp * _dropout_mult(i, j, b, bq, bk, seed_ref, dropout_p)
         ds = p * (dp - delta_ref[0])
-        acc_scr[...] += jax.lax.dot(ds, k, preferred_element_type=_f32)
+        acc_scr[...] += jax.lax.dot(ds.astype(mxu), k,
+                                    preferred_element_type=_f32)
 
     if causal:
         # fully-masked block: p = 0 → ds = 0, contributes nothing to dq
@@ -289,6 +330,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         bias_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     else:
         dk_ref, dv_ref, dk_scr, dv_scr = refs
+    mxu = _operand_dtype(q_ref, k_ref, v_ref, do_ref)
     # grid is (bh, k-blocks, q-blocks): q innermost for the accumulation
     b = pl.program_id(0)
     j, i = pl.program_id(1), pl.program_id(2)
@@ -299,10 +341,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def _compute():
-        q = q_ref[0].astype(_f32)
-        k = k_ref[0].astype(_f32)
-        v = v_ref[0].astype(_f32)
-        do = do_ref[0].astype(_f32)
+        q = q_ref[0].astype(mxu)
+        k = k_ref[0].astype(mxu)
+        v = v_ref[0].astype(mxu)
+        do = do_ref[0].astype(mxu)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=_f32) * scale
         if has_bias:
@@ -314,15 +356,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             pd = p * dmult  # dropped probs: dv sees dropout(P)
         else:
             pd = p
-        dv_scr[...] += jax.lax.dot_general(pd, do, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=_f32)
+        dv_scr[...] += jax.lax.dot_general(
+            pd.astype(mxu), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=_f32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=_f32)
         if dropout_p > 0.0:
             dp = dp * dmult
         ds = p * (dp - delta_ref[0])  # (bq, bk)
-        dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=_f32)
+        dk_scr[...] += jax.lax.dot_general(
+            ds.astype(mxu), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=_f32)
 
     if causal:
         # q-block entirely above the diagonal contributes nothing to
@@ -362,7 +406,7 @@ def flash_attention_fwd(q3, k3, v3, bias, scale, causal, interpret=False,
         raise ValueError("dropout_p > 0 requires dropout_seed")
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    bq, bk = _block_sizes(sq, sk, d)
+    bq, bk = _block_sizes(sq, sk, d, _operand_dtype(q3, k3, v3).itemsize)
     sq_p, sk_p = _ceil_div(sq, bq) * bq, _ceil_div(sk, bk) * bk
     q3 = jnp.pad(q3, ((0, 0), (0, sq_p - sq), (0, 0)))
     k3 = jnp.pad(k3, ((0, 0), (0, sk_p - sk), (0, 0)))
@@ -425,7 +469,8 @@ def flash_attention_bwd(q3, k3, v3, bias, out, lse, g, scale, causal,
     """→ (dq, dk, dv) with the shapes/dtypes of q3/k3/v3."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    bq, bk = _block_sizes(sq, sk, d)
+    bq, bk = _block_sizes(sq, sk, d,
+                          _operand_dtype(q3, k3, v3, g).itemsize)
     sq_p, sk_p = _ceil_div(sq, bq) * bq, _ceil_div(sk, bk) * bk
     delta = jnp.sum(g.astype(_f32) * out.astype(_f32), axis=-1)  # (BH, Sq)
     q3 = jnp.pad(q3, ((0, 0), (0, sq_p - sq), (0, 0)))
@@ -533,7 +578,15 @@ def flash_min_sk() -> int:
     S=2048/w=256 1.82x — flash decisively wins the shapes it exists
     for, and the 256-512 boundary is a wash.  APEX_TPU_FLASH_MIN_SK
     overrides (0 forces flash everywhere); otherwise a ledger-measured
-    win for this chip moves the boundary off the 512 prior."""
+    win for this chip moves the boundary off the 512 prior.
+
+    Those receipts are owed a re-measurement: they were taken with the
+    kernels widening q, k, v and dO to fp32 before every product and
+    tiling 256 x 512.  Since PR 30 the MXU operands keep the input dtype
+    (statistics and accumulators stay fp32) and bf16 operands tile
+    512 x 1024, which took the S = 1024 causal call from 5.0 to 3.0 ms
+    on the v5e (PERF.md, PR 30); the crossover can only have moved
+    down.  The threshold itself is not changed here."""
     import os
     env = os.environ.get("APEX_TPU_FLASH_MIN_SK")
     if env is not None:

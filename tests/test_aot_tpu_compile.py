@@ -95,6 +95,26 @@ def test_flash_attention_compiles(chip, which, batch, seq, window, calls):
     _check(compiled, calls)
 
 
+def test_flash_attention_with_bias_and_dropout_compiles(chip):
+    """The variant that needs most fast memory a grid step — a full
+    (Sq, Sk) bias block and the dropout hash beside the scores — at the
+    tiles bf16 operands take: what keeps them at 512 x 1024."""
+    from apex_tpu.contrib.multihead_attn.attn_funcs import flash_attention
+
+    def loss(q, k, v, bias, seed):
+        return jnp.sum(flash_attention(
+            q, k, v, bias=bias, causal=True, dropout_p=0.1,
+            dropout_seed=seed).astype(jnp.float32))
+
+    q = _sds((4, HEADS, 2048, HEAD_DIM), jnp.bfloat16, chip)
+    bias = _sds((4, 2048, 2048), jnp.float32, chip)
+    seed = _sds((), jnp.int32, chip)
+    with force_mode("compiled"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q, bias, seed).compile()
+    _check(compiled, 3)
+
+
 def test_fused_adam_compiles(chip):
     from apex_tpu.kernels.multi_tensor import fused_adam
 
@@ -120,6 +140,19 @@ def _kernel_calls(compiled):
     ``pallas_call(name=...)`` and are what the device trace shows."""
     return [ln.split("=")[0] for ln in compiled.as_text().splitlines()
             if "tpu_custom_call" in ln and " custom-call(" in ln]
+
+
+def _kernel_operands(compiled, kernel):
+    """The operand types (``bf16[96,1024,64]``) of each custom call that
+    ``kernel`` names, as the compiled program constrains them."""
+    found = []
+    for ln in compiled.as_text().splitlines():
+        if ("tpu_custom_call" in ln and " custom-call(" in ln
+                and kernel in ln.split("=")[0]):
+            constraints = ln.split("operand_layout_constraints={")[1]
+            found.append(re.findall(r"(\w+\[[\d,]*\])\{",
+                                    constraints.split("}}")[0]))
+    return found
 
 
 def test_decode_program_compiles(chip):
@@ -377,6 +410,14 @@ def test_fused_train_step_compiles(chip):
     # the kernels go by their own names in the device trace: the custom
     # calls' instruction names come from ``pallas_call(name=...)``
     calls = _kernel_calls(compiled)
-    for kernel in ("flash_attn_fwd", "flash_attn_bwd_dq",
-                   "flash_attn_bwd_dkv"):
+    # q, k, v (and dO) reach the kernels as bf16 in the (B*H, S, D) layout:
+    # no upcast comes back in front of the MXU, and the layout stays the
+    # one ``flash_attn_roofline`` finds the kernels by
+    qkv = f"bf16[{8 * HEADS},1024,{HEAD_DIM}]"
+    for kernel, tensors in (("flash_attn_fwd", 3), ("flash_attn_bwd_dq", 4),
+                            ("flash_attn_bwd_dkv", 4)):
         assert sum(kernel in c for c in calls) == LAYERS, (kernel, calls)
+        operands = _kernel_operands(compiled, kernel)
+        assert len(operands) == LAYERS, (kernel, operands)
+        for ops in operands:
+            assert ops[:tensors] == [qkv] * tensors, (kernel, ops)

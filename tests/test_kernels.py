@@ -12,6 +12,7 @@ eager arm differs from any jitted arm by ~1 ulp while two jitted arms
 (the only configuration production runs) agree exactly.
 """
 import functools
+import hashlib
 import json
 import os
 
@@ -179,6 +180,231 @@ def test_flash_interpret_parity_masks(rng, tmp_ledger, causal, window):
         q, k, v, None, causal, scale, window=window))))(q, k, v)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                rtol=3e-4, atol=3e-5)
+
+
+# --- the kernels' MXU operands keep the dtype the tensors came in -----------
+
+
+def _l2_gap(got, ref):
+    got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
+    return float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
+
+
+def _int8_rounded(x):
+    """Each row of the last axis on its own 255-step grid: the
+    precision one below bfloat16's 8 significant bits."""
+    step = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0
+    return jnp.round(x / step) * step
+
+
+# Relative L2 gap to the float32 reference on the same (widened) bf16
+# inputs.  Readings on the two shapes below: the kernel 2.0e-3 on out
+# (the bf16 result's own rounding) and 2.8e-3 .. 3.0e-3 on dq, dk, dv;
+# the reference itself on int8-rounded inputs 7.0e-3 .. 1.1e-2.  The
+# tolerance is the geometric mean of the two nearest: 1.5x of room on
+# either side.
+BF16_L2_TOL = 4.6e-3
+
+
+@pytest.mark.parametrize("s,d,window", [(1100, 32, None), (328, 64, 96)],
+                         ids=["ragged_two_k_blocks", "sliding_window"])
+def test_flash_bf16_operands_against_f32_reference(rng, tmp_ledger, s, d,
+                                                   window):
+    """bf16 q, k, v: the products take them as stored and P, dS are
+    rounded to bf16 for the other four; softmax statistics and
+    accumulators stay float32, so the results stay within bf16's own
+    rounding of the float32 reference — which int8-rounded inputs do
+    not."""
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, s, d)), jnp.bfloat16)
+               for _ in range(3))
+    wide = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    scale = 1.0 / np.sqrt(d)
+
+    def ref_out(q, k, v):
+        return attention_reference(q, k, v, None, True, scale, window=window)
+
+    def flash_out(q, k, v):
+        return flash_attention(q, k, v, causal=True, sliding_window=window)
+
+    w = jnp.asarray(rng.standard_normal((1, 2, s, d)), jnp.float32)
+
+    def grads(fn, args):
+        return jax.grad(lambda *a: jnp.sum(
+            fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2))(*args)
+
+    ref = (ref_out(*wide),) + grads(ref_out, wide)
+    with force_mode("interpret"):
+        got = (flash_out(q, k, v),) + grads(flash_out, (q, k, v))
+    low = tuple(_int8_rounded(x) for x in wide)
+    control = (ref_out(*low),) + grads(ref_out, low)
+    for name, g, r, c in zip(("out", "dq", "dk", "dv"), got, ref, control):
+        assert g.dtype == jnp.bfloat16, name
+        assert _l2_gap(g, r) < BF16_L2_TOL, (name, _l2_gap(g, r))
+        assert _l2_gap(c, r) > BF16_L2_TOL, (name, _l2_gap(c, r))
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general equation under ``jaxpr``, the branches of
+    ``pl.when`` included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _dot_generals(sub)
+    return found
+
+
+def _kernel_bodies(fn, *args):
+    """name -> body jaxpr of each pallas_call that ``fn`` stages."""
+    def calls(jaxpr):
+        out = {}
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out[eqn.params["name"]] = eqn.params["jaxpr"]
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    out.update(calls(sub))
+        return out
+    return calls(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("dropout_p", [0.0, 0.1], ids=["plain", "dropout"])
+def test_flash_dot_operands_keep_the_input_dtype(dtype, dropout_p):
+    """The mechanism itself: all nine products of a block pair (forward
+    S, PV; dq S, dP, dS.K; dkv S, P^T.dO, dP, dS^T.Q) take operands of
+    the tensors' dtype and accumulate in float32."""
+    from apex_tpu.kernels import attention as ka
+    x = jax.ShapeDtypeStruct((2, 256, 64), dtype)
+    lse = jax.ShapeDtypeStruct((2, 256), jnp.float32)
+    seed = jnp.int32(7) if dropout_p else None
+    bodies = _kernel_bodies(
+        lambda q, k, v: ka.flash_attention_fwd(
+            q, k, v, None, 0.125, True, interpret=True,
+            dropout_p=dropout_p, dropout_seed=seed), x, x, x)
+    bodies.update(_kernel_bodies(
+        lambda q, k, v, o, l, g: ka.flash_attention_bwd(
+            q, k, v, None, o, l, g, 0.125, True, interpret=True,
+            dropout_p=dropout_p, dropout_seed=seed), x, x, x, x, lse, x))
+    want = {"flash_attn_fwd": 2, "flash_attn_bwd_dq": 3,
+            "flash_attn_bwd_dkv": 4}
+    assert {n: len(_dot_generals(b)) for n, b in bodies.items()} == want
+    for name, body in bodies.items():
+        for eqn in _dot_generals(body):
+            assert [a.aval.dtype for a in eqn.invars] == [dtype, dtype], \
+                (name, eqn)
+            assert eqn.outvars[0].aval.dtype == jnp.float32, (name, eqn)
+
+
+@pytest.mark.parametrize("sq,sk,d,itemsize,want", [
+    (1024, 1024, 64, 4, (256, 512)),     # fp32: the tiles its bits follow
+    (1024, 1024, 64, 2, (512, 1024)),    # bf16: fewer, larger grid steps
+    (4096, 4096, 128, 2, (512, 1024)),
+    (200, 328, 64, 2, (200, 328)),       # short inputs: one block
+    (2048, 2048, 256, 2, (512, 512)),    # wide heads: what fits VMEM
+    (2048, 2048, 1024, 4, (256, 256)),
+])
+def test_flash_block_sizes_follow_lengths_width_and_itemsize(
+        sq, sk, d, itemsize, want):
+    from apex_tpu.kernels import attention as ka
+    assert ka._block_sizes(sq, sk, d, itemsize) == want
+    assert ka._vmem_estimate(*want, d, itemsize) <= ka._VMEM_BUDGET
+    if itemsize == 4:       # the default is fp32's: callers of old
+        assert ka._block_sizes(sq, sk, d) == want
+
+
+# sha256 of out, lse, dq, dk, dv (float32 inputs, jitted, interpret mode)
+# as the kernels of commit e1d87c4 gave them, before their products took
+# bf16 operands: float32 callers keep those bits.  ``canary`` is the same
+# digest of a plain dot + exp + row sum on this backend: where it differs
+# the CPU rounds otherwise than the one the digests were recorded on, and
+# the comparison would say nothing of the kernels.
+F32_DIGESTS = {
+    "causal_ragged": {
+        "canary":
+            "6066f8d710655982648d7c8b7539c24f36d6fdb74d82eb2d25374be7ef448353",
+        "out":
+            "04b4e1785ffc713ec831b0e76a96a6a979f79089a41f2eddc1bf5112f58f6cef",
+        "lse":
+            "df384f71c1f7b6e89c7f2da145304b7c953304aed5046fe260d3cbf342e921d5",
+        "dq":
+            "505841e4043b2d67a9f2edc56f80a15ac7df828cf47f68481f1bce5dcdc6eb2f",
+        "dk":
+            "20249289e8acab794c3be2774b050e0b5a26211c33507664d7a8542c38421de8",
+        "dv":
+            "99f0394fe0d38af8312d8385232b371ab8fe49659837997d6b389b1041714563",
+    },
+    "band_bias_dropout": {
+        "canary":
+            "615d5fa7db13861d1cc2dacef832f688df72a2bf10ac9c57620693572ce21c10",
+        "out":
+            "8d3726b6bfaa9ba9b115ce5614889c67f1dd4b8589799f2130b5b2488ba77ce5",
+        "lse":
+            "042bc15ebf9720f30209df3320a6366f32f1aabd0d58520235c273a42d162ab6",
+        "dq":
+            "72550d46a0b2627f4dcd1c25d487bb90dc83787061e73299ed8da254837fcaa9",
+        "dk":
+            "22ddc0ee8a52cce0ec44b9ef9dd4543facaa4720041058b3180eb548c2ca1dbc",
+        "dv":
+            "6b9df91b1231cb98ca9eda0959ea8fbc26cd89347bd832ff9a74f3907986c291",
+    },
+}
+
+
+def _digest(arrays):
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(np.asarray(a, np.float32)).tobytes())
+    return h.hexdigest()
+
+
+def _f32_case(which):
+    from apex_tpu.kernels import attention as ka
+    r = np.random.default_rng(30)
+    if which == "causal_ragged":        # 2 q blocks, padded rows and keys
+        bh, sq, sk, d = 3, 328, 328, 64
+        kw = {}
+    else:                               # 3 x 2 blocks, band, bias, dropout
+        bh, sq, sk, d = 2, 600, 600, 32
+        kw = dict(window=160, dropout_p=0.1, dropout_seed=jnp.int32(11))
+    q = jnp.asarray(r.standard_normal((bh, sq, d)), jnp.float32)
+    k, v = (jnp.asarray(r.standard_normal((bh, sk, d)), jnp.float32)
+            for _ in range(2))
+    g = jnp.asarray(r.standard_normal((bh, sq, d)), jnp.float32)
+    bias = None
+    if which != "causal_ragged":
+        bias = jnp.asarray(r.standard_normal((1, sq, sk)), jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    @jax.jit
+    def run(q, k, v, g, bias):
+        out, lse = ka.flash_attention_fwd(q, k, v, bias, scale, True,
+                                          interpret=True, **kw)
+        return (out, lse) + ka.flash_attention_bwd(
+            q, k, v, bias, out, lse, g, scale, True, interpret=True, **kw)
+
+    @jax.jit
+    def canary(q, k):
+        s = jnp.exp(jax.lax.dot_general(
+            q[0], k[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale)
+        return s, jnp.sum(s, axis=1)
+
+    return run(q, k, v, g, bias), canary(q, k)
+
+
+@pytest.mark.parametrize("which", ["causal_ragged", "band_bias_dropout"])
+def test_flash_f32_bits_are_the_parents(which):
+    got, canary = _f32_case(which)
+    want = F32_DIGESTS[which]
+    if _digest(canary) != want["canary"]:
+        pytest.skip("this CPU rounds a plain dot/exp/sum otherwise than "
+                    "the one the digests were recorded on")
+    names = ("out", "lse", "dq", "dk", "dv")
+    assert {n: _digest([a]) for n, a in zip(names, got)} == \
+        {n: want[n] for n in names}
 
 
 def test_ring_sp_composition_honors_ledger_fallback(rng, tmp_ledger):

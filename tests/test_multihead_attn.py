@@ -223,7 +223,7 @@ def test_flash_causal_block_skip_multi_block(rng, shape, monkeypatch):
     agreement must be as tight as the unskipped kernel's."""
     from apex_tpu.ops.pallas import attention as A
 
-    monkeypatch.setattr(A, "_block_sizes", lambda sq, sk, d: (64, 64))
+    monkeypatch.setattr(A, "_block_sizes", lambda *shape: (64, 64))
     sq, sk = shape
     q, k, v = _qkv(rng, sq=sq, sk=sk, d=32)
     scale = 1.0 / np.sqrt(q.shape[-1])
