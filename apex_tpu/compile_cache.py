@@ -2,7 +2,7 @@
 that turns on JAX's persistent compilation cache.
 
 Everything the package builds for itself — XLA executables, the native
-host runtime's ``.so``, the kernel calibration ledger — lives under one
+host runtime's ``.so`` — lives under one
 directory inside the checkout, :func:`cache_root` (git-ignored).  The
 path is fixed on purpose: it is part of the compilation cache's key, so
 a directory made from a temporary name, a pid or the time never hits.

@@ -32,33 +32,10 @@ import jax
 import jax.numpy as jnp
 
 from ...nn.functional import dropout_mask
-from ...kernels.dispatch import pallas_mode
 from ...kernels import attention as _k
 
 _f32 = jnp.float32
 _NEG = -1e30
-
-
-def _flash_min_sk():
-    """Key-length threshold below which compiled dispatch prefers XLA's
-    own attention over the Pallas flash kernel — the kernel module owns
-    the measured boundary (env override > ledger-measured win > the 512
-    round-4 prior; see :func:`apex_tpu.kernels.attention.flash_min_sk`
-    for the v5e receipts)."""
-    return _k.flash_min_sk()
-
-
-_XLA_SCORES_BYTE_CAP = _k.XLA_SCORES_BYTE_CAP
-
-
-def _use_xla_attention(b, h, sq, sk):
-    """Compiled-mode dispatch: take the materializing XLA path only when
-    it is both faster (short keys) and memory-harmless (small total
-    score tensor).  Kept as the shape-level oracle; ``flash_attention``
-    itself decides through ``kernels.dispatch`` so ledger entries can
-    override per shape."""
-    return sk < _flash_min_sk() and \
-        b * h * sq * sk * 4 <= _XLA_SCORES_BYTE_CAP
 
 
 def attention_reference(q4, k4, v4, bias, causal, scale, window=None,
@@ -179,17 +156,11 @@ def flash_attention(q4, k4, v4, bias=None, causal=False, scale=None,
                              "training PRNG key)")
     if scale is None:
         scale = 1.0 / math.sqrt(q4.shape[-1])
-    mode = pallas_mode()
-    # dispatch policy: the registered probe encodes the measured
-    # crossover (min-sk boundary + score-byte cap) and a ledger entry
-    # for this chip/shape overrides it; the decision is trace-time
-    # static and lands in the observe event log (kernels.dispatch)
-    from ...kernels.dispatch import attention_fp, decide
-    b, h, sq, d = q4.shape
-    tier = decide("flash_attention",
-                  attention_fp(b, h, sq, k4.shape[2], d, q4.dtype,
-                               causal)).tier
-    if mode is None or tier == "xla":
+    # the kernel module's rule: trace-time static, from the mode and the
+    # shape (kernels.attention.kernel_mode)
+    b, h, sq, _ = q4.shape
+    mode = _k.kernel_mode(b, h, sq, k4.shape[2])
+    if mode is None:
         if bias is not None:
             bias = jax.lax.stop_gradient(bias)
         return attention_reference(q4, k4, v4, bias, causal, scale,

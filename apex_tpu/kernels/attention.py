@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .dispatch import choose, register_kernel
+
 _f32 = jnp.float32
 _NEG = -1e30  # finite "-inf": keeps exp(s - m) well-defined in masked blocks
 
@@ -556,8 +558,20 @@ def flash_attention_bwd(q3, k3, v3, bias, out, lse, g, scale, causal,
 
 
 # ---------------------------------------------------------------------------
-# Dispatch registration
+# The tier rule and the registration
 # ---------------------------------------------------------------------------
+
+# Key length below which a compiled program takes XLA's own attention.
+# Receipts (v5e, fwd+bwd, round-4 A/B table, an unledgered run): with
+# causal block skipping S=256 ran 1.06x XLA and S=512 0.96x (both
+# noise-level), S=1024 causal 1.24x, S=2048/D=128 1.19x, banded
+# S=2048/w=256 1.82x — flash wins the shapes it exists for and the
+# 256-512 boundary is a wash.  Since PR 30 the MXU operands keep the
+# input dtype (statistics and accumulators stay fp32) and bf16 operands
+# tile 512 x 1024, which took the S = 1024 causal call from 5.0 to
+# 3.0 ms on the v5e (PERF.md, PR 30); the crossover can only have moved
+# down.  The 512 boundary is owed a re-measurement.
+FLASH_MIN_SK = 512
 
 # the XLA fallback's score tensor (fwd scores + softmax residual for
 # backward, f32) must also stay SMALL in absolute terms — key length
@@ -567,43 +581,17 @@ def flash_attention_bwd(q3, k3, v3, bias, out, lse, g, scale, causal,
 XLA_SCORES_BYTE_CAP = 128 * 1024 * 1024
 
 
-def flash_min_sk() -> int:
-    """Key-length threshold below which compiled dispatch prefers XLA's
-    own attention over the flash kernel.
-
-    Measured on v5e (bench --kernels-timing, fwd+bwd).  Round 3, before
-    causal block skipping: S=256 ran 0.82x XLA.  Round 4, with skipping
-    (unledgered run, round 4 A/B table): S=256 1.06x, S=512 0.96x (both
-    noise-level), S=1024 causal 1.24x, S=2048/D=128 1.19x, banded
-    S=2048/w=256 1.82x — flash decisively wins the shapes it exists
-    for, and the 256-512 boundary is a wash.  APEX_TPU_FLASH_MIN_SK
-    overrides (0 forces flash everywhere); otherwise a ledger-measured
-    win for this chip moves the boundary off the 512 prior.
-
-    Those receipts are owed a re-measurement: they were taken with the
-    kernels widening q, k, v and dO to fp32 before every product and
-    tiling 256 x 512.  Since PR 30 the MXU operands keep the input dtype
-    (statistics and accumulators stay fp32) and bf16 operands tile
-    512 x 1024, which took the S = 1024 causal call from 5.0 to 3.0 ms
-    on the v5e (PERF.md, PR 30); the crossover can only have moved
-    down.  The threshold itself is not changed here."""
-    import os
-    env = os.environ.get("APEX_TPU_FLASH_MIN_SK")
-    if env is not None:
-        return int(env)
-    from .dispatch import measured_threshold
-    return measured_threshold("flash_attention", "sk", 512)
+def _compiled_takes(b, h, sq, sk) -> bool:
+    """What a compiled program gives the flash kernel: attention from
+    :data:`FLASH_MIN_SK` keys up, and any whose score tensor in the XLA
+    tier would pass :data:`XLA_SCORES_BYTE_CAP`, whatever its speed."""
+    return sk >= FLASH_MIN_SK or b * h * sq * sk * 4 > XLA_SCORES_BYTE_CAP
 
 
-def _flash_probe(dims):
-    # no-ledger default: the kernel from the measured min-sk boundary
-    # up, and ALSO wherever the XLA fallback's score tensor would be
-    # memory-harmful regardless of per-FLOP speed
-    min_sk = flash_min_sk()
-    sk = dims.get("sk", 0)
-    scores = (dims.get("b", 1) * dims.get("h", 1) * dims.get("sq", 1)
-              * sk * 4)
-    return min_sk, sk >= min_sk or scores > XLA_SCORES_BYTE_CAP
+def kernel_mode(b, h, sq, sk):
+    """The flash kernel's rule: the mode it runs in for a ``(b, h, sq,
+    sk)`` attention, or ``None`` for the XLA tier."""
+    return choose("flash_attention", compiled=_compiled_takes(b, h, sq, sk))
 
 
 def _audit_programs():
@@ -627,16 +615,9 @@ def _audit_programs():
             ("xla", _xla, (q4, q4, q4))]
 
 
-def _register():
-    from .dispatch import register_kernel
-    register_kernel(
-        "flash_attention",
-        xla_fallback=(
-            "apex_tpu.contrib.multihead_attn.attn_funcs"
-            ".attention_reference"),
-        threshold_probe=_flash_probe,
-        doc="Blockwise online-softmax attention (fwd + recompute bwd)",
-        audit_programs=_audit_programs)
-
-
-_register()
+register_kernel(
+    "flash_attention",
+    xla_fallback=(
+        "apex_tpu.contrib.multihead_attn.attn_funcs.attention_reference"),
+    doc="Blockwise online-softmax attention (fwd + recompute bwd)",
+    audit_programs=_audit_programs)

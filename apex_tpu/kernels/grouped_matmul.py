@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import decide, pallas_mode, register_kernel, shape_fp
+from .dispatch import choose, pallas_mode, register_kernel
 
 _f32 = jnp.float32
 
@@ -146,10 +146,6 @@ def _gmm_call(tile_group, n_active, lhs, rhs, *, tile, interpret):
     )(tile_group, n_active.reshape(1), lhs, rhs)
 
 
-def grouped_matmul_fp(m, k, n, g, dtype) -> str:
-    return shape_fp(m=int(m), k=int(k), n=int(n), g=int(g), dtype=str(dtype))
-
-
 def takes_tiles(kdim: int, n: int, dtype) -> bool:
     """Whether the Pallas tier's tiles fit these widths: the caller lays
     its rows out with :data:`TILE_ROWS` then, else with tiles of one."""
@@ -163,15 +159,21 @@ def grouped_matmul(lhs, rhs, layout: TileLayout):
     -> ``(M, N)``: each row with its own group's matrix.  Rows no pair
     holds come back undefined in the Pallas tier and zero in the XLA
     tier: the caller selects by ``layout.row_of_pair``."""
-    if layout.tile == TILE_ROWS and \
-            takes_tiles(lhs.shape[1], rhs.shape[2], lhs.dtype):
-        fp = grouped_matmul_fp(lhs.shape[0], lhs.shape[1], rhs.shape[2],
-                               rhs.shape[0], lhs.dtype)
-        if decide("routed_experts", fp).tier == "pallas":
-            return _gmm_call(layout.tile_group, layout.n_active, lhs,
-                             rhs.astype(lhs.dtype), tile=layout.tile,
-                             interpret=pallas_mode() == "interpret")
+    mode = kernel_mode(lhs, rhs, layout.tile)
+    if mode is not None:
+        return _gmm_call(layout.tile_group, layout.n_active, lhs,
+                         rhs.astype(lhs.dtype), tile=layout.tile,
+                         interpret=mode == "interpret")
     return _gmm_xla(lhs, rhs, layout.padded)
+
+
+def kernel_mode(lhs, rhs, tile):
+    """The rule: the mode the kernel runs in, or ``None`` for the XLA
+    tier.  The kernel streams each used expert's matrix once and skips
+    the unused tiles, so it is taken wherever the rows were laid out for
+    it and its tiles fit (PERF.md section 6, PR 29)."""
+    return choose("routed_experts", fits=tile == TILE_ROWS and takes_tiles(
+        lhs.shape[1], rhs.shape[2], lhs.dtype))
 
 
 def _gmm_xla(lhs, rhs, padded):
@@ -181,12 +183,6 @@ def _gmm_xla(lhs, rhs, padded):
         lhs, rhs.astype(lhs.dtype), padded,
         precision=None if exact else jax.lax.Precision.HIGHEST,
         preferred_element_type=_f32).astype(lhs.dtype)
-
-
-def _gmm_probe(dims):
-    """No-ledger prior: the kernel streams each used expert's matrix
-    once and skips the unused tiles."""
-    return 1, True
 
 
 def _audit_programs():
@@ -211,6 +207,5 @@ def _audit_programs():
 register_kernel(
     "routed_experts",
     xla_fallback="apex_tpu.kernels.grouped_matmul._gmm_xla",
-    threshold_probe=_gmm_probe,
     doc="Grouped matmul of routed experts: rows sorted by expert",
     audit_programs=_audit_programs)

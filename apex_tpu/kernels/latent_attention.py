@@ -30,7 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..inference.quant import QuantKV
-from .dispatch import decide, pallas_mode, register_kernel, shape_fp
+from .dispatch import choose, register_kernel
 from .paged_attention import (CHUNK_BLOCKS, _attend_live_blocks,
                               _probs_times, _valid, gather_kv)
 
@@ -121,11 +121,6 @@ def _decode_pallas(q, pool, layer, tables, positions, scaling, rank, window,
                         rank=rank, interpret=interpret)
 
 
-def latent_attention_fp(b, nb, h, w, rank, bs, dtype) -> str:
-    return shape_fp(b=int(b), nb=int(nb), h=int(h), w=int(w),
-                    rank=int(rank), bs=int(bs), dtype=str(dtype))
-
-
 def latent_decode_attention(q, pool, layer, tables, positions, scaling,
                             rank, window=None):
     """The decode tick's attention over a latent pool: ``q (B, H, W)``
@@ -133,20 +128,24 @@ def latent_decode_attention(q, pool, layer, tables, positions, scaling,
     pad row, whose output the caller discards), against the session's
     rows of ``layer`` -> ``(B, H, rank)`` fp32.
 
-    Two tiers behind :func:`~apex_tpu.kernels.dispatch.decide`, chosen as
+    Two tiers, chosen by :func:`kernel_mode` as
     ``paged_decode_attention``'s are: the Pallas kernel wherever its
     tiles fit (rows of whole lane rows, blocks of whole sublane tiles,
     a plain pool), the XLA tier otherwise."""
-    mode = pallas_mode()
-    if mode is not None and _kernel_takes(q, pool, rank):
-        fp = latent_attention_fp(q.shape[0], tables.shape[1], q.shape[1],
-                                 q.shape[2], rank, pool.shape[3], pool.dtype)
-        if decide("latent_attention", fp).tier == "pallas":
-            return _decode_pallas(q, pool, layer, tables, positions,
-                                  scaling, rank, window,
-                                  mode == "interpret")
+    mode = kernel_mode(q, pool, rank)
+    if mode is not None:
+        return _decode_pallas(q, pool, layer, tables, positions,
+                              scaling, rank, window, mode == "interpret")
     return _decode_xla(q, pool, layer, tables, positions, scaling, rank,
                        window)
+
+
+def kernel_mode(q, pool, rank):
+    """The rule, as ``paged_attention``'s: the mode the kernel runs in
+    wherever its tiles fit (it reads the live blocks once where the XLA
+    tier gathers the whole table; PERF.md section 6, PR 29), else
+    ``None`` for the XLA tier."""
+    return choose("latent_attention", fits=_kernel_takes(q, pool, rank))
 
 
 def _kernel_takes(q, pool, rank) -> bool:
@@ -156,12 +155,6 @@ def _kernel_takes(q, pool, rank) -> bool:
     return pool.shape[1] == 1 and pool.shape[4] % 128 == 0 \
         and rank % 128 == 0 and pool.shape[3] % rows == 0 \
         and q.shape[1] % 8 == 0
-
-
-def _latent_probe(dims):
-    """No-ledger prior, as ``paged_attention``'s: the kernel reads the
-    live blocks once where the XLA tier gathers the whole table."""
-    return 1, True
 
 
 def _audit_programs():
@@ -185,6 +178,5 @@ def _audit_programs():
 register_kernel(
     "latent_attention",
     xla_fallback="apex_tpu.kernels.latent_attention._decode_xla",
-    threshold_probe=_latent_probe,
     doc="Decode attention over a latent (MLA) paged cache, absorbed form",
     audit_programs=_audit_programs)

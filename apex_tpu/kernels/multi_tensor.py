@@ -21,11 +21,12 @@ last bit may differ: ``tests/test_kernels.py`` holds the two paths to
 2 ulps, and ``chip_smoke.py`` prints the same comparison in compiled
 mode on the chip.
 
-Dispatch: like the norm kernels (round-5 receipt: 0.93-1.03x — XLA
-fuses elementwise chains well on its own), the fused update is
-UNPROVEN on compiled TPU, so the registered threshold probe defaults to
-XLA there; a ledger entry with a measured win flips it.  Interpret mode
-always exercises the kernel — that mode exists to test it.
+Dispatch (:func:`kernel_mode`): like the norm kernels (round-5 receipt:
+0.93-1.03x — XLA fuses elementwise chains well on its own), the fused
+update is UNPROVEN on compiled TPU, so a compiled program takes the
+per-bucket XLA path; the verdict is owed an A/B (docs/kernels.md).
+Interpret mode always exercises the kernel — that mode exists to test
+it.
 """
 from __future__ import annotations
 
@@ -88,14 +89,6 @@ def _unpack(panel, tensors):
         out.append(flat[off:off + t.size].reshape(t.shape))
         off += t.size
     return out
-
-
-def group_fp(op: str, tensors) -> str:
-    """Ledger fingerprint for one packed group."""
-    dtype = {str(t.dtype) for t in tensors}
-    return _dispatch.multi_tensor_fp(
-        op, sum(t.size for t in tensors), len(tensors),
-        dtype.pop() if len(dtype) == 1 else "mixed")
 
 
 # ---------------------------------------------------------------------------
@@ -263,11 +256,12 @@ def fused_adam(noop_flag, tensor_lists, lr, beta1, beta2, eps, step,
 # ---------------------------------------------------------------------------
 
 
-def _elementwise_probe(dims):
-    # the norm-kernel lesson generalized: XLA fuses elementwise chains
-    # near-roofline on its own, so an unmeasured fused update defaults
-    # to XLA on compiled backends; a ledger win flips it per shape
-    return None, False
+def kernel_mode(op: str):
+    """The rule for ``multi_tensor_<op>``: the norm-kernel lesson
+    generalized — XLA fuses elementwise chains near-roofline on its own,
+    so the unmeasured fused update runs in interpret mode only and
+    ``None`` (the per-bucket XLA path) is the answer everywhere else."""
+    return _dispatch.choose(f"multi_tensor_{op}", compiled=False)
 
 
 def _audit_tensor_lists(depth):
@@ -314,14 +308,12 @@ def _adam_audit_programs():
 _dispatch.register_kernel(
     "multi_tensor_sgd",
     xla_fallback="apex_tpu.ops.multi_tensor.sgd_unfused",
-    threshold_probe=_elementwise_probe,
     doc="Packed momentum-SGD group update (fused_sgd)",
     audit_programs=_sgd_audit_programs)
 
 _dispatch.register_kernel(
     "multi_tensor_adam",
     xla_fallback="apex_tpu.ops.multi_tensor.adam_unfused",
-    threshold_probe=_elementwise_probe,
     doc="Packed Adam/AdamW group update (fused_adam)",
     audit_programs=_adam_audit_programs)
 
@@ -347,7 +339,7 @@ def multi_tensor_sgd(noop_flag, tensor_lists, wd, momentum, dampening, lr,
         return _ops.sgd_unfused(flag, lists, *hyper)
 
     return _dispatch.run(
-        "multi_tensor_sgd", group_fp("sgd", tensor_lists[0]),
+        "multi_tensor_sgd", kernel_mode("sgd") is not None,
         (noop_flag, tensor_lists), pallas_fn=pallas_fn, xla_fn=xla_fn,
         static_key=hyper, donate_argnums=(1,))
 
@@ -370,6 +362,6 @@ def multi_tensor_adam(noop_flag, tensor_lists, lr, beta1, beta2, eps,
         return _ops.adam_unfused(flag, lists, *hyper)
 
     return _dispatch.run(
-        "multi_tensor_adam", group_fp("adam", tensor_lists[0]),
+        "multi_tensor_adam", kernel_mode("adam") is not None,
         (noop_flag, tensor_lists), pallas_fn=pallas_fn, xla_fn=xla_fn,
         static_key=hyper, donate_argnums=(1,))

@@ -33,7 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..inference.quant import QuantKV
-from .dispatch import decide, pallas_mode, register_kernel, shape_fp
+from .dispatch import choose, register_kernel
 
 _f32 = jnp.float32
 
@@ -311,11 +311,6 @@ def _decode_pallas(q, pool, layer, tables, positions, scaling, window,
                         interpret=interpret)
 
 
-def paged_attention_fp(b, nb, h, d, bs, dtype) -> str:
-    return shape_fp(b=int(b), nb=int(nb), h=int(h), d=int(d), bs=int(bs),
-                    dtype=str(dtype))
-
-
 def paged_decode_attention(q, pool, layer, tables, positions, scaling,
                            window=None):
     """The decode tick's attention: ``q (B, H, D)``, one query row a
@@ -323,19 +318,25 @@ def paged_decode_attention(q, pool, layer, tables, positions, scaling,
     the caller discards), against the session's blocks of ``layer``
     -> ``(B, H*D)`` fp32.
 
-    Two tiers behind :func:`~apex_tpu.kernels.dispatch.decide`: the
-    Pallas kernel takes the tables by scalar prefetch and DMAs each live
-    block from the pool in HBM (a session at depth 300 reads 19 blocks,
-    not its table's 64); the XLA tier gathers the whole table.  An int8
-    pool, or rows that are not whole tiles, are the XLA tier's."""
-    mode = pallas_mode()
-    if mode is not None and _kernel_takes(q, pool):
-        fp = paged_attention_fp(q.shape[0], tables.shape[1], q.shape[1],
-                                q.shape[2], pool.shape[3], pool.dtype)
-        if decide("paged_attention", fp).tier == "pallas":
-            return _decode_pallas(q, pool, layer, tables, positions,
-                                  scaling, window, mode == "interpret")
+    Two tiers, chosen by :func:`kernel_mode`: the Pallas kernel takes the
+    tables by scalar prefetch and DMAs each live block from the pool in
+    HBM (a session at depth 300 reads 19 blocks, not its table's 64);
+    the XLA tier gathers the whole table.  An int8 pool, or rows that
+    are not whole tiles, are the XLA tier's."""
+    mode = kernel_mode(q, pool)
+    if mode is not None:
+        return _decode_pallas(q, pool, layer, tables, positions,
+                              scaling, window, mode == "interpret")
     return _decode_xla(q, pool, layer, tables, positions, scaling, window)
+
+
+def kernel_mode(q, pool):
+    """The rule: the mode the kernel runs in, or ``None`` for the XLA
+    tier.  The kernel reads the live blocks once where the XLA tier
+    writes and re-reads a gathered copy of the whole table, so it is
+    taken wherever its tiles fit (PERF.md section 6, PR 27, has the
+    chip's reading at gpt2-medium's widths)."""
+    return choose("paged_attention", fits=_kernel_takes(q, pool))
 
 
 def _kernel_takes(q, pool) -> bool:
@@ -345,14 +346,6 @@ def _kernel_takes(q, pool) -> bool:
         return False
     rows = 8 * 4 // jnp.dtype(pool.dtype).itemsize
     return pool.shape[4] % 128 == 0 and pool.shape[3] % rows == 0
-
-
-def _paged_probe(dims):
-    """No-ledger prior: the kernel reads the live blocks once where the
-    XLA tier writes and re-reads a gathered copy of the whole table, so
-    it is taken wherever its tiles fit (PERF.md section 6, PR 27, has
-    the chip's reading at gpt2-medium's widths)."""
-    return 1, True
 
 
 def _audit_programs():
@@ -376,6 +369,5 @@ def _audit_programs():
 register_kernel(
     "paged_attention",
     xla_fallback="apex_tpu.kernels.paged_attention._decode_xla",
-    threshold_probe=_paged_probe,
     doc="Decode attention through block tables: live KV blocks by DMA",
     audit_programs=_audit_programs)

@@ -1,10 +1,6 @@
-"""spec_verify: the serve engine's batched draft/verify decode tick as
-a measured dispatch tier.
-
-Speculative decoding is a perf *claim* — "the draft accepts enough
-tokens that one (k+1)-wide verify pass beats k+1 one-token decode
-ticks" — so it registers here like any Pallas kernel and gets priced by
-the same ledger machinery.  The tiers:
+"""spec_verify: the serve engine's batched draft/verify decode tick, in
+the registry so that the jaxpr verifier traces it beside the plain
+decode program it replaces.
 
 * **"pallas"** — the fused draft-propose + target-verify program body
   (:func:`apex_tpu.serve.kernels.build_spec_verify_fn`), committing
@@ -12,17 +8,10 @@ the same ledger machinery.  The tiers:
 * **"xla"** (the declared fallback) — the plain one-token decode
   program (:func:`apex_tpu.serve.kernels.build_decode_fn`).
 
-Both tiers emit bitwise-identical greedy tokens (acceptance only ever
-truncates to a prefix of the target's own argmax stream), so a ledger
-entry's ``win`` is a pure tokens/s ratio at equal batch — measured by
-``bench.py --kernels``' spec_verify probe, which times one verify
-dispatch against the k+1 chained decode dispatches it replaces on a
-self-draft (full-acceptance) trace.  ``ServeEngine(spec="auto")``
-consults :func:`~apex_tpu.kernels.dispatch.decide` with this kernel's
-fingerprint per packed bucket shape and falls back to plain decode
-ticks below the win region; with no Pallas backend (CPU serving)
-``decide`` says "xla" — tests and CPU benches opt in with
-``spec="on"``.
+Both emit bitwise-identical greedy tokens (acceptance only ever
+truncates to a prefix of the target's own argmax stream).  Which one
+runs is not a kernel choice: a ``ServeEngine`` given a draft
+speculates.
 
 This module deliberately imports nothing from ``apex_tpu.serve`` at
 module level — it exists so the kernel is in :func:`catalog` whenever
@@ -34,27 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .dispatch import measured_threshold, register_kernel, shape_fp
-
-
-def spec_verify_fp(*, b, k, s_t, s_d, dtype) -> str:
-    """Ledger fingerprint for one spec-verify dispatch shape: batch
-    bucket ``b``, draft depth ``k``, target/draft table
-    widths ``s_t``/``s_d`` (table bucket x block_size), pool dtype.
-    Built by the SAME helper at probe time (bench) and decision time
-    (the engine's ``spec="auto"`` path)."""
-    return shape_fp(b=int(b), k=int(k), s_t=int(s_t), s_d=int(s_d),
-                    dtype=str(dtype))
-
-
-def _spec_verify_probe(dims):
-    """No-ledger prior: speculative verify pays when the draft proposes
-    at least ``thr`` tokens per tick — at the >= 2 tokens/tick
-    acceptance floor a k >= 2 draft amortizes the verify chunk's extra
-    width.  A measured winning ``k`` boundary for this chip moves the
-    threshold off the prior."""
-    thr = float(measured_threshold("spec_verify", "k", 2))
-    return thr, dims.get("k", 0) >= thr
+from .dispatch import register_kernel
 
 
 def _audit_programs():
@@ -97,9 +66,8 @@ def _audit_programs():
 register_kernel(
     "spec_verify",
     xla_fallback="apex_tpu.serve.kernels.build_decode_fn",
-    threshold_probe=_spec_verify_probe,
     doc="Batched speculative draft/verify decode tick (serve v2): "
         "fused k-step draft propose + (k+1)-wide target verify vs the "
         "plain one-token decode program it replaces; both tiers emit "
-        "bitwise-identical greedy tokens, so win is pure tokens/s",
+        "bitwise-identical greedy tokens",
     audit_programs=_audit_programs)

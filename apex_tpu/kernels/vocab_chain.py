@@ -2,13 +2,12 @@
 cross-entropy (Pallas, :mod:`.lm_head_xent`) with the chunked XLA chain
 (:mod:`apex_tpu.contrib.xentropy.chunked`) as the declared fallback.
 
-Round-4/5 history, now encoded as dispatch data instead of prose: the
-fused kernel measured **0.69x** against XLA's own lowering at
-(8192, 50257, 768) fwd+bwd, while the *program-level* chunked chain won
-**+13-15%** in-step — so the registered probe defaults every compiled
-shape to the chunked XLA path, and only a ledger entry with a measured
-win routes a shape to the kernel.  Interpret mode exercises the kernel
-(parity coverage); the kernel itself stays tested evidence either way.
+Round-4/5 history: the fused kernel measured **0.69x** against XLA's
+own lowering at (8192, 50257, 768) fwd+bwd, while the *program-level*
+chunked chain won **+13-15%** in-step — so a compiled program takes the
+chunked XLA path at every shape (:func:`kernel_mode`; the verdict is
+older than the code and owed an A/B, docs/kernels.md).  Interpret mode
+exercises the kernel (parity coverage).
 """
 from __future__ import annotations
 
@@ -20,11 +19,12 @@ from . import dispatch as _dispatch
 from .lm_head_xent import _fused_kernel_path
 
 
-def _vocab_chain_probe(dims):
-    # 0.69x at (n=8192, v=50257, e=768): no known win region on
-    # compiled TPU — default XLA everywhere until the ledger says
-    # otherwise (docs/kernels.md carries the receipts)
-    return None, False
+def kernel_mode():
+    """The rule: 0.69x at (n=8192, v=50257, e=768) and no known win
+    region on compiled TPU, so the kernel runs in interpret mode only
+    and ``None`` (the chunked XLA chain) is the answer everywhere
+    else."""
+    return _dispatch.choose("vocab_chain_loss", compiled=False)
 
 
 def _audit_programs():
@@ -45,7 +45,6 @@ def _audit_programs():
 _dispatch.register_kernel(
     "vocab_chain_loss",
     xla_fallback="apex_tpu.contrib.xentropy.chunked.chunked_lm_head_loss",
-    threshold_probe=_vocab_chain_probe,
     doc="Fused LM-head + cross-entropy (online-softmax over vocab blocks)",
     audit_programs=_audit_programs)
 
@@ -73,17 +72,14 @@ def vocab_chain_loss(hidden, head_weight, labels, smoothing=0.0,
     plain = isinstance(smoothing, (int, float)) and smoothing == 0.0
     kernel_eligible = plain and (logical_vocab is None
                                  or logical_vocab >= v)
-    if kernel_eligible:
-        fp = _dispatch.vocab_chain_fp(n, v, e, hidden.dtype)
-        d = _dispatch.decide("vocab_chain_loss", fp)
-        if d.tier == "pallas":
-            x2d = hidden.reshape(n, e)
-            lab = labels.reshape(n).astype(jnp.int32)
-            per = _fused_kernel_path(x2d, head_weight, lab)
-            # padding rows contribute zero loss AND zero gradient —
-            # the where's cotangent to the kernel branch is zero there
-            per = jnp.where(lab == padding_idx, jnp.zeros_like(per), per)
-            return per.reshape(lead)
+    if kernel_eligible and kernel_mode() is not None:
+        x2d = hidden.reshape(n, e)
+        lab = labels.reshape(n).astype(jnp.int32)
+        per = _fused_kernel_path(x2d, head_weight, lab)
+        # padding rows contribute zero loss AND zero gradient —
+        # the where's cotangent to the kernel branch is zero there
+        per = jnp.where(lab == padding_idx, jnp.zeros_like(per), per)
+        return per.reshape(lead)
     return chunked_lm_head_loss(hidden, head_weight, labels,
                                 smoothing=smoothing,
                                 padding_idx=padding_idx,

@@ -1277,37 +1277,31 @@ class KernelFallback(Rule):
     path locks those losses in: there is no seam to route the losing
     shapes back to XLA, and no probe record to ever find out.  The
     discipline is the ``apex_tpu.kernels`` tier: every kernel lives in
-    that package and registers through ``register_kernel`` with a
-    declared ``xla_fallback`` (the dotted path dispatch falls back to)
-    and a ``threshold_probe`` (the measured win region encoded as
-    data), so the calibration ledger — not the author's optimism —
-    decides dispatch per (chip, shape).  Flags: any ``pallas_call``
-    call or import outside ``apex_tpu/kernels/``, and a
-    ``register_kernel(...)`` missing a usable ``xla_fallback`` or
-    ``threshold_probe``.
+    that package, registers through ``register_kernel`` with a declared
+    ``xla_fallback`` (the dotted path of the XLA tier), and holds the
+    rule that picks the tier from the mode and the operands' shapes,
+    with its receipt beside it.  Flags: any ``pallas_call`` call or
+    import outside ``apex_tpu/kernels/``, and a ``register_kernel(...)``
+    missing a usable ``xla_fallback``.
     """
     id = "KERNEL-FALLBACK"
     summary = ("pallas_call outside the kernels tier, or a kernel "
-               "registered without a declared XLA fallback + threshold "
-               "probe")
+               "registered without a declared XLA fallback")
     hint = ("move the kernel into apex_tpu/kernels/ and register it: "
             "register_kernel(name, xla_fallback='<dotted path of the "
-            "XLA implementation>', threshold_probe=<fn(dims) -> "
-            "(threshold, use_pallas)>) — dispatch.decide() then "
-            "consults the calibration ledger and falls back below the "
-            "measured win region; see docs/kernels.md")
+            "XLA implementation>'); the rule that sends a shape to the "
+            "XLA tier lives in the kernel's own module; see "
+            "docs/kernels.md")
 
     def _missing(self, call: ast.Call) -> List[str]:
         """Registration keywords absent or constant-empty."""
         kws = {kw.arg: kw.value for kw in call.keywords if kw.arg}
-        out = []
-        for need in ("xla_fallback", "threshold_probe"):
-            val = kws.get(need)
-            if val is None:
-                out.append(need)
-            elif isinstance(val, ast.Constant) and not val.value:
-                out.append(f"{need} (empty)")
-        return out
+        val = kws.get("xla_fallback")
+        if val is None:
+            return ["xla_fallback"]
+        if isinstance(val, ast.Constant) and not val.value:
+            return ["xla_fallback (empty)"]
+        return []
 
     def check(self, module, ctx):
         in_kernels = _in_kernels_package(module)
@@ -1328,7 +1322,7 @@ class KernelFallback(Rule):
                 yield self.finding(
                     module, node,
                     "raw pallas_call outside apex_tpu/kernels/ — no "
-                    "XLA fallback seam, no probe record: losing shapes "
+                    "XLA fallback seam: losing shapes "
                     "(round-5: norms 0.93-1.03x, lm_head chain 0.69x) "
                     "can never route back to XLA")
             elif name == "register_kernel":
@@ -1337,9 +1331,8 @@ class KernelFallback(Rule):
                     yield self.finding(
                         module, node,
                         "kernel registered without " + " / ".join(missing)
-                        + " — dispatch cannot fall back to XLA below "
-                        "the win region, and the ledger has no default "
-                        "threshold to override")
+                        + " — there is no XLA tier to send a losing "
+                        "shape to")
 
 
 # ---------------------------------------------------------------------------

@@ -132,8 +132,7 @@ CATALOG: Dict[str, Dict[str, str]] = {
     "plan.search_ms": {
         "kind": "gauge", "unit": "ms",
         "description": "Wall time of the last plan_training joint "
-                       "search (enumerate → prune → rank, including "
-                       "ledger re-pricing)."},
+                       "search (enumerate → prune → rank)."},
     "plan.explored": {
         "kind": "gauge", "unit": "plans",
         "description": "Plans enumerated by the last joint search, "

@@ -145,18 +145,14 @@ def multi_tensor_maxnorm(noop_flag, tensor_lists, per_tensor: bool = False):
 # ---------------------------------------------------------------------------
 
 def _use_fused(op: str, tensor_lists) -> bool:
-    """Whether the dispatch policy routes this group to the packed
-    Pallas kernel (apex_tpu.kernels.multi_tensor).  Trace-time static:
-    consults the calibration ledger through kernels.dispatch — on CPU
-    without a forced mode this is always False and the per-bucket
-    path below runs unchanged."""
+    """Whether this group goes to the packed Pallas kernel: the kernel
+    module's rule (:func:`apex_tpu.kernels.multi_tensor.kernel_mode`,
+    trace-time static — interpret mode only, so a compiled program and
+    the CPU without a forced mode run the per-bucket path below)."""
     if not tensor_lists or not tensor_lists[0]:
         return False
-    from ..kernels import dispatch as _dispatch
-    from ..kernels.multi_tensor import group_fp
-    name = f"multi_tensor_{op}"
-    return _dispatch.decide(name, group_fp(op, tensor_lists[0])).tier \
-        == "pallas"
+    from ..kernels.multi_tensor import kernel_mode
+    return kernel_mode(op) is not None
 
 
 def multi_tensor_sgd(noop_flag, tensor_lists, wd, momentum, dampening, lr,

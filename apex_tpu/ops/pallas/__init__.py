@@ -1,6 +1,6 @@
 """Compatibility shim — the Pallas kernels moved to
-:mod:`apex_tpu.kernels` (the measured kernel tier with dispatch policy
-and calibration ledger).
+:mod:`apex_tpu.kernels` (the kernel tier; each module holds its own
+dispatch rule).
 
 This package re-exports the dispatch surface the old location provided
 (``pallas_mode``/``force_mode``/``norm_kernel_mode`` and the

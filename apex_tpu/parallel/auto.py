@@ -651,9 +651,6 @@ class Plan:
     breakdown: tuple = ()                # ((name, value), ...) — hashable
     collectives: tuple = ()
     measured_ms: Optional[float] = None
-    #: calibration-ledger citations: terms whose roofline prior was
-    #: replaced by a measured kernel time (strings, for describe())
-    ledger_terms: tuple = ()
     #: heterogeneous fleets only: per-device batch shares (ints summing
     #: EXACTLY to the global batch, device order) — the planner's
     #: replacement for the uniform global_batch/dp split.  Empty on a
@@ -825,11 +822,6 @@ class Plan:
                 + ", ".join(str(s) for s in self.device_shares)
                 + "] (heterogeneous fleet — slowest-member bound; "
                 "shares sum to the global batch)")
-        if self.ledger_terms:
-            lines.append("  calibration-ledger re-priced terms "
-                         "(measured, not roofline priors):")
-            for t in self.ledger_terms:
-                lines.append(f"    {t}")
         if self.predicted_hbm is not None:
             mem = " + ".join(
                 f"{k[4:]} {self._fmt_bytes(v)}"
@@ -1429,131 +1421,6 @@ def _mib(b):
 
 
 # ---------------------------------------------------------------------------
-# Calibration-ledger re-pricing (apex_tpu.kernels.ledger)
-# ---------------------------------------------------------------------------
-
-
-def model_fp(prof: ModelProfile, global_batch: int) -> str:
-    """The ledger's model-shape fingerprint: what makes two training
-    runs "the same workload" for plan-measurement reuse.  Built with the
-    same :func:`~apex_tpu.kernels.dispatch.shape_fp` helper the kernel
-    probes use, so one canonicalization serves both ledger sections."""
-    from ..kernels.dispatch import shape_fp
-    return shape_fp(params=int(prof.n_params),
-                    layers=int(prof.layers or 0),
-                    hidden=int(prof.hidden or 0),
-                    heads=int(prof.heads or 0),
-                    seq=int(prof.seq_len or 0),
-                    vocab=int(prof.vocab or 0),
-                    batch=int(global_batch))
-
-
-def _opt_kernel_name(optimizer) -> Optional[str]:
-    """Which registered multi-tensor kernel prices this optimizer's
-    update step (None: no registered kernel — priors keep deciding)."""
-    try:
-        from ..optimizers import FusedAdam, FusedSGD
-    except Exception:
-        return None
-    if isinstance(optimizer, FusedAdam):
-        return "multi_tensor_adam"
-    if isinstance(optimizer, FusedSGD):
-        return "multi_tensor_sgd"
-    return None
-
-
-def _plan_attention_fp(plan: Plan, prof: ModelProfile,
-                       global_batch: int) -> Optional[str]:
-    """The per-device attention-call fingerprint this plan would hand to
-    ``decide("flash_attention", ...)``: micro-batch rows, heads, the
-    sp-sharded query chunk against full keys, head dim."""
-    if not (prof.layers and prof.heads and prof.hidden and prof.seq_len):
-        return None
-    if prof.hidden % prof.heads:
-        return None
-    from ..kernels.dispatch import attention_fp
-    micro_b = max(int(global_batch // (plan.dp * plan.accum)), 1)
-    dt = "bfloat16" if prof.half_itemsize == 2 else "float32"
-    return attention_fp(micro_b, prof.heads,
-                        prof.seq_len // max(plan.sp, 1), prof.seq_len,
-                        prof.hidden // prof.heads, dtype=dt, causal=True)
-
-
-def _ledger_reprice(plan: Plan, prof: ModelProfile, spec: ChipSpec,
-                    global_batch: int, chip: str,
-                    opt_kernel: Optional[str]) -> Plan:
-    """Swap the roofline's attention and optimizer terms for
-    ledger-measured kernel times when the calibration ledger holds an
-    entry for this chip and the plan's exact shapes.
-
-    The adjustment is a delta — ``predicted_ms += measured − prior`` —
-    against the analytic estimate of the same term (attention FLOPs at
-    the sustained rate; the optimizer's read/modify/write HBM traffic at
-    bandwidth), so an empty ledger changes nothing and a measurement
-    shifts only the term it covers.  Citations land in
-    :attr:`Plan.ledger_terms` for ``describe()``.
-    """
-    try:
-        from ..kernels import ledger as _kl
-        from ..kernels.dispatch import multi_tensor_fp
-        led = _kl.get_ledger()
-    except Exception:
-        return plan
-    terms, delta_ms = [], 0.0
-    n_used = plan.n_used
-    sustained = spec.sustained_flops() / (n_used if spec.shared_host else 1)
-    hbm_bw = spec.hbm_bw / (n_used if spec.shared_host else 1)
-    micro_b = max(int(global_batch // (plan.dp * plan.accum)), 1)
-
-    afp = _plan_attention_fp(plan, prof, global_batch)
-    if afp is not None:
-        rec = led.lookup_kernel(chip, "flash_attention", afp)
-        if rec is not None:
-            tier = "pallas" if rec["win"] >= 1.0 else "xla"
-            per_call_us = rec["pallas_us" if tier == "pallas" else "xla_us"]
-            calls = prof.layers * plan.accum
-            measured_ms = per_call_us * 1e-3 * calls
-            sq = prof.seq_len // max(plan.sp, 1)
-            d = prof.hidden // prof.heads
-            # fwd 2 matmuls of 2·b·h·sq·sk·d each, bwd ≈ 2× fwd
-            attn_flops = (12.0 * calls * micro_b * prof.heads * sq
-                          * prof.seq_len * d)
-            prior_ms = attn_flops / sustained * 1e3
-            delta_ms += measured_ms - prior_ms
-            terms.append(
-                f"attention {measured_ms:.3f} ms/step ledger-measured "
-                f"(flash_attention[{afp}] {per_call_us:.1f}us/call, "
-                f"{tier} tier, win {rec['win']:.2f}x, x{calls} calls; "
-                f"roofline prior {prior_ms:.3f} ms)")
-    if opt_kernel is not None:
-        ofp = multi_tensor_fp(opt_kernel.replace("multi_tensor_", ""),
-                              prof.n_params, len(prof.param_shapes))
-        rec = led.lookup_kernel(chip, opt_kernel, ofp)
-        if rec is not None:
-            tier = "pallas" if rec["win"] >= 1.0 else "xla"
-            per_us = rec["pallas_us" if tier == "pallas" else "xla_us"]
-            shard = plan.dp if (plan.zero_stage >= 1 and plan.dp > 1) else 1
-            measured_ms = per_us * 1e-3 / shard
-            # read masters+slots+grads, write masters+slots — the
-            # bandwidth-bound analytic estimate of the update sweep
-            opt_bytes = ((3 + 2 * prof.slots_per_param)
-                         * prof.param_bytes_fp32 / shard)
-            prior_ms = opt_bytes / hbm_bw * 1e3
-            delta_ms += measured_ms - prior_ms
-            terms.append(
-                f"optimizer {measured_ms:.3f} ms/step ledger-measured "
-                f"({opt_kernel}[{ofp}] {per_us:.1f}us, {tier} tier, "
-                f"win {rec['win']:.2f}x"
-                + (f", /{shard} ZeRO shards" if shard > 1 else "")
-                + f"; roofline prior {prior_ms:.3f} ms)")
-    if not terms:
-        return plan
-    return dataclasses.replace(
-        plan, predicted_ms=max(plan.predicted_ms + delta_ms, 1e-3),
-        ledger_terms=tuple(terms))
-
-
-# ---------------------------------------------------------------------------
 # Enumeration + ranking
 # ---------------------------------------------------------------------------
 
@@ -1710,15 +1577,6 @@ def plan_training(model, optimizer, loss_fn: Callable, example_batch, *,
         cap = spec.hbm_bytes * (1.0 - hbm_reserve)
     n_plan_devices = flt.n_devices if flt is not None else len(devices)
 
-    chip_key, mfp = None, None
-    try:
-        from ..kernels import ledger as _kl
-        chip_key = _kl.chip_name(devices)
-        mfp = model_fp(prof, global_batch)
-    except Exception:
-        _kl = None
-    opt_kernel = _opt_kernel_name(optimizer)
-
     hetero = flt is not None and flt.heterogeneous
     feasible, rejected = [], []
     explored = 0
@@ -1785,9 +1643,6 @@ def plan_training(model, optimizer, loss_fn: Callable, example_batch, *,
             plan, predicted_ms=ms, predicted_hbm=mem,
             breakdown=tuple(time_bd + mem_bd), collectives=tuple(colls),
             device_shares=tuple(shares) if shares is not None else ())
-        if chip_key is not None:
-            plan = _ledger_reprice(plan, prof, spec, global_batch,
-                                   chip_key, opt_kernel)
         feasible.append(plan)
 
     # deterministic rank: predicted time, then fewer devices, lower
@@ -1798,26 +1653,6 @@ def plan_training(model, optimizer, loss_fn: Callable, example_batch, *,
                 p.offload_opt, p.offload_act, p.ep)
 
     feasible.sort(key=_rank)
-    # measured plan trials from previous runs of this same (chip, model
-    # shape) re-rank repeated runs from data — measurement outranks any
-    # prediction, exactly as a fresh auto_tune pass would
-    if chip_key is not None and mfp is not None:
-        try:
-            meas = _kl.get_ledger().plan_measurements(chip_key, mfp)
-        except Exception:
-            meas = {}
-        if meas:
-            from ..kernels.ledger import _plan_key_str
-            feasible = [
-                dataclasses.replace(p, measured_ms=float(
-                    meas[_plan_key_str(p.key())]["measured_ms"]))
-                if (p.measured_ms is None
-                    and _plan_key_str(p.key()) in meas) else p
-                for p in feasible]
-            feasible.sort(key=lambda p: (
-                p.measured_ms is None,
-                p.measured_ms if p.measured_ms is not None
-                else p.predicted_ms) + _rank(p)[1:])
     search_ms = (time.perf_counter() - t_search) * 1e3
     pruned_oom = sum(1 for _, r in rejected
                      if r.startswith("memory-infeasible"))
@@ -2198,14 +2033,6 @@ def auto_tune_report(report: PlanReport, model, optimizer, loss_fn,
                      steps: int = 3, **base_kwargs) -> PlanReport:
     """Measured refinement: compile and time the top-k predicted plans
     and re-rank by measurement (prediction breaks ties / fills gaps)."""
-    chip_key, mfp, led = None, None, None
-    try:
-        from ..kernels import ledger as _kl
-        chip_key = _kl.chip_name(devices)
-        mfp = model_fp(report.profile, report.global_batch)
-        led = _kl.get_ledger()
-    except Exception:
-        pass
     measured = []
     for plan in report.ranked[:max(k, 1)]:
         try:
@@ -2213,26 +2040,14 @@ def auto_tune_report(report: PlanReport, model, optimizer, loss_fn,
                               example_batch, devices=devices, steps=steps,
                               **base_kwargs)
             measured.append(dataclasses.replace(plan, measured_ms=ms))
-            # each trial measurement is a calibration-ledger entry —
-            # stamped with (chip, model_fp) so ledger.ingest_events can
-            # fold the event stream back in, and written through to the
-            # ledger directly so the NEXT plan_training on this shape
-            # re-ranks from measurement without an ingest pass
             _obs.event("plan.auto_tune", plan=plan.name(),
                        plan_key=plan.key(), measured_ms=ms,
-                       predicted_ms=plan.predicted_ms,
-                       chip=chip_key, model_fp=mfp)
-            if led is not None:
-                led.record_plan(chip_key, mfp, plan.key(),
-                                measured_ms=ms,
-                                predicted_ms=plan.predicted_ms,
-                                plan=plan.name(), source="auto_tune")
+                       predicted_ms=plan.predicted_ms)
         except Exception as e:        # a plan that fails to run loses
             report.rejected.append(
                 (plan, f"auto_tune trial failed: {type(e).__name__}: {e}"))
             _obs.event("plan.auto_tune", plan=plan.name(),
                        plan_key=plan.key(), measured_ms=None,
-                       chip=chip_key, model_fp=mfp,
                        error=f"{type(e).__name__}: {e}")
     measured.sort(key=lambda p: (p.measured_ms, p.predicted_ms))
     ranked = measured + [p for p in report.ranked
@@ -2285,32 +2100,13 @@ def build_planned_step(model, optimizer, loss_fn, parallel, *,
         raise TypeError(
             f"parallel= accepts 'auto' or a parallel.auto.Plan, got "
             f"{type(parallel).__name__}")
-    chip_key, mfp = None, None
-    try:
-        from ..kernels import ledger as _kl
-        chip_key = _kl.chip_name(devices)
-        if report is not None:
-            mfp = model_fp(report.profile, report.global_batch)
-    except Exception:
-        _kl = None
     _obs.event("plan.decision", plan=plan.name(), plan_key=plan.key(),
                source="auto" if report is not None else "explicit",
                n_devices=len(devices),
                predicted_ms=plan.predicted_ms,
                measured_ms=plan.measured_ms,
-               chip=chip_key, model_fp=mfp,
                feasible=len(report.ranked) if report is not None else None,
                rejected=len(report.rejected) if report is not None else None)
-    if mfp is not None:
-        # the decision itself is ledger data: record_plan keeps any
-        # prior measured_ms when this decision carries none
-        try:
-            _kl.get_ledger().record_plan(
-                chip_key, mfp, plan.key(), measured_ms=plan.measured_ms,
-                predicted_ms=plan.predicted_ms, plan=plan.name(),
-                source="decision")
-        except Exception:
-            pass
     step = apply_plan(plan, model, optimizer, loss_fn, devices=devices,
                       **base_kwargs)
     step.plan_report = report

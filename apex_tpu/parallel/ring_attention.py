@@ -46,7 +46,6 @@ import jax.numpy as jnp
 import numpy as _np
 from jax import lax
 
-from ..kernels.dispatch import pallas_mode
 from ..kernels import attention as _k
 
 _f32 = jnp.float32
@@ -317,19 +316,10 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
             f"({h_kv})")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    mode = pallas_mode()
-    if mode is not None:
-        # the ring's per-chunk flash step goes through the same dispatch
-        # policy as single-device attention: the ledger (or the probe's
-        # measured min-sk prior) decides at the LOCAL chunk shape, so an
-        # sp plan whose chunks sit below the win region falls back to
-        # the jnp chunk math instead of running a losing kernel n times
-        from ..kernels.dispatch import attention_fp, decide
-        tier = decide("flash_attention",
-                      attention_fp(b, h, s, k.shape[2], d, q.dtype,
-                                   causal)).tier
-        if tier == "xla":
-            mode = None
+    # the ring's per-chunk flash step follows the flash kernel's own rule
+    # at the LOCAL chunk shape, so an sp plan whose chunks sit below the
+    # win region runs the jnp chunk math instead of a losing kernel n times
+    mode = _k.kernel_mode(b, h, s, k.shape[2])
     q3 = q.reshape(b * h, s, d)
     k3 = k.reshape(b * h_kv, k.shape[2], d)
     v3 = v.reshape(b * h_kv, v.shape[2], d)

@@ -94,8 +94,7 @@ class DisaggregatedEngine:
                  prefill_chunk=32, cache_dtype=None, window=None,
                  prefill_blocks=None, decode_blocks=None,
                  handoff_dir=None, draft=None, spec_k=4,
-                 draft_cache_dtype="int8", spec_policy="on",
-                 prefix_cache=True):
+                 draft_cache_dtype="int8", prefix_cache=True):
         if window is not None:
             raise NotImplementedError(
                 "disaggregated serving + sliding window: handoff after "
@@ -111,8 +110,7 @@ class DisaggregatedEngine:
             block_size=block_size, max_batch=max_batch,
             prefill_chunk=prefill_chunk, cache_dtype=cache_dtype,
             phase="decode", draft=draft, spec_k=spec_k,
-            draft_cache_dtype=draft_cache_dtype,
-            spec_policy=spec_policy, prefix_cache=prefix_cache)
+            draft_cache_dtype=draft_cache_dtype, prefix_cache=prefix_cache)
         self.spec = self.decode.spec
         if handoff_dir is None:
             handoff_dir = tempfile.mkdtemp(prefix="apex_kv_handoff_")

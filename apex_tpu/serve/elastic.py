@@ -162,7 +162,7 @@ class ServeFleet:
     def __init__(self, model, *, n_engines, num_blocks, block_size=16,
                  max_batch=8, prefill_chunk=32, cache_dtype=None,
                  draft=None, spec_k=4, draft_cache_dtype="int8",
-                 spec_policy="on", kv: Optional[KVStore] = None,
+                 kv: Optional[KVStore] = None,
                  clock: Optional[SimClock] = None, deadline_s=0.25,
                  miss_threshold=2, snapshot_every=2, snapshot_dir=None,
                  snapshot_max_age_ticks=None, migrate_per_tick=None):
@@ -194,8 +194,7 @@ class ServeFleet:
                 model, num_blocks=blocks[i], block_size=block_size,
                 max_batch=max_batch, prefill_chunk=prefill_chunk,
                 cache_dtype=cache_dtype, draft=draft, spec_k=spec_k,
-                draft_cache_dtype=draft_cache_dtype,
-                spec_policy=spec_policy)
+                draft_cache_dtype=draft_cache_dtype)
             member = Member(
                 self.kv, f"serve{i}", clock=self.clock,
                 spec=json.dumps({"chip": "serve",

@@ -46,8 +46,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.dispatch import decide as _decide
-from ..kernels.spec_verify import spec_verify_fp
 from ..models.gpt import _sharded_decode_axes
 from ..observe import registry as _obs
 from ..observe import spans as _spans
@@ -100,7 +98,7 @@ class ServeEngine:
                  prefill_chunk=32, cache_dtype=None,
                  max_prefill_backlog=None, window=None, phase="unified",
                  draft=None, spec_k=4, draft_cache_dtype="int8",
-                 spec_policy="on", prefix_cache=True):
+                 prefix_cache=True):
         self._validate_model(model)
         if phase not in PHASES:
             raise ValueError(f"phase must be one of {PHASES}, got "
@@ -123,13 +121,11 @@ class ServeEngine:
         self.spec = draft is not None
         self.draft = draft
         self.spec_k = int(spec_k)
-        self._spec_policy = spec_policy
         self._d_params: List = []
         self._d_dtype_name = None
         self.dpool = None
         if self.spec:
-            self._validate_spec(model, draft, window, self.spec_k,
-                                spec_policy)
+            self._validate_spec(model, draft, window, self.spec_k)
             self._d_params = list(draft.parameters()) \
                 + list(draft.buffers())
             d_dtype = draft_cache_dtype if draft_cache_dtype is not None \
@@ -213,7 +209,7 @@ class ServeEngine:
                 f"ServeEngine runs single-shard; the model was built "
                 f"with {names}")
 
-    def _validate_spec(self, model, draft, window, spec_k, spec_policy):
+    def _validate_spec(self, model, draft, window, spec_k):
         self._validate_model(draft)
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
@@ -222,11 +218,6 @@ class ServeEngine:
                 "speculative mode + sliding window: the verify chunk "
                 "would need a per-row band mask over retired blocks — "
                 "serve one mode or the other")
-        if spec_policy not in ("on", "auto"):
-            raise ValueError(
-                f"spec_policy must be 'on' (always speculate) or "
-                f"'auto' (decide() per bucket shape), got "
-                f"{spec_policy!r}")
         if draft.tok_emb.weight.shape[0] < model.tok_emb.weight.shape[0]:
             raise ValueError(
                 "draft vocabulary is smaller than the target's — "
@@ -483,7 +474,7 @@ class ServeEngine:
             ds = self._decode_ready()
             if ds:
                 tick["decode_batch"] = len(ds)
-                if self.spec and self._spec_pays(ds):
+                if self.spec:
                     self._spec_tick(ds)
                 else:
                     self._decode_tick(ds)
@@ -643,23 +634,6 @@ class ServeEngine:
         if not self.spec:
             return ds
         return [s for s in ds if s.draft_position == s.position]
-
-    def _spec_pays(self, sessions: List[Session]) -> bool:
-        """``spec_policy="on"`` always speculates; ``"auto"`` asks the
-        kernel-dispatch ledger (decide(), cached per bucket shape)
-        whether the measured verify win covers this shape — below the
-        win region the engine falls back to plain decode ticks and the
-        catch-up path keeps the draft cache consistent."""
-        if self._spec_policy == "on":
-            return True
-        b = bucket(len(sessions), self.scheduler.max_batch)
-        nbt = bucket(max(len(s.table) for s in sessions))
-        nbd = bucket(max(len(s.draft_table) for s in sessions))
-        fp = spec_verify_fp(b=b, k=self.spec_k,
-                            s_t=nbt * self.block_size,
-                            s_d=nbd * self.block_size,
-                            dtype=self._dtype_name)
-        return _decide("spec_verify", fp).tier == "pallas"
 
     def _ensure_decode_blocks(self) -> None:
         """Every decoding session needs its table to cover the rows

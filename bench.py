@@ -186,13 +186,12 @@ def flash_attn_step_flops(attn_shapes):
     Softmax (≈5·area) and the Pallas LayerNorm (O(b·s·e)) are noise at
     these shapes and left out.
     """
-    from apex_tpu.contrib.multihead_attn.attn_funcs import \
-        _use_xla_attention
+    from apex_tpu.kernels.attention import _compiled_takes
 
     total = 0.0
     for layers, b, h, sq, sk, d, causal in attn_shapes:
-        if _use_xla_attention(b, h, sq, sk):
-            # the dispatch routes this shape to the XLA path, whose
+        if not _compiled_takes(b, h, sq, sk):
+            # the flash rule sends this shape to the XLA path, whose
             # matmuls cost analysis already counts — adding the
             # complement would double-count
             continue
@@ -214,32 +213,13 @@ def _pin_flash_dispatch():
     the shape-aware dispatch would pick), restoring the production
     dispatch afterwards — bench must not leave a process-global
     override behind."""
-    prev = os.environ.get("APEX_TPU_FLASH_MIN_SK")
-    os.environ["APEX_TPU_FLASH_MIN_SK"] = "0"
+    from apex_tpu.kernels import attention as kattn
+    prev = kattn.FLASH_MIN_SK
+    kattn.FLASH_MIN_SK = 0
     try:
         yield
     finally:
-        if prev is None:
-            os.environ.pop("APEX_TPU_FLASH_MIN_SK", None)
-        else:
-            os.environ["APEX_TPU_FLASH_MIN_SK"] = prev
-
-
-def dispatch_tier_snapshot():
-    """Which dispatch tier each hot path actually took, for the headline
-    records: one compact row per trace-time decision the
-    apex_tpu.kernels dispatch policy made in this process (kernel,
-    pallas|xla, shape fingerprint, and whether the ledger / the probe /
-    the backend mode decided).  None when no kernel routed through the
-    policy — an all-XLA step is reported as such, not silently."""
-    try:
-        from apex_tpu.kernels import dispatch as kdispatch
-    except Exception:
-        return None
-    rows = [{"kernel": d["kernel"], "tier": d["tier"],
-             "shape_fp": d["shape_fp"], "source": d["source"]}
-            for d in kdispatch.decisions()]
-    return rows or None
+        kattn.FLASH_MIN_SK = prev
 
 
 def run_kernel_checks():
@@ -732,322 +712,6 @@ def run_kernel_timing(iters=30, reps=5):
         "geomean of median-of-reps speedups, shipping kernels only "
         "(layer_norm+rms_norm+attention buckets)")
     return results, gmean
-
-
-def kernel_probe_records(iters=2, reps=3):
-    """``--kernels`` calibration stage: A/B-probe each registered
-    dispatch-tier kernel (apex_tpu.kernels.dispatch.catalog()) over a
-    small shape grid and emit one ledger-shaped record per
-    (kernel, shape)::
-
-        {"metric": "kernel_probe", "kernel", "shape_fp",
-         "pallas_us", "xla_us", "win", "threshold"}
-
-    The schema is the TPU contract — the exact rows
-    ``kernels.ledger.Ledger.ingest_events`` consumes (each record is
-    mirrored as a ``bench.kernel_probe`` observe event via
-    register_record).  Off-TPU the pallas arm runs in interpret mode (a
-    Python emulation, ~1000x off), so records are emitted for the
-    schema/plumbing contract but NOT written into the persistent
-    calibration ledger; on a compiled TPU backend each probe is written
-    through ``record_kernel`` so ``parallel="auto"`` and ``decide()``
-    re-rank the next run from measured data.
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from apex_tpu.kernels import dispatch as kdispatch
-    from apex_tpu.kernels import ledger as kledger
-    from apex_tpu.ops import pallas as pal
-
-    mode = "compiled" if jax.default_backend() == "tpu" else "interpret"
-    chip = kledger.chip_name()
-    rng = np.random.default_rng(0)
-
-    _sync = jax.block_until_ready
-
-    def _time_arms(build_fn, args):
-        """Median per-call seconds per arm; both arms compile first,
-        then ``reps`` segments of ``iters`` calls run interleaved (the
-        run_kernel_timing variance control, VERDICT r4 #3)."""
-        fns = {}
-        for arm, m in (("pallas", mode), ("xla", "off")):
-            with pal.force_mode(m):
-                fn = build_fn(arm)
-                _sync(fn(*args))    # compile + warm inside the mode ctx
-                fns[arm] = fn
-        seg = {arm: [] for arm in fns}
-        for _ in range(reps):
-            for arm, fn in fns.items():
-                t0 = time.perf_counter()
-                for _ in range(iters):
-                    out = fn(*args)
-                _sync(out)
-                seg[arm].append((time.perf_counter() - t0) / iters)
-        out = {}
-        for arm, ts in seg.items():
-            ts = sorted(ts)
-            n_ = len(ts)
-            out[arm] = (ts[n_ // 2] if n_ % 2
-                        else (ts[n_ // 2 - 1] + ts[n_ // 2]) / 2)
-        return out
-
-    probes = []
-
-    # --- flash_attention: fwd+bwd through the production 4-D surface
-    # (shape-aware dispatch pinned open by the caller's
-    # _pin_flash_dispatch so the pallas arm exercises the KERNEL) ---
-    from apex_tpu.contrib.multihead_attn.attn_funcs import flash_attention
-    for b_, h, s, d in [(1, 2, 64, 16), (1, 2, 128, 16)]:
-        q = jnp.asarray(rng.standard_normal((b_, h, s, d)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((b_, h, s, d)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((b_, h, s, d)), jnp.float32)
-
-        def build(arm, b_=b_):
-            def loss(q, k, v):
-                return jnp.sum(
-                    flash_attention(q, k, v, causal=True)
-                    .astype(jnp.float32) ** 2)
-            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        probes.append((
-            "flash_attention",
-            kdispatch.attention_fp(b_, h, s, s, d, "float32", True),
-            build, (q, k, v)))
-
-    # --- multi_tensor_{sgd,adam}: the fused group update vs the
-    # declared per-bucket XLA fallback, same bucket geometry ---
-    from apex_tpu.kernels import multi_tensor as kmt
-    from apex_tpu.ops import multi_tensor as omt
-    shapes = [(257,), (128,), (33, 7)]
-    flag = jnp.zeros((), jnp.int32)
-
-    def mk_lists(n_lists):
-        return [[jnp.asarray(rng.standard_normal(s), jnp.float32)
-                 for s in shapes] for _ in range(n_lists)]
-
-    sgd_lists = mk_lists(3)      # grads, params, momenta
-    sgd_hyper = (0.0, 0.9, 0.0, 0.1, False, False, False, 1.0)
-
-    def build_sgd(arm):
-        if arm == "pallas":
-            return jax.jit(lambda f, ls: kmt.fused_sgd(f, ls, *sgd_hyper))
-        return jax.jit(lambda f, ls: omt.sgd_unfused(f, ls, *sgd_hyper))
-    probes.append(("multi_tensor_sgd",
-                   kmt.group_fp("sgd", sgd_lists[0]),
-                   build_sgd, (flag, sgd_lists)))
-
-    adam_lists = mk_lists(4)     # grads, params, m, v
-    adam_hyper = (1e-3, 0.9, 0.999, 1e-8, 3, 0, True, 0.01)
-
-    def build_adam(arm):
-        if arm == "pallas":
-            return jax.jit(lambda f, ls: kmt.fused_adam(f, ls, *adam_hyper))
-        return jax.jit(lambda f, ls: omt.adam_unfused(f, ls, *adam_hyper))
-    probes.append(("multi_tensor_adam",
-                   kmt.group_fp("adam", adam_lists[0]),
-                   build_adam, (flag, adam_lists)))
-
-    # --- vocab_chain_loss: fused lm-head+xent kernel vs the chunked
-    # XLA chain it declares as fallback.  Both arms bypass decide() —
-    # the probe MEASURES the tiers; it must not let the policy it is
-    # calibrating pick the arm ---
-    from apex_tpu.ops.pallas.lm_head_xent import fused_lm_head_xent
-    from apex_tpu.contrib.xentropy.chunked import chunked_lm_head_loss
-    n_, v_, e_ = 64, 512, 64
-    hx = jnp.asarray(rng.standard_normal((n_, e_)) * 0.3, jnp.float32)
-    wx = jnp.asarray(rng.standard_normal((v_, e_)) * 0.1, jnp.float32)
-    lab = jnp.asarray(rng.integers(0, v_, (n_,)), jnp.int32)
-
-    def build_vc(arm):
-        if arm == "pallas":
-            # dispatches on pallas mode internally: kernel under the
-            # forced mode at trace time
-            return jax.jit(lambda h, w: jnp.sum(
-                fused_lm_head_xent(h, w, lab)))
-        return jax.jit(lambda h, w: jnp.sum(
-            chunked_lm_head_loss(h, w, lab)))
-    probes.append(("vocab_chain_loss",
-                   kdispatch.vocab_chain_fp(n_, v_, e_, "float32"),
-                   build_vc, (hx, wx)))
-
-    # --- spec_verify: the fused draft-propose + target-verify serve
-    # tick vs the k+1 chained plain decode dispatches it replaces
-    # (self-draft, so acceptance is full and both arms commit the same
-    # k+1 tokens per call — equal useful work, pure dispatch-count
-    # comparison).  Both arms bypass decide() for the same reason the
-    # vocab probe does: the probe MEASURES the tiers ---
-    import apex_tpu.nn as _ann
-    from apex_tpu.kernels.spec_verify import spec_verify_fp
-    from apex_tpu.models.gpt import GptModel as _Gpt
-    from apex_tpu.serve.kernels import (build_decode_fn,
-                                        build_spec_verify_fn)
-    from apex_tpu.serve.pool import init_pool_buffer
-
-    _ann.manual_seed(0)
-    sp_model = _Gpt(vocab_size=73, hidden=32, layers=2, heads=4,
-                    max_positions=96, dropout=0.0, attn_dropout=0.0)
-    sp_model.eval()
-    sp_params = list(sp_model.parameters()) + list(sp_model.buffers())
-    sp_vals = [p.data for p in sp_params]
-    sp_k, sp_b, sp_blocks, sp_bs = 3, 4, 10, 8
-    sp_pool = init_pool_buffer(2, 4, 8, sp_blocks, sp_bs)
-    sp_dpool = init_pool_buffer(2, 4, 8, sp_blocks, sp_bs)
-    sp_pos = 2  # rows 0..1 hold "context"; verify writes 2..2+k
-    sp_tabs = jnp.asarray(
-        [[1 + 2 * i, 2 + 2 * i] for i in range(sp_b)], jnp.int32)
-    sp_toks = jnp.asarray(
-        rng.integers(1, 72, (sp_b,)), jnp.int32)
-    sp_positions = jnp.full((sp_b,), sp_pos, jnp.int32)
-
-    def build_spec(arm):
-        if arm == "pallas":
-            fused = build_spec_verify_fn(
-                sp_model, sp_params, sp_model, sp_params, sp_bs,
-                sp_blocks, sp_k)
-            return jax.jit(fused)
-        dec = build_decode_fn(sp_model, sp_params, sp_bs, sp_blocks)
-
-        def chain(t_vals, d_vals, t_pool, d_pool, toks, pos, t_tab,
-                  d_tab):
-            tk, p = toks, t_pool
-            for j in range(sp_k + 1):
-                tk, _lg, p, _ = dec(t_vals, p, tk, pos + j, t_tab)
-            return tk, p
-        return jax.jit(chain)
-    probes.append((
-        "spec_verify",
-        spec_verify_fp(b=sp_b, k=sp_k, s_t=sp_blocks * sp_bs,
-                       s_d=sp_blocks * sp_bs, dtype="float32"),
-        build_spec,
-        (sp_vals, sp_vals, sp_pool, sp_dpool, sp_toks, sp_positions,
-         sp_tabs, sp_tabs)))
-
-    # --- paged_attention: the decode tick's attention through block
-    # tables, live blocks by DMA against the gathered XLA tier; sessions
-    # at staggered depths in a table bucketed to 8 blocks.  Both arms
-    # bypass decide(), as above ---
-    from apex_tpu.kernels import paged_attention as kpa
-    pa_b, pa_h, pa_d, pa_bs, pa_nb = 4, 2, 64, 16, 8
-    pa_pool = jnp.asarray(
-        rng.standard_normal((1, 2, 1 + pa_b * pa_nb, pa_bs, pa_h * pa_d)),
-        jnp.bfloat16).at[:, :, 0].set(0)
-    pa_pos = np.asarray([5, 40, 77, 127], np.int32)
-    pa_tabs = np.zeros((pa_b, pa_nb), np.int32)
-    for i, p_ in enumerate(pa_pos):
-        n_live = int(p_) // pa_bs + 1
-        pa_tabs[i, :n_live] = 1 + i * pa_nb + np.arange(n_live)
-    pa_q = jnp.asarray(rng.standard_normal((pa_b, pa_h, pa_d)),
-                       jnp.bfloat16)
-
-    def build_pa(arm):
-        if arm == "pallas":
-            interp = kdispatch.pallas_mode() == "interpret"
-            return jax.jit(lambda q, pool, tabs, pos: kpa._decode_pallas(
-                q, pool, 0, tabs, pos, pa_d ** -0.5, None, interp))
-        return jax.jit(lambda q, pool, tabs, pos: kpa._decode_xla(
-            q, pool, 0, tabs, pos, pa_d ** -0.5, None))
-    probes.append((
-        "paged_attention",
-        kpa.paged_attention_fp(pa_b, pa_nb, pa_h, pa_d, pa_bs, "bfloat16"),
-        build_pa, (pa_q, pa_pool, jnp.asarray(pa_tabs),
-                   jnp.asarray(pa_pos))))
-
-    # --- latent_attention: the same walk over a latent (one-stream) pool,
-    # absorbed queries of 8 heads against rows of 256 (128 latent) ---
-    from apex_tpu.kernels import latent_attention as kla
-    la_h, la_w, la_rank = 8, 256, 128
-    la_pool = jnp.asarray(
-        rng.standard_normal((1, 1, 1 + pa_b * pa_nb, pa_bs, la_w)),
-        jnp.bfloat16).at[:, :, 0].set(0)
-    la_q = jnp.asarray(rng.standard_normal((pa_b, la_h, la_w)), jnp.bfloat16)
-
-    def build_la(arm):
-        if arm == "pallas":
-            interp = kdispatch.pallas_mode() == "interpret"
-            return jax.jit(lambda q, pool, tabs, pos: kla._decode_pallas(
-                q, pool, 0, tabs, pos, 0.125, la_rank, None, interp))
-        return jax.jit(lambda q, pool, tabs, pos: kla._decode_xla(
-            q, pool, 0, tabs, pos, 0.125, la_rank, None))
-    probes.append((
-        "latent_attention",
-        kla.latent_attention_fp(pa_b, pa_nb, la_h, la_w, la_rank, pa_bs,
-                                "bfloat16"),
-        build_la, (la_q, la_pool, jnp.asarray(pa_tabs),
-                   jnp.asarray(pa_pos))))
-
-    # --- routed_experts: the grouped matmul of rows sorted by expert
-    # (tile-aligned groups, unused tiles skipped) against ragged_dot ---
-    from apex_tpu.kernels import grouped_matmul as kgm
-    gm_g, gm_k, gm_n, gm_p = 4, 256, 128, 64
-    gm_group = jnp.asarray(rng.integers(0, gm_g + 2, gm_p), jnp.int32)
-    gm_lay = kgm.tile_layout(gm_group, gm_g, gm_p, kgm.TILE_ROWS)
-    gm_lhs = jnp.asarray(rng.standard_normal(
-        (gm_lay.pair_of_row.shape[0], gm_k)), jnp.bfloat16)
-    gm_rhs = jnp.asarray(rng.standard_normal((gm_g, gm_k, gm_n)),
-                         jnp.bfloat16)
-
-    def build_gm(arm):
-        if arm == "pallas":
-            interp = kdispatch.pallas_mode() == "interpret"
-            return jax.jit(lambda lhs, rhs, tg, na, padded: kgm._gmm_call(
-                tg, na, lhs, rhs, tile=kgm.TILE_ROWS, interpret=interp))
-        return jax.jit(lambda lhs, rhs, tg, na, padded: kgm._gmm_xla(
-            lhs, rhs, padded))
-    probes.append((
-        "routed_experts",
-        kgm.grouped_matmul_fp(gm_lhs.shape[0], gm_k, gm_n, gm_g, "bfloat16"),
-        build_gm, (gm_lhs, gm_rhs, gm_lay.tile_group, gm_lay.n_active,
-                   gm_lay.padded)))
-
-    write_ledger = mode == "compiled"
-    led = kledger.get_ledger() if write_ledger else None
-    records = []
-    for name, fp, build_fn, args in probes:
-        stage("kernel_probe", f"{name} [{fp}]")
-        spec = kdispatch.catalog().get(name)
-        threshold = None
-        if spec is not None:
-            try:
-                threshold = spec.threshold_probe(kdispatch.parse_fp(fp))[0]
-            except Exception:
-                threshold = None
-        try:
-            # pin the shape-aware flash dispatch open for the TIMING
-            # only (the pallas arm must exercise the kernel at every
-            # probed shape); the threshold above was read unpinned so
-            # the record carries the production value
-            with _pin_flash_dispatch():
-                med = _time_arms(build_fn, args)
-        except Exception as e:
-            records.append({"metric": "kernel_probe", "kernel": name,
-                            "shape_fp": fp, "pallas_us": None,
-                            "xla_us": None, "win": None,
-                            "threshold": threshold, "mode": mode,
-                            "chip": chip,
-                            "error": f"{type(e).__name__}: {e}"})
-            continue
-        pallas_us = med["pallas"] * 1e6
-        xla_us = med["xla"] * 1e6
-        rec = {"metric": "kernel_probe", "kernel": name, "shape_fp": fp,
-               "pallas_us": round(pallas_us, 2),
-               "xla_us": round(xla_us, 2),
-               "win": round(xla_us / pallas_us, 4) if pallas_us else None,
-               "threshold": threshold, "mode": mode, "chip": chip,
-               "iters": iters, "reps": reps,
-               "ledger_write": write_ledger,
-               "xla_fallback": spec.xla_fallback if spec else None}
-        if write_ledger:
-            led.record_kernel(chip, name, fp, pallas_us=pallas_us,
-                              xla_us=xla_us, threshold=threshold,
-                              source="bench")
-        records.append(rec)
-    for rec in records:
-        register_record(rec)
-    if write_ledger:
-        # ledger verdicts changed under the process: cached trace-time
-        # decisions embed the old ones
-        kdispatch.reset_decisions()
-    return records
 
 
 def time_compiled_step(step, batch_arrays, iters, warmup, analytic_flops,
@@ -2875,7 +2539,7 @@ def rollout_bench_records(rounds=8, seed=0, num_blocks=64,
     eng = ServeEngine(serve_m, num_blocks=num_blocks, block_size=8,
                       max_batch=4, prefill_chunk=4,
                       draft=make_self_draft(draft_master),
-                      spec_k=4, spec_policy="on")
+                      spec_k=4)
     step = make_train_step(
         train_m, FusedAdam(list(train_m.parameters()), lr=1e-3),
         lm_loss, loss_scale=1.0)
@@ -3473,9 +3137,7 @@ def main():
     ap.add_argument("--iters", type=int, default=50)
     ap.add_argument("--warmup", type=int, default=3)
     ap.add_argument("--kernels", action="store_true",
-                    help="run only the Pallas kernel parity checks + the "
-                         "dispatch-ledger calibration probes (one "
-                         "kernel_probe record per kernel/shape)")
+                    help="run only the Pallas kernel parity checks")
     ap.add_argument("--profile", action="store_true",
                     help="measured per-op-family time attribution of one "
                          "step via the pyprof trace pipeline (pair with "
@@ -3935,15 +3597,6 @@ def main():
               and res.get("vmem_guard") == "pass")
         emit({"metric": metric_name, "value": 1.0 if ok else 0.0,
               "unit": metric_unit, "vs_baseline": None, "kernels": res})
-        # calibration stage: one dispatch-ledger record per
-        # (kernel, shape).  Soft-fail — parity above is the gate, the
-        # probe rows are the calibration payload
-        stage("kernel_probe")
-        try:
-            for rec in kernel_probe_records():
-                emit(rec)
-        except Exception as e:
-            log(f"kernel probe failed: {type(e).__name__}: {e}")
         return 0
 
     if args.spec_decode:
@@ -3972,7 +3625,6 @@ def main():
               "plain_tokens_per_sec": round(plain_toks, 1),
               "compile_s": round(compile_s, 1),
               "device_kind": (devices[0].device_kind or "").lower(),
-              "kernel_dispatch": dispatch_tier_snapshot(),
               "kernels": None})
         return 0
 
@@ -3998,7 +3650,6 @@ def main():
               "call_time_s": round(dt, 3),
               "compile_s": round(compile_s, 1),
               "device_kind": (devices[0].device_kind or "").lower(),
-              "kernel_dispatch": dispatch_tier_snapshot(),
               "kernels": None})
         return 0
 
@@ -4072,8 +3723,7 @@ def main():
                   "tflops": round(tfl, 2),
                   "mfu": round(tfl / peak, 4),
                   "device_kind": kind, "flops_source": flops_source,
-                  "kernel_dispatch": dispatch_tier_snapshot(),
-                  "kernels": None})
+                      "kernels": None})
         return 0 if ok else 1
 
     dt = compile_s = flops = None
@@ -4147,9 +3797,6 @@ def main():
         "mfu": round(mfu, 4),
         "device_kind": kind,
         "flops_source": flops_source,
-        # which tier each kernel-dispatched hot path took this process
-        # (ledger/probe/mode-attributed; docs/kernels.md)
-        "kernel_dispatch": dispatch_tier_snapshot(),
         "kernels": kernels,
     })
     return 0

@@ -286,7 +286,6 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
     _flash_case(rng, sz.window_batch, sz.heads, sz.window_seq, sz.head_dim,
                 sz.window, f"flash causal window={sz.window}")
     _fused_optimizer_comparison(rng)
-    say("kernels: dispatch decisions " + json.dumps(dispatch.decisions()))
 
 
 # ---------------------------------------------------------------------------
@@ -324,12 +323,15 @@ def phase_train(sz: Sizes, seed: int) -> None:
     import jax
     import numpy as np
 
-    from apex_tpu.kernels import dispatch
+    from apex_tpu.observe import registry as obs
     from apex_tpu.runtime import executor, step_cache
 
     step = _lm_step(sz, seed)
     ids = _ids(sz, seed, sz.batch)
     before = step_cache.kind_stats("train_step")
+    flash = {tier: obs.counter(f"kernels.dispatch.flash_attention.{tier}")
+             for tier in ("pallas", "xla")}
+    flash_before = {tier: c.value for tier, c in flash.items()}
     losses, times = [], []
     for _ in range(sz.train_steps):
         t0 = time.perf_counter()
@@ -360,15 +362,14 @@ def phase_train(sz: Sizes, seed: int) -> None:
             f"got {compiles} and {dispatches}")
     if not (executor.donation.enabled and step._donate_state):
         raise AssertionError("train: donation did not resolve on")
-    want_fp = dispatch.attention_fp(sz.batch, sz.heads, sz.seq, sz.seq,
-                                    sz.head_dim, "bfloat16", True)
-    tiers = [d["tier"] for d in dispatch.decisions()
-             if d["kernel"] == "flash_attention" and d["shape_fp"] == want_fp]
-    say(f"train: flash_attention tier for {want_fp}: {tiers}")
-    if tiers != ["pallas"]:
+    # the counter moves where the flash rule is applied, at trace time:
+    # its change across the step's one trace is the tier the step took
+    took = {tier: c.value - flash_before[tier] for tier, c in flash.items()}
+    say(f"train: flash_attention tiers traced into the step: {took}")
+    if not took["pallas"] or took["xla"]:
         raise AssertionError(
-            f"train: flash tier for the step's shape is {tiers}, "
-            f"expected ['pallas']")
+            f"train: the step's attention took {took}, expected the "
+            f"Pallas tier alone")
 
 
 # ---------------------------------------------------------------------------

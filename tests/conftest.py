@@ -18,21 +18,6 @@ if "xla_force_host_platform_device_count" not in prev:
 
 from apex_tpu import compile_cache  # noqa: E402
 
-# Hermetic calibration ledger: entries a developer's runs left in the
-# checkout's cache directory must not steer kernel dispatch (or planner
-# re-ranking) inside the test suite, and neither must another test
-# process's (xdist workers, two suites at once) — so each test process
-# gets its own empty file beside the caches and removes it on exit.
-# Tests that WANT a warm ledger point the process ledger at their own
-# tmp file explicitly.
-if "APEX_TPU_LEDGER" not in os.environ:
-    import atexit
-
-    _ledger = os.path.join(compile_cache.cache_root(),
-                           f"test_kernel_ledger.{os.getpid()}.json")
-    os.environ["APEX_TPU_LEDGER"] = _ledger
-    atexit.register(lambda: os.path.exists(_ledger) and os.remove(_ledger))
-
 import jax  # noqa: E402
 
 # Persistent XLA compilation cache: the model/inference suites compile

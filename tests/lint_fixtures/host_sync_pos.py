@@ -37,7 +37,7 @@ fetch = jax.jit(bad_fetch_step)
 scaled = jax.jit(bad_scale_step)
 
 
-def _decide(x):
+def _choose(x):
     # BAD (interprocedural): x arrives traced from the jitted caller —
     # the branch is a device fetch even though this helper never
     # mentions jax
@@ -48,4 +48,4 @@ def _decide(x):
 
 @jax.jit
 def routed_step(v):
-    return _decide(v * 2.0)
+    return _choose(v * 2.0)

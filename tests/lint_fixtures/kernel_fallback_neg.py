@@ -1,6 +1,6 @@
 """KERNEL-FALLBACK negative fixture: model code consumes the kernels
-tier through its dispatch surface, and registrations declare both the
-XLA fallback and the threshold probe."""
+tier through its dispatch surface, and registrations declare the XLA
+fallback."""
 import jax.numpy as jnp
 
 from apex_tpu.kernels import attention as _k
@@ -8,19 +8,13 @@ from apex_tpu.kernels.dispatch import register_kernel
 
 
 def model_path(q, k, v):
-    # the sanctioned route: the kernels tier decides pallas-vs-XLA from
-    # the calibration ledger; no raw pallas_call in model code
+    # the sanctioned route: the kernel's module decides pallas-vs-XLA
+    # from mode and shape; no raw pallas_call in model code
     return _k.flash_attention_fwd(q, k, v, None, 1.0, True)
-
-
-def _probe(dims):
-    # measured win region as data: below 512 keys XLA wins (round 5)
-    return 512, dims.get("sk", 0) >= 512
 
 
 register_kernel(
     "well_declared_kernel",
     xla_fallback="apex_tpu.contrib.multihead_attn.attn_funcs."
                  "attention_reference",
-    threshold_probe=_probe,
     doc="fixture: compliant registration")

@@ -1,6 +1,6 @@
 """KERNEL-FALLBACK positive fixture: raw pallas_call outside
 apex_tpu/kernels/ (two import spellings), and registrations missing the
-declared fallback / probe."""
+declared fallback."""
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -15,7 +15,7 @@ def _double_kernel(x_ref, o_ref):
 
 def model_path_kernel(x):
     # flagged: pallas_call wired straight into model code — no XLA
-    # fallback seam, no probe record
+    # fallback seam
     return pl.pallas_call(
         _double_kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
@@ -27,15 +27,8 @@ def aliased_spelling(x):
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
 
 
-def _probe(dims):
-    return None, False
-
-
 # flagged: no xla_fallback declared
-register_kernel("orphan_kernel", threshold_probe=_probe)
-
-# flagged: no threshold_probe declared
-register_kernel("blind_kernel", xla_fallback="apex_tpu.ops.some_op")
+register_kernel("orphan_kernel", doc="no fallback")
 
 # flagged: fallback declared but empty
-register_kernel("hollow_kernel", xla_fallback="", threshold_probe=_probe)
+register_kernel("hollow_kernel", xla_fallback="")

@@ -103,14 +103,6 @@ def test_plan_from_key_rejects_unknown_segment():
                            n_devices=8)
 
 
-def test_ledger_plan_key_str_carries_v3_segments():
-    from apex_tpu.kernels.ledger import _plan_key_str
-    p = auto.Plan(pp=4, micro=8, remat="full", offload_opt=1.0,
-                  n_devices=4)
-    s = _plan_key_str(p.key())
-    assert s == "1/1/1/0/1/0/pp4/micro8/remat=full/offopt=1"
-
-
 # ---------------------------------------------------------------------------
 # Satellite 5: joint search rescues a profile every dp×tp plan OOMs on
 # ---------------------------------------------------------------------------

@@ -1,10 +1,9 @@
-"""The measured kernel tier (apex_tpu.kernels): interpret-mode parity
-pins for all three kernels (flash attention incl. causal/window masks
-and the ring sp composition, fused multi-tensor updates vs the
-per-bucket stacks, the fused vocab chain vs the chunked XLA chain),
-calibration-ledger round-trips and corrupt-entry recovery, and the
-dispatch policy itself — a below-threshold ledger entry must route to
-XLA and the deciding entry must land in the observe event log.
+"""The kernel tier (apex_tpu.kernels): interpret-mode parity pins for
+all three kernels (flash attention incl. causal/window masks and the
+ring sp composition, fused multi-tensor updates vs the per-bucket
+stacks, the fused vocab chain vs the chunked XLA chain), and the rule
+each kernel's module holds for which tier a call takes — a pure
+function of the mode and the shapes, tallied where it is applied.
 
 Parity regime: fp32 comparisons are BITWISE but always jit-vs-jit —
 XLA CPU contracts mul+add into FMA under jit but not eagerly, so an
@@ -13,8 +12,6 @@ eager arm differs from any jitted arm by ~1 ulp while two jitted arms
 """
 import functools
 import hashlib
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -25,25 +22,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 from apex_tpu.contrib.multihead_attn.attn_funcs import (
     attention_reference, flash_attention)
 from apex_tpu.contrib.xentropy.chunked import chunked_lm_head_loss
-from apex_tpu.kernels import dispatch, ledger
+from apex_tpu.kernels import dispatch
 from apex_tpu.kernels.dispatch import force_mode
-from apex_tpu.kernels.multi_tensor import fused_adam, fused_sgd, group_fp
+from apex_tpu.kernels.multi_tensor import fused_adam, fused_sgd
 from apex_tpu.kernels.vocab_chain import vocab_chain_loss
 from apex_tpu.ops import multi_tensor as ops_mt
 from apex_tpu.parallel import ring_attention
 from apex_tpu.runtime import step_cache
 
 pytestmark = pytest.mark.kernels
-
-
-@pytest.fixture
-def tmp_ledger(tmp_path):
-    """A fresh ledger file + cleared decision cache, restored after."""
-    led = ledger.set_path(str(tmp_path / "ledger.json"))
-    dispatch.reset_decisions()
-    yield led
-    ledger.set_path(None)
-    dispatch.reset_decisions()
 
 
 def _tensors(rng, shapes, dtype=jnp.float32):
@@ -165,7 +152,7 @@ def _qkv(rng, dtype=jnp.float32):
 
 @pytest.mark.parametrize("causal,window", [(False, None), (True, None),
                                            (True, 24)])
-def test_flash_interpret_parity_masks(rng, tmp_ledger, causal, window):
+def test_flash_interpret_parity_masks(rng, causal, window):
     q, k, v = _qkv(rng)
     scale = 1.0 / np.sqrt(D)
     ref = attention_reference(q, k, v, None, causal, scale, window=window)
@@ -208,7 +195,7 @@ BF16_L2_TOL = 4.6e-3
 
 @pytest.mark.parametrize("s,d,window", [(1100, 32, None), (328, 64, 96)],
                          ids=["ragged_two_k_blocks", "sliding_window"])
-def test_flash_bf16_operands_against_f32_reference(rng, tmp_ledger, s, d,
+def test_flash_bf16_operands_against_f32_reference(rng, s, d,
                                                    window):
     """bf16 q, k, v: the products take them as stored and P, dS are
     rounded to bf16 for the other four; softmax statistics and
@@ -407,41 +394,51 @@ def test_flash_f32_bits_are_the_parents(which):
         {n: want[n] for n in names}
 
 
-def test_ring_sp_composition_honors_ledger_fallback(rng, tmp_ledger):
-    """The sp plan's ring step consults the same dispatch policy: a
-    losing ledger entry for the chunk shape routes every ring chunk to
-    the XLA fallback (numerics unchanged), a winning one keeps the
-    Pallas kernel — both match the gathered-sequence oracle."""
+def _tier_counts(kernel):
+    from apex_tpu.observe import registry as obs
+    return {t: obs.counter(f"kernels.dispatch.{kernel}.{t}").value
+            for t in ("pallas", "xla")}
+
+
+def test_ring_sp_composition_honors_ledger_fallback(rng, monkeypatch):
+    """The sp plan's ring step follows the flash kernel's own rule at the
+    LOCAL chunk shape: in a compiled program, chunks below
+    ``FLASH_MIN_SK`` keys send every hop to the XLA chunk math and
+    chunks from it up keep the Pallas kernel (traced, not run: the CPU
+    cannot) — and both tiers match the gathered-sequence oracle."""
+    from apex_tpu.kernels import attention as ka
+
     n = 4
     mesh = Mesh(np.array(jax.devices()[:n]), ("sp",))
     q, k, v = _qkv(rng)
     scale = 1.0 / np.sqrt(D)
     ref = attention_reference(q, k, v, None, True, scale)
-    chunk_fp = dispatch.attention_fp(B, H, S // n, S // n, D,
-                                     "float32", True)
-    chip = ledger.chip_name()
+    assert S // n < ka.FLASH_MIN_SK
 
-    def run_ring():
+    def ring():
         fn = functools.partial(ring_attention, axis_name="sp",
                                causal=True)
-        shard = jax.shard_map(fn, mesh=mesh,
-                              in_specs=P(None, None, "sp", None),
-                              out_specs=P(None, None, "sp", None),
-                              check_vma=False)
-        return jax.jit(shard)(q, k, v)
+        return jax.shard_map(fn, mesh=mesh,
+                             in_specs=P(None, None, "sp", None),
+                             out_specs=P(None, None, "sp", None),
+                             check_vma=False)
 
-    for pallas_us, xla_us, want_tier in ((100.0, 50.0, "xla"),
-                                         (50.0, 100.0, "pallas")):
-        tmp_ledger.record_kernel(chip, "flash_attention", chunk_fp,
-                                 pallas_us=pallas_us, xla_us=xla_us)
-        dispatch.reset_decisions()
-        with force_mode("interpret"):
-            out = run_ring()
+    for min_sk, want_tier in ((ka.FLASH_MIN_SK, "xla"),
+                              (S // n, "pallas")):
+        monkeypatch.setattr(ka, "FLASH_MIN_SK", min_sk)
+        before = _tier_counts("flash_attention")
+        with force_mode("compiled"):
+            program = str(jax.make_jaxpr(ring())(q, k, v))
+        took = {t: c - before[t]
+                for t, c in _tier_counts("flash_attention").items()}
+        assert took == {want_tier: 1,
+                        "pallas" if want_tier == "xla" else "xla": 0}
+        assert ("pallas_call" in program) == (want_tier == "pallas")
+    for mode in ("off", "interpret"):
+        with force_mode(mode):
+            out = jax.jit(ring())(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
-        decided = {(d["kernel"], d["tier"], d["source"])
-                   for d in dispatch.decisions()}
-        assert ("flash_attention", want_tier, "ledger") in decided
 
 
 # ---------------------------------------------------------------------------
@@ -449,7 +446,7 @@ def test_ring_sp_composition_honors_ledger_fallback(rng, tmp_ledger):
 # ---------------------------------------------------------------------------
 
 
-def test_vocab_chain_fwd_bwd_bitwise(rng, tmp_ledger):
+def test_vocab_chain_fwd_bwd_bitwise(rng):
     n, v, e = 24, 384, 64
     hidden = jnp.asarray(rng.standard_normal((n, e)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((v, e)) * 0.02, jnp.float32)
@@ -475,7 +472,7 @@ def test_vocab_chain_fwd_bwd_bitwise(rng, tmp_ledger):
                                    rtol=1e-6, atol=1e-7)
 
 
-def test_vocab_chain_smoothing_takes_chunked_path(rng, tmp_ledger):
+def test_vocab_chain_smoothing_takes_chunked_path(rng):
     """Smoothing is outside the kernel's contract — the dispatch-gated
     entry must produce the chunked chain's exact result."""
     n, v, e = 16, 256, 32
@@ -490,82 +487,7 @@ def test_vocab_chain_smoothing_takes_chunked_path(rng, tmp_ledger):
 
 
 # ---------------------------------------------------------------------------
-# ledger round-trip + corrupt-entry recovery
-# ---------------------------------------------------------------------------
-
-
-def test_ledger_round_trip(tmp_path):
-    led = ledger.Ledger(str(tmp_path / "l.json"))
-    rec = led.record_kernel("cpu", "flash_attention", "sk=512",
-                            pallas_us=50.0, xla_us=100.0, threshold=512)
-    assert rec["win"] == pytest.approx(2.0)
-    # a second process sees the same entry from disk
-    led2 = ledger.Ledger(str(tmp_path / "l.json"))
-    hit = led2.lookup_kernel("cpu", "flash_attention", "sk=512")
-    assert hit["win"] == pytest.approx(2.0)
-    assert hit["chip"] == "cpu" and hit["shape_fp"] == "sk=512"
-    assert led2.lookup_kernel("cpu", "flash_attention", "sk=64") is None
-    assert led2.lookup_kernel("tpu v5", "flash_attention", "sk=512") is None
-    # runs accumulate on refresh
-    assert led.record_kernel("cpu", "flash_attention", "sk=512",
-                             pallas_us=55.0, xla_us=95.0)["runs"] == 2
-
-
-def test_ledger_plan_round_trip_preserves_measured(tmp_path):
-    led = ledger.Ledger(str(tmp_path / "l.json"))
-    key = (2, 1, 1, 3, 1, False)
-    led.record_plan("cpu", "params=10", key, measured_ms=1.5,
-                    predicted_ms=2.0)
-    # a later decision with no measurement must not erase the data
-    led.record_plan("cpu", "params=10", key, measured_ms=None,
-                    predicted_ms=2.1, source="decision")
-    meas = led.plan_measurements("cpu", "params=10")
-    assert meas["2/1/1/3/1/0"]["measured_ms"] == 1.5
-
-
-def test_ledger_corrupt_file_and_entries_recover(tmp_path):
-    p = tmp_path / "l.json"
-    p.write_text("{ not json")
-    led = ledger.Ledger(str(p))
-    assert led.lookup_kernel("cpu", "k", "fp") is None      # not fatal
-    led.record_kernel("cpu", "k", "fp", pallas_us=1.0, xla_us=2.0)
-    assert led.lookup_kernel("cpu", "k", "fp")["win"] == 2.0
-    # corrupt ENTRIES inside a valid document are dropped, good ones kept
-    doc = json.loads(p.read_text())
-    doc["kernels"]["cpu"]["bad"] = "not-a-dict"
-    doc["kernels"]["weird"] = 7
-    doc["plans"] = {"cpu": {"mfp": {"1/1/1/0/1/0": {"measured_ms": 3.0}}}}
-    p.write_text(json.dumps(doc))
-    led2 = ledger.Ledger(str(p))
-    assert led2.lookup_kernel("cpu", "k", "fp")["win"] == 2.0
-    assert led2.plan_measurements("cpu", "mfp")
-    # an entry without a usable win ratio cannot decide dispatch
-    led2.record_kernel("cpu", "half", "fp", pallas_us=5.0, xla_us=None)
-    assert led2.lookup_kernel("cpu", "half", "fp") is None
-
-
-def test_ledger_ingest_events(tmp_path):
-    led = ledger.Ledger(str(tmp_path / "l.json"))
-    n = led.ingest_events([
-        {"event": "bench.kernel_probe", "kernel": "flash_attention",
-         "shape_fp": "sk=512", "chip": "cpu", "pallas_us": 40.0,
-         "xla_us": 80.0, "threshold": 512},
-        {"event": "plan.auto_tune", "chip": "cpu", "model_fp": "m",
-         "plan_key": [2, 1, 1, 0, 1, 0], "measured_ms": 4.2,
-         "predicted_ms": 5.0, "plan": "dp2"},
-        {"event": "plan.auto_tune", "plan_key": [1, 1, 1, 0, 1, 0],
-         "measured_ms": 9.9},                   # no chip/model_fp: skipped
-        {"event": "unrelated", "kernel": "x"},
-        "not-a-dict",
-    ])
-    assert n == 2
-    assert led.lookup_kernel("cpu", "flash_attention", "sk=512")["win"] == 2.0
-    assert led.plan_measurements("cpu", "m")["2/1/1/0/1/0"][
-        "measured_ms"] == 4.2
-
-
-# ---------------------------------------------------------------------------
-# dispatch policy: ledger verdicts route tiers, observably
+# the rules: which tier a call takes, from the mode and the shapes alone
 # ---------------------------------------------------------------------------
 
 
@@ -574,73 +496,53 @@ def _sgd_lists(rng):
     return [gs, ps, ms]
 
 
-@pytest.mark.parametrize("pallas_us,xla_us,tier", [
-    (100.0, 50.0, "xla"),           # below the win region -> XLA
-    (50.0, 100.0, "pallas"),        # measured win -> the kernel
-])
-def test_dispatch_tier_pinned_via_kind_stats(rng, tmp_ledger, pallas_us,
-                                             xla_us, tier):
+@pytest.mark.parametrize("mode,tier", [("off", "xla"),
+                                       ("interpret", "pallas")])
+def test_dispatch_tier_pinned_via_kind_stats(rng, mode, tier):
+    """The eager entry's program kind names the tier the kernel's rule
+    chose, and the counter moves with it."""
     from apex_tpu.kernels.multi_tensor import multi_tensor_sgd
-    from apex_tpu.observe import registry as obs
 
     lists = _sgd_lists(rng)
-    fp = group_fp("sgd", lists[0])
-    chip = ledger.chip_name()
-    tmp_ledger.record_kernel(chip, "multi_tensor_sgd", fp,
-                             pallas_us=pallas_us, xla_us=xla_us)
-    dispatch.reset_decisions()
     kind = f"kernel.multi_tensor_sgd.{tier}"
     other = f"kernel.multi_tensor_sgd.{'pallas' if tier == 'xla' else 'xla'}"
     before = step_cache.kind_stats(kind)["dispatches"]
     before_other = step_cache.kind_stats(other)["dispatches"]
-    with force_mode("interpret"):
+    counts = _tier_counts("multi_tensor_sgd")
+    with force_mode(mode):
         out = multi_tensor_sgd(jnp.zeros((), jnp.int32), lists,
                                0.0, 0.9, 0.0, 0.1, False, True, False)
     assert len(out) == 3
     assert step_cache.kind_stats(kind)["dispatches"] == before + 1
     assert step_cache.kind_stats(other)["dispatches"] == before_other
-    # the deciding ledger entry is in the observe event log
-    evs = [e for e in obs.events("kernels.dispatch")
-           if e.get("kernel") == "multi_tensor_sgd"
-           and e.get("shape_fp") == fp and e.get("tier") == tier]
-    assert evs, "no kernels.dispatch event for the decision"
-    assert evs[-1]["source"] == "ledger"
-    assert evs[-1]["ledger_entry"]["pallas_us"] == pallas_us
+    assert _tier_counts("multi_tensor_sgd")[tier] == counts[tier] + 1
 
 
-def test_dispatch_defaults_no_mode_is_xla(rng, tmp_ledger):
-    """CPU default (no forced mode): every kernel routes to XLA and the
+def test_dispatch_defaults_no_mode_is_xla(rng):
+    """CPU default (no forced mode): every rule answers XLA and the
     per-bucket paths run unchanged — the tier-1 invariance guarantee."""
-    d = dispatch.decide("multi_tensor_sgd", "op=sgd,n=1,t=1,dtype=float32")
-    assert d.tier == "xla" and d.source == "mode"
+    from apex_tpu.kernels import attention as ka, vocab_chain
+    from apex_tpu.kernels import multi_tensor as kmt
+
+    assert dispatch.pallas_mode() is None
+    assert ka.kernel_mode(2, 4, 1024, 1024) is None
+    assert kmt.kernel_mode("sgd") is None
+    assert vocab_chain.kernel_mode() is None
+    assert not ops_mt._use_fused("sgd", _sgd_lists(rng))
 
 
-def test_dispatch_probe_decides_compiled_unmeasured(tmp_ledger):
-    """Compiled mode with an empty ledger: the registered threshold
-    probe decides (flash: sk below the 512-key prior -> XLA, above ->
-    Pallas)."""
-    with force_mode("compiled"):
-        lo = dispatch.decide(
-            "flash_attention",
-            dispatch.attention_fp(2, 4, 64, 64, 16, "float32", True))
-        hi = dispatch.decide(
-            "flash_attention",
-            dispatch.attention_fp(2, 4, 1024, 1024, 16, "float32", True))
-    assert (lo.tier, lo.source) == ("xla", "probe")
-    assert lo.threshold == 512
-    assert (hi.tier, hi.source) == ("pallas", "probe")
-
-
-def test_flash_min_sk_reads_measured_threshold(tmp_ledger, monkeypatch):
+def test_dispatch_probe_decides_compiled_unmeasured(monkeypatch):
+    """Compiled mode: the flash rule is the module's constant and
+    nothing else — 512 keys by default, and it moves with the constant
+    (what ``bench._pin_flash_dispatch`` relies on)."""
     from apex_tpu.kernels import attention as ka
-    assert ka.flash_min_sk() == 512                  # frozen prior
-    tmp_ledger.record_kernel(
-        ledger.chip_name(), "flash_attention",
-        dispatch.attention_fp(8, 8, 256, 256, 64, "bfloat16", True),
-        pallas_us=40.0, xla_us=60.0)
-    assert ka.flash_min_sk() == 256                  # measured win at 256
-    monkeypatch.setenv("APEX_TPU_FLASH_MIN_SK", "128")
-    assert ka.flash_min_sk() == 128                  # env beats both
+
+    assert ka.FLASH_MIN_SK == 512
+    with force_mode("compiled"):
+        assert ka.kernel_mode(2, 4, 64, 64) is None
+        assert ka.kernel_mode(2, 4, 1024, 1024) == "compiled"
+        monkeypatch.setattr(ka, "FLASH_MIN_SK", 0)
+        assert ka.kernel_mode(2, 4, 64, 64) == "compiled"
 
 
 def test_kernel_catalog_declares_fallbacks():
@@ -649,97 +551,112 @@ def test_kernel_catalog_declares_fallbacks():
                  "multi_tensor_adam", "vocab_chain_loss"):
         assert name in cat, f"{name} not registered"
         assert cat[name].xla_fallback
-        assert callable(cat[name].threshold_probe)
+        assert callable(cat[name].audit_programs)
     with pytest.raises(ValueError):
-        dispatch.register_kernel("bad", xla_fallback="",
-                                 threshold_probe=lambda d: (None, False))
+        dispatch.register_kernel("bad", xla_fallback="")
 
 
-# ---------------------------------------------------------------------------
-# planner: warm ledger re-prices terms and re-ranks plans
-# ---------------------------------------------------------------------------
+def _flash_rule(b, h, sq, sk):
+    from apex_tpu.kernels import attention as ka
+    return lambda: ka.kernel_mode(b, h, sq, sk)
 
 
-def _planner_setup(rng):
-    import dataclasses as dc
+def _paged_rule(heads, head_dim, block_size, dtype):
+    from apex_tpu.kernels import paged_attention as pa
+    from apex_tpu.serve.pool import init_pool_buffer
 
-    import apex_tpu.nn as nn
-    from apex_tpu.nn import functional as F
-    from apex_tpu.optimizers import FusedAdam
-    from apex_tpu.parallel import auto
-
-    nn.manual_seed(0)
-    model = nn.Sequential(nn.Linear(64, 128), nn.ReLU(), nn.Linear(128, 8))
-    opt = FusedAdam(list(model.parameters()), lr=1e-2)
-    loss = lambda o, t: F.cross_entropy(o, t)
-    x = jnp.asarray(rng.standard_normal((64, 64)), jnp.float32)
-    y = jnp.asarray(rng.integers(0, 8, (64,)))
-    prof = auto.profile_model(model, opt, loss, (x, y))
-    # stamp transformer geometry so the attention term prices too
-    prof = dc.replace(prof, layers=2, heads=4, hidden=64, seq_len=128)
-    return auto, model, opt, loss, (x, y), prof
+    def rule():
+        pool = init_pool_buffer(1, heads, head_dim, 4, block_size, dtype)
+        return pa.kernel_mode(jnp.zeros((2, heads, head_dim), jnp.bfloat16),
+                              pool)
+    return rule
 
 
-def test_planner_warm_ledger_cites_measured_terms(rng, tmp_ledger):
-    auto, model, opt, loss, batch, prof = _planner_setup(rng)
-    chip = ledger.chip_name()
-    tmp_ledger.record_kernel(
-        chip, "multi_tensor_adam",
-        dispatch.multi_tensor_fp("adam", prof.n_params,
-                                 len(prof.param_shapes)),
-        pallas_us=40.0, xla_us=60.0)
-    tmp_ledger.record_kernel(
-        chip, "flash_attention",
-        dispatch.attention_fp(64, 4, 128, 128, 16, "float32", True),
-        pallas_us=85.0, xla_us=136.0)
-    rep = auto.plan_training(model, opt, loss, batch, profile=prof)
-    text = rep.describe()
-    assert "ledger-measured" in text
-    assert "flash_attention" in text and "multi_tensor_adam" in text
-    assert rep.best.ledger_terms
-    # the citation covers both required terms
-    joined = " ".join(rep.best.ledger_terms)
-    assert joined.startswith("attention")
-    assert "optimizer" in joined
+def _latent_rule(heads, rank, rope, block_size):
+    from apex_tpu.kernels import latent_attention as la
+
+    def rule():
+        w = rank + rope
+        pool = jax.ShapeDtypeStruct((1, 1, 4, block_size, w), jnp.bfloat16)
+        q = jax.ShapeDtypeStruct((2, heads, w), jnp.bfloat16)
+        return la.kernel_mode(q, pool, rank)
+    return rule
 
 
-def test_planner_cold_ledger_unchanged(rng, tmp_ledger):
-    auto, model, opt, loss, batch, prof = _planner_setup(rng)
-    rep = auto.plan_training(model, opt, loss, batch, profile=prof)
-    assert all(not p.ledger_terms for p in rep.ranked)
-    assert all(p.measured_ms is None for p in rep.ranked)
+def _experts_rule(kdim, n, tile):
+    from apex_tpu.kernels import grouped_matmul as gmm
+
+    def rule():
+        lhs = jax.ShapeDtypeStruct((2 * gmm.TILE_ROWS, kdim), jnp.bfloat16)
+        rhs = jax.ShapeDtypeStruct((2, kdim, n), jnp.bfloat16)
+        return gmm.kernel_mode(lhs, rhs, tile or gmm.TILE_ROWS)
+    return rule
 
 
-def test_planner_reranks_from_recorded_plan_measurement(rng, tmp_ledger):
-    auto, model, opt, loss, batch, prof = _planner_setup(rng)
-    rep = auto.plan_training(model, opt, loss, batch, profile=prof)
-    assert len(rep.ranked) > 1
-    other = rep.ranked[1]
-    tmp_ledger.record_plan(
-        ledger.chip_name(), auto.model_fp(prof, 64), other.key(),
-        measured_ms=1e-3, predicted_ms=other.predicted_ms,
-        plan=other.name())
-    rep2 = auto.plan_training(model, opt, loss, batch, profile=prof)
-    assert rep2.best.key() == other.key()
-    assert rep2.best.measured_ms == 1e-3
-    assert "measured" in rep2.best.describe()
+def _adam_rule():
+    from apex_tpu.kernels import multi_tensor as kmt
+    return kmt.kernel_mode("adam")
 
 
-def test_plan_decision_event_carries_ledger_keys(rng, tmp_ledger):
-    from apex_tpu.observe import registry as obs
-    from apex_tpu.training import make_train_step
+def _vocab_rule():
+    from apex_tpu.kernels import vocab_chain
+    return vocab_chain.kernel_mode()
 
-    auto, model, opt, loss, batch, prof = _planner_setup(rng)
-    step = make_train_step(model, opt, loss, parallel="auto",
-                           example_batch=batch,
-                           plan_options={"profile": prof})
-    evs = [e for e in obs.events("plan.decision") if e.get("model_fp")]
-    assert evs, "plan.decision missing ledger keys"
-    ev = evs[-1]
-    assert ev["chip"] == ledger.chip_name()
-    assert ev["model_fp"] == auto.model_fp(prof, 64)
-    # the decision write-through is in the ledger (predicted only)
-    assert tmp_ledger.plan_measurements(ev["chip"], ev["model_fp"]) == {}
-    doc = json.loads(open(tmp_ledger.path).read())
-    assert ev["model_fp"] in doc["plans"][ev["chip"]]
-    assert step.plan_report is not None
+
+#: 8 x 16 heads of 256 x 256 fp32 scores = 128 MiB exactly: the cap is
+#: "greater than", so one more head row passes it
+_AT_CAP = (8, 64, 256, 256)
+_OVER_CAP = (8, 65, 256, 256)
+
+RULE_CASES = [
+    # flash: the 512-key boundary and the score-byte cap, per mode
+    ("flash_attention", _flash_rule(2, 4, 256, 256), "compiled", "xla"),
+    ("flash_attention", _flash_rule(2, 4, 512, 512), "compiled", "pallas"),
+    ("flash_attention", _flash_rule(2, 4, 1024, 1024), "compiled",
+     "pallas"),
+    ("flash_attention", _flash_rule(2, 4, 128, 1024), "compiled", "pallas"),
+    ("flash_attention", _flash_rule(*_AT_CAP), "compiled", "xla"),
+    ("flash_attention", _flash_rule(*_OVER_CAP), "compiled", "pallas"),
+    ("flash_attention", _flash_rule(2, 4, 256, 256), "interpret",
+     "pallas"),
+    ("flash_attention", _flash_rule(2, 4, 1024, 1024), "interpret",
+     "pallas"),
+    ("flash_attention", _flash_rule(2, 4, 1024, 1024), "off", "xla"),
+    ("flash_attention", _flash_rule(*_OVER_CAP), "off", "xla"),
+    # the three that are taken wherever their tiles fit
+    ("paged_attention", _paged_rule(2, 64, 16, jnp.bfloat16), "compiled",
+     "pallas"),
+    ("paged_attention", _paged_rule(2, 64, 16, "int8"), "compiled", "xla"),
+    ("paged_attention", _paged_rule(4, 8, 16, jnp.bfloat16), "compiled",
+     "xla"),
+    ("paged_attention", _paged_rule(2, 64, 16, jnp.bfloat16), "off", "xla"),
+    ("latent_attention", _latent_rule(8, 128, 128, 16), "compiled",
+     "pallas"),
+    ("latent_attention", _latent_rule(8, 64, 64, 16), "compiled", "xla"),
+    ("latent_attention", _latent_rule(4, 128, 128, 16), "compiled", "xla"),
+    ("routed_experts", _experts_rule(128, 256, None), "compiled", "pallas"),
+    ("routed_experts", _experts_rule(128, 256, 1), "compiled", "xla"),
+    ("routed_experts", _experts_rule(96, 256, None), "compiled", "xla"),
+    # the two a compiled program leaves to XLA
+    ("multi_tensor_adam", _adam_rule, "compiled", "xla"),
+    ("multi_tensor_adam", _adam_rule, "interpret", "pallas"),
+    ("vocab_chain_loss", _vocab_rule, "compiled", "xla"),
+    ("vocab_chain_loss", _vocab_rule, "interpret", "pallas"),
+]
+
+
+@pytest.mark.parametrize(
+    "kernel,rule,mode,tier", RULE_CASES,
+    ids=[f"{k}-{i}-{m}-{t}" for i, (k, _, m, t) in enumerate(RULE_CASES)])
+def test_each_kernels_rule(kernel, rule, mode, tier):
+    """Every kernel's rule answers from the mode and the shapes alone:
+    the mode the kernel runs in, or ``None`` for the XLA tier — and the
+    tier's counter moves by one where the rule is applied."""
+    before = _tier_counts(kernel)
+    with force_mode(mode):
+        got = rule()
+    assert got == (None if tier == "xla" else mode)
+    after = _tier_counts(kernel)
+    other = "pallas" if tier == "xla" else "xla"
+    assert after[tier] == before[tier] + 1
+    assert after[other] == before[other]

@@ -64,7 +64,7 @@ def _loop(*, distill=False, capacity=16, max_staleness=2,
         draft = make_self_draft(draft_master)
     eng = ServeEngine(serve_m, num_blocks=num_blocks, block_size=8,
                       max_batch=4, prefill_chunk=4, draft=draft,
-                      spec_k=4, spec_policy="on")
+                      spec_k=4)
     step = _train_step(train_m)
     dist = OnlineDistiller(eng, draft_master, lr=1e-3) if distill \
         else None
@@ -315,7 +315,7 @@ def test_accept_rate_strictly_improves_over_distill_publishes():
     draft_master = _gpt(99)            # random-init draft: near-0 accept
     eng = ServeEngine(serve_m, num_blocks=64, block_size=8, max_batch=4,
                       prefill_chunk=4, draft=make_self_draft(draft_master),
-                      spec_k=4, spec_policy="on")
+                      spec_k=4)
     dist = OnlineDistiller(eng, draft_master, lr=1e-3)
     rng = np.random.default_rng(0)
     trace = [[int(t) for t in rng.integers(0, V, size=6)]

@@ -502,6 +502,27 @@ def test_close_returns_all_live_blocks(model):
     assert eng2.block_pool.in_use == 0
 
 
+def test_an_engine_with_a_draft_speculates(model):
+    """Whether to speculate is not a policy: an engine given a draft
+    takes the verify tick on every decode tick (the counter of accepted
+    drafts moves, no plain decode program is built), and the keyword
+    that used to ask a ledger is refused."""
+    from apex_tpu.inference import make_self_draft
+    draft = make_self_draft(model)
+    with pytest.raises(TypeError, match="spec_policy"):
+        ServeEngine(model, num_blocks=48, block_size=8, max_batch=4,
+                    prefill_chunk=4, draft=draft, spec_policy="on")
+    eng = ServeEngine(model, num_blocks=48, block_size=8, max_batch=4,
+                      prefill_chunk=4, draft=draft, spec_k=2)
+    plain_before = eng.metrics()["decode"]["dispatches"]
+    out = eng.run([Request("s0", [5, 9, 11, 3], 9), Request("s1", [7], 6)])
+    assert [len(out["s0"]), len(out["s1"])] == [9, 6]
+    m = eng.metrics()
+    assert m["spec"]["ticks"] >= 1
+    assert m["decode"]["dispatches"] == plain_before
+    eng.block_pool.check_no_leaks()
+
+
 # ---------------------------------------------------------------------------
 # the span tree of a tick
 # ---------------------------------------------------------------------------

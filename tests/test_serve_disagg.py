@@ -120,7 +120,7 @@ def test_unified_spec_parity_and_recompile_free(model):
     sc.clear()
     eng = ServeEngine(model, num_blocks=128, block_size=8, max_batch=4,
                       prefill_chunk=4, draft=make_self_draft(model),
-                      spec_k=3, spec_policy="on")
+                      spec_k=3)
     out = eng.run(_reqs())
     assert out == base                    # spec is exact for ANY draft
     eng.block_pool.check_no_leaks()
@@ -159,7 +159,7 @@ def test_spec_telemetry_names(model):
     hist0 = reg.histogram("serve.spec.accepted_tokens").count
     eng = ServeEngine(model, num_blocks=128, block_size=8, max_batch=4,
                       prefill_chunk=4, draft=make_self_draft(model),
-                      spec_k=2, spec_policy="on")
+                      spec_k=2)
     eng.run(_reqs())
     assert reg.histogram("serve.spec.accepted_tokens").count > hist0
     rate = reg.gauge("serve.spec.accept_rate").value
@@ -176,8 +176,7 @@ def test_divergent_draft_still_exact(model):
                      max_positions=96, dropout=0.0, attn_dropout=0.0)
     draft.eval()
     eng = ServeEngine(model, num_blocks=128, block_size=8, max_batch=4,
-                      prefill_chunk=4, draft=draft, spec_k=2,
-                      spec_policy="on")
+                      prefill_chunk=4, draft=draft, spec_k=2)
     out = eng.run(_reqs())
     assert out == base
     eng.block_pool.check_no_leaks()

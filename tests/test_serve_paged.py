@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from apex_tpu import nn
 from apex_tpu.inference.quant import kv_value, kv_write, make_kv_cache
 from apex_tpu.kernels import paged_attention as pa
-from apex_tpu.kernels.dispatch import force_mode, reset_decisions
+from apex_tpu.kernels.dispatch import force_mode
 from apex_tpu.models.gpt import GptModel
 from apex_tpu.observe import registry as obs
 from apex_tpu.serve import Request, ServeEngine
@@ -263,7 +263,7 @@ def test_reader_tiers_and_what_the_kernel_declines():
 
 def test_engine_serves_the_same_tokens_through_the_kernel():
     """A model whose rows are whole lane rows (2 heads of 64): the
-    engine in interpret mode takes the Pallas tier (``decide()``'s
+    engine in interpret mode takes the Pallas tier (the rule's
     counter says so) and emits the XLA tier's tokens."""
     nn.manual_seed(11)
     m = GptModel(vocab_size=61, hidden=128, layers=2, heads=2,
@@ -280,11 +280,9 @@ def test_engine_serves_the_same_tokens_through_the_kernel():
         return out
 
     base = serve()
-    reset_decisions()
     counter = obs.counter("kernels.dispatch.paged_attention.pallas")
     before = counter.value
     with force_mode("interpret"):
         through_kernel = serve()
-    reset_decisions()
     assert counter.value > before
     assert through_kernel == base
