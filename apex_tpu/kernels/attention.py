@@ -9,15 +9,32 @@ the per-row logsumexp for the backward (SURVEY.md §2.2 maps
 fast_multihead_attn → "Pallas fused attention, flash-style").
 
 Layout: q (B, H, Sq, D), k/v (B, H, Sk, D), flattened to (B·H, S, D) for the
-kernels.  Grid (batch·head, q-blocks, k-blocks) with the k dimension
-innermost: TPU grids execute sequentially, so the running max / denominator /
-accumulator live in VMEM scratch across the k sweep (the canonical TPU flash
-pattern).  The backward recomputes attention blockwise from the saved
-logsumexp: one kernel accumulates dq over the k sweep, a second accumulates
-dk/dv over the q sweep.
+kernels.  Two paths behind one pair of entries (``flash_attention_fwd`` /
+``flash_attention_bwd``), chosen from what a call's own operands say:
+
+* **tiled** — grid (batch·head, q-blocks, k-blocks) with the k dimension
+  innermost: TPU grids execute sequentially, so the running max /
+  denominator / accumulator live in VMEM scratch across the k sweep (the
+  canonical TPU flash pattern).  The backward recomputes attention
+  blockwise from the saved logsumexp: one kernel accumulates dq over the k
+  sweep, a second accumulates dk/dv over the q sweep.  Any length, any
+  dtype, bias, dropout.
+* **resident** (``_resident``: bf16 operands, no bias, no dropout, a whole
+  head within the VMEM estimate) — grid (batch·head,): q, k, v of a head
+  lie in VMEM whole and the key walk is a static loop inside the kernel.
+  A row block of 256 meets the keys from its window's block to its
+  diagonal block at once (one max, one exp, one sum: no running
+  statistics), only the blocks the mask crosses are masked, and one
+  backward kernel computes S, P, dP and dS once for all three gradients:
+  seven products a block pair over the causal half where the tiled
+  kernels take nine over the whole square at their widest tiles.  On the
+  v5e a grid step and a block each cost about the same whatever they
+  compute (PERF.md, PRs 30 and 32), which is what the tiled path cannot
+  get around and this one does not meet.
 
 Precision: the nine MXU products of a block pair (forward S, P·V; dq S, dP,
-dS·K; dkv S, Pᵀ·dO, dP, dSᵀ·Q) take their operands in the dtype the tensors
+dS·K; dkv S, Pᵀ·dO, dP, dSᵀ·Q; the resident path's seven are the same less
+the recomputed S and dP) take their operands in the dtype the tensors
 came in (``_operand_dtype``) and accumulate in fp32; scores, softmax
 statistics (m, l, lse, delta) and every accumulator are fp32.  bf16 q/k/v/dO
 therefore reach the MXU as stored — a bf16 x bf16 product is exact in the
@@ -43,7 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import choose, register_kernel
+from .dispatch import choose, register_kernel, tally
 
 _f32 = jnp.float32
 _NEG = -1e30  # finite "-inf": keeps exp(s - m) well-defined in masked blocks
@@ -53,7 +70,9 @@ def _ceil_div(a, b):
     return (a + b - 1) // b
 
 
-_VMEM_BUDGET = 10 * 1024 * 1024  # conservative slice of the ~16 MiB/core VMEM
+_VMEM_SCOPED = 16 * 1024 * 1024  # what the v5e compiler gives one kernel
+# the tiled kernels' slice of it: their estimate counts one buffer a block
+_VMEM_BUDGET = 10 * 1024 * 1024
 
 
 def _operand_dtype(*tensors):
@@ -111,8 +130,12 @@ def vmem_fit(sq, sk, d, itemsize=4):
             "budget_bytes": _VMEM_BUDGET, "fits": est <= _VMEM_BUDGET}
 
 
+def _round_up(x, m):
+    return _ceil_div(x, m) * m
+
+
 def _round8(x):
-    return max(8, (x + 7) // 8 * 8)
+    return max(8, _round_up(x, 8))
 
 
 def _hash_keep_u32(rows, cols, bh, seed):
@@ -384,6 +407,249 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+# ---------------------------------------------------------------------------
+# The resident path: a sequence that fits VMEM whole
+# ---------------------------------------------------------------------------
+
+_ROW_BLOCK = 256      # rows of q a resident kernel takes at a time
+_MAX_ROW_BLOCKS = 8   # they are unrolled: Mosaic's lowering grows with them
+_BWD_KEYS = 512       # keys of one product chain in the resident backward
+
+
+def _resident_plan(sq, sk, causal, window, keys):
+    """The static walk of the resident kernels: ``(bq, sq_p, sk_p, rows)``
+    with ``rows`` one ``(r0, r1, segments)`` a row block and a segment
+    ``(c0, c1, edge)`` a run of keys multiplied in one product.  A row
+    block's segments stop at its diagonal block and start at its
+    window's; ``edge`` marks those the mask crosses (the diagonal, the
+    band's lower edge, padded keys), the only ones that are masked.  Runs
+    that need no mask are merged up to ``keys`` wide (``None``: the whole
+    run).  ``None`` where a row block would see no key at all."""
+    bq = min(_ROW_BLOCK, _round_up(sq, 128))
+    sq_p, sk_p = _round_up(sq, bq), _round_up(sk, 128)
+    bk = min(_ROW_BLOCK, sk_p)
+    if not causal:
+        window = None
+    rows = []
+    for r0 in range(0, sq_p, bq):
+        r1 = r0 + bq
+        segs = []
+        for c0 in range(0, sk_p, bk):
+            c1 = min(c0 + bk, sk_p)
+            if causal and c0 > r1 - 1:
+                continue                      # above the diagonal
+            if window is not None and c1 - 1 <= r0 - window:
+                continue                      # below the band
+            edge = (c1 > sk or (causal and c1 - 1 > r0)
+                    or (window is not None and c0 <= r1 - 1 - window))
+            last = segs[-1] if segs else None
+            if (last and not edge and not last[2] and last[1] == c0
+                    and (keys is None or c1 - last[0] <= keys)):
+                segs[-1] = (last[0], c1, False)
+            else:
+                segs.append((c0, c1, edge))
+        if not segs:
+            return None
+        rows.append((r0, r1, tuple(segs)))
+    return bq, sq_p, sk_p, tuple(rows)
+
+
+def _resident_vmem_estimate(sq_p, sk_p, d, bq, widest):
+    """Bytes the resident kernels hold in VMEM, the larger of the two.
+    Backward: eight bf16 (S, D) blocks (q, k, v, out, dO, dq, dk, dv),
+    each in two buffers and padded to 128 lanes, the fp32 dk and dv
+    accumulators, and the four fp32 and two bf16 (keys, rows)
+    intermediates of a product chain ``_BWD_KEYS`` wide.  Forward: four
+    such blocks and the fp32 scores, fp32 and bf16 probabilities of the
+    ``widest`` extent a row block meets at once.  ``lse`` is a (1, S)
+    fp32 block on eight sublanes."""
+    lanes = _round_up(d, 128)
+    rows_b, keys_b = 2 * 2 * sq_p * lanes, 2 * 2 * sk_p * lanes
+    lse = 2 * 8 * sq_p * 4
+    fwd = 2 * rows_b + 2 * keys_b + lse + bq * widest * (4 + 4 + 2)
+    bwd = (4 * rows_b + 4 * keys_b + lse + 2 * sk_p * lanes * 4
+           + bq * min(widest, _BWD_KEYS) * (4 * 4 + 2 * 2))
+    return max(fwd, bwd)
+
+
+def _resident(tensors, sq, sk, d, bias, causal, window, dropout_p,
+              keys=None):
+    """The rule of the resident path: the walk (``_resident_plan``'s
+    answer, its product chains ``keys`` wide; the rule does not depend on
+    ``keys``) where a call takes it, else ``None``.  Taken by bf16 operands with no bias and no dropout
+    whose whole sequence is at most ``_MAX_ROW_BLOCKS`` row blocks and
+    fits VMEM: ``_resident_vmem_estimate``, which counts both buffers of
+    every block, within seven eighths of ``_VMEM_SCOPED`` (the rest is
+    for what Mosaic allocates besides; the AOT tests hold both sides of
+    the boundary against the compiler).  fp32 operands stay tiled (their
+    last bits follow the block order), bias and dropout too (they bring
+    (bq, bk) operands of their own)."""
+    if (bias is not None or dropout_p > 0.0
+            or _operand_dtype(*tensors) != jnp.bfloat16):
+        return None
+    plan = _resident_plan(sq, sk, causal, window, keys)
+    if plan is None:
+        return None
+    bq, sq_p, sk_p, rows = plan
+    # a row block's extent: the same however its runs are merged
+    widest = max(sum(c1 - c0 for c0, c1, _ in segs) for _, _, segs in rows)
+    if (len(rows) > _MAX_ROW_BLOCKS or _resident_vmem_estimate(
+            sq_p, sk_p, d, bq, widest) > _VMEM_SCOPED * 7 // 8):
+        return None
+    return plan
+
+
+def _keep(shape, r0, c0, sk, causal, window, rows_dim=0):
+    """The mask of an edge segment whose first score is row ``r0``, key
+    ``c0``; the rows of q lie along ``rows_dim`` of the scores."""
+    rows = r0 + jax.lax.broadcasted_iota(jnp.int32, shape, rows_dim)
+    cols = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_dim)
+    keep = cols < sk
+    if causal:
+        keep = jnp.logical_and(keep, rows >= cols)
+        if window is not None:
+            keep = jnp.logical_and(keep, cols > rows - window)
+    return keep
+
+
+def _nt(a, b):
+    """a . b^T on the MXU, fp32 out."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=_f32)
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                         causal, window, sk, rows):
+    """One head a grid step.  A row block meets its whole key extent at
+    once: scores, one max, one exp, one sum, P.V; no running statistics.
+    ``lse`` leaves as a lane-dense row."""
+    mxu = q_ref.dtype
+    for r0, r1, segs in rows:
+        q = q_ref[0, r0:r1, :]
+        scores = []
+        for c0, c1, edge in segs:
+            s = _nt(q, k_ref[0, c0:c1, :]) * scale
+            if edge:
+                s = jnp.where(_keep(s.shape, r0, c0, sk, causal, window),
+                              s, _NEG)
+            scores.append(s)
+        m = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=1, keepdims=True) for s in scores])
+        l, acc = 0.0, 0.0
+        for s, (c0, c1, _) in zip(scores, segs):
+            p = jnp.exp(s - m)
+            l = l + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc + jax.lax.dot(p.astype(mxu), v_ref[0, c0:c1, :],
+                                    preferred_element_type=_f32)
+        o_ref[0, r0:r1, :] = (acc / l).astype(o_ref.dtype)
+        # (bq, 1) -> (1, bq): the column, spread over the lanes, transposed
+        lse = jnp.broadcast_to(m + jnp.log(l), (r1 - r0, 128))
+        lse_ref[0, :, r0:r1] = jnp.transpose(lse)[0:1, :]
+
+
+def _resident_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                         dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
+                         causal, window, sk, rows):
+    """One head a grid step; S, P, dP and dS once for all three
+    gradients, seven products a block pair.  The scores are taken
+    transposed, (keys, rows): ``lse`` and ``delta`` are then lane-dense
+    rows, P^T.dO and dS^T.Q are plain products, and dS.K is the one that
+    contracts over its operands' rows.  dq of a row block is written
+    whole; dk and dv gather in fp32 scratch over the row blocks that see
+    their keys."""
+    mxu = q_ref.dtype
+    dk_scr[...] = jnp.zeros_like(dk_scr)
+    dv_scr[...] = jnp.zeros_like(dv_scr)
+    for r0, r1, segs in rows:
+        q = q_ref[0, r0:r1, :]
+        do = do_ref[0, r0:r1, :]
+        lse = lse_ref[0, :, r0:r1]
+        delta = jnp.sum(jnp.transpose(
+            do.astype(_f32) * o_ref[0, r0:r1, :].astype(_f32)),
+            axis=0, keepdims=True)
+        dq = 0.0
+        for c0, c1, edge in segs:
+            k = k_ref[0, c0:c1, :]
+            s = _nt(k, q) * scale
+            if edge:
+                s = jnp.where(_keep(s.shape, r0, c0, sk, causal, window,
+                                    rows_dim=1), s, _NEG)
+            p = jnp.exp(s - lse)
+            ds = (p * (_nt(v_ref[0, c0:c1, :], do) - delta)).astype(mxu)
+            dv_scr[c0:c1, :] += jax.lax.dot(p.astype(mxu), do,
+                                            preferred_element_type=_f32)
+            dk_scr[c0:c1, :] += jax.lax.dot(ds, q,
+                                            preferred_element_type=_f32)
+            dq = dq + jax.lax.dot_general(ds, k, (((0,), (0,)), ((), ())),
+                                          preferred_element_type=_f32)
+        dq_ref[0, r0:r1, :] = (dq * scale).astype(dq_ref.dtype)
+    dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _pad_rows(x, n, value=0):
+    """``x`` with its axis 1 padded to ``n`` (by nothing where it is)."""
+    return jnp.pad(x, ((0, 0), (0, n - x.shape[1])) + ((0, 0),) * (x.ndim - 2),
+                   constant_values=value)
+
+
+def _head_spec(s, d):
+    return pl.BlockSpec((1, s, d), lambda b: (b, 0, 0))
+
+
+# jitted: the layers of a model make the same call, and a jitted function
+# is traced and lowered (the kernel's unrolled body with it) once for all
+# of them, where a bare ``pallas_call`` is once a call
+_STATIC = ("scale", "causal", "window", "plan", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _resident_fwd(q3, k3, v3, scale, causal, window, plan, interpret):
+    bh, sq, d = q3.shape
+    sk = k3.shape[1]
+    _, sq_p, sk_p, rows = plan
+    out, lse = pl.pallas_call(
+        functools.partial(_resident_fwd_kernel, scale=scale, causal=causal,
+                          window=window, sk=sk, rows=rows),
+        grid=(bh,),
+        in_specs=[_head_spec(sq_p, d), _head_spec(sk_p, d),
+                  _head_spec(sk_p, d)],
+        out_specs=[_head_spec(sq_p, d), _head_spec(1, sq_p)],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq_p, d), q3.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, sq_p), _f32)],
+        interpret=interpret,
+        name="flash_attn_fwd",
+    )(_pad_rows(q3, sq_p), _pad_rows(k3, sk_p), _pad_rows(v3, sk_p))
+    return out[:, :sq], lse[:, 0, :sq]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _resident_bwd(q3, k3, v3, out, lse, g, scale, causal, window, plan,
+                  interpret):
+    bh, sq, d = q3.shape
+    sk = k3.shape[1]
+    _, sq_p, sk_p, rows = plan
+    rows_spec, keys_spec = _head_spec(sq_p, d), _head_spec(sk_p, d)
+    # padded q rows: lse = +big keeps p = exp(s - lse) at 0 there
+    lse = _pad_rows(lse, sq_p, -_NEG)[:, None, :]
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
+                          window=window, sk=sk, rows=rows),
+        grid=(bh,),
+        in_specs=[rows_spec, keys_spec, keys_spec, rows_spec, rows_spec,
+                  _head_spec(1, sq_p)],
+        out_specs=[rows_spec, keys_spec, keys_spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq_p, d), q3.dtype),
+                   jax.ShapeDtypeStruct((bh, sk_p, d), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, sk_p, d), v3.dtype)],
+        scratch_shapes=[pltpu.VMEM((sk_p, d), _f32)] * 2,
+        interpret=interpret,
+        name="flash_attn_bwd",
+    )(_pad_rows(q3, sq_p), _pad_rows(k3, sk_p), _pad_rows(v3, sk_p),
+      _pad_rows(out, sq_p), _pad_rows(g, sq_p), lse)
+    return dq[:, :sq], dk[:, :sk], dv[:, :sk]
+
+
 def _bias_spec(bias, bq, bk, for_dkv=False):
     b_, sq_, _ = bias.shape
     if for_dkv:
@@ -408,6 +674,12 @@ def flash_attention_fwd(q3, k3, v3, bias, scale, causal, interpret=False,
         raise ValueError("dropout_p > 0 requires dropout_seed")
     bh, sq, d = q3.shape
     sk = k3.shape[1]
+    plan = _resident((q3, k3, v3), sq, sk, d, bias, causal, window,
+                     dropout_p)
+    if plan is not None:
+        tally("flash_attention", "resident")
+        return _resident_fwd(q3, k3, v3, scale, causal, window, plan,
+                             interpret)
     bq, bk = _block_sizes(sq, sk, d, _operand_dtype(q3, k3, v3).itemsize)
     sq_p, sk_p = _ceil_div(sq, bq) * bq, _ceil_div(sk, bk) * bk
     q3 = jnp.pad(q3, ((0, 0), (0, sq_p - sq), (0, 0)))
@@ -471,6 +743,11 @@ def flash_attention_bwd(q3, k3, v3, bias, out, lse, g, scale, causal,
     """→ (dq, dk, dv) with the shapes/dtypes of q3/k3/v3."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
+    plan = _resident((q3, k3, v3, g), sq, sk, d, bias, causal, window,
+                     dropout_p, _BWD_KEYS)
+    if plan is not None:
+        return _resident_bwd(q3, k3, v3, out, lse, g, scale, causal, window,
+                             plan, interpret)
     bq, bk = _block_sizes(sq, sk, d,
                           _operand_dtype(q3, k3, v3, g).itemsize)
     sq_p, sk_p = _ceil_div(sq, bq) * bq, _ceil_div(sk, bk) * bk

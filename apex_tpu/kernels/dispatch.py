@@ -152,15 +152,22 @@ def choose(kernel: str, *, fits: bool = True, compiled: bool = True):
     choice is counted where it is made, at trace time, as
     ``kernels.dispatch.<kernel>.pallas`` or ``.xla`` (here, so that no
     kernel module imports ``observe``)."""
-    from ..observe import registry as _obs
     mode = pallas_mode()
     if not fits or (mode == "compiled" and not compiled):
         mode = None
-    tier = "xla" if mode is None else "pallas"
-    # tpu-lint: disable=OBS-IN-JIT deliberate trace-time telemetry: the
-    # counter says which tier each traced program took, once a trace
-    _obs.counter(f"kernels.dispatch.{kernel}.{tier}").inc()
+    tally(kernel, "xla" if mode is None else "pallas")
     return mode
+
+
+def tally(kernel: str, path: str):
+    """Count one traced call of ``kernel`` under
+    ``kernels.dispatch.<kernel>.<path>``: the tier where :func:`choose`
+    answers, and what else a kernel's module chooses between from its
+    operands (the flash kernels' ``resident`` path)."""
+    from ..observe import registry as _obs
+    # tpu-lint: disable=OBS-IN-JIT deliberate trace-time telemetry: the
+    # counter says which path each traced program took, once a trace
+    _obs.counter(f"kernels.dispatch.{kernel}.{path}").inc()
 
 
 # ---------------------------------------------------------------------------

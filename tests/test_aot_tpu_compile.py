@@ -83,8 +83,8 @@ def _flash(window=None):
 
 @pytest.mark.parametrize("which,batch,seq,window,calls", [
     ("fwd", 8, 1024, None, 1),
-    ("bwd", 8, 1024, None, 3),          # forward + dq + dkv
-    ("bwd", 4, 2048, 256, 3),
+    ("bwd", 8, 1024, None, 2),          # forward + the one backward
+    ("bwd", 4, 2048, 256, 2),
 ], ids=["flash_fwd", "flash_bwd", "flash_windowed"])
 def test_flash_attention_compiles(chip, which, batch, seq, window, calls):
     fwd, bwd = _flash(window)
@@ -93,6 +93,40 @@ def test_flash_attention_compiles(chip, which, batch, seq, window, calls):
         compiled = jax.jit(fwd if which == "fwd" else bwd).lower(
             q, q, q).compile()
     _check(compiled, calls)
+
+
+RESIDENT = ["flash_attn_bwd", "flash_attn_fwd"]
+TILED = ["flash_attn_bwd_dkv", "flash_attn_bwd_dq", "flash_attn_fwd"]
+
+
+@pytest.mark.parametrize("bh,seq,dim,causal,kernels", [
+    (16 * HEADS, 1024, HEAD_DIM, True, RESIDENT),   # the train cell's call
+    (96, 2048, 64, True, RESIDENT),     # the longest the rule admits,
+    (32, 2048, 128, True, RESIDENT),    # at both head widths,
+    (32, 2048, 128, False, RESIDENT),   # and with every extent whole
+    (16, 2304, 64, True, TILED),        # a ninth row block
+    (16, 2048, 256, True, TILED),       # the first shapes past the
+    (16, 4096, 128, True, TILED),       # estimate
+], ids=["cell", "s2048_d64", "s2048_d128", "s2048_d128_whole",
+        "s2304_d64", "s2048_d256", "s4096_d128"])
+def test_flash_resident_boundary_compiles(chip, bh, seq, dim, causal,
+                                          kernels):
+    """Both sides of the resident path's VMEM boundary, held by the
+    v5e compiler and not by ``_resident_vmem_estimate``'s arithmetic
+    alone: what the rule admits compiles as the resident pair, and the
+    first shapes past it take the tiled three."""
+    from apex_tpu.contrib.multihead_attn.attn_funcs import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal)
+                       .astype(jnp.float32))
+
+    q = _sds((1, bh, seq, dim), jnp.bfloat16, chip)
+    with force_mode("compiled"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).compile()
+    _check(compiled, len(kernels))
+    assert sorted(_kernel_names(compiled)) == kernels
 
 
 def test_flash_attention_with_bias_and_dropout_compiles(chip):
@@ -142,13 +176,23 @@ def _kernel_calls(compiled):
             if "tpu_custom_call" in ln and " custom-call(" in ln]
 
 
+def _kernel_names(compiled):
+    """The same, bare: ``%transpose_jvp_flash_attn_bwd__.3`` (the
+    backward of a custom VJP under ``grad``) is ``flash_attn_bwd``."""
+    return [re.sub(r"^%(transpose_)?(jvp_)?|_*(\.\d+)?$", "",
+                   c.replace("ROOT", "").strip())
+            for c in _kernel_calls(compiled)]
+
+
 def _kernel_operands(compiled, kernel):
     """The operand types (``bf16[96,1024,64]``) of each custom call that
     ``kernel`` names, as the compiled program constrains them."""
     found = []
-    for ln in compiled.as_text().splitlines():
-        if ("tpu_custom_call" in ln and " custom-call(" in ln
-                and kernel in ln.split("=")[0]):
+    for ln, name in zip((ln for ln in compiled.as_text().splitlines()
+                         if "tpu_custom_call" in ln
+                         and " custom-call(" in ln),
+                        _kernel_names(compiled)):
+        if name == kernel:
             constraints = ln.split("operand_layout_constraints={")[1]
             found.append(re.findall(r"(\w+\[[\d,]*\])\{",
                                     constraints.split("}}")[0]))
@@ -405,18 +449,19 @@ def test_fused_train_step_compiles(chip):
     with force_mode("compiled"):
         compiled = jax.jit(step._raw_step_fn, donate_argnums=(0,)).lower(
             _on(chip, step.state), ids, ids).compile()
-    # 12 layers x (forward + dq + dkv)
-    _check(compiled, 3 * LAYERS)
+    # 12 layers x (forward + the one backward kernel): every attention
+    # of the step takes the resident path
+    _check(compiled, 2 * LAYERS)
     # the kernels go by their own names in the device trace: the custom
     # calls' instruction names come from ``pallas_call(name=...)``
-    calls = _kernel_calls(compiled)
-    # q, k, v (and dO) reach the kernels as bf16 in the (B*H, S, D) layout:
-    # no upcast comes back in front of the MXU, and the layout stays the
-    # one ``flash_attn_roofline`` finds the kernels by
+    calls = _kernel_names(compiled)
+    assert sorted({c for c in calls if "flash" in c}) == RESIDENT, calls
+    # q, k, v (and out, dO) reach the kernels as bf16 in the (B*H, S, D)
+    # layout: no upcast comes back in front of the MXU, and the layout
+    # stays the one ``flash_attn_roofline`` finds the kernels by
     qkv = f"bf16[{8 * HEADS},1024,{HEAD_DIM}]"
-    for kernel, tensors in (("flash_attn_fwd", 3), ("flash_attn_bwd_dq", 4),
-                            ("flash_attn_bwd_dkv", 4)):
-        assert sum(kernel in c for c in calls) == LAYERS, (kernel, calls)
+    for kernel, tensors in (("flash_attn_fwd", 3), ("flash_attn_bwd", 5)):
+        assert calls.count(kernel) == LAYERS, (kernel, calls)
         operands = _kernel_operands(compiled, kernel)
         assert len(operands) == LAYERS, (kernel, operands)
         for ops in operands:
