@@ -228,6 +228,11 @@ class PagedSession:
     """
 
     def __init__(self, engine):
+        if len(engine.groups) > 1:
+            raise NotImplementedError(
+                "a PagedSession holds one block table: this engine's "
+                "model keeps several cache groups (window and full "
+                "attention layers); serve it through submit/step")
         self.engine = engine
         self._table = []
         self.position = 0

@@ -29,8 +29,8 @@ _f32 = jnp.float32
 #: rows of a tile of the Pallas tier (the MXU's height); the XLA tier
 #: needs no alignment and lays the rows out with tiles of one row
 TILE_ROWS = 128
-#: the widest K and N tiles tried (2 MiB of bf16 a block, two in flight)
-_TILE_KN = (1024, 512, 256, 128)
+#: the widest K and N tile (2 MiB of bf16 a block, two in flight)
+_TILE_KN_MAX = 1024
 
 
 class TileLayout(NamedTuple):
@@ -81,7 +81,12 @@ def tile_layout(group, n_groups: int, max_rows: int, tile: int) -> TileLayout:
 
 
 def _tile_of(n: int):
-    return next((t for t in _TILE_KN if n % t == 0), None)
+    """The widest tile of whole lane rows that divides ``n``: 1024 of
+    7168 or 4096, 768 of 2304, 896 of 1792 (a grid step costs about the
+    same whatever it moves, so powers of two alone would stream a
+    2304 x 1792 matrix in 63 blocks of 128 KiB where 6 of 1.3 MiB do)."""
+    return next((t for t in range(_TILE_KN_MAX, 0, -128) if n % t == 0),
+                None)
 
 
 def _gmm_kernel(tile_group_ref, n_active_ref, lhs_ref, rhs_ref, out_ref,

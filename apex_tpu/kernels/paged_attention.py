@@ -10,6 +10,24 @@ contiguous piece.  Every reader here takes
 the pool where it lies — no program needs it in another layout, so none
 copies it.
 
+**Under a window a table is a ring.**  A layer that reads every key
+takes a session's table in logical order: entry ``i`` is the block of
+positions ``[i*block_size, (i+1)*block_size)``, null past the session's
+length.  A layer that reads a window of keys needs only the band's
+blocks, so there logical block ``i`` is entry ``i mod width`` and the
+tables stay ``ceil(window / block_size)`` and a few entries wide however
+deep the session is (``serve/scheduler.py`` packs them so; a table in
+logical order that spans the session's depth is such a ring already).
+The width of a ring is a power of two (:func:`ring_entry`), and every
+reader of one masks by position.
+
+**Grouped-query heads.**  A block stores ``kv_heads`` heads; ``heads /
+kv_heads`` query heads read each (query head ``i`` reads stored head
+``i // (heads / kv_heads)``).  The repeated rows are never made: the
+decode readers put a query head in the columns of its stored head, the
+chunk reader contracts a group of query heads against its one stored
+head.
+
 * :func:`gather_kv` — the blocks of a table, one layer, in the pool's
   own dtype, heads side by side as in the pool: the gathered view the
   prefill and speculative-verify programs attend (many query rows, few
@@ -69,40 +87,79 @@ def gather_kv(pool, layer, tables):
     return tuple(read(kv) for kv in range(streams))
 
 
+def ring_entry(i, width):
+    """The entry of a window layer's table that holds logical block
+    ``i``: ``i mod width``, as a mask (a remainder on the kernel's scalar
+    core read as +1.6% of the kernel, PERF.md section 6)."""
+    if width & (width - 1):
+        raise ValueError(
+            f"a window layer's table is a ring {width} entries wide: the "
+            f"width has to be a power of two")
+    return i & (width - 1)
+
+
 def _valid(n_slots, positions, window):
-    """``(..., n_slots)`` mask: slot ``s`` is valid for a query at
-    position ``p`` where ``s <= p`` (and ``s > p - window``: rolling.py's
-    band, over block tables)."""
+    """``(..., n_slots)`` mask over the gathered view of a table: slot
+    ``s`` is valid for a query at position ``p`` where ``s <= p``.  Under
+    a window the table is a ring: slot ``s`` holds the key at the one
+    position ``k = s (mod n_slots)`` in ``(p - n_slots, p]``, valid
+    where ``k >= 0`` and ``k > p - window`` (rolling.py's band, over
+    block tables); the ring has to span the band and the chunk's later
+    rows (``n_slots >= window + Q - 1``), which then fall outside the
+    band."""
     slots = jnp.arange(n_slots, dtype=jnp.int32)
-    valid = slots <= positions[..., None]
-    if window is not None:
-        valid = valid & (slots > positions[..., None] - window)
-    return valid
+    p = positions[..., None]
+    if window is None:
+        return slots <= p
+    k = p - jnp.mod(p - slots, n_slots)
+    return (k >= 0) & (k > p - window)
 
 
 def attend(q, k, v, positions, scaling, window=None):
     """``q (B, H, Q, D)`` at per-row query positions ``positions
-    (B, Q)`` against a gathered view ``k, v (B, S, H*D)`` ->
+    (B, Q)`` against a gathered view ``k, v (B, S, KV*D)`` ->
     ``(B, Q, H*D)`` fp32: the score / mask / softmax / combine of
-    ``GptBlock.decode_chunk`` for chunks of query rows."""
+    ``GptBlock.decode_chunk`` for chunks of query rows.  ``H / KV``
+    query heads share a stored head (one each where ``KV == H``)."""
     b, h, s_q, d = q.shape
-    k = k.reshape(b, -1, h, d)
-    v = v.reshape(b, -1, h, d)
-    scores = jnp.einsum("bhqd,bshd->bhqs", q, k,
+    k = k.reshape(b, k.shape[1], -1, d)
+    v = v.reshape(b, v.shape[1], -1, d)
+    kv = k.shape[2]
+    q = q.reshape(b, kv, h // kv, s_q, d)
+    scores = jnp.einsum("bkgqd,bskd->bkgqs", q, k,
                         preferred_element_type=_f32) * scaling
     valid = _valid(k.shape[1], positions, window)            # (B, Q, S)
-    scores = jnp.where(valid[:, None, :, :], scores, -1e30)
+    scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhqs,bshd->bqhd", probs, v,
+    o = jnp.einsum("bkgqs,bskd->bqkgd", probs, v,
                    preferred_element_type=_f32)
     return o.reshape(b, s_q, h * d)
 
 
-def _own_columns(heads, width):
-    """``(H, H*D)`` mask: the columns of a pool row that are head h's."""
+def _own_columns(heads, width, head_dim):
+    """``(H, KV*D)`` mask: the columns of a pool row that query head h
+    reads, those of stored head ``h // (H / KV)``."""
     row = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
-    return lane // (width // heads) == row
+    group = heads * head_dim // width       # query heads a stored head
+    return lane // head_dim == (row if group == 1 else row // group)
+
+
+def _in_own_columns(q, own):
+    """``q (..., H, D)`` -> ``(..., H, KV*D)``: each query head in the
+    columns of its stored head, zeros elsewhere (block-diagonal where
+    ``KV == H``)."""
+    d, width = q.shape[-1], own.shape[-1]
+    return jnp.where(own, jnp.concatenate([q] * (width // d), axis=-1),
+                     jnp.zeros((), q.dtype))
+
+
+def _from_own_columns(o, own, head_dim):
+    """``o (..., H, KV*D)`` -> ``(..., H, D)``: what each head found in
+    its own columns."""
+    o = jnp.where(own, o, 0.0)
+    return sum(o[..., i:i + head_dim]
+               for i in range(0, o.shape[-1], head_dim))
 
 
 def _probs_times(p, v, dot, in_kernel=False):
@@ -138,14 +195,14 @@ def _decode_xla(q, pool, layer, tables, positions, scaling, window):
     One query row a session, so the heads are not split out of the rows
     (on the chip a minor dimension of ``head_dim`` is a relayout of the
     whole view): the scores of all heads come from one product of a
-    block-diagonal ``(H, H*D)`` query with the ``(S, H*D)`` keys — the
-    off-diagonal zeros add nothing — and the combine from one ``(H, S) x
-    (S, H*D)`` product of which head ``h`` keeps its own columns."""
+    ``(H, KV*D)`` query, each head in the columns of its stored head,
+    with the ``(S, KV*D)`` keys — the zeros elsewhere add nothing — and
+    the combine from one ``(H, S) x (S, KV*D)`` product of which head
+    ``h`` keeps its own columns."""
     b, h, d = q.shape
-    k, v = gather_kv(pool, layer, tables)                    # (B, S, H*D)
-    own = _own_columns(h, h * d)
-    q_bd = jnp.where(own[None], q.reshape(b, 1, h * d),
-                     jnp.zeros((), q.dtype))                 # (B, H, H*D)
+    k, v = gather_kv(pool, layer, tables)                    # (B, S, KV*D)
+    own = _own_columns(h, k.shape[-1], d)
+    q_bd = _in_own_columns(q, own[None])                     # (B, H, KV*D)
     exact = q.dtype == jnp.bfloat16 and k.dtype == jnp.bfloat16
     scores = jnp.einsum(
         "bhx,bsx->bhs", q_bd, k, preferred_element_type=_f32,
@@ -156,7 +213,7 @@ def _decode_xla(q, pool, layer, tables, positions, scaling, window):
     o = _probs_times(probs, v, lambda p, v_, prec: jnp.einsum(
         "bhs,bsx->bhx", p, v_, preferred_element_type=_f32,
         precision=prec))
-    return jnp.sum(jnp.where(own[None], o, 0.0), axis=1)
+    return _from_own_columns(o, own[None], d).reshape(b, h * d)
 
 
 # ---------------------------------------------------------------------------
@@ -194,10 +251,15 @@ def _attend_live_blocks(layer, tables_ref, pos_ref, pool_ref, buf, sem, q,
     def fetch(c, slot, start):
         for j in range(chunk):
             i = c * chunk + j
-            # past the table's width: the null block (zeros), as the
-            # table's own padding past the session's length is
-            blk = jnp.where(i < nb, tables_ref[b, jnp.minimum(i, nb - 1)],
-                            0)
+            if window is None:
+                # past the table's width: the null block (zeros), as the
+                # table's own padding past the session's length is
+                blk = jnp.where(i < nb,
+                                tables_ref[b, jnp.minimum(i, nb - 1)], 0)
+            else:
+                # a ring; a block past the query's own is masked by
+                # position whatever its entry holds
+                blk = tables_ref[b, ring_entry(i, nb)]
             dma = pltpu.make_async_copy(
                 pool_ref.at[layer, :, blk], buf.at[slot, :, j],
                 sem.at[slot])
@@ -254,19 +316,30 @@ def _attend_live_blocks(layer, tables_ref, pos_ref, pool_ref, buf, sem, q,
 
 def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref,
                    buf, sem, *, heads, scaling, window, chunk):
-    """Heads lie side by side in a row of the pool: the block-diagonal
-    query and the combine are :func:`_decode_xla`'s, the walk between
-    them :func:`_attend_live_blocks`'s."""
+    """Stored heads lie side by side in a row of the pool: the query
+    with each head in its stored head's columns and the combine are
+    :func:`_decode_xla`'s, the walk between them
+    :func:`_attend_live_blocks`'s.  ``q_ref`` is ``(1, 1, H*D)`` where
+    every query head has a stored head of its own (the row is broadcast
+    and masked: heads narrower than a lane row cannot be laid side by
+    side in a kernel), else ``(1, H, D)``, whole lane rows a head."""
     hd = buf.shape[4]
-    own = _own_columns(heads, hd)
-    q = q_ref[0]                                        # (1, H*D)
+    d = q_ref.shape[1] * q_ref.shape[2] // heads
+    own = _own_columns(heads, hd, d)
+    q = q_ref[0]
     # (selected in fp32: the mask has the 32-bit tiling)
-    q_bd = jnp.where(own, jnp.broadcast_to(q.astype(_f32), (heads, hd)),
-                     0.0).astype(q.dtype)
+    if q.shape[0] == 1:                                 # (1, H*D)
+        q_bd = jnp.where(own, jnp.broadcast_to(q.astype(_f32), (heads, hd)),
+                         0.0).astype(q.dtype)
+    else:                                               # (H, D)
+        q_bd = _in_own_columns(q.astype(_f32), own).astype(q.dtype)
     o = _attend_live_blocks(layer_ref[0], tables_ref, pos_ref, pool_ref, buf,
                             sem, q_bd, scaling=scaling, window=window,
                             chunk=chunk, rank=hd)
-    o_ref[0] = jnp.sum(jnp.where(own, o, 0.0), axis=0, keepdims=True)
+    if q.shape[0] == 1:
+        o_ref[0] = jnp.sum(jnp.where(own, o, 0.0), axis=0, keepdims=True)
+    else:
+        o_ref[0] = _from_own_columns(o, own, d)
 
 
 @functools.partial(jax.jit, static_argnames=("scaling", "window",
@@ -283,25 +356,28 @@ def _decode_call(layer, tables, positions, q, pool, *, scaling, window,
     kernel = functools.partial(
         _decode_kernel, heads=heads, scaling=scaling, window=window,
         chunk=chunk)
+    # a session's queries: one row of all heads, or a row a head where
+    # the heads are grouped (see the kernel)
+    rows = (1, heads * d) if heads * d == hd else (heads, d)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1,) + rows, lambda i, *_: (i, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1,) + rows, lambda i, *_: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, 2, chunk, bs, hd), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd), _f32),
+        out_shape=jax.ShapeDtypeStruct((b,) + rows, _f32),
         interpret=interpret,
         name="paged_attention_decode",
-    )(layer, tables, positions, q.reshape(b, 1, hd), pool)
-    return out[:, 0]
+    )(layer, tables, positions, q.reshape((b,) + rows), pool)
+    return out.reshape(b, heads * d)
 
 
 def _decode_pallas(q, pool, layer, tables, positions, scaling, window,
@@ -321,8 +397,10 @@ def paged_decode_attention(q, pool, layer, tables, positions, scaling,
     Two tiers, chosen by :func:`kernel_mode`: the Pallas kernel takes the
     tables by scalar prefetch and DMAs each live block from the pool in
     HBM (a session at depth 300 reads 19 blocks, not its table's 64);
-    the XLA tier gathers the whole table.  An int8 pool, or rows that
-    are not whole tiles, are the XLA tier's."""
+    the XLA tier gathers the whole table.  An int8 pool, rows that are
+    not whole tiles, or grouped heads narrower than a lane row are the
+    XLA tier's.  ``H`` may be a multiple of the pool's stored heads
+    (grouped-query attention)."""
     mode = kernel_mode(q, pool)
     if mode is not None:
         return _decode_pallas(q, pool, layer, tables, positions,
@@ -341,11 +419,15 @@ def kernel_mode(q, pool):
 
 def _kernel_takes(q, pool) -> bool:
     """What the kernel's tiles allow: a plain pool whose rows are whole
-    lane rows and whose blocks are whole sublane tiles of its dtype."""
+    lane rows and whose blocks are whole sublane tiles of its dtype;
+    where query heads share stored heads, heads that are whole lane
+    rows themselves (each is laid in its stored head's columns)."""
     if isinstance(pool, QuantKV):
         return False
     rows = 8 * 4 // jnp.dtype(pool.dtype).itemsize
-    return pool.shape[4] % 128 == 0 and pool.shape[3] % rows == 0
+    grouped = q.shape[1] * q.shape[2] != pool.shape[4]
+    return pool.shape[4] % 128 == 0 and pool.shape[3] % rows == 0 \
+        and not (grouped and q.shape[2] % 128)
 
 
 def _audit_programs():

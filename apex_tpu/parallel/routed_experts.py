@@ -16,6 +16,9 @@ capacity-dropping, a dense one-hot dispatch.
   ``n_group`` groups by the sum of their two best biased scores, the
   best ``top_k`` experts of those groups, weights from the unbiased
   scores, normalised and scaled.
+* :func:`softmax_topk_route` — float32 logits, a softmax over ALL the
+  experts, the best ``top_k`` of them, their probabilities normalised
+  over the chosen: no bias, no groups.
 * :class:`RoutedExperts` — the router, the held experts' gated (SwiGLU)
   matrices stacked, and the dispatch: token-expert pairs that go to held
   experts are sorted by expert and taken through two grouped matmuls
@@ -62,20 +65,45 @@ def group_limited_route(x, w_router, bias, *, n_group, topk_group, top_k,
     return experts, w * scale
 
 
+def softmax_topk_route(x, w_router, *, top_k, norm_topk=True, scale=1.0):
+    """``x (T, E)`` -> ``(experts (T, top_k) int32, weights (T, top_k)
+    fp32)``: ``p = softmax(x W_r^T)`` over all ``n_experts``, the
+    ``top_k`` largest, ``p_e / sum of the chosen p`` where ``norm_topk``
+    (softmax-then-top-k renormalised and top-k-then-softmax are the same
+    numbers).  Float32 at ``highest`` whatever ``x`` is, as
+    :func:`group_limited_route`."""
+    p = jax.nn.softmax(jnp.matmul(
+        x.astype(_f32), w_router.astype(_f32).T, precision=_HI), axis=-1)
+    w, experts = jax.lax.top_k(p, top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=1, keepdims=True)
+    return experts.astype(jnp.int32), w * scale
+
+
 class RoutedExperts(Module):
     """``n_experts`` routed gated experts of which this device holds
     ``experts_held`` (ids; default all).  ``forward(ctx, x (T, E))`` ->
     ``(y (T, E), pairs (len(experts_held),) int32)``: the held experts'
     part of the layer's routed sum, and the token-expert pairs each of
     them got.  Matrices are kept ``(in, out)``: ``w_in (G, E, 2*I)`` is
-    gate | up, ``w_out (G, I, E)``."""
+    gate | up, ``w_out (G, I, E)``.  ``score``: how the router scores,
+    ``"sigmoid"`` (:func:`group_limited_route`, with its correction
+    bias) or ``"softmax"`` (:func:`softmax_topk_route`: no bias, no
+    groups)."""
 
     def __init__(self, hidden, intermediate, n_experts, top_k, *,
                  n_group=1, topk_group=1, scale=1.0, norm_topk=True,
-                 experts_held=None, init=None):
+                 experts_held=None, score="sigmoid", init=None):
         """``init(shape, fan_in) -> Parameter`` draws (or only declares)
         a parameter; ``fan_in`` None marks a bias."""
         super().__init__()
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score is 'sigmoid' or 'softmax', "
+                             f"got {score!r}")
+        if score == "softmax" and (n_group, topk_group) != (1, 1):
+            raise ValueError("the softmax router chooses among all "
+                             "experts: it has no groups")
+        self.score = score
         held = tuple(range(n_experts)) if experts_held is None \
             else tuple(int(e) for e in experts_held)
         if len(set(held)) != len(held) or \
@@ -98,11 +126,16 @@ class RoutedExperts(Module):
         init = init or _normal_init
         g = len(held)
         self.router = init((n_experts, hidden), hidden)
-        self.router_bias = init((n_experts,), None)
+        self.router_bias = init((n_experts,), None) \
+            if score == "sigmoid" else None
         self.w_in = init((g, hidden, 2 * intermediate), hidden)
         self.w_out = init((g, intermediate, hidden), intermediate)
 
     def route(self, ctx, x):
+        if self.score == "softmax":
+            return softmax_topk_route(
+                x, ctx.value(self.router), top_k=self.top_k,
+                norm_topk=self.norm_topk, scale=self.scale)
         return group_limited_route(
             x, ctx.value(self.router), ctx.value(self.router_bias),
             n_group=self.n_group, topk_group=self.topk_group,

@@ -1,6 +1,8 @@
 """ServeEngine: the continuous-batching serving loop.
 
-One engine owns one model, one paged KV pool, one scheduler, and a
+One engine owns one model, its paged KV cache (one pool a *cache group*:
+the layers that keep the same rows of a token for the same length; most
+models have one group), one scheduler, and a
 small fixed family of compiled programs — two *kinds* (``prefill_step``,
 ``decode_step``) dispatched through the one-runtime executor
 (runtime/executor.py), so serving inherits the whole training-side
@@ -86,12 +88,29 @@ class ServeEngine:
     row, rotary positions, routed experts).  What differs between them
     is read off the model's layers, never set here.
 
-    ``num_blocks`` sizes the shared pool (one block = ``block_size ×
-    layers`` tokens' rows, whose streams and width are the layers'
-    ``cache_rows``; block 0 is the reserved null block).  ``cache_dtype`` follows the session
-    convention — default the token-embedding dtype, ``"int8"`` for the
-    quantized pool.  ``window`` enables sliding-window attention with
-    block-table retirement (rolling.py's band, generalized).
+    The cache is one pool a *cache group* (``serve/kernels.py``
+    ``cache_groups``): the layers that keep the same ``cache_rows`` of a
+    token for the same ``window`` share a buffer ``(layers of the group,
+    streams, blocks, block_size, width)``, a :class:`BlockPool` and, a
+    session, a block table.  A group whose layers read a window of keys
+    retires the blocks wholly before the band as the session advances; a
+    group whose layers read every key keeps them.  Admission, growth,
+    preemption and ``finish`` ask every group: a session holds its
+    blocks in all of them or in none.  ``pools`` / ``block_pools`` list
+    them, groups without a window first; ``pool`` / ``block_pool`` are
+    the first group's (the only one of a model whose layers are alike).
+
+    ``num_blocks`` sizes the first group's pool, and that of every other
+    group without a window (one block = ``block_size`` tokens' rows in
+    each of the group's layers; block 0 is the reserved null block); a
+    further group with a window is sized from the model, ``max_batch ×
+    (ceil(window / block_size) + 2)`` blocks and a prefill chunk's, which
+    every session's band always fits.  ``cache_dtype`` follows the
+    session convention — default the token-embedding dtype, ``"int8"``
+    for the quantized pool.  ``window`` is the window of layers that
+    declare none (a GPT block; ``None``: they read every key).
+    Speculation needs one group without a window, the prefix cache one
+    group (``docs/serving.md``).
     """
 
     def __init__(self, model, *, num_blocks, block_size=16, max_batch=8,
@@ -113,8 +132,32 @@ class ServeEngine:
             else model.tok_emb.weight.data.dtype
         self._dtype_name = dtype if isinstance(dtype, str) \
             else jnp.dtype(dtype).name
-        self.pool = self._pool_for(model, dtype)
-        self.block_pool = BlockPool(self.num_blocks, self.block_size)
+        self.groups, _ = _kernels.cache_groups(model, window)
+        self._windowed = any(g.window is not None for g in self.groups)
+        # (window, layers without one, layers with one): the tick
+        # record's kv_* fields (_count_rows)
+        self._kv_kinds = (
+            min((g.window for g in self.groups if g.window is not None),
+                default=None),
+            sum(len(g.layers) for g in self.groups if g.window is None),
+            sum(len(g.layers) for g in self.groups if g.window is not None))
+        sizes = [self.num_blocks if i == 0 or g.window is None
+                 else max_batch * (blocks_for(g.window, self.block_size) + 2)
+                 + blocks_for(prefill_chunk, self.block_size) + 1
+                 for i, g in enumerate(self.groups)]
+        self.pools = [self._pool_for(g, n, dtype)
+                      for g, n in zip(self.groups, sizes)]
+        self.block_pools = [
+            BlockPool(n, self.block_size,
+                      metrics_prefix="serve." if i == 0
+                      else f"serve.{g.name}.")
+            for i, (g, n) in enumerate(zip(self.groups, sizes))]
+        self.block_pool = self.block_pools[0]
+        self._in_use_gauges = [
+            (f"serve.pool.{g.name}.blocks_in_use", bp)
+            for g, bp in zip(self.groups, self.block_pools)]
+        # one group: a prefix is one table's blocks
+        prefix_cache = prefix_cache and len(self.groups) == 1
         # -- speculative mode: a draft model served from its OWN pool
         # buffer (int8 by default — weight-only drafts are bandwidth
         # bound) whose block ids come from the SAME BlockPool free-list
@@ -132,11 +175,13 @@ class ServeEngine:
                 else draft.tok_emb.weight.data.dtype
             self._d_dtype_name = d_dtype if isinstance(d_dtype, str) \
                 else jnp.dtype(d_dtype).name
-            self.dpool = self._pool_for(draft, d_dtype)
+            self.dpool = self._pool_for(
+                _kernels.cache_groups(draft)[0][0], self.num_blocks, d_dtype)
         if max_prefill_backlog is None:
             max_prefill_backlog = 4 * prefill_chunk
         self.scheduler = Scheduler(
-            self.block_pool, max_batch=max_batch,
+            self.block_pools, windows=[g.window for g in self.groups],
+            max_batch=max_batch,
             prefill_chunk=prefill_chunk,
             max_prefill_backlog=max_prefill_backlog,
             max_positions=model.max_positions,
@@ -175,12 +220,30 @@ class ServeEngine:
         self.weight_epochs: Dict[str, int] = {"target": 0, "draft": 0}
         self.result_meta: Dict[str, dict] = {}
 
-    def _pool_for(self, model, dtype):
-        """The pool buffer whose geometry is the model's layers'."""
-        streams, heads, head_dim = model.blocks[0].cache_rows
-        return init_pool_buffer(len(model.blocks), heads, head_dim,
-                                self.num_blocks, self.block_size, dtype,
+    def _pool_for(self, group, num_blocks, dtype):
+        """The pool buffer whose geometry is the group's layers'."""
+        streams, heads, head_dim = group.rows
+        return init_pool_buffer(len(group.layers), heads, head_dim,
+                                num_blocks, self.block_size, dtype,
                                 streams=streams)
+
+    @property
+    def pool(self):
+        """The first cache group's buffer (the only one of a model whose
+        layers keep the same rows for the same length)."""
+        return self.pools[0]
+
+    @pool.setter
+    def pool(self, buf) -> None:
+        self.pools[0] = buf
+
+    def _cache(self):
+        """The pools as the programs take them: the buffer, or a tuple of
+        them where there are several groups."""
+        return self.pools[0] if len(self.pools) == 1 else tuple(self.pools)
+
+    def _set_cache(self, pools) -> None:
+        self.pools = list(pools) if len(self.pools) > 1 else [pools]
 
     @staticmethod
     def _validate_model(model):
@@ -195,13 +258,10 @@ class ServeEngine:
                 if not hasattr(blk, a):
                     raise ValueError(
                         f"ServeEngine needs block.{a} — the layer "
-                        f"protocol of serve/kernels.py "
-                        f"({type(blk).__name__} does not follow it)")
-        rows = {tuple(blk.cache_rows) for blk in model.blocks}
-        if len(rows) != 1:
-            raise NotImplementedError(
-                f"ServeEngine keeps one pool: every layer has to store "
-                f"the same rows a token, the model's store {sorted(rows)}")
+                        f"protocol of serve/kernels.py: cache_rows, "
+                        f"chunk_rows, read_decode, read_chunk, finish, "
+                        f"and window where the block reads a band of "
+                        f"keys ({type(blk).__name__} does not follow it)")
         axes = _sharded_decode_axes(model)
         if axes:
             names = ", ".join(f"{a}='{v}'" for a, v in axes)
@@ -213,11 +273,15 @@ class ServeEngine:
         self._validate_model(draft)
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-        if window is not None:
+        if self._windowed:
             raise NotImplementedError(
                 "speculative mode + sliding window: the verify chunk "
                 "would need a per-row band mask over retired blocks — "
                 "serve one mode or the other")
+        if len(self.groups) > 1 or len(_kernels.cache_groups(draft)[0]) > 1:
+            raise NotImplementedError(
+                "speculative mode serves models of one cache group: the "
+                "draft and verify programs take one pool each")
         if draft.tok_emb.weight.shape[0] < model.tok_emb.weight.shape[0]:
             raise ValueError(
                 "draft vocabulary is smaller than the target's — "
@@ -298,7 +362,7 @@ class ServeEngine:
         if epoch is None:
             epoch = self.weight_epochs["target"]
         return (f"{self._dtype_name}:b{self.block_size}:"
-                f"w{self.window}:e{int(epoch)}")
+                f"w{self.groups[0].window}:e{int(epoch)}")
 
     def _dispatch_cow(self, s: Session) -> None:
         """Materialize admission's copy-on-write forks: one paged
@@ -477,10 +541,14 @@ class ServeEngine:
                 if self.spec:
                     self._spec_tick(ds)
                 else:
+                    if self._windowed:
+                        self._count_rows(tick, ds)
                     self._decode_tick(ds)
         _obs.gauge("serve.queue_depth").set(len(self.scheduler.queue))
         _obs.gauge("serve.active_sessions").set(
             len(self.scheduler.sessions))
+        for name, bp in self._in_use_gauges:
+            _obs.gauge(name).set(bp.in_use)
         return self.scheduler.has_work()
 
     def _admit(self) -> int:
@@ -545,19 +613,32 @@ class ServeEngine:
         with _spans.span("serve.prefill_chunk", rid=s.rid, n_real=n):
             self._prefill(s, chunk, n)
 
+    def _tables(self, packed):
+        """Packed tables (one group's, or a tuple a group) as the
+        programs' int32 operands."""
+        if isinstance(packed, tuple):
+            return tuple(np.asarray(t, np.int32) for t in packed)
+        return np.asarray(packed, np.int32)
+
     def _prefill(self, s: Session, chunk: int, n: int) -> None:
         prefill_prog, _ = self._programs()
         t0 = s.position
+        if self._windowed:
+            # a window group's blocks are granted a chunk at a time (the
+            # ones before the band go back as the prompt goes in)
+            last_chunk = t0 + n >= len(s.prefill_src)
+            if not self._grow_or_preempt(s, t0 + n + last_chunk):
+                return
         toks = list(s.prefill_src[t0:t0 + n])
         toks += [0] * (chunk - n)
-        nb = bucket(len(s.table))
-        table = s.table + [0] * (nb - len(s.table))
-        last, self.pool, counted = _executor.executor.submit(
+        last, pools, counted = _executor.executor.submit(
             prefill_prog,
-            (self._vals(), self.pool,
-             np.asarray([toks], np.int32), np.asarray([table], np.int32),
+            (self._vals(), self._cache(),
+             np.asarray([toks], np.int32),
+             self._tables(self.scheduler.pack_groups([s], 1)[1]),
              np.int32(t0), np.int32(n)),
             step=next(self._dispatch_no))
+        self._set_cache(pools)
         if counted is not None:
             self._counted.append(counted)
         if self.spec and s.draft_position == t0:
@@ -579,8 +660,7 @@ class ServeEngine:
                 step=next(self._dispatch_no))
             s.draft_position = t0 + n
         s.position = t0 + n
-        if self.window is not None:
-            self.scheduler.retire_window_blocks(s, self.window)
+        self._retire(s)
         self._note_commit(s)
         if s.prefill_remaining > 0:
             return
@@ -647,30 +727,44 @@ class ServeEngine:
                 continue                     # preempted below us
             if self.spec and s.draft_position < s.position:
                 continue                     # catch-up session: no tick
-            need = s.position + 1 + slack
-            while not (self.scheduler.grow(s, need)
-                       and (not self.spec
-                            or self.scheduler.grow(s, need,
-                                                   draft=True))):
-                victim = self.scheduler.preempt_for(s)
-                _obs.counter("serve.preemptions").inc()
-                _obs.event("serve.request", rid=victim.rid,
-                           phase="preempted", tick=self._tick,
-                           generated=len(victim.out))
-                if victim is s:
-                    break
+            self._grow_or_preempt(s, s.position + 1 + slack)
+
+    def _grow_or_preempt(self, s: Session, need: int) -> bool:
+        """Every table of ``s`` (every cache group's, and the draft's)
+        grown to cover ``need`` rows, the newest sessions preempted
+        while a pool is dry; False if ``s`` itself had to go."""
+        while not (self.scheduler.grow(s, need)
+                   and (not self.spec
+                        or self.scheduler.grow(s, need, draft=True))):
+            victim = self.scheduler.preempt_for(s)
+            _obs.counter("serve.preemptions").inc()
+            _obs.event("serve.request", rid=victim.rid,
+                       phase="preempted", tick=self._tick,
+                       generated=len(victim.out))
+            if victim is s:
+                return False
+        return True
+
+    def _retire(self, s: Session) -> None:
+        """The blocks wholly before the band of every window group go
+        back to their pools."""
+        if self._windowed:
+            n = self.scheduler.retire_window_blocks(s)
+            if n:
+                _obs.counter("serve.window.blocks_retired").inc(n)
 
     def _decode_tick(self, sessions: List[Session]) -> None:
         _, decode_prog = self._programs()
         with _spans.span("serve.pack"):
             b, nb, tokens, positions, tables = \
                 self.scheduler.pack_decode(sessions)
-        nxt, _logits, self.pool, counted = _executor.executor.submit(
+        nxt, _logits, pools, counted = _executor.executor.submit(
             decode_prog,
-            (self._vals(), self.pool,
+            (self._vals(), self._cache(),
              np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
-             np.asarray(tables, np.int32)),
+             self._tables(tables)),
             step=next(self._dispatch_no))
+        self._set_cache(pools)
         if counted is not None:
             self._counted.append(counted)
         with _spans.span("serve.fetch", what="tokens"):
@@ -688,12 +782,25 @@ class ServeEngine:
                 tok = int(nxt[i])
                 s.out.append(tok)
                 s.pending_tok = tok
-                if self.window is not None:
-                    self.scheduler.retire_window_blocks(s, self.window)
+                self._retire(s)
                 self._note_commit(s)
                 if s.finished():
                     self._finish(s)
                     rec["n_finished"] += 1
+
+    def _count_rows(self, tick: dict, sessions: List[Session]) -> None:
+        """What one layer of each kind reads in this tick's decode
+        dispatch, on the tick's ``serve.step`` record
+        (docs/observability.md): a query at position p reads keys 0 .. p
+        in a layer without a window and the last ``window`` of them in
+        one with."""
+        window, layers_full, layers_window = self._kv_kinds
+        depths = [s.position + 1 for s in sessions]
+        tick["kv_rows_full"] = sum(depths)
+        tick["kv_rows_window"] = sum(min(d, window) for d in depths)
+        tick["kv_layers_full"] = layers_full
+        tick["kv_layers_window"] = layers_window
+        tick["kv_window"] = window
 
     def _publish_moe(self, tick: dict) -> None:
         """What the tick's programs counted in their routed layers —
@@ -806,6 +913,10 @@ class ServeEngine:
         None when a batch slot / blocks are not available right now
         (the coordinator retries next tick)."""
         from ..runtime.resilience import load_kv_handoff
+        if len(self.groups) > 1:
+            raise NotImplementedError(
+                "a KV handoff streams one cache group's blocks; this "
+                "model's layers form several")
         need_pos = len(request.prompt) + request.max_new_tokens \
             + self.scheduler.pos_slack
         if need_pos > self.scheduler.max_positions:
@@ -890,7 +1001,7 @@ class ServeEngine:
 
     def close(self) -> None:
         """Tear the engine down: return every live session's blocks —
-        target AND draft tables — to the :class:`BlockPool`, drop the
+        every cache group's AND draft tables — to the pools, drop the
         queue (queued sessions hold no blocks), and assert the pool is
         leak-free.  An engine dropped mid-run without this strands its
         resident sessions' blocks; the elastic fleet also calls it when
@@ -900,7 +1011,8 @@ class ServeEngine:
         for s in list(self.scheduler.sessions):
             self.scheduler.finish(s)
         self.scheduler.queue.clear()
-        self.block_pool.check_no_leaks()
+        for bp in self.block_pools:
+            bp.check_no_leaks()
 
     def __enter__(self) -> "ServeEngine":
         return self

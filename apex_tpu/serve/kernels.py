@@ -13,7 +13,12 @@ step-cache keying discipline, enforced by the SERVE-SHAPE lint rule.
 Every layer of a servable model follows ONE protocol
 (:func:`_through_blocks`): the body hands it the chunk and its positions
 and gets back its queries and the row(s) to store; the layer says what
-it keeps of a token and how a query reads it.  ``GptBlock`` keeps a K
+it keeps of a token, for how long (``window``), and how a query reads
+it.  Layers that keep the same rows for the same length share a *cache
+group* (:func:`cache_groups`): a pool buffer and a block table a session
+of their own, so a model whose layers mix window and full attention
+keeps the band's blocks for the one kind and every block for the
+other.  ``GptBlock`` keeps a K
 and a V row of all heads (its learned positions were added at the
 embedding) and reuses the model's own decode pieces —
 ``_chunk_qkv`` (LN1 + interleaved QKV projection), ``_attn_mlp_tail``
@@ -23,8 +28,10 @@ lookup — so the paged path cannot drift numerically from the
 contiguous-cache path it is parity-tested against (tests/test_serve.py,
 tests/test_serve_paged.py); a latent block (``models/latent_moe.py``)
 rotates by the positions and keeps one latent row a token, read by all
-heads alike.  What is here is the index plumbing, and it keeps the pool
-where it lies (``serve/pool.py``: ``(layers, streams, num_blocks,
+heads alike; a grouped-query block (``models/gqa_moe.py``) rotates by
+its own kind's tables and keeps a K and a V row of its stored heads.
+What is here is the index plumbing, and it keeps each pool where it lies
+(``serve/pool.py``: ``(layers of the group, streams, num_blocks,
 block_size, width)``, row-major on the device):
 
 * **write** — each layer sets its fresh rows in the donated pool in
@@ -52,13 +59,47 @@ write — padding never touches the null block's zeros.
 """
 from __future__ import annotations
 
+from typing import NamedTuple, Optional, Tuple
+
 import jax
 import jax.numpy as jnp
 
 from ..inference import QuantKV, absmax_int8, gather_rows
+from ..kernels.paged_attention import ring_entry
 from ..nn.modules import Ctx
 
 _f32 = jnp.float32
+
+
+class CacheGroup(NamedTuple):
+    """Layers that keep the same rows of a token for the same length."""
+    rows: Tuple[int, int, int]     # (streams, heads, head_dim) a token
+    window: Optional[int]          # keys a query reads; None: all of them
+    layers: Tuple[int, ...]        # the model's layers, in order
+
+    @property
+    def name(self) -> str:
+        return "full" if self.window is None else f"window{self.window}"
+
+
+def cache_groups(model, window=None):
+    """``(groups, where)``: the model's layers grouped by what they keep
+    (``blk.cache_rows``) and for how long (``blk.window``; a layer that
+    declares none takes ``window``, the engine-wide default), groups
+    without a window first, and for each layer ``(its group, its place
+    in that group's pool)``."""
+    keys = [(tuple(blk.cache_rows),
+             getattr(blk, "window", None) or window)
+            for blk in model.blocks]
+    order = sorted(dict.fromkeys(keys), key=lambda k: k[1] is not None)
+    groups = [CacheGroup(rows, w, tuple(
+        i for i, k in enumerate(keys) if k == (rows, w)))
+        for rows, w in order]
+    where = [None] * len(keys)
+    for g, grp in enumerate(groups):
+        for at, layer in enumerate(grp.layers):
+            where[layer] = (g, at)
+    return groups, where
 
 
 def _ctx(params, vals):
@@ -71,16 +112,21 @@ def _ctx(params, vals):
 # ---------------------------------------------------------------------------
 
 
-def row_targets(tables, positions, live, block_size, num_blocks, streams):
+def row_targets(tables, positions, live, block_size, num_blocks, streams,
+                ring=False):
     """Where the rows of logical ``positions (B, Q)`` live in one layer
     of the pool: index arrays ``(stream, block, offset)``, each
-    ``(streams*B*Q,)`` — the first stream's rows, then the next's.  Rows
-    that are not ``live (B, Q)`` (bucket padding, a chunk's zero-padded
-    tail) point past the pool, so :func:`write_rows` drops them —
-    padding never touches the null block's zeros."""
+    ``(streams*B*Q,)`` — the first stream's rows, then the next's.
+    ``ring``: the tables are a window group's (logical block ``i`` at
+    entry ``i mod width``: ``kernels/paged_attention.py``).  Rows that
+    are not ``live (B, Q)`` (bucket padding, a chunk's zero-padded tail)
+    point past the pool, so :func:`write_rows` drops them — padding
+    never touches the null block's zeros."""
     p = jnp.clip(positions, 0)
-    tgt = jnp.take_along_axis(
-        tables, jnp.minimum(p // block_size, tables.shape[1] - 1), axis=1)
+    width = tables.shape[1]
+    entry = ring_entry(p // block_size, width) if ring \
+        else jnp.minimum(p // block_size, width - 1)
+    tgt = jnp.take_along_axis(tables, entry, axis=1)
     tgt = jnp.where(live, tgt, num_blocks).reshape(-1)
     stream = jnp.repeat(jnp.arange(streams, dtype=tgt.dtype), tgt.shape[0])
     return stream, jnp.tile(tgt, streams), \
@@ -157,15 +203,22 @@ def _head(ctx, model, x):
         x, jnp.swapaxes(ctx.value(table), 0, 1).astype(x.dtype)))
 
 
-def _through_blocks(ctx, model, pool, x, q_pos, live, tables, block_size,
-                    num_blocks, window, *, decode):
+def _num_blocks(pool) -> int:
+    return (pool.q if isinstance(pool, QuantKV) else pool).shape[2]
+
+
+def _through_blocks(ctx, model, pools, x, q_pos, live, tables, block_size,
+                    window, *, decode):
     """``x (B, Q, E)`` at positions ``q_pos (B, Q)``, of which ``live
     (B, Q)`` are real, through every block (``decode``: the tick's one
     row a session, ``Q == 1``), by the one layer protocol every servable
     block follows:
 
     * ``blk.cache_rows`` — ``(streams, heads, head_dim)``: what the block
-      keeps of a token (the pool's geometry is read off it);
+      keeps of a token (its pool's geometry is read off it);
+    * ``blk.window`` — how many keys a query of this block reads (the
+      query's own among them), or None for all of them; a block without
+      the attribute takes ``window``, the engine-wide default;
     * ``blk.chunk_rows(ctx, x, positions) -> (q, rows)`` — its queries
       and the rows to store, positions in hand (rotary or unused);
     * ``blk.read_decode`` / ``blk.read_chunk(q, pool, layer, tables,
@@ -177,26 +230,41 @@ def _through_blocks(ctx, model, pool, x, q_pos, live, tables, block_size,
       block, and what it counted on the way over the ``live`` rows (a
       routed layer's token-expert pairs a held expert; None).
 
-    Each layer writes the live rows into the pool and then attends —
+    ``pools`` and ``tables`` are one buffer and one ``(B, nb)`` table
+    where all layers are of one cache group, else a tuple of each, a
+    group (:func:`cache_groups`): a layer is handed its group's buffer
+    and table, its place in that buffer and its own window.
+
+    Each layer writes the live rows into its pool and then attends —
     the write-then-read of ``GptBlock.decode_chunk``, so a query finds
     its own key where every other key is (through an int8 pool: exactly
-    the bytes stored).  Returns ``(x, pool, counted)``: ``counted`` the
+    the bytes stored).  Returns ``(x, pools, counted)``: ``counted`` the
     layers' counts stacked ``(layers that count, ...)``, None if none
     does."""
-    streams = model.blocks[0].cache_rows[0]
-    targets = row_targets(tables, q_pos, live, block_size, num_blocks,
-                          streams)
+    groups, where = cache_groups(model, window)
+    one = not isinstance(tables, (tuple, list))
+    pools, tables = ([pools], [tables]) if one else (list(pools), tables)
+    if len(pools) != len(groups):
+        raise ValueError(f"the model's layers form {len(groups)} cache "
+                         f"groups, the program was handed {len(pools)} "
+                         f"pools")
+    targets = [row_targets(tables[g], q_pos, live, block_size,
+                           _num_blocks(pools[g]), grp.rows[0],
+                           ring=grp.window is not None)
+               for g, grp in enumerate(groups)]
     pos = q_pos[:, 0] if decode else q_pos
     counted = []
     for layer, blk in enumerate(model.blocks):
+        g, at = where[layer]
         q, rows = blk.chunk_rows(ctx, x, q_pos)
-        pool = write_rows(pool, layer, targets, rows)
+        pools[g] = write_rows(pools[g], at, targets[g], rows)
         read = blk.read_decode if decode else blk.read_chunk
-        x, n = blk.finish(ctx, x, read(q, pool, layer, tables, pos, window),
-                          live)
+        x, n = blk.finish(ctx, x, read(q, pools[g], at, tables[g], pos,
+                                       groups[g].window), live)
         if n is not None:
             counted.append(n)
-    return x, pool, jnp.stack(counted) if counted else None
+    return x, pools[0] if one else tuple(pools), \
+        jnp.stack(counted) if counted else None
 
 
 def build_decode_fn(model, params, block_size, num_blocks, window=None):
@@ -205,7 +273,11 @@ def build_decode_fn(model, params, block_size, num_blocks, window=None):
     ``fn(vals, pool, tokens, positions, tables) ->
     (next_tokens, logits, pool, counted)`` with ``tokens (B,)`` the last emitted
     token per session, ``positions (B,)`` its ingest position (``-1`` =
-    dead pad row), ``tables (B, nb)``.  Greedy sampling happens
+    dead pad row), ``tables (B, nb)``; for a model of several cache
+    groups ``pool`` and ``tables`` are tuples, one of each a group
+    (:func:`cache_groups`).  ``num_blocks`` is what the pools were built
+    with (each pool's own shape is what is read); ``window`` the window
+    of layers that declare none.  Greedy sampling happens
     in-program (argmax over the masked logits — the same reduction the
     session path's ``make_sampler(0, ...)`` runs), so the engine's host
     round-trip per tick is one small int array; the logits ride along
@@ -220,7 +292,7 @@ def build_decode_fn(model, params, block_size, num_blocks, window=None):
         x = _embed(ctx, model, tokens[:, None], positions[:, None])
         x, pool, counted = _through_blocks(
             ctx, model, pool, x, positions[:, None], positions[:, None] >= 0,
-            tables, block_size, num_blocks, window, decode=True)
+            tables, block_size, window, decode=True)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)[:, 0]               # (B, V)
         nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
@@ -251,7 +323,7 @@ def build_prefill_fn(model, params, block_size, num_blocks,
         # chunk row d lands at position t0 + d; live rows only
         x, pool, counted = _through_blocks(
             ctx, model, pool, x, pos, (rows < n_real)[None, :], table,
-            block_size, num_blocks, window, decode=False)
+            block_size, window, decode=False)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)                  # (1, chunk, V)
         last = jax.lax.dynamic_index_in_dim(
@@ -312,7 +384,7 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
             x = _embed(d_ctx, draft, tok[:, None], pos_j[:, None])
             x, d_pool, _ = _through_blocks(
                 d_ctx, draft, d_pool, x, pos_j[:, None], pos_j[:, None] >= 0,
-                d_tables, block_size, num_blocks, None, decode=True)
+                d_tables, block_size, None, decode=True)
             if j < k:                  # step k only writes its KV row
                 x = draft.ln_f.forward(d_ctx, x)
                 logits = _head(d_ctx, draft, x)[:, 0]
@@ -326,7 +398,7 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
         x = _embed(t_ctx, target, chunk, q_pos)
         x, t_pool, _ = _through_blocks(
             t_ctx, target, t_pool, x, q_pos, q_live, t_tables, block_size,
-            num_blocks, None, decode=False)
+            None, decode=False)
         x = target.ln_f.forward(t_ctx, x)
         logits = _head(t_ctx, target, x)                # (B, kp1, V)
         emitted = jnp.argmax(logits, axis=-1).astype(tokens.dtype)

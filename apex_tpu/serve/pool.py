@@ -1,4 +1,8 @@
-"""Paged KV block pool: one preallocated HBM buffer for every session.
+"""Paged KV block pool: one preallocated HBM buffer for every session
+(one a cache group, where a model's layers keep different rows of a
+token or keep them for different lengths: ``serve/kernels.py``
+``cache_groups``; each group has a buffer and a :class:`BlockPool` of
+its own).
 
 The single-session decode paths (inference/session.py, models.gpt
 generate) each allocate private ``(B, H, S_max, D)`` caches sized for
@@ -15,9 +19,10 @@ POOL geometry and the bucket dims, never on which sessions are resident,
 so session churn cannot force a recompile.
 
 Layout: ``(layers, streams, num_blocks, block_size, heads*head_dim)`` —
-the geometry is the model's layers' (``block.cache_rows``): a GPT block
-keeps two streams, k and v on axis 1, each a row of all heads; a latent
-(MLA) block keeps one stream of one "head", the token's latent row.
+the geometry is the group's layers' (``block.cache_rows``): a GPT block
+keeps two streams, k and v on axis 1, each a row of all heads (a
+grouped-query block: of its stored heads); a latent (MLA) block keeps
+one stream of one "head", the token's latent row.
 Block id on axis 2 so a session's table indexes one axis, and one
 token's row contiguous and minor-most (a whole number of lane rows, so
 the device keeps the array row-major and the serve programs read and
@@ -38,8 +43,8 @@ automatic prefix caching lineage) lets N sessions whose token chains
 share a committed prefix hold the SAME physical blocks.  Full blocks
 are *committed* under a rolling content hash of their token chain
 (:func:`chain_key` — keyed by the parent block's hash, the block's
-tokens, and a tag carrying cache dtype / block size / attention window
-/ model weight epoch, so an int8 pool never matches an fp32 chain and
+tokens, and a tag carrying cache dtype / block size / the cache group's
+window / model weight epoch, so an int8 pool never matches an fp32 chain and
 a ``publish_weights`` hot-swap never serves stale KV).  The
 ``hash → physical block`` index (:meth:`BlockPool.acquire_prefix`)
 turns admission into a chain walk: matched blocks are adopted by
@@ -126,7 +131,7 @@ def chain_key(parent: str, tokens: Sequence[int], tag: str) -> str:
     """The content hash of ONE full block: rolling over ``parent`` (the
     previous block's key, ``""`` for the chain head), the block's token
     ids, and ``tag`` — the engine's cache-compatibility stamp (dtype,
-    block size, window, weight epoch).  Two blocks share a key iff they
+    block size, the cache group's window, weight epoch).  Two blocks share a key iff they
     hold the KV of the same token prefix computed under the same cache
     geometry and weights — which is exactly when their bytes are
     interchangeable."""

@@ -17,7 +17,9 @@ serve/kernels.py are policy-free):
 * **admission** — FIFO, gated on three budgets: batch slots
   (``max_batch``), KV blocks (the prompt plus one decode block of
   headroom must fit the pool *whole* — half-admitted sessions would
-  deadlock), and prefill backlog (``max_prefill_backlog`` tokens not
+  deadlock; where the model's layers form several cache groups, every
+  group's pool has to take the session, or none does), and prefill
+  backlog (``max_prefill_backlog`` tokens not
   yet ingested across admitted sessions — the queue-depth/token-budget
   backpressure that keeps time-to-first-token bounded under load:
   admitting a 30th long prompt helps nobody's SLO; a prompt longer than
@@ -40,8 +42,9 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
+from ..inference.rolling import window_retired_blocks
 from .pool import BlockPool, NULL_BLOCK, blocks_for, chain_key, chain_keys
 
 QUEUED, PREFILL, DECODE, DONE = "queued", "prefill", "decode", "done"
@@ -93,12 +96,15 @@ class Request:
 @dataclass
 class Session:
     """Scheduler-side state of one admitted request.  The KV state a
-    session owns is exactly ``table`` (physical block ids) plus
-    ``position`` (KV rows written) — no private cache buffer; the pool
-    holds the bytes."""
+    session owns is exactly ``tables`` (physical block ids, one table a
+    cache group; ``table`` is the first group's, the only one of most
+    models) plus ``position`` (KV rows written) — no private cache
+    buffer; the pools hold the bytes.  A table is in logical order,
+    entry ``i`` the block of positions ``[i*bs, (i+1)*bs)``; under a
+    window the entries before the band are NULL."""
     request: Request
     seq: int                               # admission order (preemption)
-    table: List[int] = field(default_factory=list)
+    tables: List[List[int]] = field(default_factory=lambda: [[]])
     position: int = 0                      # KV rows written so far
     state: str = PREFILL
     prefill_src: Tuple[int, ...] = ()      # tokens still to ingest
@@ -145,6 +151,14 @@ class Session:
         return self.request.rid
 
     @property
+    def table(self) -> List[int]:
+        return self.tables[0]
+
+    @table.setter
+    def table(self, ids: List[int]) -> None:
+        self.tables[0] = ids
+
+    @property
     def prefill_remaining(self) -> int:
         return len(self.prefill_src) - self.position
 
@@ -170,17 +184,37 @@ class Scheduler:
     ``next_prefill()``, ``decode_sessions()`` (+ ``grow()`` /
     ``preempt_for()`` when blocks run out), and ``finish()``."""
 
-    def __init__(self, pool: BlockPool, *, max_batch: int,
+    def __init__(self, pool, *, max_batch: int,
                  prefill_chunk: int, max_prefill_backlog: int,
                  max_positions: int, spec_tables: bool = False,
                  pos_slack: int = 0, prefix_cache: bool = True,
-                 cache_tag: str = "kv"):
+                 cache_tag: str = "kv",
+                 windows: Optional[Sequence[Optional[int]]] = None):
+        """``pool``: one :class:`BlockPool`, or one a cache group with
+        ``windows`` beside them (each group's window, None where its
+        layers read every key)."""
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {prefill_chunk}")
-        self.pool = pool
+        self.pools: List[BlockPool] = list(pool) \
+            if isinstance(pool, (list, tuple)) else [pool]
+        self.pool = self.pools[0]
+        self.windows = list(windows) if windows is not None \
+            else [None] * len(self.pools)
+        if len(self.windows) != len(self.pools):
+            raise ValueError("one window (or None) a pool")
+        if len(self.pools) > 1 and (spec_tables or prefix_cache):
+            raise ValueError(
+                "several cache groups: the prefix cache and draft tables "
+                "address one group's blocks; serve without them")
+        bs = self.pool.block_size
+        #: a window group's tables as packed: a ring this wide, enough
+        #: for the band, a prefill chunk after it and their two ends
+        self.ring = [None if w is None else bucket(
+            blocks_for(w + prefill_chunk, bs) + 2)
+            for w in self.windows]
         self.max_batch = max_batch
         self.prefill_chunk = prefill_chunk
         self.max_prefill_backlog = max_prefill_backlog
@@ -188,8 +222,9 @@ class Scheduler:
         # prefix cache: admission walks each request's token chain
         # through the pool's hash index and prefills only the cold
         # suffix.  cache_tag stamps the chain keys with everything KV
-        # bytes depend on besides tokens (dtype/block size/window/
-        # weight epoch) — the engine owns it and re-tags on publish.
+        # bytes depend on besides tokens (dtype/block size/the cache
+        # group's window/weight epoch) — the engine owns it and re-tags
+        # on publish.  One group: a prefix is one table's blocks.
         self.prefix_cache = bool(prefix_cache)
         self.cache_tag = cache_tag
         # speculative mode: every session also owns a draft block table
@@ -209,16 +244,20 @@ class Scheduler:
     def _reject_never_fit(self, request: Request) -> None:
         need = len(request.prompt) + request.max_new_tokens \
             + self.pos_slack
-        blocks_need = blocks_for(need, self.pool.block_size)
-        if self.spec_tables:
-            blocks_need *= 2               # target + draft tables
-        cap_blocks = self.pool.capacity
-        if need > self.max_positions or blocks_need > cap_blocks:
-            self.rejected.append(request.rid)
-            raise ValueError(
-                f"request {request.rid}: {need} positions exceed "
-                f"max_positions {self.max_positions} / pool capacity "
-                f"{cap_blocks * self.pool.block_size}")
+        bs = self.pool.block_size
+        for pool, window in zip(self.pools, self.windows):
+            # a window group holds the band and the chunk being written
+            held = need if window is None \
+                else min(need, window + self.prefill_chunk + bs)
+            blocks_need = blocks_for(held, bs)
+            if self.spec_tables:
+                blocks_need *= 2           # target + draft tables
+            if need > self.max_positions or blocks_need > pool.capacity:
+                self.rejected.append(request.rid)
+                raise ValueError(
+                    f"request {request.rid}: {need} positions exceed "
+                    f"max_positions {self.max_positions} / pool capacity "
+                    f"{pool.capacity * bs}")
 
     def submit(self, request: Request) -> None:
         """Queue a request (FIFO).  Requests that can NEVER fit — more
@@ -269,7 +308,11 @@ class Scheduler:
         engine dispatches the paged block-copy.  Recompute re-admission
         (preempted or shed sessions) takes the same path and typically
         re-acquires its own just-retired blocks from the cached tier —
-        preemption recovery without re-prefill."""
+        preemption recovery without re-prefill.  Under a window a hit
+        keeps only the adopted blocks the band still reaches and, like
+        any prompt there, is granted its cold blocks a chunk at a time
+        (:meth:`_first_grant`), so its table never outgrows the ring
+        :meth:`pack_tables` packs it into."""
         admitted = []
         while self.queue:
             s = self.queue[0]
@@ -280,7 +323,6 @@ class Scheduler:
             src = s.prefill_src if s.pending_tok is not None \
                 else s.request.prompt
             bs = self.pool.block_size
-            need_total = blocks_for(len(src) + 1, bs)
             shared: List[int] = []
             keys: List[str] = []
             if self.prefix_cache:
@@ -298,18 +340,33 @@ class Scheduler:
                     fork = True
             else:
                 pos0 = hit
+            # under a window the blocks of a cached prefix that lie
+            # wholly before the band are not kept: their entries are
+            # NULL from the start, as retirement would leave them
+            lo = window_retired_blocks(pos0, self.windows[0], bs)
+            self.pool.free(shared[:lo])
+            held = shared[lo:]
             # the budget is on tokens not yet ingested: a request
             # longer than all of it enters when nothing else is waiting
             # to be ingested (sessions that only decode are no backlog)
             backlog = self._backlog_tokens()
             if backlog and backlog + (len(src) - pos0) \
                     > self.max_prefill_backlog:
-                self.pool.free(shared)
+                self.pool.free(held)
                 break
+            need_total = self._first_grant(0, pos0, len(src) + 1)
             cold = need_total - len(shared) + (1 if fork else 0)
             ids = self.pool.alloc(cold)
             if ids is None:
-                self.pool.free(shared)
+                self.pool.free(held)
+                break
+            # every further cache group takes the session too, or none
+            # does (no prefix is shared there: several groups serve
+            # without the cache)
+            more = self._alloc_more(pos0, len(src) + 1)
+            if more is None:
+                self.pool.free(ids)
+                self.pool.free(held)
                 break
             draft_ids: List[int] = []
             if self.spec_tables:
@@ -321,17 +378,19 @@ class Scheduler:
                 draft_ids = self.pool.alloc(need_total)
                 if draft_ids is None:
                     self.pool.free(ids)
-                    self.pool.free(shared)
+                    self.pool.free(held)
                     break
             self.queue.popleft()
             s.seq = self._seq
             self._seq += 1
+            s.tables = [[]] + more
+            table = [NULL_BLOCK] * lo + held
             if fork:
-                fsrc, fdst = shared[-1], ids[0]
-                s.table = shared[:-1] + [fdst] + ids[1:]
-                s.cow_pending = [(len(shared) - 1, fsrc, fdst)]
+                fsrc, fdst = table[-1], ids[0]
+                s.table = table[:-1] + ids
+                s.cow_pending = [(len(table) - 1, fsrc, fdst)]
             else:
-                s.table = shared + ids
+                s.table = table + ids
                 s.cow_pending = []
             s.draft_table = draft_ids
             s.position = pos0
@@ -347,6 +406,32 @@ class Scheduler:
             self.sessions.append(s)
             admitted.append(s)
         return admitted
+
+    def _first_grant(self, group: int, pos0: int, n_positions: int) -> int:
+        """The length admission gives the table of ``group`` for a
+        session that starts at position ``pos0`` (0, or past a cached
+        prefix): all of ``n_positions`` where the group keeps every key,
+        through the first chunk where it keeps a window (the rest is
+        granted chunk by chunk, :meth:`grow`, as the blocks before the
+        band are retired: a table never spans more than the ring)."""
+        if self.windows[group] is not None:
+            n_positions = min(n_positions, pos0 + self.prefill_chunk)
+        return blocks_for(n_positions, self.pool.block_size)
+
+    def _alloc_more(self, pos0: int,
+                    n_positions: int) -> Optional[List[List[int]]]:
+        """The tables of the cache groups after the first.  None, and
+        nothing taken, unless every group can give its part."""
+        out: List[List[int]] = []
+        for g in range(1, len(self.pools)):
+            ids = self.pools[g].alloc(
+                self._first_grant(g, pos0, n_positions))
+            if ids is None:
+                for p, t in zip(self.pools[1:], out):
+                    p.free(t)
+                return None
+            out.append(ids)
+        return out
 
     def complete_cow(self, s: Session) -> int:
         """Release the shared source of every pending copy-on-write
@@ -405,18 +490,23 @@ class Scheduler:
 
     def grow(self, s: Session, n_positions: int,
              draft: bool = False) -> bool:
-        """Extend ``s.table`` (or ``s.draft_table``) to cover
-        ``n_positions`` KV rows; False if the pool is dry (caller
-        preempts and retries)."""
-        table = s.draft_table if draft else s.table
-        need = blocks_for(n_positions, self.pool.block_size) \
-            - len(table)
-        if need <= 0:
-            return True
-        ids = self.pool.alloc(need)
-        if ids is None:
-            return False
-        table.extend(ids)
+        """Extend every table of ``s`` (or its ``draft_table``) to cover
+        ``n_positions`` KV rows; False, and nothing taken, if a pool is
+        dry (caller preempts and retries)."""
+        tables = [s.draft_table] if draft else s.tables
+        want = blocks_for(n_positions, self.pool.block_size)
+        short = [(pool, table) for pool, table in zip(self.pools, tables)
+                 if len(table) < want]
+        got = []
+        for pool, table in short:
+            ids = pool.alloc(want - len(table))
+            if ids is None:
+                for p, t in got:
+                    p.free(t)
+                return False
+            got.append((pool, ids))
+        for (_, table), (_, ids) in zip(short, got):
+            table.extend(ids)
         return True
 
     def evict(self, victim: Session) -> Session:
@@ -433,12 +523,8 @@ class Scheduler:
         another engine with the same chain) usually re-adopts them —
         eviction stops costing the prefix its prefill."""
         self.complete_cow(victim)
-        self.pool.free(b for b in victim.table if b != NULL_BLOCK)
-        self.pool.free(b for b in victim.draft_table
-                       if b != NULL_BLOCK)
+        self._free_tables(victim)
         self.sessions.remove(victim)
-        victim.table = []
-        victim.draft_table = []
         victim.position = 0
         victim.draft_position = 0
         victim.hash_chain = []
@@ -471,51 +557,106 @@ class Scheduler:
         self.queue.appendleft(victim)
         return victim
 
+    def _free_tables(self, s: Session) -> None:
+        """Every group's blocks, and the draft table's, back to their
+        pools."""
+        for pool, table in zip(self.pools, s.tables):
+            pool.free(b for b in table if b != NULL_BLOCK)
+        self.pool.free(b for b in s.draft_table if b != NULL_BLOCK)
+        s.tables = [[] for _ in self.pools]
+        s.draft_table = []
+
     def finish(self, s: Session) -> None:
         self.complete_cow(s)
-        self.pool.free(b for b in s.table if b != NULL_BLOCK)
-        self.pool.free(b for b in s.draft_table if b != NULL_BLOCK)
-        s.table = []
-        s.draft_table = []
+        self._free_tables(s)
         s.state = DONE
         self.sessions.remove(s)
 
-    def retire_window_blocks(self, s: Session, window: int) -> int:
-        """Free the leading blocks of a sliding-window session that no
+    def retire_window_blocks(self, s: Session) -> int:
+        """Free the leading blocks of a window group's table that no
         future query's band can reach (rolling.py's closed form,
-        block-tabled).  Retired table entries become NULL — logical
-        indexing is positional, so the prefix stays, pointing at the
-        zero block the band mask already excludes.  Returns the number
-        of blocks returned to the pool."""
-        from ..inference.rolling import window_retired_blocks
-        n = window_retired_blocks(s.position, window,
-                                  self.pool.block_size)
-        freed = [b for b in s.table[:n] if b != NULL_BLOCK]
-        if freed:
-            self.pool.free(freed)
-            for i in range(n):
-                s.table[i] = NULL_BLOCK
-        return len(freed)
+        block-tabled), in every group that has a window.  Retired table
+        entries become NULL — a session's table stays in logical order,
+        so the prefix stays, pointing at the zero block the band mask
+        already excludes (and :meth:`pack_tables` leaves out).  Returns
+        the number of blocks returned to the pools."""
+        total = 0
+        for pool, window, table in zip(self.pools, self.windows, s.tables):
+            if window is None:
+                continue
+            n = window_retired_blocks(s.position, window, pool.block_size)
+            # entries before the last retirement's are NULL already
+            i = min(n, len(table))
+            freed = []
+            while i > 0 and table[i - 1] != NULL_BLOCK:
+                i -= 1
+                freed.append(table[i])
+                table[i] = NULL_BLOCK
+            if freed:
+                pool.free(freed)
+                total += len(freed)
+        return total
 
     # -- packing -----------------------------------------------------------
+
+    def pack_tables(self, sessions: List[Session], rows: int,
+                    group: int = 0):
+        """``(bucket_blocks, tables)`` of one cache group for a dispatch
+        of ``rows`` batch rows (the sessions, then all-null padding).  A
+        group that keeps every key packs each table whole, padded to the
+        next block bucket; a window group packs the blocks from the band
+        on as a ring of its fixed width, logical block ``i`` at entry
+        ``i mod width``."""
+        window, width = self.windows[group], self.ring[group]
+        tables = []
+        if window is None:
+            nb = bucket(max(len(s.tables[group]) for s in sessions))
+            for s in sessions:
+                t = s.tables[group]
+                tables.append(t + [NULL_BLOCK] * (nb - len(t)))
+        else:
+            nb = width
+            bs = self.pool.block_size
+            for s in sessions:
+                t = s.tables[group]
+                lo = min(window_retired_blocks(s.position, window, bs),
+                         len(t))
+                live, at = t[lo:], lo % nb
+                if len(live) > nb:
+                    raise RuntimeError(
+                        f"session {s.rid} holds {len(live)} blocks of a "
+                        f"window group whose ring is {nb} wide")
+                head = min(len(live), nb - at)
+                row = [NULL_BLOCK] * nb
+                row[at:at + head] = live[:head]
+                row[:len(live) - head] = live[head:]
+                tables.append(row)
+        tables += [[NULL_BLOCK] * nb] * (rows - len(sessions))
+        return nb, tables
 
     def pack_decode(self, sessions: List[Session]):
         """Bucketed operand arrays for one decode tick:
         ``(bucket_batch, bucket_blocks, tokens, positions, tables)``
         as host int32 lists — dead rows carry ``position = -1`` and
-        all-null tables (the kernels' drop encoding)."""
+        all-null tables (the kernels' drop encoding).  With several
+        cache groups ``bucket_blocks`` and ``tables`` are tuples, one of
+        each a group."""
         b = bucket(len(sessions), self.max_batch)
-        nb = bucket(max(len(s.table) for s in sessions))
-        tokens, positions, tables = [], [], []
-        for s in sessions:
-            tokens.append(s.pending_tok)
-            positions.append(s.position)
-            tables.append(s.table + [NULL_BLOCK] * (nb - len(s.table)))
-        for _ in range(b - len(sessions)):
-            tokens.append(0)
-            positions.append(-1)
-            tables.append([NULL_BLOCK] * nb)
+        tokens = [s.pending_tok for s in sessions] + [0] * (b - len(sessions))
+        positions = [s.position for s in sessions] \
+            + [-1] * (b - len(sessions))
+        nb, tables = self.pack_groups(sessions, b)
         return b, nb, tokens, positions, tables
+
+    def pack_groups(self, sessions: List[Session], rows: int):
+        """:meth:`pack_tables` of every cache group as the programs take
+        them: ``(bucket_blocks, tables)`` of the one group, or a tuple of
+        each where there are several."""
+        packed = [self.pack_tables(sessions, rows, g)
+                  for g in range(len(self.pools))]
+        if len(packed) == 1:
+            return packed[0]
+        return tuple(nb for nb, _ in packed), tuple(t for _, t in packed)
 
     def pack_spec(self, sessions: List[Session]):
         """Bucketed operands for one speculative tick:

@@ -466,3 +466,98 @@ def test_fused_train_step_compiles(chip):
         assert len(operands) == LAYERS, (kernel, operands)
         for ops in operands:
             assert ops[:tensors] == [qkv] * tensors, (kernel, ops)
+
+
+# -- the serve programs of the benchmark's window-and-full MoE cell ------------
+# (perfbench/configs/mellum2-12b-a2.5b-l8.json at its published widths:
+# 7.59 GB of bf16 weights as abstract parameters, a cache of two groups:
+# the 2 full layers' pool of 49153 blocks and the 6 window layers' pool,
+# sized by the engine, of 16 rows of 512 each).  PR 27's pin for every
+# group, and the grouped-query reader under its own name.
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """The cell's model with parameters that have shapes and no values,
+    and its engine settings."""
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    bench = os.path.join(here, "..", "perfbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from pb import cells
+    with open(os.path.join(bench, "configs",
+                           "mellum2-12b-a2.5b-l8.json")) as f:
+        cfg = json.load(f)
+    model = cells.family_module(cfg["builder"]).model(cfg)
+    model.eval()
+    return cfg, model
+
+
+@pytest.mark.parametrize("which,batch", [("decode", 128), ("prefill", 1)],
+                         ids=["decode_b128", "prefill_chunk512"])
+def test_mixed_serve_programs_take_every_pool_where_it_lies(chip, mixed,
+                                                            which, batch):
+    from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import blocks_for, init_pool_buffer
+    from apex_tpu.serve.scheduler import bucket
+    cfg, model = mixed
+    sv = cfg["serve"]
+    bs, chunk = sv["block_size"], sv["prefill_chunk"]
+    params = list(model.parameters())
+    vals = [_sds(p.shape, jnp.bfloat16, chip) for p in params]
+    groups, _ = serve_kernels.cache_groups(model)
+    assert [(g.rows, g.window, len(g.layers)) for g in groups] == \
+        [((2, 4, 128), None, 2), ((2, 4, 128), 1024, 6)]
+    # the sizes ServeEngine gives the two pools (serve/engine.py)
+    sizes = [sv["num_blocks"],
+             sv["max_batch"] * (blocks_for(1024, bs) + 2)
+             + blocks_for(chunk, bs) + 1]
+    assert sizes[1] == 8481
+    pools = tuple(_on(chip, jax.eval_shape(lambda g=g, n=n: init_pool_buffer(
+        len(g.layers), g.rows[1], g.rows[2], n, bs,
+        jnp.dtype(sv["cache_dtype"]), streams=g.rows[0])))
+        for g, n in zip(groups, sizes))
+    # the full group's tables at a whole context, the window group's ring
+    nb = (cfg["max_position_embeddings"] // bs,
+          bucket(blocks_for(1024 + chunk, bs) + 2))
+    assert nb == (384, 128)
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, chip)
+    build = {"decode": serve_kernels.build_decode_fn,
+             "prefill": serve_kernels.build_prefill_fn}[which]
+    fn = build(model, params, bs, sv["num_blocks"])
+    rows = batch if which == "decode" else 1
+    tables = tuple(i32(rows, bucket(n)) for n in nb)
+    args = (i32(batch), i32(batch), tables) if which == "decode" \
+        else (i32(1, chunk), tables, i32(), i32())
+    with force_mode("compiled"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            vals, pools, *args).compile()
+    ma = _check(compiled, 0)
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert ma.alias_size_in_bytes >= pool_bytes     # both updated in place
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"\n[{which}] arguments {ma.argument_size_in_bytes / 2**30:.3f} "
+          f"temporaries {ma.temp_size_in_bytes / 2**30:.3f} total "
+          f"{total / 2**30:.3f} GiB; pools {pool_bytes / 2**30:.3f}")
+    assert total < USABLE_BYTES, total / 2 ** 30
+    assert ma.temp_size_in_bytes < 2 ** 30, ma.temp_size_in_bytes / 2 ** 30
+    text = compiled.as_text()
+    small = min(pools, key=lambda p: p.size)
+    for op, ln in _pool_sized_results(compiled, small):
+        assert op in ("parameter", "bitcast", "scatter", "fusion",
+                      "tuple", "get-tuple-element"), ln[:300]
+        if op == "fusion":
+            assert " scatter(" in _called_computation(text, ln), ln[:300]
+    calls = _kernel_calls(compiled)
+    layers = len(model.blocks)
+    assert sum("routed_experts" in c for c in calls) == 2 * layers, calls
+    assert sum("paged_attention_decode" in c for c in calls) \
+        == (layers if which == "decode" else 0), calls
+    if which == "decode":
+        for pool in pools:      # nothing takes gather_kv's flat view
+            flat = pool.shape[0] * pool.shape[1] * pool.shape[2]
+            assert f"[{flat},{pool.shape[3]},{pool.shape[4]}]" not in text

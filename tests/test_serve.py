@@ -455,6 +455,74 @@ def test_windowed_engine_retires_blocks(model):
     eng.block_pool.check_no_leaks()
 
 
+def _windowed(model, **kw):
+    # window 8 over blocks of 4, chunks of 4: tables packed as rings of 8
+    kw = {"num_blocks": 32, "max_batch": 2, **kw}
+    return ServeEngine(model, block_size=4, prefill_chunk=4, window=8, **kw)
+
+
+@pytest.mark.parametrize("shared,length", [(4, 35), (20, 35), (36, 36)],
+                         ids=["one_block", "past_the_band", "whole_prompt"])
+def test_windowed_engine_takes_a_cached_prefix_longer_than_its_ring(
+        model, shared, length):
+    """A prompt of nine blocks whose head another request left in the
+    prefix cache, under a window whose ring is eight entries wide: the
+    hit is granted its blocks a chunk at a time like any other prompt,
+    the cached blocks before the band are not kept, and the answer is
+    what an engine without the cache gives."""
+    rng = np.random.default_rng(11)
+    first = [int(t) for t in rng.integers(1, 72, length)]
+    second = first[:shared] + [int(t) for t in
+                               rng.integers(1, 72, length - shared)]
+    plain = _windowed(model, prefix_cache=False)
+    want = plain.run([Request("b", second, 6)])["b"]
+    eng = _windowed(model)
+    assert eng.scheduler.ring == [8]
+    eng.run([Request("a", first, 6)])
+    out = eng.run([Request("b", second, 6)])["b"]
+    assert out == want
+    assert eng.metrics()["prefix_cache"]["prefill_tokens_saved"] \
+        == min(shared // 4 * 4, length - 1)
+    eng.close()
+    plain.close()
+
+
+@pytest.mark.parametrize("how", ["dry_pool", "cached"])
+def test_windowed_engine_preempts_and_readmits_a_long_session(model, how):
+    """Sessions deeper than the ring are preempted and come back: in a
+    pool that holds one band and a half by recompute (the cached tier
+    was evicted under the same pressure), in a roomy one through the
+    prefix cache, adopting the blocks they left behind — retired ones
+    among them, far more than a ring holds.  Either way the answers are
+    those of a run nobody interrupted."""
+    obs.get_registry().reset()
+    rng = np.random.default_rng(12)
+    reqs = [Request(f"p{i}", [int(t) for t in rng.integers(1, 72, 30 + i)],
+                    24) for i in range(2)]
+    roomy = _windowed(model)
+    want = roomy.run(reqs)
+    assert obs.counter("serve.preemptions").value == 0
+    roomy.close()
+    if how == "dry_pool":
+        eng = _windowed(model, num_blocks=6)
+        out = eng.run(reqs)
+        assert obs.counter("serve.preemptions").value > 0
+    else:
+        eng = _windowed(model)
+        for r in reqs:
+            eng.submit(r)
+        while not all(s.position > 44 for s in eng.scheduler.sessions) \
+                or len(eng.scheduler.sessions) < 2:
+            eng.step()
+        for s in list(eng.scheduler.sessions):
+            eng.scheduler.preempt_for(s)
+        out = eng.run([])
+        # each came back past its cached blocks, a whole ring and more
+        assert eng.metrics()["prefix_cache"]["prefill_tokens_saved"] >= 80
+    assert out == want
+    eng.close()
+
+
 def test_submit_rejects_never_fit_requests(model):
     eng = ServeEngine(model, num_blocks=4, block_size=4, max_batch=2,
                       prefill_chunk=4)

@@ -11,6 +11,7 @@ sampled tokens.
 import math
 import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -207,6 +208,7 @@ def test_the_engine_serves_it_through_submit_and_step(served):
     shared = _toks(7, 12, vocab)
     prompts = {"a": shared + _toks(8, 5, vocab),
                "b": shared + _toks(9, 9, vocab), "c": _toks(10, 6, vocab)}
+    began = time.perf_counter_ns()      # other engines' ticks lie before
     out = eng.run([Request(r, p, 6) for r, p in prompts.items()])
     for rid, p in prompts.items():
         seq = list(p)
@@ -217,7 +219,8 @@ def test_the_engine_serves_it_through_submit_and_step(served):
             seq.append(tok)
     assert eng.metrics()["prefix_cache"]["prefill_tokens_saved"] > 0
     steps = [e for e in observe.events("span")
-             if e.get("span") == "serve.step" and "moe_pairs" in e]
+             if e.get("span") == "serve.step" and "moe_pairs" in e
+             and e["t0_ns"] >= began]
     assert steps and all(
         e["moe_layers"] == 2 and e["moe_held"] == 2
         and e["moe_pairs_max"] <= e["moe_pairs"]
@@ -362,11 +365,47 @@ def _program_layer(cfg, leaves, x):
     return np.asarray(y), np.asarray(pairs)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+def _softmax_shares_add_up():
+    """The softmax branch of the same layer (``score="softmax"``: no
+    bias, no groups, no shared expert): 8 experts, 2 a token, against
+    the uncut module and the grouped-query family's reference."""
+    rng = np.random.default_rng(8)
+    leaves = {"router": rng.standard_normal((8, 64)) / 8,
+              "w_in": rng.standard_normal((8, 64, 32)) / 8,
+              "w_out": rng.standard_normal((8, 16, 64)) / 4}
+    leaves = {k: jnp.asarray(v, jnp.float32) for k, v in leaves.items()}
+    x = jnp.asarray(rng.standard_normal((40, 64)), jnp.float32)
+
+    def part(held):
+        mod = RoutedExperts(64, 16, 8, 2, experts_held=held, score="softmax")
+        assert mod.router_bias is None
+        mod.router.data = leaves["router"]
+        mod.w_in.data = leaves["w_in"][jnp.asarray(held)]
+        mod.w_out.data = leaves["w_out"][jnp.asarray(held)]
+        y, pairs = mod.forward(Ctx(training=False), x)
+        return np.asarray(y), int(np.asarray(pairs).sum())
+    whole, n = part(list(range(8)))
+    assert n == 40 * 2
+    shares = [part([e]) for e in range(8)]
+    assert sum(n for _, n in shares) == 40 * 2          # every pair, once
+    np.testing.assert_allclose(sum(y for y, _ in shares), whole, atol=2e-5)
+    ref = cells._module_from(os.path.join(
+        REPO, "perfbench", "pb", "reference_gqa_moe.py"), "reference")
+    cfg = {"num_experts": 8, "num_experts_per_tok": 2,
+           "norm_topk_prob": True, "moe_intermediate_size": 16}
+    want, _ = ref._experts(cfg, {"e." + k: v for k, v in leaves.items()},
+                           "e.", x, None)
+    np.testing.assert_allclose(whole, np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+def test_the_shares_add_up_to_the_uncut_layer(score):
     """Guide ``model-configs``, section 4: the routed parts that all 16
     shares give (one expert each here), with the shared expert counted
     once, add up to what the uncut reference gives for the whole
-    layer."""
+    layer; the softmax router's layer likewise."""
+    if score == "softmax":
+        return _softmax_shares_add_up()
     whole = _layer_cfg(range(16))
     leaves = FAMILY.draw(whole, jax.random.PRNGKey(5), jnp.float32)
     x = jnp.asarray(np.random.default_rng(6).standard_normal((40, 64)),
