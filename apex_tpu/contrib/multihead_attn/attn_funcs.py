@@ -121,6 +121,30 @@ def _flash_vjp_bwd(causal, scale, interpret, window, dropout_p, saved, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_packed(lin, head_dim, causal, scale, interpret):
+    """Flash attention on the QKV projection's output itself, (B, T,
+    3.H.D) -> (B, T, H.D), for the calls ``kernels.attention.packed_mode``
+    admits; ``lin``'s columns as ``flash_attention_packed_fwd`` reads
+    them (``kernels.attention.packed_row_order``)."""
+    return _k.flash_attention_packed_fwd(lin, head_dim, scale, causal,
+                                         interpret)[0]
+
+
+def _flash_packed_vjp_fwd(lin, head_dim, causal, scale, interpret):
+    ctx, lse = _k.flash_attention_packed_fwd(lin, head_dim, scale, causal,
+                                             interpret)
+    return ctx, (lin, ctx, lse)
+
+
+def _flash_packed_vjp_bwd(head_dim, causal, scale, interpret, saved, g):
+    return (_k.flash_attention_packed_bwd(*saved, g, head_dim, scale,
+                                          causal, interpret),)
+
+
+_flash_packed.defvjp(_flash_packed_vjp_fwd, _flash_packed_vjp_bwd)
+
+
 def flash_attention(q4, k4, v4, bias=None, causal=False, scale=None,
                     sliding_window=None, dropout_p=0.0, dropout_seed=None):
     """Fused scaled-dot-product attention, (B, H, S, D) layout.
@@ -245,6 +269,20 @@ def _attn_with_dropout(q3, k3, v3, bias, heads, scale, dropout_prob, key,
     return jnp.einsum("bts,bsd->btd", p, v3.astype(_f32)).astype(q3.dtype)
 
 
+def _out_projection(ctx, ow, output_biases, tensor_parallel_axis):
+    """The heads-major context (.., H.D) through the output projection."""
+    out = jnp.matmul(ctx, ow.T)
+    if tensor_parallel_axis is not None:
+        # the row-parallel reduction (Megatron g: psum fwd, identity
+        # bwd): one collective for the whole column→row attention pair;
+        # bias added once, after the reduction
+        from ...parallel.tensor_parallel import reduce_from_tp_region
+        out = reduce_from_tp_region(out, tensor_parallel_axis)
+    if output_biases is not None:
+        out = out + output_biases
+    return out
+
+
 def self_attn_func(use_time_mask, is_training, heads, scale, inputs,
                    input_weights, output_weights, input_biases=None,
                    output_biases=None, mask=None, dropout_prob=0.0,
@@ -294,11 +332,34 @@ def self_attn_func(use_time_mask, is_training, heads, scale, inputs,
         if ib is not None:
             ib = rows[1]
         e = heads * head_dim
+    dropout = dropout_prob if is_training else 0.0
+    packed = None
+    if use_flash and seq_parallel_axis is None:
+        # the rule of the packed kernels, from what the projection gives
+        # (kernels.attention.packed_mode)
+        packed = _k.packed_mode(
+            jax.ShapeDtypeStruct((b, t, iw.shape[0]),
+                                 jnp.result_type(inputs, iw)),
+            head_dim, mask, causal, dropout)
+    if packed is not None:
+        # the kernels read q, k, v where the projection writes them and
+        # write the context where the output projection reads it, their
+        # blocks every row of a sequence: both projections run batch-major
+        # (a (T, B, .) array is tiled over its last two axes, so a view of
+        # it a sequence at a time would be a copy), and XLA carries that
+        # layout through the block around them
+        lin = jnp.matmul(jnp.swapaxes(inputs, 0, 1),
+                         _k.packed_row_order(iw, head_dim).T)
+        if ib is not None:
+            lin = lin + _k.packed_row_order(ib, head_dim)
+        ctx = _flash_packed(lin, head_dim, causal, scale,
+                            packed == "interpret")
+        return jnp.swapaxes(_out_projection(
+            ctx, ow, output_biases, tensor_parallel_axis), 0, 1)
     lin = jnp.matmul(inputs, iw.T)
     if ib is not None:
         lin = lin + ib
     q3, k3, v3 = _split_interleaved_qkv(lin, t, b, heads, head_dim)
-    dropout = dropout_prob if is_training else 0.0
     if seq_parallel_axis is not None:
         from ...parallel.ring_attention import (ring_attention,
                                                 ulysses_attention)
@@ -383,16 +444,7 @@ def self_attn_func(use_time_mask, is_training, heads, scale, inputs,
         ctx3 = _attn_with_dropout(q3, k3, v3, bias, heads, scale, dropout,
                                   key, use_time_mask_causal=causal)
     ctx = jnp.swapaxes(ctx3, 0, 1).reshape(t, b, e)
-    out = jnp.matmul(ctx, ow.T)
-    if tensor_parallel_axis is not None:
-        # the row-parallel reduction (Megatron g: psum fwd, identity
-        # bwd): one collective for the whole column→row attention pair;
-        # bias added once, after the reduction
-        from ...parallel.tensor_parallel import reduce_from_tp_region
-        out = reduce_from_tp_region(out, tensor_parallel_axis)
-    if output_biases is not None:
-        out = out + output_biases
-    return out
+    return _out_projection(ctx, ow, output_biases, tensor_parallel_axis)
 
 
 def encdec_attn_func(use_time_mask, is_training, heads, scale, inputs_q,
@@ -453,8 +505,4 @@ def encdec_attn_func(use_time_mask, is_training, heads, scale, inputs_q,
         ctx3 = _attn_with_dropout(q3, k3, v3, bias, heads, scale, dropout,
                                   key)
     ctx = jnp.swapaxes(ctx3, 0, 1).reshape(tq, b, e)
-    out = jnp.matmul(ctx, ow.T)
-    if tensor_parallel_axis is not None:
-        from ...parallel.tensor_parallel import reduce_from_tp_region
-        out = reduce_from_tp_region(out, tensor_parallel_axis)
-    return out
+    return _out_projection(ctx, ow, None, tensor_parallel_axis)

@@ -32,6 +32,14 @@ kernels.  Two paths behind one pair of entries (``flash_attention_fwd`` /
   compute (PERF.md, PRs 30 and 32), which is what the tiled path cannot
   get around and this one does not meet.
 
+The resident kernels have a second entry, *packed*
+(``flash_attention_packed_fwd`` / ``_bwd``, ``packed_mode``'s rule), for a
+self-attention between its two projections: blocks are cut by ``BlockSpec``
+index maps out of the QKV projection's (B, T, 3.H.D) output itself — two
+64-wide heads a 128-lane row — and the context is written as the output
+projection reads it, so no (B.H, S, D) tensor is made.  Same kernel
+bodies, same walk, same arithmetic.
+
 Precision: the nine MXU products of a block pair (forward S, P·V; dq S, dP,
 dS·K; dkv S, Pᵀ·dO, dP, dSᵀ·Q; the resident path's seven are the same less
 the recomputed S and dP) take their operands in the dtype the tensors
@@ -60,7 +68,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import choose, register_kernel, tally
+from .dispatch import choose, pallas_mode, register_kernel, tally
 
 _f32 = jnp.float32
 _NEG = -1e30  # finite "-inf": keeps exp(s - m) well-defined in masked blocks
@@ -454,31 +462,37 @@ def _resident_plan(sq, sk, causal, window, keys):
     return bq, sq_p, sk_p, tuple(rows)
 
 
-def _resident_vmem_estimate(sq_p, sk_p, d, bq, widest):
+def _resident_vmem_estimate(sq_p, sk_p, d, bq, widest, heads=1):
     """Bytes the resident kernels hold in VMEM, the larger of the two.
     Backward: eight bf16 (S, D) blocks (q, k, v, out, dO, dq, dk, dv),
     each in two buffers and padded to 128 lanes, the fp32 dk and dv
     accumulators, and the four fp32 and two bf16 (keys, rows)
     intermediates of a product chain ``_BWD_KEYS`` wide.  Forward: four
     such blocks and the fp32 scores, fp32 and bf16 probabilities of the
-    ``widest`` extent a row block meets at once.  ``lse`` is a (1, S)
-    fp32 block on eight sublanes."""
+    ``widest`` extent a row block meets at once.  Where a block of width
+    ``d`` holds ``heads`` heads side by side (the packed entry) their
+    walks are unrolled one after the other and the compiler keeps every
+    head's intermediates, in the forward with one more fp32 copy of the
+    scores each (its own reading for two heads at S = 1792: 27 B a
+    score).  ``lse`` is a (1, S) fp32 block on eight sublanes."""
     lanes = _round_up(d, 128)
     rows_b, keys_b = 2 * 2 * sq_p * lanes, 2 * 2 * sk_p * lanes
     lse = 2 * 8 * sq_p * 4
-    fwd = 2 * rows_b + 2 * keys_b + lse + bq * widest * (4 + 4 + 2)
+    fwd = (2 * rows_b + 2 * keys_b + lse
+           + heads * bq * widest * (4 + 4 + 2 + (4 if heads > 1 else 0)))
     bwd = (4 * rows_b + 4 * keys_b + lse + 2 * sk_p * lanes * 4
-           + bq * min(widest, _BWD_KEYS) * (4 * 4 + 2 * 2))
+           + heads * bq * min(widest, _BWD_KEYS) * (4 * 4 + 2 * 2))
     return max(fwd, bwd)
 
 
 def _resident(tensors, sq, sk, d, bias, causal, window, dropout_p,
-              keys=None):
+              keys=None, heads=1):
     """The rule of the resident path: the walk (``_resident_plan``'s
     answer, its product chains ``keys`` wide; the rule does not depend on
-    ``keys``) where a call takes it, else ``None``.  Taken by bf16 operands with no bias and no dropout
-    whose whole sequence is at most ``_MAX_ROW_BLOCKS`` row blocks and
-    fits VMEM: ``_resident_vmem_estimate``, which counts both buffers of
+    ``keys``) where a call takes it, else ``None``.  Taken by bf16
+    operands with no bias and no dropout whose whole sequence is at most
+    ``_MAX_ROW_BLOCKS`` row blocks and fits VMEM:
+    ``_resident_vmem_estimate``, which counts both buffers of
     every block, within seven eighths of ``_VMEM_SCOPED`` (the rest is
     for what Mosaic allocates besides; the AOT tests hold both sides of
     the boundary against the compiler).  fp32 operands stay tiled (their
@@ -494,7 +508,7 @@ def _resident(tensors, sq, sk, d, bias, causal, window, dropout_p,
     # a row block's extent: the same however its runs are merged
     widest = max(sum(c1 - c0 for c0, c1, _ in segs) for _, _, segs in rows)
     if (len(rows) > _MAX_ROW_BLOCKS or _resident_vmem_estimate(
-            sq_p, sk_p, d, bq, widest) > _VMEM_SCOPED * 7 // 8):
+            sq_p, sk_p, d, bq, widest, heads) > _VMEM_SCOPED * 7 // 8):
         return None
     return plan
 
@@ -518,83 +532,115 @@ def _nt(a, b):
                                preferred_element_type=_f32)
 
 
-def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+def _head_lanes(x, h, d):
+    """``x`` with the lanes outside head ``h`` zeroed, where its lane row
+    holds several ``d``-wide heads side by side; ``x`` itself where one
+    head fills it.  A product that contracts the lanes then sums that
+    head's terms and exact zeros, one that keeps them leaves the other
+    heads' lanes zero, and no lane moves."""
+    if x.shape[-1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane >= h * d) & (lane < (h + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, scale,
                          causal, window, sk, rows):
-    """One head a grid step.  A row block meets its whole key extent at
-    once: scores, one max, one exp, one sum, P.V; no running statistics.
-    ``lse`` leaves as a lane-dense row."""
+    """A lane row of heads a grid step: q and out are (rows, L), k and v
+    (keys, L), ``lse`` (L // d, rows); one ``d``-wide head where L is
+    ``d``, else L // d of them side by side, taken one after the other
+    (``_head_lanes``).  A row block meets its whole key extent at once:
+    scores, one max, one exp, one sum, P.V; no running statistics.
+    ``lse`` leaves as a lane-dense row a head."""
     mxu = q_ref.dtype
     for r0, r1, segs in rows:
-        q = q_ref[0, r0:r1, :]
-        scores = []
-        for c0, c1, edge in segs:
-            s = _nt(q, k_ref[0, c0:c1, :]) * scale
-            if edge:
-                s = jnp.where(_keep(s.shape, r0, c0, sk, causal, window),
-                              s, _NEG)
-            scores.append(s)
-        m = functools.reduce(jnp.maximum, [
-            jnp.max(s, axis=1, keepdims=True) for s in scores])
-        l, acc = 0.0, 0.0
-        for s, (c0, c1, _) in zip(scores, segs):
-            p = jnp.exp(s - m)
-            l = l + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc + jax.lax.dot(p.astype(mxu), v_ref[0, c0:c1, :],
-                                    preferred_element_type=_f32)
-        o_ref[0, r0:r1, :] = (acc / l).astype(o_ref.dtype)
-        # (bq, 1) -> (1, bq): the column, spread over the lanes, transposed
-        lse = jnp.broadcast_to(m + jnp.log(l), (r1 - r0, 128))
-        lse_ref[0, :, r0:r1] = jnp.transpose(lse)[0:1, :]
+        q = q_ref[r0:r1, :]
+        outs = []
+        for h in range(q.shape[-1] // d):
+            qh = _head_lanes(q, h, d)
+            scores = []
+            for c0, c1, edge in segs:
+                s = _nt(qh, k_ref[c0:c1, :]) * scale
+                if edge:
+                    s = jnp.where(_keep(s.shape, r0, c0, sk, causal, window),
+                                  s, _NEG)
+                scores.append(s)
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(s, axis=1, keepdims=True) for s in scores])
+            l, acc = 0.0, 0.0
+            for s, (c0, c1, _) in zip(scores, segs):
+                p = jnp.exp(s - m)
+                l = l + jnp.sum(p, axis=1, keepdims=True)
+                acc = acc + jax.lax.dot(p.astype(mxu), v_ref[c0:c1, :],
+                                        preferred_element_type=_f32)
+            # acc holds P.V for every head's lanes of v: keep this head's
+            outs.append(_head_lanes(acc / l, h, d))
+            # (bq, 1) -> (1, bq): the column, spread over the lanes,
+            # transposed
+            lse = jnp.broadcast_to(m + jnp.log(l), (r1 - r0, 128))
+            lse_ref[h:h + 1, r0:r1] = jnp.transpose(lse)[0:1, :]
+        o_ref[r0:r1, :] = sum(outs[1:], outs[0]).astype(o_ref.dtype)
 
 
 def _resident_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                         dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
-                         causal, window, sk, rows):
-    """One head a grid step; S, P, dP and dS once for all three
-    gradients, seven products a block pair.  The scores are taken
-    transposed, (keys, rows): ``lse`` and ``delta`` are then lane-dense
-    rows, P^T.dO and dS^T.Q are plain products, and dS.K is the one that
-    contracts over its operands' rows.  dq of a row block is written
-    whole; dk and dv gather in fp32 scratch over the row blocks that see
-    their keys."""
+                         dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, d,
+                         scale, causal, window, sk, rows):
+    """A lane row of heads a grid step, the refs as the forward's; S, P,
+    dP and dS once for all three gradients, seven products a block pair.
+    The scores are taken transposed, (keys, rows): ``lse`` and ``delta``
+    are then lane-dense rows, P^T.dO and dS^T.Q are plain products, and
+    dS.K is the one that contracts over its operands' rows.  dq of a row
+    block is written whole; dk and dv gather in fp32 scratch over the
+    row blocks that see their keys (and over the heads of the lane row,
+    each into its own lanes)."""
     mxu = q_ref.dtype
     dk_scr[...] = jnp.zeros_like(dk_scr)
     dv_scr[...] = jnp.zeros_like(dv_scr)
     for r0, r1, segs in rows:
-        q = q_ref[0, r0:r1, :]
-        do = do_ref[0, r0:r1, :]
-        lse = lse_ref[0, :, r0:r1]
-        delta = jnp.sum(jnp.transpose(
-            do.astype(_f32) * o_ref[0, r0:r1, :].astype(_f32)),
-            axis=0, keepdims=True)
+        q = q_ref[r0:r1, :]
+        do = do_ref[r0:r1, :]
+        do_o = do.astype(_f32) * o_ref[r0:r1, :].astype(_f32)
         dq = 0.0
-        for c0, c1, edge in segs:
-            k = k_ref[0, c0:c1, :]
-            s = _nt(k, q) * scale
-            if edge:
-                s = jnp.where(_keep(s.shape, r0, c0, sk, causal, window,
-                                    rows_dim=1), s, _NEG)
-            p = jnp.exp(s - lse)
-            ds = (p * (_nt(v_ref[0, c0:c1, :], do) - delta)).astype(mxu)
-            dv_scr[c0:c1, :] += jax.lax.dot(p.astype(mxu), do,
-                                            preferred_element_type=_f32)
-            dk_scr[c0:c1, :] += jax.lax.dot(ds, q,
-                                            preferred_element_type=_f32)
-            dq = dq + jax.lax.dot_general(ds, k, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=_f32)
-        dq_ref[0, r0:r1, :] = (dq * scale).astype(dq_ref.dtype)
-    dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        for h in range(q.shape[-1] // d):
+            qh, doh = _head_lanes(q, h, d), _head_lanes(do, h, d)
+            lse = lse_ref[h:h + 1, r0:r1]
+            delta = jnp.sum(jnp.transpose(_head_lanes(do_o, h, d)),
+                            axis=0, keepdims=True)
+            for c0, c1, edge in segs:
+                k = k_ref[c0:c1, :]
+                s = _nt(k, qh) * scale
+                if edge:
+                    s = jnp.where(_keep(s.shape, r0, c0, sk, causal, window,
+                                        rows_dim=1), s, _NEG)
+                p = jnp.exp(s - lse)
+                ds = (p * (_nt(v_ref[c0:c1, :], doh) - delta)).astype(mxu)
+                dv_scr[c0:c1, :] += jax.lax.dot(p.astype(mxu), doh,
+                                                preferred_element_type=_f32)
+                dk_scr[c0:c1, :] += jax.lax.dot(ds, qh,
+                                                preferred_element_type=_f32)
+                dq = dq + jax.lax.dot_general(
+                    ds, _head_lanes(k, h, d), (((0,), (0,)), ((), ())),
+                    preferred_element_type=_f32)
+        dq_ref[r0:r1, :] = (dq * scale).astype(dq_ref.dtype)
+    # the keys' rows of the block: every row of a (keys, D) block, the
+    # first of a packed (rows, 128) one, whose rows past the keys belong
+    # to no key and are cut off by the caller
+    keys = dk_scr.shape[0]
+    dk_ref[0:keys, :] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0:keys, :] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _pad_rows(x, n, value=0):
-    """``x`` with its axis 1 padded to ``n`` (by nothing where it is)."""
-    return jnp.pad(x, ((0, 0), (0, n - x.shape[1])) + ((0, 0),) * (x.ndim - 2),
-                   constant_values=value)
+def _pad_rows(x, n, value=0, axis=1):
+    """``x`` with ``axis`` padded to ``n`` (by nothing where it is)."""
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, n - x.shape[axis])
+    return jnp.pad(x, pad, constant_values=value)
 
 
 def _head_spec(s, d):
-    return pl.BlockSpec((1, s, d), lambda b: (b, 0, 0))
+    """A head of a (B.H, S, D) array: its leading axis is squeezed."""
+    return pl.BlockSpec((None, s, d), lambda b: (b, 0, 0))
 
 
 # jitted: the layers of a model make the same call, and a jitted function
@@ -609,8 +655,8 @@ def _resident_fwd(q3, k3, v3, scale, causal, window, plan, interpret):
     sk = k3.shape[1]
     _, sq_p, sk_p, rows = plan
     out, lse = pl.pallas_call(
-        functools.partial(_resident_fwd_kernel, scale=scale, causal=causal,
-                          window=window, sk=sk, rows=rows),
+        functools.partial(_resident_fwd_kernel, d=d, scale=scale,
+                          causal=causal, window=window, sk=sk, rows=rows),
         grid=(bh,),
         in_specs=[_head_spec(sq_p, d), _head_spec(sk_p, d),
                   _head_spec(sk_p, d)],
@@ -633,8 +679,8 @@ def _resident_bwd(q3, k3, v3, out, lse, g, scale, causal, window, plan,
     # padded q rows: lse = +big keeps p = exp(s - lse) at 0 there
     lse = _pad_rows(lse, sq_p, -_NEG)[:, None, :]
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
-                          window=window, sk=sk, rows=rows),
+        functools.partial(_resident_bwd_kernel, d=d, scale=scale,
+                          causal=causal, window=window, sk=sk, rows=rows),
         grid=(bh,),
         in_specs=[rows_spec, keys_spec, keys_spec, rows_spec, rows_spec,
                   _head_spec(1, sq_p)],
@@ -648,6 +694,158 @@ def _resident_bwd(q3, k3, v3, out, lse, g, scale, causal, window, plan,
     )(_pad_rows(q3, sq_p), _pad_rows(k3, sk_p), _pad_rows(v3, sk_p),
       _pad_rows(out, sq_p), _pad_rows(g, sq_p), lse)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
+
+
+# ---------------------------------------------------------------------------
+# The packed entry: the resident kernels on the projection's own layout
+# ---------------------------------------------------------------------------
+
+_LANES = 128
+
+
+def _qkv_views(ref):
+    """The q, k and v lane rows of a (rows, 3 x 128) block."""
+    return [ref.at[:, i * _LANES:(i + 1) * _LANES] for i in range(3)]
+
+
+def _packed_fwd_kernel(qkv_ref, o_ref, lse_ref, **kw):
+    _resident_fwd_kernel(*_qkv_views(qkv_ref), o_ref, lse_ref, **kw)
+
+
+def _packed_bwd_kernel(qkv_ref, o_ref, do_ref, lse_ref, dqkv_ref, dk_scr,
+                       dv_scr, **kw):
+    _resident_bwd_kernel(*_qkv_views(qkv_ref), o_ref, do_ref, lse_ref,
+                         *_qkv_views(dqkv_ref), dk_scr, dv_scr, **kw)
+
+
+def _group_spec(s, width):
+    """Rows ``0:s`` of lane-row group ``g`` of batch row ``b`` of a (B,
+    S, n x width) array, the batch axis squeezed."""
+    return pl.BlockSpec((None, s, width), lambda b, g: (b, 0, g))
+
+
+def _lse_spec(heads, s):
+    return pl.BlockSpec((None, None, heads, s), lambda b, g: (b, g, 0, 0))
+
+
+_PACKED_STATIC = ("d", "scale", "causal", "plan", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_PACKED_STATIC)
+def _packed_fwd(lin, d, scale, causal, plan, interpret):
+    b, t, w = lin.shape
+    _, t_p, _, rows = plan
+    groups, heads = w // (3 * _LANES), _LANES // d
+    ctx, lse = pl.pallas_call(
+        functools.partial(_packed_fwd_kernel, d=d, scale=scale,
+                          causal=causal, window=None, sk=t, rows=rows),
+        grid=(b, groups),
+        in_specs=[_group_spec(t_p, 3 * _LANES)],
+        out_specs=[_group_spec(t_p, _LANES), _lse_spec(heads, t_p)],
+        out_shape=[jax.ShapeDtypeStruct((b, t_p, w // 3), lin.dtype),
+                   jax.ShapeDtypeStruct((b, groups, heads, t_p), _f32)],
+        interpret=interpret,
+        name="flash_attn_fwd",
+    )(_pad_rows(lin, t_p))
+    return ctx[:, :t], lse[..., :t]
+
+
+@functools.partial(jax.jit, static_argnames=_PACKED_STATIC)
+def _packed_bwd(lin, ctx, lse, g, d, scale, causal, plan, interpret):
+    b, t, w = lin.shape
+    _, t_p, sk_p, rows = plan
+    groups, heads = w // (3 * _LANES), _LANES // d
+    qkv_spec, rows_spec = (_group_spec(t_p, 3 * _LANES),
+                           _group_spec(t_p, _LANES))
+    dlin = pl.pallas_call(
+        functools.partial(_packed_bwd_kernel, d=d, scale=scale,
+                          causal=causal, window=None, sk=t, rows=rows),
+        grid=(b, groups),
+        in_specs=[qkv_spec, rows_spec, rows_spec, _lse_spec(heads, t_p)],
+        out_specs=qkv_spec,
+        out_shape=jax.ShapeDtypeStruct((b, t_p, w), lin.dtype),
+        scratch_shapes=[pltpu.VMEM((sk_p, _LANES), _f32)] * 2,
+        interpret=interpret,
+        name="flash_attn_bwd",
+    )(_pad_rows(lin, t_p), _pad_rows(ctx, t_p), _pad_rows(g, t_p),
+      # padded q rows: lse = +big keeps p = exp(s - lse) at 0 there
+      _pad_rows(lse, t_p, -_NEG, axis=3))
+    return dlin[:, :t]
+
+
+def _packed_plan(lin, d, causal, keys=None):
+    """The packed entry's walk, or ``None``: ``lin`` is (B, T, 3.H.D)
+    with whole lane rows of heads (D 128, or D 64 and an even H) and the
+    resident rule holds for a sequence of T rows that are its own keys.
+    The blocks are the resident path's by another cut, a 384-lane block
+    of q, k and v for three of 128 and the same again for their
+    gradients, so ``_resident_vmem_estimate`` counts them at 128 lanes,
+    with the intermediates of each head of a lane row."""
+    _, t, w = lin.shape
+    if d not in (64, _LANES) or w % (3 * _LANES):
+        return None
+    return _resident((lin,), t, t, _LANES, None, causal, None, 0.0, keys,
+                     heads=_LANES // d)
+
+
+def packed_mode(lin, d, bias, causal, dropout_p):
+    """The rule of the packed entry for a self-attention whose projection
+    gives ``lin`` (B, T, 3.H.D; an array or its shape and dtype): the
+    mode the kernels run in, or ``None`` for :func:`flash_attention_fwd`
+    on split heads, whose call then makes and counts its own choice.
+    Taken where :func:`kernel_mode` would take the flash kernel and the
+    resident rule holds for whole lane rows of heads (``_packed_plan``),
+    with no bias and no dropout; counted here as ``.pallas``, since no
+    other call asks for this attention."""
+    b, t, w = lin.shape
+    mode = pallas_mode()
+    if (mode is None or bias is not None or dropout_p > 0.0
+            or _packed_plan(lin, d, causal) is None
+            or (mode == "compiled"
+                and not _compiled_takes(b, w // (3 * d), t, t))):
+        return None
+    tally("flash_attention", "pallas")
+    return mode
+
+
+def packed_row_order(rows, d):
+    """Rows of a QKV projection (weight (3.H.D, E) or bias (3.H.D,))
+    from the stored ``[q_h, k_h, v_h]`` a head to the column order
+    :func:`flash_attention_packed_fwd` reads: at D 64 the two heads of a
+    pair side by side, ``[q_h q_h+1 | k_h k_h+1 | v_h v_h+1]``, each a
+    whole lane row; at D 128 the stored order is that already.  Taken at
+    trace time on the weight as the step holds it (its transpose lands
+    on the gradient); the parameter, its checkpoints and a
+    tensor-parallel row block keep the stored order."""
+    per = _LANES // d
+    if per == 1:
+        return rows
+    grouped = rows.reshape((-1, per, 3, d) + rows.shape[1:])
+    return jnp.swapaxes(grouped, 1, 2).reshape(rows.shape)
+
+
+def flash_attention_packed_fwd(lin, d, scale, causal, interpret=False):
+    """The resident forward on the QKV projection's own output, for the
+    shapes ``packed_mode`` admits.  ``lin`` is (B, T, 3.H.D), a row's
+    columns in groups of 3 x 128: a lane row of queries, one of keys, one
+    of values, each ``128 // d`` heads side by side (at D 128 the stored
+    ``[q_h, k_h, v_h]``; at D 64 ``[q_h q_h+1 | k_h k_h+1 | v_h v_h+1]``).
+    A grid step takes one group of one sequence as a (T, 384) block and
+    writes its heads' context as a (T, 128) block of (B, T, H.D): no
+    (B.H, S, D) tensor exists.  Returns (context, lse (B, groups,
+    128 // d, T) fp32)."""
+    tally("flash_attention", "resident")
+    tally("flash_attention", "packed")
+    return _packed_fwd(lin, d, scale, causal, _packed_plan(lin, d, causal),
+                       interpret)
+
+
+def flash_attention_packed_bwd(lin, ctx, lse, g, d, scale, causal,
+                               interpret=False):
+    """-> d ``lin`` (B, T, 3.H.D), each group's dq, dk and dv written
+    where the forward read q, k and v; ``g`` is d context, (B, T, H.D)."""
+    return _packed_bwd(lin, ctx, lse, g, d, scale, causal,
+                       _packed_plan(lin, d, causal, _BWD_KEYS), interpret)
 
 
 def _bias_spec(bias, bq, bk, for_dkv=False):

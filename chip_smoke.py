@@ -331,7 +331,7 @@ def phase_train(sz: Sizes, seed: int) -> None:
     ids = _ids(sz, seed, sz.batch)
     before = step_cache.kind_stats("train_step")
     flash = {tier: obs.counter(f"kernels.dispatch.flash_attention.{tier}")
-             for tier in ("pallas", "xla", "resident")}
+             for tier in ("pallas", "xla", "resident", "packed")}
     flash_before = {tier: c.value for tier, c in flash.items()}
     losses, times = [], []
     for _ in range(sz.train_steps):
@@ -366,17 +366,19 @@ def phase_train(sz: Sizes, seed: int) -> None:
     # the counters move where the flash rules are applied, at trace time:
     # their change across the step's one trace is the tier the step took,
     # and how many of its Pallas calls took the resident kernels (a whole
-    # bf16 sequence in VMEM; every one at these sizes)
+    # bf16 sequence in VMEM; every one at these sizes), entered packed on
+    # the projection's own output (whole lane rows of heads; every one)
     took = {tier: c.value - flash_before[tier] for tier, c in flash.items()}
     say(f"train: flash_attention tiers traced into the step: {took}")
     if not took["pallas"] or took["xla"]:
         raise AssertionError(
             f"train: the step's attention took {took}, expected the "
             f"Pallas tier alone")
-    if took["resident"] < took["pallas"]:
+    if min(took["resident"], took["packed"]) < took["pallas"]:
         raise AssertionError(
             f"train: {took['resident']} of the step's {took['pallas']} "
-            f"Pallas attention calls took the resident kernels")
+            f"Pallas attention calls took the resident kernels and "
+            f"{took['packed']} their packed entry")
 
 
 # ---------------------------------------------------------------------------

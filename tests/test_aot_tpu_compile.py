@@ -14,6 +14,7 @@ case steers it *in the test*: ``force_mode("compiled")`` for the kernel
 mode, ``donate_state=True`` / ``donate_argnums`` for donation.
 """
 import json
+import math
 import os
 import re
 
@@ -127,6 +128,50 @@ def test_flash_resident_boundary_compiles(chip, bh, seq, dim, causal,
             q, q, q).compile()
     _check(compiled, len(kernels))
     assert sorted(_kernel_names(compiled)) == kernels
+
+
+@pytest.mark.parametrize("t,b,heads,dim,causal,packed,kernels", [
+    (1024, 16, HEADS, HEAD_DIM, True, True, RESIDENT),  # the train cell's
+    (1536, 2, 12, 64, True, True, RESIDENT),    # the longest the rule admits:
+    (1536, 2, 12, 64, False, True, RESIDENT),   # pairs of heads (two heads'
+    (2048, 2, 8, 128, True, True, RESIDENT),    # scores at once), a head a
+    (2048, 2, 8, 128, False, True, RESIDENT),   # lane row, every extent whole
+    (600, 2, 4, 64, True, True, RESIDENT),      # rows padded to a row block
+    (1792, 2, 12, 64, True, False, RESIDENT),   # the first pairs past it
+    (2048, 2, 3, 64, True, False, RESIDENT),    # take the split entry, as
+                                                # an odd head does
+    (2304, 2, 2, 64, True, False, TILED),       # a ninth row block
+    (4096, 1, 2, 128, True, False, TILED),      # past every estimate
+], ids=["cell", "s1536_d64_pairs", "s1536_d64_pairs_whole", "s2048_d128",
+        "s2048_d128_whole", "s600_d64_padded", "s1792_d64_pairs",
+        "odd_heads", "s2304_d64", "s4096_d128"])
+def test_flash_packed_boundary_compiles(chip, t, b, heads, dim, causal,
+                                        packed, kernels):
+    """Both sides of the packed entry's rule, held by the v5e compiler:
+    what it admits compiles as the resident pair on (T, 384) blocks of
+    the projection's (B, T, 3.H.D) output, within the kernel's VMEM (the
+    estimate counts a 384-lane block for three of 128 lanes), and what
+    it does not takes the split entry's kernels on (B.H, T, D)."""
+    from apex_tpu.contrib.multihead_attn.attn_funcs import self_attn_func
+    e = heads * dim
+
+    def loss(x, iw, ow):
+        return jnp.sum(self_attn_func(
+            False, True, heads, dim ** -0.5, x, iw, ow, use_flash=True,
+            causal=causal).astype(jnp.float32))
+
+    with force_mode("compiled"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            _sds((t, b, e), jnp.bfloat16, chip),
+            _sds((3 * e, e), jnp.bfloat16, chip),
+            _sds((e, e), jnp.bfloat16, chip)).compile()
+    _check(compiled, len(kernels))
+    assert sorted(_kernel_names(compiled)) == kernels
+    # the forward's first operand, its rows padded to whole blocks
+    first = _kernel_operands(compiled, "flash_attn_fwd")[0][0]
+    lead, rows, width = map(int, re.findall(r"\d+", first)[1:])
+    assert rows >= t and (lead, width) == (
+        (b, 3 * e) if packed else (b * heads, dim)), first
 
 
 def test_flash_attention_with_bias_and_dropout_compiles(chip):
@@ -433,39 +478,108 @@ def test_latent_serve_programs_take_the_pool_where_it_lies(chip, latent,
         assert f"[{flat},{pool.shape[3]},{pool.shape[4]}]" not in text
 
 
-def test_fused_train_step_compiles(chip):
-    """The whole GPT-2-small fused step at 8 x 1024 — bf16, FusedAdam,
-    chunked LM-head loss, state donated — with the flash kernel in."""
+TRAIN_BATCH = 16
+
+
+@pytest.fixture(scope="module")
+def train_step(chip):
+    """The whole GPT-2-small fused step at the train cell's 16 x 1024 —
+    bf16, FusedAdam, chunked LM-head loss, no dropout and no attention
+    biases, state donated — with the flash kernels in, compiled once for
+    the cases below."""
     from apex_tpu.contrib.xentropy import make_chunked_lm_loss
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.training import make_train_step
 
-    model = _gpt2_small(attn_dropout=0.0, output_hidden=True)
+    model = _gpt2_small(dropout=0.0, attn_dropout=0.0, attn_bias=False,
+                        output_hidden=True)
     opt = FusedAdam(list(model.parameters()), lr=6e-4, weight_decay=0.1)
     step = make_train_step(
         model, opt, make_chunked_lm_loss(vocab_size=VOCAB, padding_idx=-1),
         half_dtype=jnp.bfloat16, loss_scale=1.0, donate_state=True)
-    ids = _sds((8, 1024), jnp.int32, chip)
-    with force_mode("compiled"):
-        compiled = jax.jit(step._raw_step_fn, donate_argnums=(0,)).lower(
+    ids = _sds((TRAIN_BATCH, 1024), jnp.int32, chip)
+    # the chip's own choice for every kernel, as ``pallas_mode`` makes it
+    # there: ``force_mode("compiled")`` would also turn the norm kernels
+    # on, which the chip leaves to XLA, and their custom calls pin the
+    # residual stream's layout (a copy of ``bf16[1024,16,768]`` at each)
+    with pytest.MonkeyPatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        return jax.jit(step._raw_step_fn, donate_argnums=(0,)).lower(
             _on(chip, step.state), ids, ids).compile()
-    # 12 layers x (forward + the one backward kernel): every attention
-    # of the step takes the resident path
-    _check(compiled, 2 * LAYERS)
+
+
+def test_fused_train_step_compiles(train_step):
+    """Every attention of the step takes the packed resident pair."""
+    # 12 layers x (forward + the one backward kernel)
+    _check(train_step, 2 * LAYERS)
     # the kernels go by their own names in the device trace: the custom
     # calls' instruction names come from ``pallas_call(name=...)``
-    calls = _kernel_names(compiled)
+    calls = _kernel_names(train_step)
     assert sorted({c for c in calls if "flash" in c}) == RESIDENT, calls
-    # q, k, v (and out, dO) reach the kernels as bf16 in the (B*H, S, D)
-    # layout: no upcast comes back in front of the MXU, and the layout
-    # stays the one ``flash_attn_roofline`` finds the kernels by
-    qkv = f"bf16[{8 * HEADS},1024,{HEAD_DIM}]"
-    for kernel, tensors in (("flash_attn_fwd", 3), ("flash_attn_bwd", 5)):
+    # the kernels read the QKV projection's output and write the context
+    # (and read dO, and write the projection's gradient) as bf16 in the
+    # batch-major layout of the projections themselves: no upcast comes
+    # back in front of the MXU, and no (B.H, S, D) tensor exists
+    lin = f"bf16[{TRAIN_BATCH},1024,{3 * HIDDEN}]"
+    ctx = f"bf16[{TRAIN_BATCH},1024,{HIDDEN}]"
+    lse = f"f32[{TRAIN_BATCH},{HIDDEN // 128},{128 // HEAD_DIM},1024]"
+    for kernel, tensors in (("flash_attn_fwd", [lin]),
+                            ("flash_attn_bwd", [lin, ctx, ctx, lse])):
         assert calls.count(kernel) == LAYERS, (kernel, calls)
-        operands = _kernel_operands(compiled, kernel)
+        operands = _kernel_operands(train_step, kernel)
         assert len(operands) == LAYERS, (kernel, operands)
         for ops in operands:
-            assert ops[:tensors] == [qkv] * tensors, (kernel, ops)
+            assert ops == tensors, (kernel, ops)
+    assert f"[{TRAIN_BATCH * HEADS},1024,{HEAD_DIM}]" not in \
+        train_step.as_text()
+
+
+def _layout_instructions(compiled):
+    """``(name, dtype, dims)`` of the entry computation's instructions
+    that only move data: ``copy``, ``reshape``, ``transpose`` and the
+    fusions XLA names after them or after ``pad``."""
+    text = compiled.as_text()
+    found = []
+    for ln in text[text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", ln)
+        if not m:
+            continue
+        name, dtype, dims, op = m.groups()
+        if op in ("copy", "reshape", "transpose") or (
+                op == "fusion"
+                and re.match(r"(copy|reshape|transpose|pad)", name)):
+            found.append((name, dtype, [int(d) for d in dims.split(",")
+                                        if d]))
+    return found
+
+
+def test_fused_train_step_moves_no_attention_layout(train_step):
+    """What ISSUE 34 rests on.  At the parent the step re-tiled the QKV
+    projection's output, cut q, k, v out of it and turned them to (B.H,
+    T, D), turned the context and dO back, and padded, added and twice
+    turned the projection's gradient: 9 instructions and 428 MB written
+    a layer, 108 instructions of ``bf16[1024,192,*]`` or
+    ``bf16[16384,2304]`` in the entry computation.  The packed kernels
+    read and write where the projections do, batch-major, and XLA
+    carries that layout through the block: no instruction of the entry
+    computation that only moves data writes a tensor as large as a
+    layer's context any more (the residual stream's twelve
+    ``f32[1024,16,768]`` copies went with them); what is left is the
+    embedding table's copy and the row order of ``in_proj_weight`` and
+    of its gradient."""
+    moves = _layout_instructions(train_step)
+    tokens = TRAIN_BATCH * 1024
+    big = [(name, dtype, dims) for name, dtype, dims in moves
+           if math.prod(dims) >= tokens * HIDDEN
+           and dims != [VOCAB, HIDDEN]]
+    assert not big, big
+    for name, dtype, dims in moves:
+        assert (dims[:2] != [1024, TRAIN_BATCH * HEADS]
+                and dims != [tokens, 3 * HIDDEN]), (name, dtype, dims)
+    written = sum(math.prod(dims) * (2 if dtype == "bf16" else 4)
+                  for _, dtype, dims in moves)
+    assert written < 0.6e9, f"{written / 1e9:.2f} GB (5.89 at the parent)"
 
 
 # -- the serve programs of the benchmark's window-and-full MoE cell ------------

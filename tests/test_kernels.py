@@ -531,6 +531,221 @@ def test_flash_resident_rule(case, resident):
                                                before["xla"])
 
 
+# --- the packed entry: the resident kernels on the projection's layout -------
+
+
+def _packed_count():
+    from apex_tpu.observe import registry as obs
+    return obs.counter("kernels.dispatch.flash_attention.packed").value
+
+
+def _attn_operands(rng, t, b, heads, d, dtype=jnp.bfloat16, in_bias=False):
+    e = heads * d
+    x, g = (jnp.asarray(rng.standard_normal((t, b, e)), dtype)
+            for _ in range(2))
+    iw = jnp.asarray(rng.standard_normal((3 * e, e)) / np.sqrt(e), dtype)
+    ow = jnp.asarray(rng.standard_normal((e, e)) / np.sqrt(e), dtype)
+    ib = (jnp.asarray(rng.standard_normal((3 * e,)), dtype) if in_bias
+          else None)
+    return x, iw, ow, ib, g
+
+
+def _split_path(heads, scale, x, iw, ow, ib=None, mask=None, causal=False,
+                dropout_p=0.0, key=None, attend=None):
+    """``self_attn_func(use_flash=True)`` as it was before the packed
+    entry, from the public pieces: the projection, the interleaved split
+    to (B.H, T, D), ``flash_attention`` on (B, H, T, D) (or ``attend``
+    in its place), the heads-major context back to (T, B, H.D), the
+    output projection.  ``heads`` are those of ``iw``'s rows."""
+    from apex_tpu.contrib.multihead_attn import attn_funcs as af
+    t, b, _ = x.shape
+    d = iw.shape[0] // (3 * heads)
+    lin = jnp.matmul(x, iw.T)
+    if ib is not None:
+        lin = lin + ib
+    q4, k4, v4 = (a.reshape(b, heads, t, d) for a in
+                  af._split_interleaved_qkv(lin, t, b, heads, d))
+    bias = af._masks_to_bias(mask, False, b, heads, t, t)
+    seed = af._dropout_seed(key) if dropout_p > 0.0 else None
+    if attend is None:
+        attend = functools.partial(flash_attention, bias=bias,
+                                   dropout_p=dropout_p, dropout_seed=seed)
+    ctx4 = attend(q4, k4, v4, causal=causal, scale=scale)
+    ctx = jnp.swapaxes(ctx4.reshape(b * heads, t, d), 0, 1)
+    return jnp.matmul(ctx.reshape(t, b, heads * d), ow.T)
+
+
+def _with_grads(fn, g, *args):
+    out, vjp = jax.vjp(fn, *args)
+    return (out,) + vjp(g)
+
+
+@pytest.mark.parametrize("t,b,heads,d,causal,in_bias", [
+    (1024, 1, 12, 64, True, False),     # the train cell's layer, one row
+    (1024, 1, 2, 64, False, False),
+    (328, 2, 2, 64, True, True),        # T no multiple of 128: padded rows
+    (600, 1, 4, 64, False, False),
+    (1024, 1, 1, 128, True, False),     # a head a lane row: stored order
+    (200, 2, 2, 128, False, True),
+], ids=["d64_h12_s1024_causal", "d64_h2_s1024", "d64_ragged_causal_bias",
+        "d64_h4_ragged", "d128_s1024_causal", "d128_ragged_bias"])
+def test_flash_packed_against_the_split_entry(rng, t, b, heads, d, causal,
+                                              in_bias):
+    """Where its rule holds, ``self_attn_func`` hands the QKV projection's
+    output to the resident kernels as it lies and takes their context as
+    the output projection reads it: one forward and one backward call,
+    no (B.H, T, D) operand, and the output and every gradient (to the
+    inputs, ``in_proj_weight`` in its stored row order, its bias, the
+    output projection) agree with the split (B, H, T, D) entry far
+    inside bf16's rounding."""
+    from apex_tpu.contrib.multihead_attn import self_attn_func
+    x, iw, ow, ib, g = _attn_operands(rng, t, b, heads, d, in_bias=in_bias)
+    scale = d ** -0.5
+    args = (x, iw, ow) + ((ib,) if in_bias else ())
+
+    def packed(x, iw, ow, ib=None):
+        return self_attn_func(False, True, heads, scale, x, iw, ow, ib,
+                              use_flash=True, causal=causal)
+
+    def split(x, iw, ow, ib=None):
+        return _split_path(heads, scale, x, iw, ow, ib, causal=causal)
+
+    with force_mode("interpret"):
+        before = (_packed_count(), _flash_paths())
+        bodies = _kernel_bodies(lambda *a: _with_grads(packed, g, *a), *args)
+        after = (_packed_count(), _flash_paths())
+        got = jax.jit(lambda *a: _with_grads(packed, g, *a))(*args)
+        want = jax.jit(lambda *a: _with_grads(split, g, *a))(*args)
+    assert sorted(bodies) == ["flash_attn_bwd", "flash_attn_fwd"]
+    # counted once a forward trace, as a Pallas and a resident call too
+    assert after[0] - before[0] == 1
+    for tier, n in (("pallas", 1), ("resident", 1), ("xla", 0)):
+        assert after[1][tier] - before[1][tier] == n, tier
+    # the kernels' blocks are whole lane rows of (B, T, .) arrays
+    for name, body in bodies.items():
+        shapes = [v.aval.shape for v in body.invars]
+        assert (t_p := shapes[0][0]) >= t and shapes[0][1] == 384, shapes
+        assert all(sh[-1] in (128, 384, t_p) for sh in shapes), (name,
+                                                                 shapes)
+    for name, a, w in zip(("out", "dx", "diw", "dow", "dib"), got, want):
+        assert a.shape == w.shape and a.dtype == jnp.bfloat16, name
+        # the bias gradient is a bf16 sum over T x B rows, which the two
+        # paths hold in another order: its own rounding, not the kernels'
+        tol = 4 * BF16_L2_TOL if name == "dib" else BF16_L2_TOL / 10
+        assert _l2_gap(a, w) < tol, (name, _l2_gap(a, w))
+
+
+def _sharded(axis, n, fn, *specs):
+    mesh = Mesh(np.array(jax.devices()[:n]), (axis,))
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs[:-1],
+                         out_specs=specs[-1], check_vma=False)
+
+
+PACKED_RULE_CASES = {
+    # name: (heads, d, what rides along, packed)
+    "even_heads_d64": (2, 64, {}, True),
+    "three_heads_d64": (3, 64, {}, False),
+    "head_width_32": (4, 32, {}, False),
+    "fp32_operands": (2, 64, {"dtype": jnp.float32}, False),
+    "a_mask": (2, 64, {"mask": True}, False),
+    "dropout": (2, 64, {"dropout_p": 0.1}, False),
+    "sequence_parallel_axis": (2, 64, {"sp": 2}, False),
+    "tp_even_local_heads": (4, 64, {"tp": 2}, True),
+    "tp_odd_local_heads": (6, 64, {"tp": 2}, False),
+}
+
+
+@pytest.mark.parametrize("heads,d,rides,packed",
+                         list(PACKED_RULE_CASES.values()),
+                         ids=list(PACKED_RULE_CASES))
+def test_flash_packed_rule(rng, heads, d, rides, packed):
+    """The packed entry's rule is read off the call: bf16, whole lane
+    rows of *local* heads (D 128, or D 64 and an even count), no mask, no
+    dropout, no sequence-parallel axis.  Every other call takes the
+    split path as before: ``.packed`` does not count, and the output and
+    the gradients to the inputs and both weights are those of the split
+    composition, written out here from the public pieces, to the bit.
+    Where the rule holds on a tensor-parallel head shard, the gradient
+    comes back through the lane-row order of the *local* row block to
+    the full weight in its stored order, within bf16's rounding."""
+    from apex_tpu.contrib.multihead_attn import self_attn_func
+    t, b = 256, 2
+    x, iw, ow, _, _ = _attn_operands(rng, t, b, heads, d,
+                                     rides.get("dtype", jnp.bfloat16))
+    mask = (jnp.arange(t)[None, :] >= jnp.asarray([[t], [t - 56]])
+            if rides.get("mask") else None)
+    dropout_p = rides.get("dropout_p", 0.0)
+    key = jax.random.PRNGKey(5) if dropout_p else None
+    scale = d ** -0.5
+
+    def attn(x, iw, ow, **axes):
+        return self_attn_func(False, True, heads, scale, x, iw, ow,
+                              mask=mask, dropout_prob=dropout_p, key=key,
+                              use_flash=True, causal=mask is None, **axes)
+
+    def split(x, iw, ow):
+        return _split_path(heads, scale, x, iw, ow, mask=mask,
+                           causal=mask is None, dropout_p=dropout_p, key=key)
+
+    if "sp" in rides:
+        # the split composition on a time shard, written out: the
+        # projections local, the attention on the ring
+        def split_sp(x, iw, ow):
+            return _split_path(heads, scale, x, iw, ow, causal=True,
+                               attend=functools.partial(ring_attention,
+                                                        axis_name="sp"))
+        specs = (P("sp"), P(), P(), P("sp"))
+        fn = _sharded("sp", rides["sp"], functools.partial(
+            attn, seq_parallel_axis="sp"), *specs)
+        split = _sharded("sp", rides["sp"], split_sp, *specs)
+    elif "tp" in rides:
+        # the split composition on a head shard: the layer's entry
+        # protocol (f on the stream, a row block of in_proj, a column
+        # block of out_proj), the split path on the local heads, g
+        from apex_tpu.parallel.tensor_parallel import (
+            reduce_from_tp_region, tp_attn_begin)
+
+        def split_tp(x, iw, ow):
+            (x,), local, (iw,), (ow,) = tp_attn_begin("tp", heads, [x],
+                                                      [iw], [ow])
+            return reduce_from_tp_region(_split_path(
+                local, scale, x, iw, ow, causal=True), "tp")
+        specs = (P(), P(), P(), P())
+        fn = _sharded("tp", rides["tp"], functools.partial(
+            attn, tensor_parallel_axis="tp"), *specs)
+        split = _sharded("tp", rides["tp"], split_tp, *specs)
+    else:
+        fn = attn
+
+    g = jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+    with force_mode("interpret"):
+        before = _packed_count()
+        got = jax.jit(lambda *a: _with_grads(fn, g, *a))(x, iw, ow)
+        assert _packed_count() - before == int(packed)
+        want = jax.jit(lambda *a: _with_grads(split, g, *a))(x, iw, ow)
+    for name, a, w in zip(("out", "dx", "diw", "dow"), got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype, name
+        if packed:
+            assert _l2_gap(a, w) < BF16_L2_TOL / 10, (name, _l2_gap(a, w))
+        else:
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(w, np.float32), name)
+    if "tp" in rides:
+        # and the head shards together are the unsharded layer: every row
+        # of the full weight's gradient lands where the weight stores it
+        with force_mode("interpret"):
+            whole = jax.jit(lambda *a: _with_grads(
+                lambda x, iw, ow: _split_path(heads, scale, x, iw, ow,
+                                              causal=True), g, *a))(x, iw, ow)
+        for name, a, w in zip(("out", "dx", "diw", "dow"), got, whole):
+            if name in ("diw", "dow"):
+                # with its checks off shard_map hands a replicated operand
+                # the mean over the axis of its shards' cotangents, and
+                # each shard's is its own block with zeros beside it
+                a = a.astype(jnp.float32) * rides["tp"]
+            assert _l2_gap(a, w) < BF16_L2_TOL, (name, _l2_gap(a, w))
+
+
 def _tier_counts(kernel):
     from apex_tpu.observe import registry as obs
     return {t: obs.counter(f"kernels.dispatch.{kernel}.{t}").value
