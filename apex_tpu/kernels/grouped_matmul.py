@@ -9,8 +9,9 @@ moving anything.
 
 * :func:`tile_layout` — from each pair's group to the rows' layout.
 * :func:`grouped_matmul` — ``lhs (M, K)`` x ``rhs (G, K, N)`` by that
-  layout: the registered ``routed_experts`` kernel (its name in a device
-  trace), with ``jax.lax.ragged_dot`` as its XLA tier.
+  layout (or ``rhs (G, N, K)``, each matrix kept the other way round):
+  the registered ``routed_experts`` kernel (its name in a device trace),
+  with ``jax.lax.ragged_dot`` as its XLA tier.
 """
 from __future__ import annotations
 
@@ -31,6 +32,9 @@ _f32 = jnp.float32
 TILE_ROWS = 128
 #: the widest K and N tile (2 MiB of bf16 a block, two in flight)
 _TILE_KN_MAX = 1024
+#: the widest width taken whole where no tile divides it (a block of
+#: 1856 x 896 bf16 is 3.2 MiB, two in flight)
+_WHOLE_MAX = 2048
 
 
 class TileLayout(NamedTuple):
@@ -82,15 +86,20 @@ def tile_layout(group, n_groups: int, max_rows: int, tile: int) -> TileLayout:
 
 def _tile_of(n: int):
     """The widest tile of whole lane rows that divides ``n``: 1024 of
-    7168 or 4096, 768 of 2304, 896 of 1792 (a grid step costs about the
-    same whatever it moves, so powers of two alone would stream a
-    2304 x 1792 matrix in 63 blocks of 128 KiB where 6 of 1.3 MiB do)."""
+    7168 or 4096, 768 of 2304, 896 of 1792, 896 or 2688 (a grid step
+    costs about the same whatever it moves, so powers of two alone would
+    stream a 2304 x 1792 matrix in 63 blocks of 128 KiB where 6 of 1.3
+    MiB do).  Where none divides it (1856 is 14.5 lane rows) the whole
+    width is one block, which is legal whatever its lane count since it
+    is as wide as its array, if it is more than a lane row, whole
+    sublane tiles, and no wider than :data:`_WHOLE_MAX`."""
+    whole = n % 8 == 0 and 128 < n <= _WHOLE_MAX
     return next((t for t in range(_TILE_KN_MAX, 0, -128) if n % t == 0),
-                None)
+                n if whole else None)
 
 
 def _gmm_kernel(tile_group_ref, n_active_ref, lhs_ref, rhs_ref, out_ref,
-                acc_ref, *, nk):
+                acc_ref, *, nk, transposed):
     i, k = pl.program_id(0), pl.program_id(2)
     active = i < n_active_ref[0]
 
@@ -100,18 +109,25 @@ def _gmm_kernel(tile_group_ref, n_active_ref, lhs_ref, rhs_ref, out_ref,
 
     @pl.when(active)
     def _():
-        acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
-                                preferred_element_type=_f32)
+        if transposed:      # rhs block (tn, tk): both contracted on K
+            acc_ref[...] += jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=_f32)
+        else:
+            acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+                                    preferred_element_type=_f32)
 
     @pl.when(active & (k == nk - 1))
     def _():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _gmm_call(tile_group, n_active, lhs, rhs, *, tile, interpret):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret",
+                                             "transposed"))
+def _gmm_call(tile_group, n_active, lhs, rhs, *, tile, interpret,
+              transposed=False):
     m, kdim = lhs.shape
-    _, _, n = rhs.shape
+    n = rhs.shape[1 if transposed else 2]
     tk, tn = _tile_of(kdim), _tile_of(n)
     nj, nk = n // tn, kdim // tk
 
@@ -128,27 +144,40 @@ def _gmm_call(tile_group, n_active, lhs, rhs, *, tile, interpret):
 
     def rhs_at(i, j, k, tg, na):
         ic, jc, kc = at(i, j, k, na)
-        return tg[ic], kc, jc
+        return (tg[ic], jc, kc) if transposed else (tg[ic], kc, jc)
 
     def out_at(i, j, k, tg, na):
         ic, jc, _ = at(i, j, k, na)
         return ic, jc
 
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, nk=nk),
+        functools.partial(_gmm_kernel, nk=nk, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(m // tile, nj, nk),
             in_specs=[pl.BlockSpec((tile, tk), lhs_at),
-                      pl.BlockSpec((None, tk, tn), rhs_at)],
+                      pl.BlockSpec((None, tn, tk) if transposed
+                                   else (None, tk, tn), rhs_at)],
             out_specs=pl.BlockSpec((tile, tn), out_at),
             scratch_shapes=[pltpu.VMEM((tile, tn), _f32)]),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            **_vmem(tile, tk, tn, lhs.dtype)),
         interpret=interpret,
         name="routed_experts",
     )(tile_group, n_active.reshape(1), lhs, rhs)
+
+
+def _vmem(tile, tk, tn, dtype) -> dict:
+    """The scoped-VMEM limit where a block is a whole width past the
+    widest tile: both operands' and the result's blocks two in flight,
+    the accumulator, and as much again for the compiler's own.  The
+    tiled widths keep the default limit (and their program)."""
+    size = jnp.dtype(dtype).itemsize
+    need = 2 * size * (tile * tk + tk * tn + tile * tn) + 4 * tile * tn
+    return {"vmem_limit_bytes": max(32 << 20, 2 * need)} \
+        if max(tk, tn) > _TILE_KN_MAX else {}
 
 
 def takes_tiles(kdim: int, n: int, dtype) -> bool:
@@ -159,26 +188,31 @@ def takes_tiles(kdim: int, n: int, dtype) -> bool:
         and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(_f32))
 
 
-def grouped_matmul(lhs, rhs, layout: TileLayout):
+def grouped_matmul(lhs, rhs, layout: TileLayout, transposed=False):
     """``lhs (M, K)`` rows laid out by ``layout`` times ``rhs (G, K, N)``
-    -> ``(M, N)``: each row with its own group's matrix.  Rows no pair
-    holds come back undefined in the Pallas tier and zero in the XLA
-    tier: the caller selects by ``layout.row_of_pair``."""
-    mode = kernel_mode(lhs, rhs, layout.tile)
+    -> ``(M, N)``: each row with its own group's matrix.  ``transposed``:
+    ``rhs`` is ``(G, N, K)``, each matrix ``(out, in)``, and is read
+    where it lies (both operands contracted on their minor dimension).
+    Rows no pair holds come back undefined in the Pallas tier and zero
+    in the XLA tier: the caller selects by ``layout.row_of_pair``."""
+    mode = kernel_mode(lhs, rhs, layout.tile, transposed)
     if mode is not None:
         return _gmm_call(layout.tile_group, layout.n_active, lhs,
                          rhs.astype(lhs.dtype), tile=layout.tile,
-                         interpret=mode == "interpret")
+                         interpret=mode == "interpret",
+                         transposed=transposed)
+    if transposed:
+        rhs = jnp.swapaxes(rhs, 1, 2)
     return _gmm_xla(lhs, rhs, layout.padded)
 
 
-def kernel_mode(lhs, rhs, tile):
+def kernel_mode(lhs, rhs, tile, transposed=False):
     """The rule: the mode the kernel runs in, or ``None`` for the XLA
     tier.  The kernel streams each used expert's matrix once and skips
     the unused tiles, so it is taken wherever the rows were laid out for
     it and its tiles fit (PERF.md section 6, PR 29)."""
     return choose("routed_experts", fits=tile == TILE_ROWS and takes_tiles(
-        lhs.shape[1], rhs.shape[2], lhs.dtype))
+        lhs.shape[1], rhs.shape[1 if transposed else 2], lhs.dtype))
 
 
 def _gmm_xla(lhs, rhs, padded):

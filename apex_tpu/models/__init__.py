@@ -11,6 +11,9 @@ from .latent_moe import (  # noqa: F401
     LatentAttention, LatentMoeBlock, LatentMoeModel, yarn_tables)
 from .gqa_moe import (  # noqa: F401
     GqaAttention, GqaMoeBlock, GqaMoeModel)
+from .hybrid_ssm_moe import (  # noqa: F401
+    HybridAttnBlock, HybridExpertBlock, HybridMambaBlock, HybridSsmMoeModel,
+    MambaMixer)
 from .vit import VitBlock, VitModel, vit_base, vit_small  # noqa: F401
 from .hf import (gpt2_from_hf, gpt2_to_hf_state_dict,  # noqa: F401
                  llama_from_hf, llama_to_hf_state_dict,

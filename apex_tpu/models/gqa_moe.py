@@ -54,7 +54,8 @@ class GqaAttention(nn.Module):
     """``window``: the keys a query reads (None: all).  ``rope``: the
     layer's rotary parameters, ``{"rope_theta": ...}`` for plain RoPE or
     the YaRN block (``factor``, ``original_max_position_embeddings``,
-    ``beta_fast``, ``beta_slow`` beside it)."""
+    ``beta_fast``, ``beta_slow`` beside it); None: nothing is rotated
+    (a model whose other layers carry the order)."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim, window, rope, init):
         super().__init__()
@@ -63,7 +64,7 @@ class GqaAttention(nn.Module):
                              f"{kv_heads} stored heads evenly")
         self.heads, self.kv_heads, self.head_dim = heads, kv_heads, head_dim
         self.window = window
-        self.rope = dict(rope)
+        self.rope = None if rope is None else dict(rope)
         self.scaling = head_dim ** -0.5
         self.q = init((hidden, heads * head_dim), hidden)
         self.k = init((hidden, kv_heads * head_dim), hidden)
@@ -81,11 +82,14 @@ class GqaAttention(nn.Module):
         and ``k (B, S, KV, D)`` rotated, ``v (B, S, KV, D)``; float32."""
         b, s, _ = h.shape
         d = self.head_dim
-        cos, sin = self.tables(jnp.clip(positions, 0))
-        cos, sin = cos[:, :, None], sin[:, :, None]
+        if self.rope is not None:
+            cos, sin = self.tables(jnp.clip(positions, 0))
+            cos, sin = cos[:, :, None], sin[:, :, None]
         q = _mm(h, ctx.value(self.q)).reshape(b, s, self.heads, d)
         k = _mm(h, ctx.value(self.k)).reshape(b, s, self.kv_heads, d)
         v = _mm(h, ctx.value(self.v)).reshape(b, s, self.kv_heads, d)
+        if self.rope is None:
+            return q, k, v
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
     def forward(self, ctx, h, positions):
@@ -98,31 +102,10 @@ class GqaAttention(nn.Module):
         return _mm(o, ctx.value(self.o))
 
 
-class GqaMoeBlock(nn.Module):
-    """RMSNorm -> grouped-query attention -> residual, RMSNorm -> routed
-    experts -> residual."""
-
-    def __init__(self, hidden, attn: GqaAttention, experts: RoutedExperts,
-                 eps):
-        super().__init__()
-        self.ln1 = FusedRMSNorm(hidden, eps=eps)
-        self.attn = attn
-        self.ln2 = FusedRMSNorm(hidden, eps=eps)
-        self.experts = experts
-
-    def _ffn(self, ctx, h, live=None):
-        """-> ``(y, pairs)``: the held experts' part of the routed sum
-        and the pairs each of them got from the ``live`` rows."""
-        y, pairs = self.experts.forward(
-            ctx, h.reshape(-1, h.shape[-1]),
-            None if live is None else live.reshape(-1))
-        return y.reshape(h.shape), pairs
-
-    def forward(self, ctx, x, positions):
-        x = x + self.attn.forward(ctx, self.ln1.forward(ctx, x), positions)
-        return x + self._ffn(ctx, self.ln2.forward(ctx, x))[0]
-
-    # -- the serve engine's layer protocol (serve/kernels.py) --------------
+class GqaRows:
+    """What a block whose ``attn`` is a :class:`GqaAttention` behind
+    ``ln1`` keeps of a token and how its queries read it: the rows'
+    half of the serve engine's layer protocol (``serve/kernels.py``)."""
 
     @property
     def cache_rows(self):
@@ -149,6 +132,33 @@ class GqaMoeBlock(nn.Module):
     def read_chunk(self, q, pool, layer, tables, positions, window):
         k, v = gather_kv(pool, layer, tables)
         return attend(q, k, v, positions, self.attn.scaling, window)
+
+
+class GqaMoeBlock(GqaRows, nn.Module):
+    """RMSNorm -> grouped-query attention -> residual, RMSNorm -> routed
+    experts -> residual."""
+
+    def __init__(self, hidden, attn: GqaAttention, experts: RoutedExperts,
+                 eps):
+        super().__init__()
+        self.ln1 = FusedRMSNorm(hidden, eps=eps)
+        self.attn = attn
+        self.ln2 = FusedRMSNorm(hidden, eps=eps)
+        self.experts = experts
+
+    def _ffn(self, ctx, h, live=None):
+        """-> ``(y, pairs)``: the held experts' part of the routed sum
+        and the pairs each of them got from the ``live`` rows."""
+        y, pairs = self.experts.forward(
+            ctx, h.reshape(-1, h.shape[-1]),
+            None if live is None else live.reshape(-1))
+        return y.reshape(h.shape), pairs
+
+    def forward(self, ctx, x, positions):
+        x = x + self.attn.forward(ctx, self.ln1.forward(ctx, x), positions)
+        return x + self._ffn(ctx, self.ln2.forward(ctx, x))[0]
+
+    # -- the serve engine's layer protocol: the rows are GqaRows' ----------
 
     def finish(self, ctx, x, o, live):
         x = x + _mm(o, ctx.value(self.attn.o))

@@ -19,11 +19,13 @@ capacity-dropping, a dense one-hot dispatch.
 * :func:`softmax_topk_route` — float32 logits, a softmax over ALL the
   experts, the best ``top_k`` of them, their probabilities normalised
   over the chosen: no bias, no groups.
-* :class:`RoutedExperts` — the router, the held experts' gated (SwiGLU)
-  matrices stacked, and the dispatch: token-expert pairs that go to held
-  experts are sorted by expert and taken through two grouped matmuls
-  (``kernels/grouped_matmul.py``: ``routed_experts`` in a device trace);
-  the result comes back with the pairs each held expert got.
+* :class:`RoutedExperts` — the router, the held experts' matrices
+  stacked (gated: gate | up and down, ``act(gate) * up``; or not gated:
+  one input matrix and down, ``act(up)``), and the dispatch: token-expert
+  pairs that go to held experts are sorted by expert and taken through
+  two grouped matmuls (``kernels/grouped_matmul.py``: ``routed_experts``
+  in a device trace); the result comes back with the pairs each held
+  expert got.
 """
 from __future__ import annotations
 
@@ -80,26 +82,42 @@ def softmax_topk_route(x, w_router, *, top_k, norm_topk=True, scale=1.0):
     return experts.astype(jnp.int32), w * scale
 
 
+#: an expert's activation: SwiGLU's gate, or the squared relu of an
+#: expert that is not gated
+ACTS = {"silu": jax.nn.silu, "relu2": lambda h: jnp.square(jax.nn.relu(h))}
+
+
 class RoutedExperts(Module):
-    """``n_experts`` routed gated experts of which this device holds
+    """``n_experts`` routed experts of which this device holds
     ``experts_held`` (ids; default all).  ``forward(ctx, x (T, E))`` ->
     ``(y (T, E), pairs (len(experts_held),) int32)``: the held experts'
     part of the layer's routed sum, and the token-expert pairs each of
-    them got.  Matrices are kept ``(in, out)``: ``w_in (G, E, 2*I)`` is
-    gate | up, ``w_out (G, I, E)``.  ``score``: how the router scores,
-    ``"sigmoid"`` (:func:`group_limited_route`, with its correction
-    bias) or ``"softmax"`` (:func:`softmax_topk_route`: no bias, no
-    groups)."""
+    them got.  ``gated`` (SwiGLU): ``w_in (G, E, 2*I)`` is gate | up,
+    kept ``(in, out)``, and an expert is ``W_out (act(gate) * up)``; not
+    gated: ``w_in (G, I, E)``, kept ``(out, in)`` as ``w_out (G, I, E)``
+    lies, and an expert is ``W_out act(W_in x)`` (an intermediate width
+    that is no whole number of lane rows, as 1856, is then nowhere a
+    minor dimension: the device keeps a ``(E, 1856)`` matrix the other
+    way round and hands a kernel that wants it row-major a copy, 0.3 GB
+    a layer a step at the widths of PERF.md section 4's fourth
+    configuration).  ``act``: one of
+    :data:`ACTS`.  ``score``: how the router scores, ``"sigmoid"``
+    (:func:`group_limited_route`, with its correction bias) or
+    ``"softmax"`` (:func:`softmax_topk_route`: no bias, no groups)."""
 
     def __init__(self, hidden, intermediate, n_experts, top_k, *,
                  n_group=1, topk_group=1, scale=1.0, norm_topk=True,
-                 experts_held=None, score="sigmoid", init=None):
+                 experts_held=None, score="sigmoid", gated=True,
+                 act="silu", init=None):
         """``init(shape, fan_in) -> Parameter`` draws (or only declares)
         a parameter; ``fan_in`` None marks a bias."""
         super().__init__()
         if score not in ("sigmoid", "softmax"):
             raise ValueError(f"score is 'sigmoid' or 'softmax', "
                              f"got {score!r}")
+        if act not in ACTS:
+            raise ValueError(f"act is one of {sorted(ACTS)}, got {act!r}")
+        self.gated, self.act = bool(gated), act
         if score == "softmax" and (n_group, topk_group) != (1, 1):
             raise ValueError("the softmax router chooses among all "
                              "experts: it has no groups")
@@ -128,7 +146,8 @@ class RoutedExperts(Module):
         self.router = init((n_experts, hidden), hidden)
         self.router_bias = init((n_experts,), None) \
             if score == "sigmoid" else None
-        self.w_in = init((g, hidden, 2 * intermediate), hidden)
+        self.w_in = init((g, hidden, 2 * intermediate) if gated
+                         else (g, intermediate, hidden), hidden)
         self.w_out = init((g, intermediate, hidden), intermediate)
 
     def route(self, ctx, x):
@@ -150,7 +169,8 @@ class RoutedExperts(Module):
         w_in, w_out = ctx.value(self.w_in), ctx.value(self.w_out)
         # rows meet the matrices in the type the matrices are stored in
         dt = w_in.dtype
-        tile = TILE_ROWS if takes_tiles(e, w_in.shape[2], dt) \
+        tile = TILE_ROWS \
+            if takes_tiles(e, w_in.shape[2 if self.gated else 1], dt) \
             and takes_tiles(w_out.shape[1], e, dt) else 1
         # a token's experts are distinct: at most min(k, g) of them here
         group = jnp.asarray(self._local)[experts]
@@ -159,10 +179,11 @@ class RoutedExperts(Module):
         lay = tile_layout(group.reshape(-1), g, t * min(k, g), tile)
         token_of_row = jnp.maximum(lay.pair_of_row, 0) // k
         xs = x.astype(dt)[token_of_row]                      # (M, E)
-        gate_up = grouped_matmul(xs, w_in, lay)
+        up = grouped_matmul(xs, w_in, lay, transposed=not self.gated)
         i = w_out.shape[1]
-        h = jax.nn.silu(gate_up[:, :i].astype(_f32)) \
-            * gate_up[:, i:].astype(_f32)
+        h = ACTS[self.act](up[:, :i].astype(_f32))
+        if self.gated:
+            h = h * up[:, i:].astype(_f32)
         ys = grouped_matmul(h.astype(dt), w_out, lay)        # (M, E)
         # each token's held pairs, read back from their rows (selected,
         # not multiplied by zero: a row no pair holds is undefined)

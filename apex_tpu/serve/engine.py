@@ -54,7 +54,8 @@ from ..observe import spans as _spans
 from ..observe import watchdog as _watchdog
 from ..runtime import executor as _executor
 from . import kernels as _kernels
-from .pool import BlockPool, blocks_for, init_pool_buffer
+from .pool import (BlockPool, blocks_for, init_pool_buffer,
+                   init_state_buffers)
 from .scheduler import DECODE, Request, Scheduler, Session, bucket
 
 #: per-engine token in the serve program static keys — two engines over
@@ -111,6 +112,17 @@ class ServeEngine:
     declare none (a GPT block; ``None``: they read every key).
     Speculation needs one group without a window, the prefix cache one
     group (``docs/serving.md``).
+
+    Layers that keep a state of a *session* and nothing of a token (a
+    state-space layer: ``block.cache_rows is None``, ``block.state``)
+    form *state groups* (``serve/kernels.py`` ``state_groups``):
+    ``states`` lists each group's buffers ``(layers of the group,
+    max_batch + 1, *shape)``, sized from the model and ``max_batch``
+    alone and in the layers' own dtypes; a session holds one slot of
+    them from admission to ``finish`` or preemption, and starts from
+    zeros whatever the slot held.  Such a model is served without the
+    prefix cache, speculation and the KV handoffs (``docs/serving.md``
+    says why); a model with no such layer is served as it always was.
     """
 
     def __init__(self, model, *, num_blocks, block_size=16, max_batch=8,
@@ -133,6 +145,19 @@ class ServeEngine:
         self._dtype_name = dtype if isinstance(dtype, str) \
             else jnp.dtype(dtype).name
         self.groups, _ = _kernels.cache_groups(model, window)
+        self.state_groups, _ = _kernels.state_groups(model)
+        # a row a slot: as many slots as batch rows, and the null slot
+        self.states = [init_state_buffers(g.state, len(g.layers), max_batch)
+                       for g in self.state_groups]
+        # the layers that keep a state and the bytes of it a session a
+        # layer (the mean layer's, were the groups' to differ): the tick
+        # record's ssm_* fields
+        kept = [(len(g.layers), sum(int(np.prod(shape)) * jnp.dtype(dt).itemsize
+                                    for shape, dt in g.state))
+                for g in self.state_groups]
+        self._state_layers = sum(n for n, _ in kept)
+        self._state_bytes = sum(n * size for n, size in kept) \
+            // max(self._state_layers, 1)
         self._windowed = any(g.window is not None for g in self.groups)
         # (window, layers without one, layers with one): the tick
         # record's kv_* fields (_count_rows)
@@ -156,8 +181,10 @@ class ServeEngine:
         self._in_use_gauges = [
             (f"serve.pool.{g.name}.blocks_in_use", bp)
             for g, bp in zip(self.groups, self.block_pools)]
-        # one group: a prefix is one table's blocks
-        prefix_cache = prefix_cache and len(self.groups) == 1
+        # one group: a prefix is one table's blocks; a state a session:
+        # a hit would need a snapshot of it at the block's boundary
+        prefix_cache = prefix_cache and len(self.groups) == 1 \
+            and not self.state_groups
         # -- speculative mode: a draft model served from its OWN pool
         # buffer (int8 by default — weight-only drafts are bandwidth
         # bound) whose block ids come from the SAME BlockPool free-list
@@ -188,7 +215,8 @@ class ServeEngine:
             spec_tables=self.spec,
             pos_slack=self.spec_k if self.spec else 0,
             prefix_cache=prefix_cache,
-            cache_tag=self._cache_tag(epoch=0))
+            cache_tag=self._cache_tag(epoch=0),
+            state_slots=bool(self.state_groups))
         self._token = next(_SERVE_TOKENS)
         weakref.finalize(self, _forget_programs, self._token)
         self._donate = _executor.donation.enabled
@@ -242,8 +270,17 @@ class ServeEngine:
         them where there are several groups."""
         return self.pools[0] if len(self.pools) == 1 else tuple(self.pools)
 
-    def _set_cache(self, pools) -> None:
+    def _set_cache(self, pools, states=()) -> None:
+        """What a program gave back: the pools, and after them the state
+        groups' buffers where the model has any."""
         self.pools = list(pools) if len(self.pools) > 1 else [pools]
+        if states:
+            self.states = list(states[0])
+
+    def _states(self):
+        """The state groups' buffers as the programs take them (donated,
+        beside the pools): nothing for a model that has none."""
+        return (tuple(self.states),) if self.state_groups else ()
 
     @staticmethod
     def _validate_model(model):
@@ -253,15 +290,26 @@ class ServeEngine:
                 raise ValueError(
                     f"ServeEngine needs model.{a} (the decode protocol)")
         for blk in model.blocks:
-            for a in ("cache_rows", "chunk_rows", "read_decode",
-                      "read_chunk", "finish"):
+            rows = ("cache_rows", "chunk_rows", "read_decode", "read_chunk",
+                    "finish")
+            state = ("cache_rows", "state", "step", "chunk", "finish")
+            keeps_rows = getattr(blk, "cache_rows", ()) is not None
+            for a in rows if keeps_rows else state:
                 if not hasattr(blk, a):
                     raise ValueError(
                         f"ServeEngine needs block.{a} — the layer "
-                        f"protocol of serve/kernels.py: cache_rows, "
-                        f"chunk_rows, read_decode, read_chunk, finish, "
-                        f"and window where the block reads a band of "
-                        f"keys ({type(blk).__name__} does not follow it)")
+                        f"protocol of serve/kernels.py: a block that "
+                        f"keeps rows of a token has {', '.join(rows)}, "
+                        f"and window where the block reads a band of keys; one "
+                        f"that keeps a state of a session (cache_rows = "
+                        f"None) has {', '.join(state[1:])} "
+                        f"({type(blk).__name__} does not follow it)")
+        if not any(getattr(blk, "cache_rows", None) is not None
+                   for blk in model.blocks):
+            raise NotImplementedError(
+                "ServeEngine counts a session's positions by the blocks "
+                "it holds: a model needs a layer that keeps rows of a "
+                "token")
         axes = _sharded_decode_axes(model)
         if axes:
             names = ", ".join(f"{a}='{v}'" for a, v in axes)
@@ -273,6 +321,11 @@ class ServeEngine:
         self._validate_model(draft)
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+        if self.state_groups or _kernels.state_groups(draft)[0]:
+            raise NotImplementedError(
+                "speculative mode + a state a session: a rejected draft "
+                "would need the state rolled back to the last accepted "
+                "token — serve one or the other")
         if self._windowed:
             raise NotImplementedError(
                 "speculative mode + sliding window: the verify chunk "
@@ -301,18 +354,20 @@ class ServeEngine:
         if self._decode_prog is None:
             key = (self._token, self._phase, self.block_size,
                    self._dtype_name, self.window, self._donate)
+            # the pools, and the state groups' buffers beside them
+            donate = (1, 2) if self.state_groups else (1,)
             self._decode_prog = _executor.Program(
                 "decode_step", key,
                 _kernels.build_decode_fn(
                     self.model, self._params, self.block_size,
                     self.num_blocks, self.window),
-                donate_argnums=(1,) if self._donate else ())
+                donate_argnums=donate if self._donate else ())
             self._prefill_prog = _executor.Program(
                 "prefill_step", key,
                 _kernels.build_prefill_fn(
                     self.model, self._params, self.block_size,
                     self.num_blocks, self.window),
-                donate_argnums=(1,) if self._donate else ())
+                donate_argnums=donate if self._donate else ())
         return self._prefill_prog, self._decode_prog
 
     def _spec_programs(self):
@@ -543,6 +598,8 @@ class ServeEngine:
                 else:
                     if self._windowed:
                         self._count_rows(tick, ds)
+                    if self._state_layers:
+                        self._count_state(tick, ds)
                     self._decode_tick(ds)
         _obs.gauge("serve.queue_depth").set(len(self.scheduler.queue))
         _obs.gauge("serve.active_sessions").set(
@@ -631,14 +688,15 @@ class ServeEngine:
                 return
         toks = list(s.prefill_src[t0:t0 + n])
         toks += [0] * (chunk - n)
-        last, pools, counted = _executor.executor.submit(
+        slot = (np.asarray([s.slot], np.int32),) if self.state_groups else ()
+        last, pools, counted, *states = _executor.executor.submit(
             prefill_prog,
-            (self._vals(), self._cache(),
+            (self._vals(), self._cache(), *self._states(),
              np.asarray([toks], np.int32),
              self._tables(self.scheduler.pack_groups([s], 1)[1]),
-             np.int32(t0), np.int32(n)),
+             np.int32(t0), np.int32(n), *slot),
             step=next(self._dispatch_no))
-        self._set_cache(pools)
+        self._set_cache(pools, states)
         if counted is not None:
             self._counted.append(counted)
         if self.spec and s.draft_position == t0:
@@ -758,13 +816,15 @@ class ServeEngine:
         with _spans.span("serve.pack"):
             b, nb, tokens, positions, tables = \
                 self.scheduler.pack_decode(sessions)
-        nxt, _logits, pools, counted = _executor.executor.submit(
+            slots = (np.asarray(self.scheduler.pack_slots(sessions, b),
+                                np.int32),) if self.state_groups else ()
+        nxt, _logits, pools, counted, *states = _executor.executor.submit(
             decode_prog,
-            (self._vals(), self._cache(),
+            (self._vals(), self._cache(), *self._states(),
              np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
-             self._tables(tables)),
+             self._tables(tables), *slots),
             step=next(self._dispatch_no))
-        self._set_cache(pools)
+        self._set_cache(pools, states)
         if counted is not None:
             self._counted.append(counted)
         with _spans.span("serve.fetch", what="tokens"):
@@ -801,6 +861,15 @@ class ServeEngine:
         tick["kv_layers_full"] = layers_full
         tick["kv_layers_window"] = layers_window
         tick["kv_window"] = window
+
+    def _count_state(self, tick: dict, sessions: List[Session]) -> None:
+        """What the state-space layers move in this tick's decode
+        dispatch, on the tick's ``serve.step`` record
+        (docs/observability.md): each live session's state is read once
+        and written once by every layer that keeps one."""
+        tick["ssm_sessions"] = len(sessions)
+        tick["ssm_layers"] = self._state_layers
+        tick["ssm_state_bytes"] = 2 * len(sessions) * self._state_bytes
 
     def _publish_moe(self, tick: dict) -> None:
         """What the tick's programs counted in their routed layers —
@@ -913,10 +982,10 @@ class ServeEngine:
         None when a batch slot / blocks are not available right now
         (the coordinator retries next tick)."""
         from ..runtime.resilience import load_kv_handoff
-        if len(self.groups) > 1:
+        if len(self.groups) > 1 or self.state_groups:
             raise NotImplementedError(
                 "a KV handoff streams one cache group's blocks; this "
-                "model's layers form several")
+                "model's layers form several, or keep a state a session")
         need_pos = len(request.prompt) + request.max_new_tokens \
             + self.scheduler.pos_slack
         if need_pos > self.scheduler.max_positions:
@@ -1013,6 +1082,8 @@ class ServeEngine:
         self.scheduler.queue.clear()
         for bp in self.block_pools:
             bp.check_no_leaks()
+        if self.scheduler.slots is not None:
+            self.scheduler.slots.check_no_leaks()
 
     def __enter__(self) -> "ServeEngine":
         return self

@@ -11,14 +11,22 @@ compiled program instead of retracing — the serving analogue of the
 step-cache keying discipline, enforced by the SERVE-SHAPE lint rule.
 
 Every layer of a servable model follows ONE protocol
-(:func:`_through_blocks`): the body hands it the chunk and its positions
-and gets back its queries and the row(s) to store; the layer says what
-it keeps of a token, for how long (``window``), and how a query reads
-it.  Layers that keep the same rows for the same length share a *cache
-group* (:func:`cache_groups`): a pool buffer and a block table a session
-of their own, so a model whose layers mix window and full attention
-keeps the band's blocks for the one kind and every block for the
-other.  ``GptBlock`` keeps a K
+(:func:`_through_blocks`), which has two halves.  A layer that keeps
+something of a *token* is handed the chunk and its positions and gives
+back its queries and the row(s) to store; it says what it keeps, for how
+long (``window``), and how a query reads it.  Layers that keep the same
+rows for the same length share a *cache group* (:func:`cache_groups`): a
+pool buffer and a block table a session of their own, so a model whose
+layers mix window and full attention keeps the band's blocks for the one
+kind and every block for the other.  A layer that keeps something of a
+*session* (``cache_rows = None``: a state-space layer's state, which is
+read, updated and written back every step whatever the depth; or nothing
+at all, as a feed-forward layer of its own) says what (``state``) and
+takes one step of a batch or one chunk of a session with the buffers in
+hand; layers of equal ``state`` share a *state group*
+(:func:`state_groups`): buffers ``(layers of the group, slots, *shape)``,
+a row a *slot* that a session holds from admission to ``finish``.
+``GptBlock`` keeps a K
 and a V row of all heads (its learned positions were added at the
 embedding) and reuses the model's own decode pieces —
 ``_chunk_qkv`` (LN1 + interleaved QKV projection), ``_attn_mlp_tail``
@@ -29,7 +37,9 @@ contiguous-cache path it is parity-tested against (tests/test_serve.py,
 tests/test_serve_paged.py); a latent block (``models/latent_moe.py``)
 rotates by the positions and keeps one latent row a token, read by all
 heads alike; a grouped-query block (``models/gqa_moe.py``) rotates by
-its own kind's tables and keeps a K and a V row of its stored heads.
+its own kind's tables and keeps a K and a V row of its stored heads; a
+state-space block (``models/hybrid_ssm_moe.py``) keeps a float32 state
+and its convolution's last inputs a session.
 What is here is the index plumbing, and it keeps each pool where it lies
 (``serve/pool.py``: ``(layers of the group, streams, num_blocks,
 block_size, width)``, row-major on the device):
@@ -48,14 +58,16 @@ block_size, width)``, row-major on the device):
   time, in the pool's dtype.
 
 No program holds a view of all layers, an fp32 copy of the pool's rows,
-or the pool in another layout (tests/test_aot_tpu_compile.py pins it on
-the compiled v5e programs).
+or the pool in another layout, and none copies or widens a state buffer
+(tests/test_aot_tpu_compile.py pins it on the compiled v5e programs).
 
 Dead batch rows (bucket padding) are encoded as ``position == -1``:
 their tables are all-null (reads see zeros the mask excludes), their
 embedding lookups clip to row 0 (outputs discarded), and their KV write
 targets are redirected past the pool so ``mode="drop"`` discards the
-write — padding never touches the null block's zeros.
+write — padding never touches the null block's zeros.  Their slot is the
+*null slot*, the last row of every state buffer, which they read and
+write and no session holds.
 """
 from __future__ import annotations
 
@@ -82,24 +94,63 @@ class CacheGroup(NamedTuple):
         return "full" if self.window is None else f"window{self.window}"
 
 
-def cache_groups(model, window=None):
-    """``(groups, where)``: the model's layers grouped by what they keep
-    (``blk.cache_rows``) and for how long (``blk.window``; a layer that
-    declares none takes ``window``, the engine-wide default), groups
-    without a window first, and for each layer ``(its group, its place
-    in that group's pool)``."""
-    keys = [(tuple(blk.cache_rows),
-             getattr(blk, "window", None) or window)
-            for blk in model.blocks]
-    order = sorted(dict.fromkeys(keys), key=lambda k: k[1] is not None)
-    groups = [CacheGroup(rows, w, tuple(
-        i for i, k in enumerate(keys) if k == (rows, w)))
-        for rows, w in order]
-    where = [None] * len(keys)
+class StateGroup(NamedTuple):
+    """Layers that keep the same state of a session."""
+    state: Tuple[tuple, ...]       # ((shape, dtype), ...) a session
+    layers: Tuple[int, ...]        # the model's layers, in order
+
+
+class StateRef(NamedTuple):
+    """What a layer of a state group is handed: the group's buffers
+    ``(layers of the group, slots, *shape)``, one for each entry of the
+    layer's ``state``; its place in them; and the slot of each session of
+    the dispatch."""
+    bufs: tuple
+    layer: int
+    slots: jax.Array               # (B,) int32
+
+
+def _where(groups, n_layers):
+    where = [None] * n_layers
     for g, grp in enumerate(groups):
         for at, layer in enumerate(grp.layers):
             where[layer] = (g, at)
-    return groups, where
+    return where
+
+
+def cache_groups(model, window=None):
+    """``(groups, where)``: the model's layers that keep rows of a token,
+    grouped by what they keep (``blk.cache_rows``) and for how long
+    (``blk.window``; a layer that declares none takes ``window``, the
+    engine-wide default), groups without a window first, and for each
+    such layer ``(its group, its place in that group's pool)`` (None for
+    a layer that keeps no rows: :func:`state_groups`)."""
+    keys = [None if blk.cache_rows is None else
+            (tuple(blk.cache_rows), getattr(blk, "window", None) or window)
+            for blk in model.blocks]
+    order = sorted(dict.fromkeys(k for k in keys if k is not None),
+                   key=lambda k: k[1] is not None)
+    groups = [CacheGroup(rows, w, tuple(
+        i for i, k in enumerate(keys) if k == (rows, w)))
+        for rows, w in order]
+    return groups, _where(groups, len(keys))
+
+
+def state_groups(model):
+    """``(groups, where)``: the model's layers that keep a state of a
+    session (``blk.cache_rows is None`` and ``blk.state``, a tuple of
+    ``(shape, dtype)``, not empty), grouped by that state in the order
+    the model meets them, and for each such layer ``(its group, its place
+    in that group's buffers)``.  A layer that keeps neither rows nor a
+    state (an expert layer that is a layer of its own) is in no group:
+    it rides the state half of the protocol and is handed no buffer."""
+    keys = [tuple((tuple(shape), jnp.dtype(dt).name)
+                  for shape, dt in blk.state)
+            if blk.cache_rows is None else () for blk in model.blocks]
+    groups = [StateGroup(state, tuple(
+        i for i, k in enumerate(keys) if k == state))
+        for state in dict.fromkeys(k for k in keys if k)]
+    return groups, _where(groups, len(keys))
 
 
 def _ctx(params, vals):
@@ -208,11 +259,11 @@ def _num_blocks(pool) -> int:
 
 
 def _through_blocks(ctx, model, pools, x, q_pos, live, tables, block_size,
-                    window, *, decode):
+                    window, *, decode, states=(), slots=None):
     """``x (B, Q, E)`` at positions ``q_pos (B, Q)``, of which ``live
     (B, Q)`` are real, through every block (``decode``: the tick's one
     row a session, ``Q == 1``), by the one layer protocol every servable
-    block follows:
+    block follows.  A block that keeps rows of a token:
 
     * ``blk.cache_rows`` — ``(streams, heads, head_dim)``: what the block
       keeps of a token (its pool's geometry is read off it);
@@ -230,18 +281,43 @@ def _through_blocks(ctx, model, pools, x, q_pos, live, tables, block_size,
       block, and what it counted on the way over the ``live`` rows (a
       routed layer's token-expert pairs a held expert; None).
 
+    A block that keeps none (``blk.cache_rows is None``):
+
+    * ``blk.state`` — ``((shape, dtype), ...)``: what the block keeps of
+      a *session* (a state-space layer's state and its convolution's last
+      inputs; empty for a block that keeps nothing), in dtypes of its
+      own (the cache's dtype is the rows');
+    * ``blk.step(ctx, x (B, E), state, live (B,)) -> (o, bufs)`` — one
+      position of every session of the batch (the decode tick);
+      ``state`` a :class:`StateRef`: the buffers of the block's group,
+      its place in them and the sessions' slots (padding rows: the null
+      slot); ``bufs`` the buffers with those slots' rows stepped;
+    * ``blk.chunk(ctx, x (Q, E), state, n_real, first) -> (o, bufs)`` —
+      a chunk of ONE session of which the first ``n_real`` rows are real
+      (the others leave the state alone); ``first``: the chunk starts
+      the session, so the state it starts from is zeros whatever the
+      slot's last session left;
+    * ``blk.finish(ctx, x, o, live)`` as above.
+
     ``pools`` and ``tables`` are one buffer and one ``(B, nb)`` table
     where all layers are of one cache group, else a tuple of each, a
     group (:func:`cache_groups`): a layer is handed its group's buffer
     and table, its place in that buffer and its own window.
 
+    ``states``: a tuple of buffers a state group (:func:`state_groups`),
+    ``slots (B,)`` each session's row of them; a chunk of several
+    sessions (the speculative verify) is no state layer's.
+
     Each layer writes the live rows into its pool and then attends —
     the write-then-read of ``GptBlock.decode_chunk``, so a query finds
     its own key where every other key is (through an int8 pool: exactly
-    the bytes stored).  Returns ``(x, pools, counted)``: ``counted`` the
+    the bytes stored).  Returns ``(x, pools, counted)``, and the states
+    after them where the model has a state group: ``counted`` the
     layers' counts stacked ``(layers that count, ...)``, None if none
     does."""
     groups, where = cache_groups(model, window)
+    _, s_where = state_groups(model)
+    states = list(states)
     one = not isinstance(tables, (tuple, list))
     pools, tables = ([pools], [tables]) if one else (list(pools), tables)
     if len(pools) != len(groups):
@@ -255,16 +331,30 @@ def _through_blocks(ctx, model, pools, x, q_pos, live, tables, block_size,
     pos = q_pos[:, 0] if decode else q_pos
     counted = []
     for layer, blk in enumerate(model.blocks):
-        g, at = where[layer]
-        q, rows = blk.chunk_rows(ctx, x, q_pos)
-        pools[g] = write_rows(pools[g], at, targets[g], rows)
-        read = blk.read_decode if decode else blk.read_chunk
-        x, n = blk.finish(ctx, x, read(q, pools[g], at, tables[g], pos,
-                                       groups[g].window), live)
+        if where[layer] is None:
+            # (a block that keeps nothing is handed no buffer)
+            g, at = s_where[layer] or (None, 0)
+            ref = StateRef(() if g is None else states[g], at, slots)
+            if decode:
+                o, kept = blk.step(ctx, x[:, 0], ref, live[:, 0])
+            else:
+                o, kept = blk.chunk(
+                    ctx, x[0], ref, jnp.sum(live[0], dtype=jnp.int32),
+                    q_pos[0, 0] == 0)
+            if g is not None:
+                states[g] = kept
+        else:
+            g, at = where[layer]
+            q, rows = blk.chunk_rows(ctx, x, q_pos)
+            pools[g] = write_rows(pools[g], at, targets[g], rows)
+            read = blk.read_decode if decode else blk.read_chunk
+            o = read(q, pools[g], at, tables[g], pos, groups[g].window)
+        x, n = blk.finish(ctx, x, o, live)
         if n is not None:
             counted.append(n)
-    return x, pools[0] if one else tuple(pools), \
+    out = x, pools[0] if one else tuple(pools), \
         jnp.stack(counted) if counted else None
+    return out + (tuple(states),) if states else out
 
 
 def build_decode_fn(model, params, block_size, num_blocks, window=None):
@@ -286,17 +376,32 @@ def build_decode_fn(model, params, block_size, num_blocks, window=None):
     way (:func:`_through_blocks`: a routed layer's token-expert pairs a
     held expert, ``(routed layers, held experts)`` i32), None for a
     model whose layers count nothing; the engine fetches it with the
-    tokens."""
-    def fn(vals, pool, tokens, positions, tables):
-        ctx = _ctx(params, vals)
+    tokens.
+
+    Where the model has a state group (:func:`state_groups`) the body is
+    ``fn(vals, pool, states, tokens, positions, tables, slots) -> (...,
+    pool, counted, states)``: ``states`` a tuple of buffers a group,
+    ``slots (B,)`` each session's row of them (a dead row: the null
+    slot)."""
+    def through(ctx, pool, tokens, positions, tables, **state):
         x = _embed(ctx, model, tokens[:, None], positions[:, None])
-        x, pool, counted = _through_blocks(
+        x, pool, counted, *states = _through_blocks(
             ctx, model, pool, x, positions[:, None], positions[:, None] >= 0,
-            tables, block_size, window, decode=True)
+            tables, block_size, window, decode=True, **state)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)[:, 0]               # (B, V)
         nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
-        return nxt, logits, pool, counted
+        return (nxt, logits, pool, counted, *states)
+
+    # (one name for both: a device trace knows the program as jit_fn)
+    if state_groups(model)[0]:
+        def fn(vals, pool, states, tokens, positions, tables, slots):
+            return through(_ctx(params, vals), pool, tokens, positions,
+                           tables, states=states, slots=slots)
+    else:
+        def fn(vals, pool, tokens, positions, tables):
+            return through(_ctx(params, vals), pool, tokens, positions,
+                           tables)
     return fn
 
 
@@ -314,21 +419,34 @@ def build_prefill_fn(model, params, block_size, num_blocks,
     prefix length (both traced i32 — the bucketed chunk width, not the
     prompt length, keys compilation).  ``last_logits (1, V)`` is row
     ``n_real - 1`` — the next-token distribution once the final chunk
-    lands; ``counted`` as the decode body's."""
-    def fn(vals, pool, toks, table, t0, n_real):
-        ctx = _ctx(params, vals)
+    lands; ``counted`` as the decode body's.
+
+    Where the model has a state group the body is ``fn(vals, pool,
+    states, toks, table, t0, n_real, slot) -> (last_logits, pool,
+    counted, states)``, ``slot (1,)`` the session's; the chunk at ``t0 ==
+    0`` starts from zeros and not from what the slot holds."""
+    def through(ctx, pool, toks, table, t0, n_real, **state):
         rows = jnp.arange(toks.shape[1], dtype=jnp.int32)
         pos = (t0 + rows)[None, :]                        # (1, chunk)
         x = _embed(ctx, model, toks, pos)
         # chunk row d lands at position t0 + d; live rows only
-        x, pool, counted = _through_blocks(
+        x, pool, counted, *states = _through_blocks(
             ctx, model, pool, x, pos, (rows < n_real)[None, :], table,
-            block_size, window, decode=False)
+            block_size, window, decode=False, **state)
         x = model.ln_f.forward(ctx, x)
         logits = _head(ctx, model, x)                  # (1, chunk, V)
         last = jax.lax.dynamic_index_in_dim(
             logits, jnp.clip(n_real - 1, 0), axis=1, keepdims=False)
-        return last, pool, counted
+        return (last, pool, counted, *states)
+
+    if state_groups(model)[0]:
+        def fn(vals, pool, states, toks, table, t0, n_real, slot):
+            return through(_ctx(params, vals), pool, toks, table, t0,
+                           n_real, states=states, slots=slot)
+    else:
+        def fn(vals, pool, toks, table, t0, n_real):
+            return through(_ctx(params, vals), pool, toks, table, t0,
+                           n_real)
     return fn
 
 

@@ -122,6 +122,60 @@ def init_pool_buffer(layers, heads, head_dim, num_blocks, block_size,
     return jnp.zeros(shape, dtype)
 
 
+def init_state_buffers(state, layers: int, slots: int):
+    """The device-side buffers of one *state group* (``serve/kernels.py``
+    ``state_groups``: layers that keep the same state of a session and
+    nothing of a token): one ``(layers, slots + 1, *shape)`` array of
+    zeros for each ``(shape, dtype)`` of ``state``, a row a slot.  A
+    session holds one slot from admission to ``finish`` and every layer
+    of the group reads, updates and writes back its row of that slot
+    each step; the last row is the *null slot*, which the padding rows
+    of a batch bucket read and write and no session holds.  The dtype is
+    the layer's own (a state-space layer's state is float32 whatever the
+    cache's: the recurrence sums thousands of steps), and nothing here
+    depends on a session's depth, so the group is sized by ``max_batch``
+    alone."""
+    return tuple(jnp.zeros((layers, slots + 1) + tuple(shape), dtype)
+                 for shape, dtype in state)
+
+
+class SlotPool:
+    """Host-side free list of the state slots ``0 .. slots - 1`` (the
+    null slot, ``slots``, is never handed out).  There are as many slots
+    as batch rows, so a session that is admitted always finds one."""
+
+    def __init__(self, slots: int):
+        self.slots = int(slots)
+        self._free = list(range(self.slots - 1, -1, -1))
+        self.started = 0
+
+    @property
+    def null(self) -> int:
+        return self.slots
+
+    @property
+    def in_use(self) -> int:
+        return self.slots - len(self._free)
+
+    def take(self) -> int:
+        self.started += 1
+        _obs.counter("serve.state.slots_started").inc()
+        slot = self._free.pop()
+        _obs.gauge("serve.state.slots_in_use").set(self.in_use)
+        return slot
+
+    def give(self, slot: int) -> None:
+        if slot in self._free or not 0 <= slot < self.slots:
+            raise ValueError(f"state slot {slot} is not held")
+        self._free.append(slot)
+        _obs.gauge("serve.state.slots_in_use").set(self.in_use)
+
+    def check_no_leaks(self) -> None:
+        if self.in_use:
+            raise AssertionError(
+                f"state slot leak: {self.in_use} of {self.slots} still held")
+
+
 # ---------------------------------------------------------------------------
 # Content hashing: the rolling token-chain key
 # ---------------------------------------------------------------------------

@@ -45,7 +45,8 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
 from ..inference.rolling import window_retired_blocks
-from .pool import BlockPool, NULL_BLOCK, blocks_for, chain_key, chain_keys
+from .pool import (BlockPool, NULL_BLOCK, SlotPool, blocks_for, chain_key,
+                   chain_keys)
 
 QUEUED, PREFILL, DECODE, DONE = "queued", "prefill", "decode", "done"
 
@@ -145,6 +146,9 @@ class Session:
     # tokens of this request's prompt that admission found cached (the
     # rows prefill will NOT recompute) — telemetry for hit-rate
     prefix_hit_tokens: int = 0
+    # its row of the state groups' buffers, from admission to finish or
+    # preemption (None: the model keeps no state of a session)
+    slot: Optional[int] = None
 
     @property
     def rid(self) -> str:
@@ -189,10 +193,13 @@ class Scheduler:
                  max_positions: int, spec_tables: bool = False,
                  pos_slack: int = 0, prefix_cache: bool = True,
                  cache_tag: str = "kv",
-                 windows: Optional[Sequence[Optional[int]]] = None):
+                 windows: Optional[Sequence[Optional[int]]] = None,
+                 state_slots: bool = False):
         """``pool``: one :class:`BlockPool`, or one a cache group with
         ``windows`` beside them (each group's window, None where its
-        layers read every key)."""
+        layers read every key).  ``state_slots``: the model keeps a state
+        of a session (``serve/kernels.py`` ``state_groups``), so every
+        admitted session holds one of ``max_batch`` slots."""
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if prefill_chunk < 1:
@@ -215,6 +222,11 @@ class Scheduler:
         self.ring = [None if w is None else bucket(
             blocks_for(w + prefill_chunk, bs) + 2)
             for w in self.windows]
+        self.slots = SlotPool(max_batch) if state_slots else None
+        if state_slots and (spec_tables or prefix_cache):
+            raise ValueError(
+                "a state a session: a cached prefix has no snapshot of it "
+                "and a rejected draft no way back; serve without them")
         self.max_batch = max_batch
         self.prefill_chunk = prefill_chunk
         self.max_prefill_backlog = max_prefill_backlog
@@ -393,6 +405,8 @@ class Scheduler:
                 s.table = table + ids
                 s.cow_pending = []
             s.draft_table = draft_ids
+            if self.slots is not None:
+                s.slot = self.slots.take()
             s.position = pos0
             s.draft_position = 0
             s.prefill_src = src
@@ -559,12 +573,15 @@ class Scheduler:
 
     def _free_tables(self, s: Session) -> None:
         """Every group's blocks, and the draft table's, back to their
-        pools."""
+        pools, and the session's state slot with them."""
         for pool, table in zip(self.pools, s.tables):
             pool.free(b for b in table if b != NULL_BLOCK)
         self.pool.free(b for b in s.draft_table if b != NULL_BLOCK)
         s.tables = [[] for _ in self.pools]
         s.draft_table = []
+        if s.slot is not None:
+            self.slots.give(s.slot)
+            s.slot = None
 
     def finish(self, s: Session) -> None:
         self.complete_cow(s)
@@ -647,6 +664,12 @@ class Scheduler:
             + [-1] * (b - len(sessions))
         nb, tables = self.pack_groups(sessions, b)
         return b, nb, tokens, positions, tables
+
+    def pack_slots(self, sessions: List[Session], rows: int) -> List[int]:
+        """Each session's state slot for a dispatch of ``rows`` batch
+        rows; the padding rows take the null slot."""
+        return [s.slot for s in sessions] \
+            + [self.slots.null] * (rows - len(sessions))
 
     def pack_groups(self, sessions: List[Session], rows: int):
         """:meth:`pack_tables` of every cache group as the programs take

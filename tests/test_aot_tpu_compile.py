@@ -675,3 +675,118 @@ def test_mixed_serve_programs_take_every_pool_where_it_lies(chip, mixed,
         for pool in pools:      # nothing takes gather_kv's flat view
             flat = pool.shape[0] * pool.shape[1] * pool.shape[2]
             assert f"[{flat},{pool.shape[3]},{pool.shape[4]}]" not in text
+
+
+# -- the serve programs of the benchmark's state-space hybrid cell -------------
+# (perfbench/configs/nemotron3-nano-30b-a3b-ep4-l13.json at its published
+# widths: 4.31 GB of bf16 weights as abstract parameters; a cache of one
+# group of rows, the 2 attention layers' pool of 65537 blocks, and one
+# state group, the 6 Mamba layers' 257 slots of a float32 state and the
+# convolution's last inputs, sized by the engine).  PR 27's pin for the
+# pool and for the state buffers: no program relayouts, copies or widens
+# either.
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """The cell's model with parameters that have shapes and no values,
+    and its engine settings."""
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    bench = os.path.join(here, "..", "perfbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from pb import cells
+    with open(os.path.join(bench, "configs",
+                           "nemotron3-nano-30b-a3b-ep4-l13.json")) as f:
+        cfg = json.load(f)
+    model = cells.family_module(cfg["builder"]).model(
+        cfg, dtype=jnp.bfloat16)
+    model.eval()
+    return cfg, model
+
+
+@pytest.mark.parametrize("which,batch", [("decode", 256), ("prefill", 1)],
+                         ids=["decode_b256", "prefill_chunk512"])
+def test_hybrid_serve_programs_take_pool_and_state_where_they_lie(
+        chip, hybrid, which, batch):
+    from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import init_pool_buffer, init_state_buffers
+    cfg, model = hybrid
+    sv = cfg["serve"]
+    bs, chunk = sv["block_size"], sv["prefill_chunk"]
+    params = list(model.parameters())
+    vals = [_sds(p.shape, jnp.bfloat16, chip) for p in params]
+    groups, _ = serve_kernels.cache_groups(model)
+    assert [(g.rows, g.window, g.layers) for g in groups] == \
+        [((2, 2, 128), None, (5, 12))]
+    sgroups, _ = serve_kernels.state_groups(model)
+    assert [(g.state, len(g.layers)) for g in sgroups] == [
+        ((((128, 4096), "float32"), ((3, 6144), "bfloat16")), 6)]
+    g = groups[0]
+    pool = _on(chip, jax.eval_shape(lambda: init_pool_buffer(
+        len(g.layers), g.rows[1], g.rows[2], sv["num_blocks"], bs,
+        jnp.dtype(sv["cache_dtype"]), streams=g.rows[0])))
+    # the sizes ServeEngine gives the state groups: a row a slot, and
+    # the null slot
+    states = tuple(_on(chip, jax.eval_shape(
+        lambda sg=sg: init_state_buffers(sg.state, len(sg.layers),
+                                         sv["max_batch"])))
+        for sg in sgroups)
+    assert [s.shape for s in states[0]] == [(6, 257, 128, 4096),
+                                            (6, 257, 3, 6144)]
+    nb = cfg["max_position_embeddings"] // bs       # a whole context
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, chip)
+    build = {"decode": serve_kernels.build_decode_fn,
+             "prefill": serve_kernels.build_prefill_fn}[which]
+    fn = build(model, params, bs, sv["num_blocks"])
+    args = (i32(batch), i32(batch), i32(batch, nb), i32(batch)) \
+        if which == "decode" \
+        else (i32(1, chunk), i32(1, nb), i32(), i32(), i32(1))
+    with force_mode("compiled"):
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            vals, pool, states, *args).compile()
+    ma = _check(compiled, 0)
+    state_bytes = sum(s.size * s.dtype.itemsize for s in states[0])
+    pool_bytes = pool.size * pool.dtype.itemsize
+    # the pool and both state buffers are updated in place
+    assert ma.alias_size_in_bytes >= pool_bytes + state_bytes
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"\n[{which}] arguments {ma.argument_size_in_bytes / 2**30:.3f} "
+          f"temporaries {ma.temp_size_in_bytes / 2**30:.3f} total "
+          f"{total / 2**30:.3f} GiB; pool {pool_bytes / 2**30:.3f} states "
+          f"{state_bytes / 2**30:.3f}")
+    assert total < USABLE_BYTES, total / 2 ** 30
+    # no state-sized temporary (3.06 GiB); the decode program's are 0.1
+    # GiB, the prefill program's 2.3: its attention's float32 scores of
+    # a chunk over a whole context's view, (2, 16, 512, 4096), a few at
+    # a time (the chunk path of kernels/paged_attention.py, as every
+    # served family's)
+    assert ma.temp_size_in_bytes < (2 ** 29 if which == "decode"
+                                    else 5 * 2 ** 29), \
+        ma.temp_size_in_bytes / 2 ** 30
+    text = compiled.as_text()
+    # an expert's input matrix is read where it lies: no copy of a
+    # layer's stack (0.3 GiB) to another layout
+    assert "bf16[32,2688,1856]" not in text
+    big = states[0][0]
+    for op, ln in _pool_sized_results(compiled, big):
+        # the state's buffer only ever passes through: the kernel's
+        # aliased result, or a prefill chunk's one slot set in place; the
+        # pool (larger still) has its rows scattered into it
+        of_pool = " bf16[" in ln.split("(")[0]
+        assert op in ("parameter", "bitcast", "tuple", "get-tuple-element",
+                      "fusion") + (("scatter",) if of_pool else (
+                          "custom-call", "dynamic-update-slice")), ln[:300]
+        if op == "fusion":
+            assert (" scatter(" if of_pool else "dynamic-update-slice(") \
+                in _called_computation(text, ln), ln[:300]
+    calls = _kernel_calls(compiled)
+    assert sum("routed_experts" in c for c in calls) == 2 * 5, calls
+    assert sum("ssm_state_update" in c for c in calls) \
+        == (6 if which == "decode" else 0), calls
+    assert sum("paged_attention_decode" in c for c in calls) \
+        == (2 if which == "decode" else 0), calls

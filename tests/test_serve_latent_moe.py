@@ -8,6 +8,7 @@ of the program; the program's parameters carry the reference's leaf
 names, so one set of arrays feeds both.  Logits are compared, never
 sampled tokens.
 """
+import json
 import math
 import os
 import sys
@@ -398,14 +399,70 @@ def _softmax_shares_add_up():
     np.testing.assert_allclose(whole, np.asarray(want), atol=2e-5)
 
 
-@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+def _ungated_shares_add_up():
+    """The layer whose experts are not gated (``gated=False,
+    act="relu2"``: one input matrix an expert, kept ``(out, in)``;
+    sigmoid scores with a bias and a scale, no groups): the parts that
+    the four shares of ``experts_held`` give (4 of 16 experts each), the
+    shared expert counted once, against the state-space hybrid family's
+    reference for the uncut layer."""
+    family = cells.family_module("hybrid_ssm_moe")
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "nemotron3-nano-30b-a3b-ep4-l13.json")) as f:
+        base = json.load(f)
+    base.update(family.tiny(base))
+
+    def cfg_of(held):
+        return dict(base, router_experts=16, experts_held=list(held),
+                    n_routed_experts=len(held), num_experts_per_tok=3)
+    whole = cfg_of(range(16))
+    leaves = family.draw(whole, jax.random.PRNGKey(5), jnp.float32)
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((40, 64)),
+                    jnp.float32)
+    ref = cells._module_from(os.path.join(REPO, whole["reference"]),
+                             "reference")
+    p = "blocks.1."                                     # an E layer
+    want, _ = ref._experts(whole, leaves, p + "experts.", x, None)
+    shared = ref._relu2(x, leaves[p + "w_in"], leaves[p + "w_out"], None)
+    total, pairs = np.zeros_like(want), 0
+    for first in range(0, 16, 4):
+        share = cfg_of(range(first, first + 4))
+        # a share's draw is a slice of the whole model's draw
+        drawn = family.draw(share, jax.random.PRNGKey(5), jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(drawn[p + "experts.w_in"]),
+            np.asarray(leaves[p + "experts.w_in"][first:first + 4]))
+        mod = RoutedExperts(
+            64, share["moe_intermediate_size"], 16, 3,
+            scale=share["routed_scaling_factor"],
+            experts_held=share["experts_held"], gated=False, act="relu2")
+        assert mod.w_in.shape == mod.w_out.shape == (4, 24, 64)
+        for name in ("router", "router_bias", "w_in", "w_out"):
+            getattr(mod, name).data = drawn[p + "experts." + name]
+        y, n = mod.forward(Ctx(training=False), x)
+        # ... and is what the reference gives for the same share
+        np.testing.assert_allclose(
+            np.asarray(y) + np.asarray(shared),
+            np.asarray(ref._experts(share, drawn, p + "experts.", x,
+                                    None)[0]), atol=1e-5)
+        total += np.asarray(y)
+        pairs += int(np.asarray(n).sum())
+    assert pairs == 40 * 3                              # every pair, once
+    np.testing.assert_allclose(total + np.asarray(shared), np.asarray(want),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax", "relu2"])
 def test_the_shares_add_up_to_the_uncut_layer(score):
     """Guide ``model-configs``, section 4: the routed parts that all 16
     shares give (one expert each here), with the shared expert counted
     once, add up to what the uncut reference gives for the whole
-    layer; the softmax router's layer likewise."""
+    layer; the softmax router's layer, and the layer whose experts are
+    not gated, likewise."""
     if score == "softmax":
         return _softmax_shares_add_up()
+    if score == "relu2":
+        return _ungated_shares_add_up()
     whole = _layer_cfg(range(16))
     leaves = FAMILY.draw(whole, jax.random.PRNGKey(5), jnp.float32)
     x = jnp.asarray(np.random.default_rng(6).standard_normal((40, 64)),

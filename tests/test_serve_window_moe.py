@@ -152,13 +152,15 @@ class _Hand:
 # -- (a) prefill in chunks, then decode, through the two groups -----------------
 
 
-@pytest.mark.parametrize("kv_heads", [8, 2, 1], ids=["mha", "gqa4", "mqa8"])
-def test_two_groups_logits_match_the_reference_forward(kv_heads):
+@pytest.mark.parametrize("kv_heads,heads", [(8, 8), (2, 8), (1, 8), (1, 16)],
+                         ids=["mha", "gqa4", "mqa8", "mqa16"])
+def test_two_groups_logits_match_the_reference_forward(kv_heads, heads):
     """Two sessions, the second joining while the first decodes, to
     depths past ``window + 2 blocks`` (blocks have retired, the window
-    group's ring has wrapped), for 1, 4 and 8 query heads a stored
-    head."""
-    cfg = _tiny_cfg(num_key_value_heads=kv_heads)
+    group's ring has wrapped), for 1, 4, 8 and 16 query heads a stored
+    head (16: the state-space hybrid's attention layers, PR 35)."""
+    cfg = _tiny_cfg(num_key_value_heads=kv_heads,
+                    **{FAMILY.HEADS: heads})
     model, leaves = _served(cfg)
     vocab = cfg["vocab_size"]
     a, b = _toks(1, 60, vocab), _toks(2, 45, vocab)
@@ -428,12 +430,14 @@ def test_one_group_engines_are_what_they_were():
 # -- (e) the Pallas tiers in interpret mode against their XLA tiers ------------
 
 
-@pytest.mark.parametrize("window", [None, 40], ids=["full", "window40"])
-def test_grouped_query_reader_tiers_agree(window):
-    """8 query heads on 2 stored heads of 128, ragged depths, a dead pad
-    row; bfloat16 pool (exact products in both tiers) and a ring table
-    for the window case."""
-    heads, kv, d, bs, nb = 8, 2, 128, 16, 8
+@pytest.mark.parametrize("window,heads", [(None, 8), (40, 8), (None, 32)],
+                         ids=["full", "window40", "full_16_a_stored_head"])
+def test_grouped_query_reader_tiers_agree(window, heads):
+    """8 (or 32: the state-space hybrid's 16 a stored head, a 32 x 256
+    query tile) query heads on 2 stored heads of 128, ragged depths, a
+    dead pad row; bfloat16 pool (exact products in both tiers) and a ring
+    table for the window case."""
+    kv, d, bs, nb = 2, 128, 16, 8
     rng = np.random.default_rng(9)
     pool = jnp.asarray(rng.standard_normal((2, 2, 1 + 4 * nb, bs, kv * d)),
                        jnp.bfloat16)
