@@ -939,12 +939,8 @@ def run_seq2seq_throughput(batch, seq_len, iters, warmup,
     opt = FusedAdam(list(model.parameters()), lr=1e-3)
 
     if chunked:
-        from apex_tpu.contrib.xentropy import chunked_lm_head_loss
-
-        def loss_fn(out, tgt_out):
-            hidden, table = out
-            return jnp.mean(chunked_lm_head_loss(hidden, table, tgt_out,
-                                                 padding_idx=-1))
+        from apex_tpu.contrib.xentropy import make_chunked_lm_loss
+        loss_fn = make_chunked_lm_loss(padding_idx=-1, shift=False)
     else:
         token_losses = _lm_loss_fns(loss_mode == "plain")
 
@@ -980,8 +976,8 @@ def _lm_head_loss(loss_mode, vocab, chunk_rows=None):
       fused    materialized logits + contrib fused xentropy (round-4
                default)
       plain    materialized logits + F.cross_entropy
-      chunked  output_hidden model + chunked_lm_head_loss: head matmul
-               and loss run per row-chunk under jax.checkpoint, (N, V)
+      chunked  output_hidden model + make_chunked_lm_loss: head matmul,
+               loss and gradient run per row-chunk in one loop, (N, V)
                never materializes
       kernel   output_hidden model + the Pallas fused lm-head+loss
                kernel (ops/pallas/lm_head_xent) wired INTO the step —

@@ -333,6 +333,9 @@ def phase_train(sz: Sizes, seed: int) -> None:
     flash = {tier: obs.counter(f"kernels.dispatch.flash_attention.{tier}")
              for tier in ("pallas", "xla", "resident", "packed")}
     flash_before = {tier: c.value for tier, c in flash.items()}
+    head = {path: obs.counter(f"kernels.dispatch.lm_head_loss.{path}")
+            for path in ("grad_with_forward", "checkpointed")}
+    head_before = {path: c.value for path, c in head.items()}
     losses, times = [], []
     for _ in range(sz.train_steps):
         t0 = time.perf_counter()
@@ -379,6 +382,14 @@ def phase_train(sz: Sizes, seed: int) -> None:
             f"train: {took['resident']} of the step's {took['pallas']} "
             f"Pallas attention calls took the resident kernels and "
             f"{took['packed']} their packed entry")
+    # the factory's loss took its gradient with its forward (one loop
+    # over row chunks), and nothing took the checkpointed two
+    took = {path: c.value - head_before[path] for path, c in head.items()}
+    say(f"train: lm_head_loss paths traced into the step: {took}")
+    if took != {"grad_with_forward": 1, "checkpointed": 0}:
+        raise AssertionError(
+            f"train: the step's LM-head loss took {took}, expected one "
+            f"gradient-with-forward pass")
 
 
 # ---------------------------------------------------------------------------

@@ -582,6 +582,85 @@ def test_fused_train_step_moves_no_attention_layout(train_step):
     assert written < 0.6e9, f"{written / 1e9:.2f} GB (5.89 at the parent)"
 
 
+def _computations(compiled):
+    """``{name: [instruction lines]}`` of the compiled module's text."""
+    comps, cur = {}, None
+    for ln in compiled.as_text().splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(ln)
+    return comps
+
+
+def _reached_from(comps, name, seen=None):
+    """``name`` and every computation it calls, fusions included."""
+    seen = set() if seen is None else seen
+    if name in comps and name not in seen:
+        seen.add(name)
+        for ln in comps[name]:
+            for callee in re.findall(
+                    r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", ln):
+                _reached_from(comps, callee, seen)
+    return seen
+
+
+def _products(comps, names):
+    """Each ``convolution`` of the named computations as the list of its
+    result's and its operands' dimensions."""
+    found = []
+    for name in names:
+        dims = {}
+        for ln in comps[name]:
+            m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", ln)
+            if m:
+                dims[m.group(1)] = [int(d) for d in m.group(2).split(",")
+                                    if d]
+        for ln in comps[name]:
+            m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .*? convolution\((.*?)\),",
+                         ln)
+            if m:
+                found.append([dims[m.group(1)]] + [
+                    dims[o] for o in re.findall(r"%[\w.\-]+", m.group(2))])
+    return found
+
+
+def test_fused_train_step_holds_one_loss_loop(train_step):
+    """What ISSUE 36 rests on.  At the parent the step held two loops
+    over the loss's row chunks, the second computing every chunk's logits
+    again: four products as wide as the vocabulary a chunk (my CPU-side
+    compile, PR 36: bodies of 1 and 3 ``convolution``s, ``d table``
+    carried as ``bf16[50257,768]``, 4,003,557,888 B of temporaries).  The
+    factory's loss takes its gradient with its forward: one loop, three
+    such products, ``d table`` a float32 carry, and nothing as wide as
+    the vocabulary times the rows leaves the loop."""
+    comps = _computations(train_step)
+    whiles = [ln for lines in comps.values() for ln in lines
+              if re.search(r" while\(", ln)]
+    assert len(whiles) == 1, whiles
+    carried = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \((.*?)\) while\(",
+                       whiles[0]).group(1)
+    assert f"f32[{VOCAB},{HIDDEN}]" in carried, carried
+    assert not re.search(rf"\[\d+,{VOCAB}\]|\[{VOCAB},\d+,", carried), carried
+    body = re.search(r"body=%?([\w.\-]+)", whiles[0]).group(1)
+    wide = [p for p in _products(comps, _reached_from(comps, body))
+            if any(VOCAB in dims for dims in p)]
+    # logits, d hidden, d table
+    assert len(wide) == 3, wide
+    outside = [p for p in _products(comps, set(comps)
+                                    - _reached_from(comps, body))
+               if any(VOCAB in dims for dims in p)]
+    assert not outside, outside
+    temp = train_step.memory_analysis().temp_size_in_bytes
+    assert temp < 4.6e9, (
+        f"{temp} B of temporaries; 4,365,810,176 with the float32 "
+        f"d table and d hidden kept from the forward to the backward "
+        f"(PR 36), 4,003,557,888 at its parent")
+
+
 # -- the serve programs of the benchmark's window-and-full MoE cell ------------
 # (perfbench/configs/mellum2-12b-a2.5b-l8.json at its published widths:
 # 7.59 GB of bf16 weights as abstract parameters, a cache of two groups:
