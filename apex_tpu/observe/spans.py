@@ -1,9 +1,9 @@
 """Trace spans: one context manager, two outputs, one record.
 
-``span("ckpt.save")`` emits (a) a structured ``span`` event + latency
-histogram into the metrics registry and (b) a
-``jax.profiler.TraceAnnotation`` so the same region shows up in device
-profiles — host events and XLA timelines line up by name.
+``span("ckpt.save")`` emits (a) a structured ``span`` event into the
+metrics registry and (b) a ``jax.profiler.TraceAnnotation`` so the same
+region shows up in device profiles — host events and XLA timelines line
+up by name.
 
 The record is what a tree is built from: a process-unique ``id``, the
 ``parent`` (the span that was open *on the same thread* when this one
@@ -16,6 +16,20 @@ it gives its own.  The annotation is named
 ``tick``) as arguments, so a profiler host event joins to its record.
 :func:`recorded` reads the records back.
 
+A root record (``parent`` None) also carries ``clock_ns``: the
+profiler's host clock (``time.time_ns()``, the realtime clock TSL
+stamps its host events with) less ``time.perf_counter_ns()``, both read
+beside its ``t0_ns``.  A time ``t`` of the root or of any record below
+it is ``t + clock_ns`` on the profiler's clock; a trace file counts
+from its session's ``profile_start_time`` on that clock.
+
+A collector pause is a record too: ``host.gc``, with the collection's
+``generation``, from one ``gc.callbacks`` hook installed on import.  Its
+parent is the span open on the collecting thread (its ``tick`` is that
+parent's), and it has an annotation like any span.  The hook takes no
+lock: its records reach the registry at the next span's exit on any
+thread, or at :func:`recorded`.
+
 Spans are host-side instrumentation; entering one from jit-traced code
 is a host round-trip and is flagged by the OBS-IN-JIT lint rule.
 Thread-safe: the prefetch worker and async-checkpoint writer open spans
@@ -24,7 +38,9 @@ the watchdog reads ``last_span()`` from its heartbeat thread.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import itertools
 import threading
 import time
@@ -47,6 +63,10 @@ _open = threading.local()           # .stack: this thread's open records
 
 _trace_annotation = None
 _trace_annotation_probed = False
+
+#: finished ``host.gc`` records not yet in the registry (appended from
+#: the collector's hook, which must not take the registry's lock)
+_gc_done: collections.deque = collections.deque()
 
 
 def _get_trace_annotation():
@@ -77,10 +97,29 @@ def annotation_name(name: str, fields: Dict[str, Any]) -> str:
     return f"{name}.{kind}" if kind is not None else name
 
 
+def _annotation(name: str, rec: Dict[str, Any]):
+    """The profiler annotation of a record, not yet entered."""
+    annotation = _get_trace_annotation()
+    if annotation is None:
+        return contextlib.nullcontext()
+    args = {"id": rec["id"], "tick": rec["tick"]} if "tick" in rec \
+        else {"id": rec["id"]}
+    return annotation(name, **args)
+
+
+def _flush_gc() -> None:
+    while _gc_done:
+        try:
+            rec = _gc_done.popleft()
+        except IndexError:          # another thread took the last one
+            return
+        _registry.event("span", **rec)
+
+
 @contextlib.contextmanager
 def span(name: str, **fields: Any):
-    """Time a region; emit a ``span`` event and a ``span.<name>_ms``
-    histogram sample on exit, wrapped in a profiler TraceAnnotation.
+    """Time a region; emit a ``span`` event on exit, wrapped in a
+    profiler TraceAnnotation.
 
     Yields the record being built (the caller's fields, ``span``,
     ``id``, ``parent``, ``t0_ns``): the caller may add fields that
@@ -97,15 +136,11 @@ def span(name: str, **fields: Any):
            "parent": parent["id"] if parent else None}
     if parent and "tick" in parent:
         rec.setdefault("tick", parent["tick"])
-    annotation = _get_trace_annotation()
-    if annotation is None:
-        cm = contextlib.nullcontext()
-    else:
-        args = {"id": sid, "tick": rec["tick"]} if "tick" in rec \
-            else {"id": sid}
-        cm = annotation(annotation_name(name, fields), **args)
+    cm = _annotation(annotation_name(name, fields), rec)
     stack.append(rec)
     t0 = rec["t0_ns"] = time.perf_counter_ns()
+    if parent is None:
+        rec["clock_ns"] = time.time_ns() - t0
     with _state_lock:
         _last_span = {"span": name, "started_ms": t0 / 1e6, **fields}
     try:
@@ -114,15 +149,43 @@ def span(name: str, **fields: Any):
     finally:
         t1 = rec["t1_ns"] = time.perf_counter_ns()
         stack.pop()
-        dur_ms = rec["dur_ms"] = (t1 - t0) / 1e6
-        _registry.histogram(f"span.{name}_ms").observe(dur_ms)
+        rec["dur_ms"] = (t1 - t0) / 1e6
+        _flush_gc()
         _registry.event("span", **rec)
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """The ``gc.callbacks`` hook: a collection is a ``host.gc`` record
+    below the span open on the collecting thread."""
+    if phase == "start":
+        stack = getattr(_open, "stack", None)
+        parent = stack[-1] if stack else None
+        rec = {"generation": info["generation"], "span": "host.gc",
+               "id": next(_ids), "parent": parent["id"] if parent else None}
+        if parent and "tick" in parent:
+            rec["tick"] = parent["tick"]
+        cm = _annotation("host.gc", rec)
+        t0 = rec["t0_ns"] = time.perf_counter_ns()
+        if parent is None:
+            rec["clock_ns"] = time.time_ns() - t0
+        cm.__enter__()
+        _open.gc = rec, cm
+        return
+    rec, cm = _open.gc
+    t1 = rec["t1_ns"] = time.perf_counter_ns()
+    cm.__exit__(None, None, None)
+    rec["dur_ms"] = (t1 - rec["t0_ns"]) / 1e6
+    _gc_done.append(rec)
+
+
+gc.callbacks.append(_on_gc)
 
 
 def recorded(since_ns: Optional[int] = None) -> List[Dict[str, Any]]:
     """The ``span`` records in memory (the newest ``SPAN_RING``) that
     started at or after ``since_ns`` on the ``time.perf_counter_ns()``
     clock, oldest first by start: a parent comes before its children."""
+    _flush_gc()
     recs = _registry.events("span")
     if since_ns is not None:
         recs = [r for r in recs if r["t0_ns"] >= since_ns]

@@ -567,7 +567,8 @@ class ServeEngine:
                          prefill_rid=None) as tick:
             more = self._run_tick(tick)
             if self._counted:       # what no decode fetch took with it
-                self._fetched += jax.device_get(self._counted)
+                with _spans.span("serve.fetch", what="counts"):
+                    self._fetched += jax.device_get(self._counted)
                 self._counted = []
             if self._fetched:
                 self._publish_moe(tick)
@@ -816,13 +817,14 @@ class ServeEngine:
         with _spans.span("serve.pack"):
             b, nb, tokens, positions, tables = \
                 self.scheduler.pack_decode(sessions)
-            slots = (np.asarray(self.scheduler.pack_slots(sessions, b),
-                                np.int32),) if self.state_groups else ()
+            operands = (np.asarray(tokens, np.int32),
+                        np.asarray(positions, np.int32), self._tables(tables))
+            if self.state_groups:
+                operands += (np.asarray(self.scheduler.pack_slots(
+                    sessions, b), np.int32),)
         nxt, _logits, pools, counted, *states = _executor.executor.submit(
             decode_prog,
-            (self._vals(), self._cache(), *self._states(),
-             np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
-             self._tables(tables), *slots),
+            (self._vals(), self._cache(), *self._states(), *operands),
             step=next(self._dispatch_no))
         self._set_cache(pools, states)
         if counted is not None:
@@ -903,12 +905,11 @@ class ServeEngine:
         with _spans.span("serve.pack"):
             b, nbt, nbd, tokens, positions, t_tables, d_tables = \
                 self.scheduler.pack_spec(sessions)
+            operands = tuple(np.asarray(x, np.int32) for x in
+                             (tokens, positions, t_tables, d_tables))
         emitted, n_acc, self.pool, self.dpool = _executor.executor.submit(
             spec_prog,
-            (self._vals(), self._d_vals(), self.pool, self.dpool,
-             np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
-             np.asarray(t_tables, np.int32),
-             np.asarray(d_tables, np.int32)),
+            (self._vals(), self._d_vals(), self.pool, self.dpool, *operands),
             step=next(self._dispatch_no))
         with _spans.span("serve.fetch", what="spec_tokens"):
             emitted = np.asarray(emitted)

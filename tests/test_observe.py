@@ -3,6 +3,7 @@ zero-dispatch on-device telemetry carry (bitwise grad-norm parity with an
 eager recompute, 1-compile/1-dispatch pin under accumulation), trace
 spans, and the stall watchdog (fires under an injected chaos stall, stays
 silent on a clean run)."""
+import gc
 import json
 import time
 
@@ -22,6 +23,19 @@ from apex_tpu.runtime import chaos, step_cache
 from apex_tpu.training import make_train_step
 
 pytestmark = pytest.mark.observe
+
+
+@pytest.fixture(autouse=True)
+def _no_automatic_collections():
+    """The span tests read exact lists of records, and a collection is a
+    ``host.gc`` record: a test that wants one collects by hand."""
+    from apex_tpu.observe import spans
+    was = gc.isenabled()
+    gc.disable()
+    spans._flush_gc()               # collections of the tests before
+    yield
+    if was:
+        gc.enable()
 
 
 def _mlp(seed=0, din=8, hidden=16, dout=4):
@@ -78,7 +92,7 @@ def test_registry_jsonl_schema_roundtrip(tmp_path):
     assert "c" not in snap["counters"] and "g" in snap["gauges"]
 
 
-def test_span_emits_event_histogram_and_last_span():
+def test_span_emits_event_and_last_span():
     reg = get_registry()
     reg.clear_events()
     with span("test.region", phase="fwd"):
@@ -87,7 +101,7 @@ def test_span_emits_event_histogram_and_last_span():
     assert ev["phase"] == "fwd" and ev["dur_ms"] >= 0
     assert ev["schema"] == SCHEMA_VERSION
     assert last_span()["span"] == "test.region"
-    assert reg.histogram("span.test.region_ms").count >= 1
+    assert ev["dur_ms"] == (ev["t1_ns"] - ev["t0_ns"]) / 1e6
 
 
 def test_nested_spans_record_a_tree():
@@ -220,6 +234,76 @@ def test_annotation_name_carries_the_kind(monkeypatch, fields, name, args):
         pass
     assert made == [(name, dict(args, id=rec["id"]))]
     assert spans.annotation_name("dispatch", fields) == name
+
+
+def test_clock_ns_is_on_root_records_only():
+    """A root record carries the profiler's clock less perf_counter,
+    read beside its start; a record below it is mapped by its root's."""
+    import threading
+    get_registry().clear_events()
+    before = time.time_ns() - time.perf_counter_ns()
+    with span("t.root", tick=2) as root:
+        with span("t.child") as child:
+            pass
+
+    def worker():
+        with span("t.worker"):
+            pass
+    th = threading.Thread(target=worker)
+    th.start()
+    th.join(timeout=10)
+    after = time.time_ns() - time.perf_counter_ns()
+    recs = {e["span"]: e for e in get_registry().events("span")}
+    for name in ("t.root", "t.worker"):
+        assert recs[name]["parent"] is None
+        # the two clocks' difference, to the time of two reads
+        assert min(before, after) - 1_000_000 <= recs[name]["clock_ns"] \
+            <= max(before, after) + 1_000_000
+    assert "clock_ns" not in child and "clock_ns" in root
+    assert "clock_ns" not in recs["t.child"]
+
+
+def test_a_collection_is_a_host_gc_record_of_the_open_span(monkeypatch):
+    """A collector pause is a ``host.gc`` record: below the span open on
+    the collecting thread and of its tick, with its own annotation; one
+    outside every span is a root with a clock."""
+    import contextlib
+
+    from apex_tpu.observe import spans
+    made = []
+
+    def fake(label, **kw):
+        made.append((label, kw))
+        return contextlib.nullcontext()
+    spans._get_trace_annotation()           # probe before patching
+    monkeypatch.setattr(spans, "_trace_annotation", fake)
+    get_registry().clear_events()
+    with span("t.root", tick=6):
+        with span("t.child") as child:
+            gc.collect(1)
+    gc.collect(0)
+    gcs = [r for r in spans.recorded() if r["span"] == "host.gc"]
+    assert [r["generation"] for r in gcs] == [1, 0]
+    inner, outer = gcs
+    assert inner["parent"] == child["id"] and inner["tick"] == 6
+    assert "clock_ns" not in inner
+    assert child["t0_ns"] <= inner["t0_ns"] <= inner["t1_ns"] \
+        <= child["t1_ns"]
+    assert inner["dur_ms"] == (inner["t1_ns"] - inner["t0_ns"]) / 1e6
+    assert outer["parent"] is None and "tick" not in outer
+    assert "clock_ns" in outer
+    assert ("host.gc", {"id": inner["id"], "tick": 6}) in made
+    assert ("host.gc", {"id": outer["id"]}) in made
+
+
+def test_no_span_histogram_is_left():
+    """A span's duration is on its record; only the documented operator
+    histograms (``serve.decode_tick_ms``, ...) are histograms."""
+    reg = get_registry()
+    with span("t.hist", kind="k"):
+        pass
+    assert not [h for h in reg.snapshot()["histograms"]
+                if h.startswith("span.")]
 
 
 # ---------------------------------------------------------------------------

@@ -597,7 +597,8 @@ def test_an_engine_with_a_draft_speculates(model):
 
 #: what may open directly under ``serve.step`` (docs/observability.md)
 TICK_CHILDREN = {"serve.admit", "serve.prefill_chunk", "serve.ensure_blocks",
-                 "serve.pack", "dispatch", "serve.fetch", "serve.commit"}
+                 "serve.pack", "dispatch", "serve.fetch", "serve.commit",
+                 "host.gc"}
 
 
 @pytest.mark.parametrize("spec", [False, True], ids=["plain", "speculative"])
@@ -613,7 +614,9 @@ def test_every_tick_records_a_span_tree(model, spec):
             for i in range(4)]
     out = eng.run(reqs, arrivals=[0, 0, 1, 3])
     assert all(len(out[r.rid]) == 5 for r in reqs)
-    recs = spans.recorded()
+    # a collection between two ticks is a root of no tick
+    recs = [r for r in spans.recorded()
+            if r["span"] != "host.gc" or r["parent"] is not None]
     by_id = {r["id"]: r for r in recs}
     steps = [r for r in recs if r["span"] == "serve.step"]
     # one root a tick, in order, none nested in another span
@@ -668,4 +671,47 @@ def test_every_tick_records_a_span_tree(model, spec):
     assert hist.count == len(decoding)
     assert hist.last == decoding[-1]["dur_ms"]
     assert hist.total == pytest.approx(sum(r["dur_ms"] for r in decoding))
+    eng.block_pool.check_no_leaks()
+
+
+def test_a_prefill_only_tick_reads_its_counts_under_a_fetch(model):
+    """What a tick's programs counted and no decode fetch took with it
+    (a tick that only prefills) is read under ``serve.fetch`` of what
+    ``counts``, after the chunk that counted it."""
+    from apex_tpu.observe import spans
+    eng = ServeEngine(model, num_blocks=64, block_size=8, max_batch=4,
+                      prefill_chunk=4)
+    eng.submit(Request("p", list(range(1, 13)), 3))     # three chunks
+    # a routed model's chunk hands back its counts: stand one in
+    eng._counted.append(jnp.zeros((1, 2), jnp.int32))
+    since = spans.recorded()[-1]["t0_ns"] + 1 if spans.recorded() else 0
+    eng.step()
+    recs = spans.recorded(since)
+    (root,) = [r for r in recs if r["span"] == "serve.step"]
+    kids = [r for r in recs if r["parent"] == root["id"]
+            and r["span"] != "host.gc"]
+    assert [k["span"] for k in kids] == ["serve.admit", "serve.prefill_chunk",
+                                         "serve.ensure_blocks", "serve.fetch"]
+    assert kids[-1]["what"] == "counts" and kids[-1]["tick"] == root["tick"]
+    assert root["decode_batch"] == 0 and root["moe_pairs"] == 0
+    assert eng._counted == []
+    eng.close()
+
+
+def test_the_pack_span_holds_the_operands_conversion(model, monkeypatch):
+    """A decode tick's tables become the program's arrays inside
+    ``serve.pack``; a prefill chunk's stay under its own span."""
+    from apex_tpu.observe import spans
+    eng = ServeEngine(model, num_blocks=64, block_size=8, max_batch=4,
+                      prefill_chunk=4)
+    seen = []
+    tables = eng._tables
+
+    def spy(packed):
+        seen.append(spans._open.stack[-1]["span"])
+        return tables(packed)
+    monkeypatch.setattr(eng, "_tables", spy)
+    out = eng.run([Request("a", [3, 4, 5, 6, 7, 8], 4)])
+    assert len(out["a"]) == 4
+    assert seen == ["serve.prefill_chunk"] * 2 + ["serve.pack"] * 3
     eng.block_pool.check_no_leaks()
