@@ -19,12 +19,14 @@ docs/serving.md.
 from .disagg import DisaggregatedEngine
 from .elastic import FleetMember, ServeFleet, StaleEpochError
 from .engine import ServeEngine
-from .pool import BlockPool, NULL_BLOCK, blocks_for, init_pool_buffer
+from .pool import (BlockPool, BlockTable, NULL_BLOCK, blocks_for,
+                   init_pool_buffer)
 from .scheduler import Request, SLO_CLASSES, Scheduler, Session, bucket
 
 __all__ = [
     "DisaggregatedEngine", "ServeEngine", "ServeFleet", "FleetMember",
     "StaleEpochError", "SLO_CLASSES", "Request", "Scheduler",
-    "Session", "bucket", "BlockPool", "NULL_BLOCK", "blocks_for",
+    "Session", "bucket", "BlockPool", "BlockTable", "NULL_BLOCK",
+    "blocks_for",
     "init_pool_buffer",
 ]

@@ -56,7 +56,7 @@ from ..runtime import executor as _executor
 from . import kernels as _kernels
 from .pool import (BlockPool, blocks_for, init_pool_buffer,
                    init_state_buffers)
-from .scheduler import DECODE, Request, Scheduler, Session, bucket
+from .scheduler import DECODE, Request, Scheduler, Session
 
 #: per-engine token in the serve program static keys — two engines over
 #: identically-shaped models must never share a cache entry (their
@@ -673,10 +673,9 @@ class ServeEngine:
 
     def _tables(self, packed):
         """Packed tables (one group's, or a tuple a group) as the
-        programs' int32 operands."""
-        if isinstance(packed, tuple):
-            return tuple(np.asarray(t, np.int32) for t in packed)
-        return np.asarray(packed, np.int32)
+        programs' operands: the scheduler's int32 arrays as they are,
+        fresh for this dispatch (``docs/serving.md``, packing)."""
+        return packed
 
     def _prefill(self, s: Session, chunk: int, n: int) -> None:
         prefill_prog, _ = self._programs()
@@ -708,13 +707,11 @@ class ServeEngine:
             # target cursor PAST rows the draft never saw — it skips
             # lockstep and repairs through the catch-up path instead.
             draft_prog, _ = self._spec_programs()
-            nbd = bucket(len(s.draft_table))
-            d_table = s.draft_table + [0] * (nbd - len(s.draft_table))
             _dl, self.dpool, _ = _executor.executor.submit(
                 draft_prog,
                 (self._d_vals(), self.dpool,
                  np.asarray([toks], np.int32),
-                 np.asarray([d_table], np.int32),
+                 self.scheduler.pack_rows([s.draft_table], 1)[1],
                  np.int32(t0), np.int32(n)),
                 step=next(self._dispatch_no))
             s.draft_position = t0 + n
@@ -754,12 +751,10 @@ class ServeEngine:
         d0 = s.draft_position
         n = min(chunk, s.position - d0)
         toks = list(fed[d0:d0 + n]) + [0] * (chunk - n)
-        nbd = bucket(len(s.draft_table))
-        d_table = s.draft_table + [0] * (nbd - len(s.draft_table))
         _dl, self.dpool, _ = _executor.executor.submit(
             draft_prog,
-            (self._d_vals(), self.dpool,
-             np.asarray([toks], np.int32), np.asarray([d_table], np.int32),
+            (self._d_vals(), self.dpool, np.asarray([toks], np.int32),
+             self.scheduler.pack_rows([s.draft_table], 1)[1],
              np.int32(d0), np.int32(n)),
             step=next(self._dispatch_no))
         s.draft_position = d0 + n
@@ -814,9 +809,10 @@ class ServeEngine:
 
     def _decode_tick(self, sessions: List[Session]) -> None:
         _, decode_prog = self._programs()
-        with _spans.span("serve.pack"):
+        with _spans.span("serve.pack") as rec:
             b, nb, tokens, positions, tables = \
                 self.scheduler.pack_decode(sessions)
+            rec["entries"] = b * (sum(nb) if isinstance(nb, tuple) else nb)
             operands = (np.asarray(tokens, np.int32),
                         np.asarray(positions, np.int32), self._tables(tables))
             if self.state_groups:
@@ -902,11 +898,13 @@ class ServeEngine:
         pattern, eos/max_new truncation, or preemption does to tick
         boundaries."""
         _, spec_prog = self._spec_programs()
-        with _spans.span("serve.pack"):
+        with _spans.span("serve.pack") as rec:
             b, nbt, nbd, tokens, positions, t_tables, d_tables = \
                 self.scheduler.pack_spec(sessions)
-            operands = tuple(np.asarray(x, np.int32) for x in
-                             (tokens, positions, t_tables, d_tables))
+            rec["entries"] = b * (nbt + nbd)
+            operands = (np.asarray(tokens, np.int32),
+                        np.asarray(positions, np.int32),
+                        *self._tables((t_tables, d_tables)))
         emitted, n_acc, self.pool, self.dpool = _executor.executor.submit(
             spec_prog,
             (self._vals(), self._d_vals(), self.pool, self.dpool, *operands),
@@ -1020,15 +1018,7 @@ class ServeEngine:
             if draft_ids:
                 self.block_pool.free(draft_ids)
             raise
-        s = Session(request, self.scheduler._seq)
-        self.scheduler._seq += 1
-        s.table = ids
-        s.draft_table = draft_ids
-        s.position = int(position)
-        s.draft_position = 0
-        s.state = DECODE
-        s.prefill_src = ()
-        s.emit_on_prefill = False
+        s = self.scheduler.import_session(request, ids, draft_ids, position)
         s.pending_tok = int(pending_tok)
         s.out = list(out)
         s.t_queued = t_queued
@@ -1049,7 +1039,7 @@ class ServeEngine:
             # session (mixed-epoch semantics, docs/rollout.md) but must
             # never be published for cross-request reuse
             s.cacheable = False
-        self.scheduler.sessions.append(s)
+        self._retire(s)
         _obs.event("serve.request", rid=s.rid, phase="ingested",
                    tick=self._tick, blocks=have,
                    generated=len(s.out))

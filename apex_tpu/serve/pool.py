@@ -70,9 +70,10 @@ from __future__ import annotations
 import hashlib
 import threading
 from collections import OrderedDict
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..inference.quant import QuantKV
 from ..observe import registry as _obs
@@ -84,6 +85,97 @@ NULL_BLOCK = 0
 def blocks_for(n_positions: int, block_size: int) -> int:
     """Blocks needed to hold ``n_positions`` KV rows."""
     return -(-max(int(n_positions), 0) // block_size)
+
+
+class BlockTable:
+    """One session's block table of one cache group: logical block ``i``
+    -> physical id, in logical order, held as an int32 row (its capacity
+    doubles as it grows) and a length.  It reads as a list of Python
+    ints — ``len``, iteration, indexing and slices (a slice is a list)
+    — so the pools, the hash index and the handoff manifests see plain
+    ids; ``append`` / ``extend`` grow it and ``table[i] = NULL_BLOCK``
+    retires an entry.  The scheduler packs a tick's tables by copying
+    :attr:`ids` (and :attr:`ring`) into a fresh array, so no Python int
+    is made on the way to the device.
+
+    ``ring``: the width of a window group's ring (``Scheduler.ring``),
+    None for a group that keeps every key.  The table then keeps the
+    ring as the programs read it beside its row, logical block ``i`` at
+    entry ``i mod ring``, written where an entry is; retiring an entry
+    clears its ring slot only while the slot still holds that block, so
+    the ring does not depend on whether a tick grows the table before or
+    after it retires the band's tail.
+
+    Every entry written in place counts into the counter
+    ``serve.tables.entries_patched`` (docs/observability.md)."""
+
+    __slots__ = ("_ids", "_n", "ring")
+
+    def __init__(self, ids: Sequence[int] = (), ring: Optional[int] = None):
+        self._ids = np.zeros(max(len(ids), 4), np.int32)
+        self._n = 0
+        self.ring = None if ring is None else np.zeros(ring, np.int32)
+        self.extend(ids)
+
+    @property
+    def ids(self) -> np.ndarray:
+        """The entries as an int32 view of the table's own row: copy it
+        before a program may read it (the row changes in place)."""
+        return self._ids[:self._n]
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __iter__(self):
+        return iter(self.ids.tolist())
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return self.ids[i].tolist()
+        return int(self.ids[i])
+
+    def __setitem__(self, i: int, block: int) -> None:
+        old = self[i]
+        i %= self._n
+        self._ids[i] = block
+        if self.ring is not None:
+            slot = i % len(self.ring)
+            if block != NULL_BLOCK:
+                self.ring[slot] = block
+            elif self.ring[slot] == old:
+                self.ring[slot] = NULL_BLOCK
+        _obs.counter("serve.tables.entries_patched").inc()
+
+    def __repr__(self) -> str:
+        return f"BlockTable({self.ids.tolist()})"
+
+    def append(self, block: int) -> None:
+        self.extend((block,))
+
+    def extend(self, ids: Sequence[int]) -> None:
+        k = len(ids)
+        if not k:
+            return
+        n = self._n
+        if n + k > len(self._ids):
+            grown = np.zeros(max(2 * len(self._ids), n + k), np.int32)
+            grown[:n] = self._ids[:n]
+            self._ids = grown
+        self._ids[n:n + k] = ids
+        self._n = n + k
+        if self.ring is not None:
+            w = len(self.ring)
+            for i in range(n, n + k):
+                self.ring[i % w] = self._ids[i]
+        _obs.counter("serve.tables.entries_patched").inc(k)
+
+    def clear(self) -> None:
+        """Every entry dropped (the blocks went back to their pool)."""
+        if self._n:
+            _obs.counter("serve.tables.entries_patched").inc(self._n)
+        self._n = 0
+        if self.ring is not None:
+            self.ring[:] = NULL_BLOCK
 
 
 def init_pool_buffer(layers, heads, head_dim, num_blocks, block_size,

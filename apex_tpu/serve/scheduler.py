@@ -6,8 +6,9 @@ request's latency and the batch slots they vacate idle.  Continuous
 batching (Orca's iteration-level scheduling, vLLM's default) re-packs
 the live set every tick: a session that finishes frees its batch slot
 and its KV blocks *this* tick, and a queued request can take them the
-next.  This module is the host-side half of that loop — pure Python
-over integers, deterministic for a given request/arrival stream (the
+next.  This module is the host-side half of that loop — host integer
+bookkeeping (a session's block tables are int32 rows, packed by array
+copies), deterministic for a given request/arrival stream (the
 packing-determinism test replays a seeded Poisson trace twice and
 diffs the decisions).
 
@@ -44,9 +45,11 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from ..inference.rolling import window_retired_blocks
-from .pool import (BlockPool, NULL_BLOCK, SlotPool, blocks_for, chain_key,
-                   chain_keys)
+from .pool import (BlockPool, BlockTable, NULL_BLOCK, SlotPool, blocks_for,
+                   chain_key, chain_keys)
 
 QUEUED, PREFILL, DECODE, DONE = "queued", "prefill", "decode", "done"
 
@@ -97,15 +100,16 @@ class Request:
 @dataclass
 class Session:
     """Scheduler-side state of one admitted request.  The KV state a
-    session owns is exactly ``tables`` (physical block ids, one table a
-    cache group; ``table`` is the first group's, the only one of most
-    models) plus ``position`` (KV rows written) — no private cache
-    buffer; the pools hold the bytes.  A table is in logical order,
-    entry ``i`` the block of positions ``[i*bs, (i+1)*bs)``; under a
-    window the entries before the band are NULL."""
+    session owns is exactly ``tables`` (physical block ids, one
+    :class:`BlockTable` a cache group; ``table`` is the first group's,
+    the only one of most models) plus ``position`` (KV rows written) —
+    no private cache buffer; the pools hold the bytes.  A table is in
+    logical order, entry ``i`` the block of positions ``[i*bs,
+    (i+1)*bs)``; under a window the entries before the band are NULL."""
     request: Request
     seq: int                               # admission order (preemption)
-    tables: List[List[int]] = field(default_factory=lambda: [[]])
+    tables: List[BlockTable] = field(
+        default_factory=lambda: [BlockTable()])
     position: int = 0                      # KV rows written so far
     state: str = PREFILL
     prefill_src: Tuple[int, ...] = ()      # tokens still to ingest
@@ -116,7 +120,7 @@ class Session:
     # SAME BlockPool free-list, and how many draft KV rows are written
     # (lags `position` when a handed-off session's draft cache is still
     # catching up on the prompt; equal once spec ticks may include it)
-    draft_table: List[int] = field(default_factory=list)
+    draft_table: BlockTable = field(default_factory=BlockTable)
     draft_position: int = 0
     # target weight epoch the session was admitted under (engine-stamped
     # at admission; epochs only grow, so this is the OLDEST weights any
@@ -155,12 +159,8 @@ class Session:
         return self.request.rid
 
     @property
-    def table(self) -> List[int]:
+    def table(self) -> BlockTable:
         return self.tables[0]
-
-    @table.setter
-    def table(self, ids: List[int]) -> None:
-        self.tables[0] = ids
 
     @property
     def prefill_remaining(self) -> int:
@@ -395,16 +395,15 @@ class Scheduler:
             self.queue.popleft()
             s.seq = self._seq
             self._seq += 1
-            s.tables = [[]] + more
             table = [NULL_BLOCK] * lo + held
             if fork:
-                fsrc, fdst = table[-1], ids[0]
-                s.table = table[:-1] + ids
-                s.cow_pending = [(len(table) - 1, fsrc, fdst)]
+                s.cow_pending = [(len(table) - 1, table[-1], ids[0])]
+                table = table[:-1]
             else:
-                s.table = table + ids
                 s.cow_pending = []
-            s.draft_table = draft_ids
+            s.tables = [self.new_table(g, t)
+                        for g, t in enumerate([table + ids] + more)]
+            s.draft_table = BlockTable(draft_ids)
             if self.slots is not None:
                 s.slot = self.slots.take()
             s.position = pos0
@@ -420,6 +419,29 @@ class Scheduler:
             self.sessions.append(s)
             admitted.append(s)
         return admitted
+
+    def new_table(self, group: int, ids: Sequence[int] = ()) -> BlockTable:
+        """A session's table of cache group ``group`` holding ``ids``
+        (a ring beside it where the group keeps a window)."""
+        return BlockTable(ids, self.ring[group])
+
+    def import_session(self, request: Request, ids: Sequence[int],
+                       draft_ids: Sequence[int], position: int) -> Session:
+        """A session whose KV rows ``0 .. position - 1`` arrived in the
+        blocks ``ids`` from another engine (a KV handoff; one cache
+        group), joined to the live set in DECODE; ``draft_ids`` is its
+        draft table, empty of rows.  The caller retires a window's
+        blocks before the band, as after any advance."""
+        s = Session(request, self._seq)
+        self._seq += 1
+        s.tables = [self.new_table(0, ids)]
+        s.draft_table = BlockTable(draft_ids)
+        s.position = int(position)
+        s.state = DECODE
+        s.prefill_src = ()
+        s.emit_on_prefill = False
+        self.sessions.append(s)
+        return s
 
     def _first_grant(self, group: int, pos0: int, n_positions: int) -> int:
         """The length admission gives the table of ``group`` for a
@@ -508,19 +530,32 @@ class Scheduler:
         ``n_positions`` KV rows; False, and nothing taken, if a pool is
         dry (caller preempts and retries)."""
         tables = [s.draft_table] if draft else s.tables
-        want = blocks_for(n_positions, self.pool.block_size)
-        short = [(pool, table) for pool, table in zip(self.pools, tables)
-                 if len(table) < want]
+        bs = self.pool.block_size
+        want = blocks_for(n_positions, bs)
+        short = [g for g, table in enumerate(tables) if len(table) < want]
+        for g in short:
+            # the blocks from the band on have to fit the ring the
+            # table is packed into (the most they are is now: the band
+            # only moves on from here)
+            window, width = self.windows[g], self.ring[g]
+            if window is None:
+                continue
+            live = want - min(window_retired_blocks(s.position, window, bs),
+                              want)
+            if live > width:
+                raise RuntimeError(
+                    f"session {s.rid} would hold {live} blocks of a window "
+                    f"group whose ring is {width} wide")
         got = []
-        for pool, table in short:
-            ids = pool.alloc(want - len(table))
+        for g in short:
+            ids = self.pools[g].alloc(want - len(tables[g]))
             if ids is None:
-                for p, t in got:
-                    p.free(t)
+                for g2, ids2 in got:
+                    self.pools[g2].free(ids2)
                 return False
-            got.append((pool, ids))
-        for (_, table), (_, ids) in zip(short, got):
-            table.extend(ids)
+            got.append((g, ids))
+        for g, ids in got:
+            tables[g].extend(ids)
         return True
 
     def evict(self, victim: Session) -> Session:
@@ -574,11 +609,10 @@ class Scheduler:
     def _free_tables(self, s: Session) -> None:
         """Every group's blocks, and the draft table's, back to their
         pools, and the session's state slot with them."""
-        for pool, table in zip(self.pools, s.tables):
+        held = list(zip(self.pools, s.tables)) + [(self.pool, s.draft_table)]
+        for pool, table in held:
             pool.free(b for b in table if b != NULL_BLOCK)
-        self.pool.free(b for b in s.draft_table if b != NULL_BLOCK)
-        s.tables = [[] for _ in self.pools]
-        s.draft_table = []
+            table.clear()
         if s.slot is not None:
             self.slots.give(s.slot)
             s.slot = None
@@ -615,6 +649,22 @@ class Scheduler:
         return total
 
     # -- packing -----------------------------------------------------------
+    # Every pack fills fresh int32 arrays from the sessions' BlockTable
+    # rows, one slice copy a session: the program's operand is never a
+    # view of a table that a later grow or retirement patches in place
+    # (JAX may alias a host array it is handed until it has read it).
+
+    @staticmethod
+    def pack_rows(tables: Sequence[BlockTable], rows: int):
+        """``(bucket_blocks, tables)`` of tables packed whole, in logical
+        order: ``rows`` rows (the tables, then all-null padding) of the
+        next block bucket of the longest."""
+        nb = bucket(max(len(t) for t in tables))
+        out = np.full((rows, nb), NULL_BLOCK, np.int32)
+        for r, t in enumerate(tables):
+            ids = t.ids
+            out[r, :len(ids)] = ids
+        return nb, out
 
     def pack_tables(self, sessions: List[Session], rows: int,
                     group: int = 0):
@@ -623,41 +673,24 @@ class Scheduler:
         group that keeps every key packs each table whole, padded to the
         next block bucket; a window group packs the blocks from the band
         on as a ring of its fixed width, logical block ``i`` at entry
-        ``i mod width``."""
-        window, width = self.windows[group], self.ring[group]
-        tables = []
-        if window is None:
-            nb = bucket(max(len(s.tables[group]) for s in sessions))
-            for s in sessions:
-                t = s.tables[group]
-                tables.append(t + [NULL_BLOCK] * (nb - len(t)))
-        else:
-            nb = width
-            bs = self.pool.block_size
-            for s in sessions:
-                t = s.tables[group]
-                lo = min(window_retired_blocks(s.position, window, bs),
-                         len(t))
-                live, at = t[lo:], lo % nb
-                if len(live) > nb:
-                    raise RuntimeError(
-                        f"session {s.rid} holds {len(live)} blocks of a "
-                        f"window group whose ring is {nb} wide")
-                head = min(len(live), nb - at)
-                row = [NULL_BLOCK] * nb
-                row[at:at + head] = live[:head]
-                row[:len(live) - head] = live[head:]
-                tables.append(row)
-        tables += [[NULL_BLOCK] * nb] * (rows - len(sessions))
-        return nb, tables
+        ``i mod width`` — each table's own :attr:`BlockTable.ring`
+        (:meth:`grow` keeps a table's blocks from the band on within it)."""
+        nb = self.ring[group]
+        if nb is None:
+            return self.pack_rows([s.tables[group] for s in sessions], rows)
+        out = np.full((rows, nb), NULL_BLOCK, np.int32)
+        out[:len(sessions)] = np.stack([s.tables[group].ring
+                                        for s in sessions])
+        return nb, out
 
     def pack_decode(self, sessions: List[Session]):
-        """Bucketed operand arrays for one decode tick:
-        ``(bucket_batch, bucket_blocks, tokens, positions, tables)``
-        as host int32 lists — dead rows carry ``position = -1`` and
-        all-null tables (the kernels' drop encoding).  With several
-        cache groups ``bucket_blocks`` and ``tables`` are tuples, one of
-        each a group."""
+        """Bucketed operands for one decode tick:
+        ``(bucket_batch, bucket_blocks, tokens, positions, tables)`` —
+        tokens and positions as host int lists, the tables as int32
+        arrays; dead rows carry ``position = -1`` and all-null tables
+        (the kernels' drop encoding).  With several cache groups
+        ``bucket_blocks`` and ``tables`` are tuples, one of each a
+        group."""
         b = bucket(len(sessions), self.max_batch)
         tokens = [s.pending_tok for s in sessions] + [0] * (b - len(sessions))
         positions = [s.position for s in sessions] \
@@ -688,19 +721,9 @@ class Scheduler:
         draft pool's tables, bucketed independently (the draft cache
         may cover fewer rows than the target's after a handoff)."""
         b = bucket(len(sessions), self.max_batch)
-        nbt = bucket(max(len(s.table) for s in sessions))
-        nbd = bucket(max(len(s.draft_table) for s in sessions))
-        tokens, positions, t_tables, d_tables = [], [], [], []
-        for s in sessions:
-            tokens.append(s.pending_tok)
-            positions.append(s.position)
-            t_tables.append(s.table
-                            + [NULL_BLOCK] * (nbt - len(s.table)))
-            d_tables.append(s.draft_table
-                            + [NULL_BLOCK] * (nbd - len(s.draft_table)))
-        for _ in range(b - len(sessions)):
-            tokens.append(0)
-            positions.append(-1)
-            t_tables.append([NULL_BLOCK] * nbt)
-            d_tables.append([NULL_BLOCK] * nbd)
+        pad = b - len(sessions)
+        tokens = [s.pending_tok for s in sessions] + [0] * pad
+        positions = [s.position for s in sessions] + [-1] * pad
+        nbt, t_tables = self.pack_rows([s.table for s in sessions], b)
+        nbd, d_tables = self.pack_rows([s.draft_table for s in sessions], b)
         return b, nbt, nbd, tokens, positions, t_tables, d_tables

@@ -135,7 +135,7 @@ def _pool_books_balance(sched):
     whole pool."""
     from collections import Counter
     occ = Counter(b for s in sched.sessions
-                  for b in s.table + s.draft_table if b != NULL_BLOCK)
+                  for b in [*s.table, *s.draft_table] if b != NULL_BLOCK)
     for b, n in occ.items():
         assert sched.pool.refcount(b) == n, \
             f"block {b}: {n} table occurrences, refcount " \
@@ -714,4 +714,330 @@ def test_the_pack_span_holds_the_operands_conversion(model, monkeypatch):
     out = eng.run([Request("a", [3, 4, 5, 6, 7, 8], 4)])
     assert len(out["a"]) == 4
     assert seen == ["serve.prefill_chunk"] * 2 + ["serve.pack"] * 3
+    eng.block_pool.check_no_leaks()
+
+
+# ---------------------------------------------------------------------------
+# block tables: int32 rows patched where they change, packed by copies
+# ---------------------------------------------------------------------------
+
+
+def test_a_block_table_reads_as_a_list_and_keeps_its_ring():
+    """A table reads as its list of Python ints however it grew, and its
+    ring is the same whether an entry is retired before or after the
+    block that takes its slot is appended."""
+    from apex_tpu.serve import BlockTable
+    t = BlockTable([7, 3])
+    t.extend(list(range(10, 20)))
+    t.append(99)                               # past two doublings
+    want = [7, 3, *range(10, 20), 99]
+    assert list(t) == want and len(t) == 13 and t[-1] == 99
+    assert t[2:5] == [10, 11, 12] and type(t[0]) is int
+    assert all(type(b) is int for b in t)
+    assert t.ids.dtype == np.int32 and t.ids.tolist() == want
+    rings = []
+    for retire_first in (True, False):
+        r = BlockTable([1, 2, 3, 4], ring=4)
+        if retire_first:
+            r[0] = NULL_BLOCK
+            r.append(5)
+        else:
+            r.append(5)                        # takes block 1's slot
+            r[0] = NULL_BLOCK
+        assert list(r) == [NULL_BLOCK, 2, 3, 4, 5]
+        rings.append(r.ring.tolist())
+    assert rings == [[5, 2, 3, 4]] * 2
+    r.clear()
+    assert len(r) == 0 and r.ring.tolist() == [NULL_BLOCK] * 4
+
+
+def _family_model(family, config):
+    """The tiny model of a benchmark family (``perfbench/families``)."""
+    import json
+    import os
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(repo, "perfbench"))
+    from pb import cells
+    fam = cells.family_module(family)
+    with open(os.path.join(repo, "perfbench", "configs",
+                           f"{config}.json")) as f:
+        cfg = json.load(f)
+    cfg.update(fam.tiny(cfg))
+    return fam.model(cfg)
+
+
+def _churn_engine(model, case):
+    """An engine whose scheduler the churn drives (nothing is
+    dispatched): one group without a window, with the prefix cache and
+    a draft's tables; one group with a window, with the prefix cache;
+    the mixed model's full and window groups; the state model's group
+    and slots."""
+    from apex_tpu.inference import make_self_draft
+    kw = {"num_blocks": 40, "block_size": 4, "max_batch": 6,
+          "prefill_chunk": 8}
+    if case == "full":
+        return ServeEngine(model, draft=make_self_draft(model), spec_k=2,
+                           **kw)
+    if case == "window":
+        return ServeEngine(model, window=8, **kw)
+    if case == "full+window":
+        return ServeEngine(_family_model(
+            "gqa_moe", "mellum2-12b-a2.5b-l8"), **kw)
+    return ServeEngine(_family_model(
+        "hybrid_ssm_moe", "nemotron3-nano-30b-a3b-ep4-l13"), **kw)
+
+
+def _plain_pack(sched, sessions, rows, group):
+    """One cache group's packing as plain lists, from each table's
+    entries: whole and padded to the next block bucket without a window,
+    else the entries from the band on at ``i mod width``."""
+    window, width = sched.windows[group], sched.ring[group]
+    tables = [list(s.tables[group]) for s in sessions]
+    if window is None:
+        return _plain_whole(tables, rows)
+    packed = []
+    for s, t in zip(sessions, tables):
+        row = [NULL_BLOCK] * width
+        lo = min(window_retired_blocks(s.position, window,
+                                       sched.pool.block_size), len(t))
+        for i in range(lo, len(t)):
+            row[i % width] = t[i]
+        packed.append(row)
+    return width, packed + [[NULL_BLOCK] * width] * (rows - len(sessions))
+
+
+def _plain_whole(tables, rows):
+    nb = bucket(max(len(t) for t in tables))
+    return nb, [list(t) + [NULL_BLOCK] * (nb - len(t)) for t in tables] \
+        + [[NULL_BLOCK] * nb] * (rows - len(tables))
+
+
+def _assert_packs_plain(sched, sessions):
+    """Every packing of ``sessions`` — a decode tick's, a prefill chunk's
+    one row, a speculative tick's — equals the plain-list one, as int32
+    arrays."""
+    b, nb, _, _, tables = sched.pack_decode(sessions)
+    for rows, (nbs, packed) in ((b, (nb, tables)),
+                                (1, sched.pack_groups(sessions[:1], 1))):
+        if len(sched.pools) == 1:
+            nbs, packed = (nbs,), (packed,)
+        for g, got in enumerate(packed):
+            assert got.dtype == np.int32
+            assert (nbs[g], got.tolist()) == \
+                _plain_pack(sched, sessions[:rows], rows, g)
+    b, nbt, nbd, _, _, t_tables, d_tables = sched.pack_spec(sessions)
+    assert (nbt, t_tables.tolist()) == \
+        _plain_whole([s.table for s in sessions], b)
+    assert (nbd, d_tables.tolist()) == \
+        _plain_whole([s.draft_table for s in sessions], b)
+
+
+def _books_balance(sched):
+    """Every table occurrence of a block is one reference of its
+    group's pool (the draft tables draw on the first group's)."""
+    from collections import Counter
+    for g, pool in enumerate(sched.pools):
+        occ = Counter(b for s in sched.sessions
+                      for b in [*s.tables[g],
+                                *(s.draft_table if g == 0 else [])]
+                      if b != NULL_BLOCK)
+        assert {b: pool.refcount(b) for b in occ} == dict(occ)
+        assert len(occ) == pool.in_use
+
+
+def _grow_or_preempt(sched, s, need):
+    """``ServeEngine._grow_or_preempt`` at the scheduler level."""
+    while not (sched.grow(s, need) and (not sched.spec_tables
+                                        or sched.grow(s, need, draft=True))):
+        if sched.preempt_for(s) is s:
+            return False
+    return True
+
+
+@pytest.mark.parametrize("case", ["full", "window", "full+window", "state"])
+def test_packed_tables_equal_a_plain_list_packing_under_churn(model, case):
+    """A seeded churn through the engine's own scheduler — admission
+    with prefix hits and copy-on-write forks (one group), growth, window
+    retirement, preemption and eviction, finish, KV handoff imports (one
+    group, no state) — and after every step the int32 tables that
+    ``pack_decode``, ``pack_groups`` and ``pack_spec`` return equal a
+    packing of plain lists."""
+    eng = _churn_engine(model, case)
+    sched = eng.scheduler
+    windowed = any(w is not None for w in sched.windows)
+    imports = len(sched.pools) == 1 and sched.slots is None
+    rng = np.random.default_rng(38)
+    vocab = 60
+    templates = [[int(t) for t in rng.integers(1, vocab, n)]
+                 for n in (4, 8, 13)]
+    slack = sched.pos_slack
+    top = min(sched.max_positions, 64) - slack
+    n, i, tick = 120, 0, 0
+    seen = {"hits": 0, "forks": 0, "imports": 0, "retired": 0, "evicts": 0}
+
+    def request(rid):
+        if sched.prefix_cache and rng.random() < 0.5:
+            prompt = list(templates[int(rng.integers(len(templates)))])
+            prompt += [int(t) for t in rng.integers(
+                1, vocab, int(rng.integers(0, 3)))]
+        else:
+            prompt = [int(t) for t in rng.integers(
+                1, vocab, int(rng.integers(1, 30)))]
+        return Request(rid, prompt,
+                       int(rng.integers(1, top - len(prompt))))
+
+    while i < n or sched.has_work():
+        tick += 1
+        assert tick < 20_000, "churn failed to drain"
+        for _ in range(int(rng.integers(0, 3))):
+            if i < n:
+                sched.submit(request(f"r{i}"))
+                i += 1
+        for s in sched.admit():
+            seen["hits"] += s.prefix_hit_tokens > 0
+            seen["forks"] += sched.complete_cow(s)
+        _books_balance(sched)
+        # one prefill chunk (ServeEngine._prefill)
+        s = sched.next_prefill()
+        if s is not None:
+            t0 = s.position
+            k = min(sched.prefill_chunk, s.prefill_remaining)
+            last = t0 + k >= len(s.prefill_src)
+            if not windowed or _grow_or_preempt(sched, s, t0 + k + last):
+                _assert_packs_plain(sched, [s])
+                s.position = s.draft_position = t0 + k
+                seen["retired"] += sched.retire_window_blocks(s)
+                sched.note_commit(s)
+                if s.prefill_remaining == 0:
+                    s.state = DECODE
+                    if s.emit_on_prefill:
+                        s.out.append(_sim_tok(s.position))
+                        s.pending_tok = s.out[-1]
+                        if s.finished():
+                            sched.finish(s)
+        # one decode tick (_ensure_decode_blocks, _decode_tick)
+        for s in list(sched.decode_sessions()):
+            if s.state == DECODE:
+                _grow_or_preempt(sched, s, s.position + 1 + slack)
+        live = sched.decode_sessions()
+        if live:
+            _assert_packs_plain(sched, live)
+        for s in live:
+            s.position += 1
+            s.draft_position = s.position
+            s.out.append(_sim_tok(s.position))
+            s.pending_tok = s.out[-1]
+            seen["retired"] += sched.retire_window_blocks(s)
+            sched.note_commit(s)
+            if s.finished():
+                sched.finish(s)
+        if live:
+            _assert_packs_plain(sched, live)
+        _books_balance(sched)
+        if sched.sessions and rng.random() < 0.05:
+            victim = sched.sessions[int(rng.integers(len(sched.sessions)))]
+            sched.evict(victim)
+            seen["evicts"] += 1
+            sched.queue.append(victim)
+        # a KV handoff's import (ServeEngine.ingest_handoff)
+        if imports and rng.random() < 0.1 \
+                and len(sched.sessions) < sched.max_batch:
+            prompt = [int(t) for t in rng.integers(1, vocab, 12)]
+            out = [int(t) for t in rng.integers(1, vocab, 9)]
+            position = len(prompt) + len(out) - 1
+            have = blocks_for(position, sched.pool.block_size)
+            ids = sched.pool.alloc(have)
+            draft = sched.pool.alloc(have) if sched.spec_tables else []
+            if ids is None or draft is None:
+                sched.pool.free(ids or [])
+                sched.pool.free(draft or [])
+            else:
+                s = sched.import_session(
+                    Request(f"h{tick}", prompt, len(out) + 8), ids, draft,
+                    position)
+                s.out, s.pending_tok = out, out[-1]
+                seen["retired"] += sched.retire_window_blocks(s)
+                seen["imports"] += 1
+                _assert_packs_plain(sched, [s])
+    for pool in sched.pools:
+        pool.check_no_leaks()
+    # the churn is not degenerate
+    assert seen["evicts"] > 0
+    assert (seen["imports"] > 0) == imports
+    assert (seen["retired"] > 0) == windowed
+    if sched.prefix_cache:
+        assert seen["hits"] > 0 and seen["forks"] > 0
+
+
+def test_a_decode_dispatch_gets_a_fresh_tables_operand(model):
+    """The tables a decode tick hands its program are a fresh array,
+    never a view of a session's table (JAX may alias a host array until
+    it has read it): a table grown after the dispatch leaves the operand
+    as it was."""
+    eng = ServeEngine(model, num_blocks=64, block_size=4, max_batch=4,
+                      prefill_chunk=8)
+    handed = []
+    tables = eng._tables
+
+    def keep(packed):
+        handed.append((packed, packed.copy()))
+        return tables(packed)
+    for i in range(3):
+        eng.submit(Request(f"t{i}", [3 + i, 4, 5, 6, 7, 8, 9], 12))
+    while len(eng.scheduler.decode_sessions()) < 3:
+        eng.step()
+    eng._tables = keep
+    eng.step()
+    (operand, was), = handed[-1:]
+    sessions = eng.scheduler.decode_sessions()
+    assert operand.tolist() == was.tolist()
+    for s in sessions:
+        assert not np.shares_memory(operand, s.table.ids)
+        assert eng.scheduler.grow(s, s.position + 12)
+    assert operand.tolist() == was.tolist()
+    eng.run([])
+    eng.block_pool.check_no_leaks()
+
+
+def test_the_tables_counters_are_recorded(model):
+    """``serve.tables.entries_patched`` counts every table entry written
+    in place — admission's, growth's, retirement's, a released table's —
+    and each ``serve.pack`` record carries ``entries``, the rows times
+    the block buckets the tick packed (docs/observability.md)."""
+    from apex_tpu.observe import spans
+    patched = obs.counter("serve.tables.entries_patched")
+    eng = ServeEngine(model, num_blocks=64, block_size=4, max_batch=4,
+                      prefill_chunk=8, window=8, prefix_cache=False)
+    sched = eng.scheduler
+    was = patched.value
+    sched.submit(Request("c", list(range(1, 11)), 30))
+    s, = sched.admit()
+    assert len(s.table) == 2 and patched.value - was == 2    # first chunk
+    was = patched.value
+    s.position = 8
+    assert sched.grow(s, 24)                                 # 6 blocks
+    assert patched.value - was == 4
+    was = patched.value
+    s.position = 20
+    assert sched.retire_window_blocks(s) == 3
+    assert patched.value - was == 3
+    was = patched.value
+    assert sched.grow(s, 24)                                 # covered
+    assert patched.value == was
+    sched.finish(s)
+    assert patched.value - was == 6 and len(s.table) == 0
+    # the engine: each decode tick's serve.pack record says what it packed
+    packs = []
+    pack_decode = sched.pack_decode
+
+    def spy(sessions):
+        b, nb, *rest = pack_decode(sessions)
+        packs.append(b * nb)
+        return (b, nb, *rest)
+    sched.pack_decode = spy
+    since = spans.recorded()[-1]["t0_ns"] + 1 if spans.recorded() else 0
+    eng.run([Request(f"e{i}", [2 + i] * (3 + 5 * i), 9) for i in range(3)])
+    recs = [r for r in spans.recorded(since) if r["span"] == "serve.pack"]
+    assert packs and [r["entries"] for r in recs] == packs
     eng.block_pool.check_no_leaks()
