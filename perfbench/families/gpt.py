@@ -199,13 +199,6 @@ def train_flops_per_token(cfg, seq_len: int) -> float:
         + 3.0 * attn_flops_fwd(cfg, 1, causal_kv)
 
 
-def flash_attn_operand_shape(cfg, rows: int, seq_len: int) -> tuple:
-    """The (batch x heads, sequence, head size) shape of the train
-    step's attention operands: what the flash kernels' custom calls
-    carry in the trace."""
-    return (rows * cfg["n_head"], seq_len, cfg["n_embd"] // cfg["n_head"])
-
-
 def flash_attn_flops_train(cfg, batch: int, seq_len: int) -> float:
     """Causal attention of one train step as the algorithm needs it:
     forward 2 matmuls (QK^T, PV), backward 4 (dV, dP, dQ, dK), over the

@@ -5,15 +5,14 @@ from pb.counts import roofline_seconds
 
 
 def flash_attn_kernels_roofline(ctx, ops):
-    """Causal attention of the train step, forward and backward, counted
-    as ``roofline.flash_attn_roofline`` counts it (the family's
-    ``flash_attn_flops_train`` and ``flash_attn_bytes_train`` a step,
-    over the window's steps) against the device time of the operations
-    named in ``ops`` (``pb.trace.op_family`` of an ``XLA Ops`` event: its
-    name without the trailing number).  That reader finds the kernels by
-    an operand of (batch x heads, sequence, head size); this one finds
-    them wherever they read and write.  An attention that XLA implements
-    as fusions carries none of the names: then nothing is read."""
+    """Causal attention of the train step, forward and backward: the
+    least time of what the family counts a step (``flash_attn_flops_train``
+    and ``flash_attn_bytes_train``), over the window's steps, against the
+    device time of the operations named in ``ops`` (``pb.trace.op_family``
+    of an ``XLA Ops`` event: its name without the trailing number), which
+    are the kernels wherever their operands lie.  An attention that XLA
+    implements as fusions carries none of the names: then nothing is
+    read."""
     tr = ctx.get("trace")
     if not tr or not tr.get("ops") or not ctx.get("peaks"):
         return None

@@ -49,26 +49,3 @@ def kernel_roofline(ctx, ops, per, flops, nbytes):
     least = sum(roofline_seconds(count_flops(cfg, tk), count_bytes(cfg, tk),
                                  ctx["peaks"])[0] for tk in ticks)
     return 100.0 * least / seconds
-
-
-def flash_attn_roofline(ctx):
-    """Causal attention of the train step, forward and backward: the
-    custom calls in the trace whose operands have the step's
-    (batch x heads, sequence, head size) shape.  An attention that XLA
-    implements as fusions carries no name to find it by (PERF.md, open
-    questions): then nothing is read."""
-    tr = ctx.get("trace")
-    if not tr or not tr.get("ops"):
-        return None
-    family, cfg, mix, c = ctx["family"], ctx["cfg"], ctx["mix"], ctx["counters"]
-    rows = mix["global_batch"] // c["chips"]
-    shape = "[%d,%d,%d]" % family.flash_attn_operand_shape(
-        cfg, rows, mix["seq_len"])
-    seconds = sum(dur for name, _, dur in tr["ops"]
-                  if "custom-call" in name and shape in name) / 1e9
-    if seconds <= 0:
-        return None
-    flops = family.flash_attn_flops_train(cfg, rows, mix["seq_len"])
-    nbytes = family.flash_attn_bytes_train(cfg, rows, mix["seq_len"])
-    least = roofline_seconds(flops, nbytes, ctx["peaks"])[0]
-    return 100.0 * least * c["steps"] / seconds

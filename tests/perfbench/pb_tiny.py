@@ -29,6 +29,99 @@ def _dump(path, data):
         json.dump(data, f, indent=1)
 
 
+def files_under(path):
+    """Every file under ``path`` and the bytes it holds."""
+    out = {}
+    for root, _, files in os.walk(path):
+        for f in files:
+            with open(os.path.join(root, f), "rb") as fh:
+                out[os.path.join(root, f)] = fh.read()
+    return out
+
+
+def model_config_pr(tmp, files=None, configs=(), cells=(), metrics=(),
+                    not_shared=(), source=REPO):
+    """``source``'s BENCHMARK.json and ``perfbench/`` copied into ``tmp``
+    as a later ``model_config`` PR leaves them: it adds files and appends
+    entries, and edits no file that is there.
+
+    - ``files``: ``{path under perfbench/: text}``, written beside the
+      others;
+    - ``configs``: entries appended after the last configuration;
+    - ``cells``: ``(entry, like)`` pairs: the entry appended after the
+      last cell, cell ``like``'s limits file copied under its name, and
+      its name appended to every metric that lists ``like`` but those
+      named in ``not_shared``;
+    - ``metrics``: ``(entry, spec)`` pairs: the entry appended after the
+      last per-layer metric, ``spec`` its ``perfbench/metrics/`` file.
+
+    Returns the copy and what its files held before anything was added."""
+    tmp = str(tmp)
+    pb = os.path.join(tmp, "perfbench")
+    shutil.copy(os.path.join(source, "BENCHMARK.json"), tmp)
+    shutil.copytree(os.path.join(source, "perfbench"), pb,
+                    ignore=shutil.ignore_patterns("__pycache__", ".trace"))
+    before = files_under(pb)
+    for path, text in (files or {}).items():
+        os.makedirs(os.path.dirname(os.path.join(pb, path)), exist_ok=True)
+        with open(os.path.join(pb, path), "w") as f:
+            f.write(text)
+    bench = _load(os.path.join(tmp, "BENCHMARK.json"))
+    bench["configs"].extend(configs)
+    for entry, like in cells:
+        bench["workloads"].append(entry)
+        shutil.copy(os.path.join(pb, "workloads", like + ".json"),
+                    os.path.join(pb, "workloads", entry["name"] + ".json"))
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if like in m.get("workloads", []) and m["name"] not in not_shared:
+                m["workloads"].append(entry["name"])
+    for entry, spec in metrics:
+        bench["per_layer"].append(entry)
+        _dump(os.path.join(pb, "metrics", entry["name"] + ".json"), spec)
+    _dump(os.path.join(tmp, "BENCHMARK.json"), bench)
+    return tmp, before
+
+
+def added_checkout(tmp, source=REPO):
+    """``source`` as the next ``model_config`` PR may leave it: after the
+    last configuration a copy of each under another name, after the last
+    cell one like each on that copy, listed wherever its like is listed
+    (nothing runs here, so every shared metric takes it), and for each
+    end-to-end metric of some cells a per-layer metric of the added ones,
+    with its file.  A test of the declarations holds here as it holds on
+    the checkout: it pins no length, order or end of a list."""
+    bench = _load(os.path.join(source, "BENCHMARK.json"))
+    files, configs = {}, []
+    for conf in bench["configs"]:
+        name = "added-" + conf["name"]
+        with open(os.path.join(source, conf["file"])) as f:
+            files[f"configs/{name}.json"] = f.read()
+        configs.append(dict(conf, name=name,
+                            file=f"perfbench/configs/{name}.json"))
+    cells = [(dict(w, name="added-" + w["name"],
+                   config="added-" + w["config"]), w["name"])
+             for w in bench["workloads"]]
+    metrics = [({"name": "added_compiles_in_window." + m["name"],
+                 "unit": "compiles", "better": "lower",
+                 "source": "program_counter", "layer": "entry and executor",
+                 "moves": m["name"],
+                 "workloads": ["added-" + w for w in m["workloads"]]},
+                {"reader": "counters.compiles_in_window", "args": {}})
+               for m in bench["end_to_end"] if "workloads" in m]
+    return model_config_pr(tmp, files, configs, cells, metrics,
+                           source=source)[0]
+
+
+#: the checkouts a test of the declarations reads: this one, and a copy as
+#: the next ``model_config`` PR may leave it
+CHECKOUTS = ["checkout", "added"]
+
+
+def checkout(which, tmp):
+    """This checkout, or (``"added"``) ``added_checkout`` in ``tmp``."""
+    return REPO if which == "checkout" else added_checkout(tmp)
+
+
 def tiny_config(cfg, repo, float32=True):
     """``cfg`` at the sizes its own family gives for a CPU test."""
     from pb import cells

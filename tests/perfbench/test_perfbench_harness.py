@@ -5,7 +5,6 @@ underneath."""
 import ast
 import json
 import os
-import shutil
 
 import numpy as np
 import pytest
@@ -41,15 +40,6 @@ def _run(repo, name, trace=0, seed=SEEDS[0], seconds=1.0, **fault):
 CELLS = [w["name"] for w in cells.load_benchmark()["workloads"]]
 
 
-def _files_under(path):
-    out = {}
-    for root, _, files in os.walk(path):
-        for f in files:
-            with open(os.path.join(root, f), "rb") as fh:
-                out[os.path.join(root, f)] = fh.read()
-    return out
-
-
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_runs_on_the_cpu_and_reports_end_to_end(repo, name):
     cell, line = _run(repo, name)
@@ -77,7 +67,7 @@ def test_traced_cell_prints_no_device_metric_off_the_chip(repo, name):
 
 def test_new_cell_and_metric_are_found_as_new_files(repo):
     """A later PR adds entries and files and edits none that is there."""
-    before = _files_under(os.path.join(repo, "perfbench"))
+    before = pb_tiny.files_under(os.path.join(repo, "perfbench"))
     bench = cells.load_benchmark(repo)
     bench["workloads"].append({
         "name": "added-cell", "config": "gpt2-small",
@@ -148,6 +138,20 @@ ADDED_CELLS = {
                       "train_tokens_per_s")}
 
 
+#: the reader of the added per-layer metrics: the added families' counts
+ADDED_COUNTS = """\
+def train_flops_per_token(ctx):
+    return ctx['family'].train_flops_per_token(
+        ctx['cfg'], ctx['mix']['seq_len'])
+
+
+def decode_flops(ctx):
+    return sum(ctx['family'].decode_step_flops(ctx['cfg'], tk)
+               for tk in ctx['counters']['ticks']
+               if 'decode_step' in tk['dispatches'])
+"""
+
+
 @pytest.fixture(scope="module")
 def added_family(tmp_path_factory):
     """A checkout's benchmark as a later ``model_config`` PR leaves it:
@@ -161,55 +165,33 @@ def added_family(tmp_path_factory):
     and two per-layer metrics fed by the families' counts.  Returns the
     checkout, the tiny copy the cells run from, and what the files held
     before."""
-    src = str(tmp_path_factory.mktemp("src"))
-    shutil.copy(os.path.join(pb_tiny.REPO, "BENCHMARK.json"), src)
-    shutil.copytree(pb_tiny.BENCH, os.path.join(src, "perfbench"),
-                    ignore=shutil.ignore_patterns("__pycache__", ".trace"))
-    before = _files_under(os.path.join(src, "perfbench"))
-    pb = os.path.join(src, "perfbench")
-    os.makedirs(os.path.join(pb, "references"))
+    files = {"readers/added_counts.py": ADDED_COUNTS}
     for fam in ("biasgpt", "ropedec"):
         for name, to in [(f"{fam}.py", f"families/{fam}.py"),
                          (f"{fam}_reference.py", f"references/{fam}.py"),
                          (f"{fam}-mini.json", f"configs/{fam}-mini.json")]:
-            shutil.copy(os.path.join(ADDED, name), os.path.join(pb, to))
-    with open(os.path.join(pb, "readers", "added_counts.py"), "w") as f:
-        f.write("def train_flops_per_token(ctx):\n"
-                "    return ctx['family'].train_flops_per_token(\n"
-                "        ctx['cfg'], ctx['mix']['seq_len'])\n\n\n"
-                "def decode_flops(ctx):\n"
-                "    return sum(ctx['family'].decode_step_flops(ctx['cfg'], "
-                "tk)\n"
-                "               for tk in ctx['counters']['ticks']\n"
-                "               if 'decode_step' in tk['dispatches'])\n")
-    bench = cells.load_benchmark(src)
-    for fam in ("biasgpt", "ropedec"):
-        bench["configs"].append({
-            "name": f"{fam}-mini",
-            "source": "tests/perfbench/data/added_family",
-            "file": f"perfbench/configs/{fam}-mini.json", "reduced": [],
-            "why": "added by a test"})
-    for name, (config, mix, like, e2e) in ADDED_CELLS.items():
-        bench["workloads"].append({
-            "name": name, "config": config, "traffic": mix,
-            "chips": 1, "why": "added by a test"})
-        shutil.copy(os.path.join(pb, "workloads", like + ".json"),
-                    os.path.join(pb, "workloads", name + ".json"))
-        for m in bench["end_to_end"] + bench["per_layer"]:
-            if like in m.get("workloads", []) and \
-                    m["name"] != "paged_attn_decode_roofline":
-                m["workloads"].append(name)
-    for reader, e2e in [("train_flops_per_token", "train_tokens_per_s"),
-                        ("decode_flops", "serve_tokens_per_s")]:
-        bench["per_layer"].append({
-            "name": "added_" + reader, "unit": "flops", "better": "lower",
-            "source": "program_counter", "layer": "step programs",
-            "moves": e2e,
-            "workloads": [n for n, c in ADDED_CELLS.items() if c[3] == e2e]})
-        pb_tiny._dump(os.path.join(pb, "metrics", f"added_{reader}.json"),
-                      {"reader": "added_counts." + reader, "args": {}})
-    with open(os.path.join(src, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+            with open(os.path.join(ADDED, name)) as f:
+                files[to] = f.read()
+    configs = [{"name": f"{fam}-mini",
+                "source": "tests/perfbench/data/added_family",
+                "file": f"perfbench/configs/{fam}-mini.json", "reduced": [],
+                "why": "added by a test"} for fam in ("biasgpt", "ropedec")]
+    added = [({"name": name, "config": config, "traffic": mix, "chips": 1,
+               "why": "added by a test"}, like)
+             for name, (config, mix, like, _) in ADDED_CELLS.items()]
+    metrics = [({"name": "added_" + reader, "unit": "flops",
+                 "better": "lower", "source": "program_counter",
+                 "layer": "step programs", "moves": e2e,
+                 "workloads": [n for n, c in ADDED_CELLS.items()
+                               if c[3] == e2e]},
+                {"reader": "added_counts." + reader, "args": {}})
+               for reader, e2e in [("train_flops_per_token",
+                                    "train_tokens_per_s"),
+                                   ("decode_flops", "serve_tokens_per_s")]]
+    # the added families have no count of the paged reader's bytes
+    src, before = pb_tiny.model_config_pr(
+        tmp_path_factory.mktemp("src"), files, configs, added, metrics,
+        not_shared={"paged_attn_decode_roofline"})
     tiny = pb_tiny.make_repo(tmp_path_factory.mktemp("tiny"), source=src)
     yield src, tiny, before
     from apex_tpu.runtime import step_cache
@@ -264,12 +246,13 @@ def test_a_family_of_other_keys_comes_in_as_files_alone(added_family, name):
         # every decode tick: 2 x matmul parameters a session at least
         got = line["metrics"]["added_decode_flops"]["value"]
         assert got > 2 * matmul * line["metrics"]["decode_batch_mean"]["value"]
-    assert _files_under(os.path.join(src, "perfbench")).items() >= \
+    assert pb_tiny.files_under(os.path.join(src, "perfbench")).items() >= \
         before.items(), "a file that was there was edited"
     untouched = cells.load_benchmark()
     now = cells.load_benchmark(src)
-    assert now["configs"][:2] == untouched["configs"]
-    assert now["workloads"][:2] == untouched["workloads"]
+    for key in ("configs", "workloads"):
+        assert now[key][:len(untouched[key])] == untouched[key], key
+        assert len(now[key]) > len(untouched[key]), key
 
 
 def test_the_added_familys_control_and_fault_are_not_correct(added_family):
@@ -370,17 +353,21 @@ def test_the_reference_imports_nothing_of_the_program(conf):
 
 
 def test_only_the_family_and_the_reference_know_the_model():
-    """Outside the family's file and the reference it is compared with,
-    nothing under ``perfbench/`` and nothing in ``pb_tiny.py`` reads a
-    GPT-2 size, names a GPT-2 leaf or imports the program's models; and
-    ``builder`` and ``reference`` are read in one place."""
+    """Outside the families' files and the references the configurations
+    are compared with, nothing under ``perfbench/`` and nothing in
+    ``pb_tiny.py`` reads a GPT-2 size, names a GPT-2 leaf or imports the
+    program's models; and ``builder`` and ``reference`` are read in one
+    place."""
     import re
     knows = re.compile(r"n_embd|n_layer|n_head|n_positions|n_inner|c_attn|"
                        r"c_proj|c_fc|\bwte\b|\bwpe\b|apex_tpu\.models|"
                        r"GptModel")
     keys = re.compile(r"""["'](builder|reference)["']""")
-    may_know = {os.path.join(pb_tiny.BENCH, "families", "gpt.py"),
-                os.path.join(pb_tiny.BENCH, "pb", "reference.py")}
+    families = os.path.join(pb_tiny.BENCH, "families")
+    may_know = {os.path.join(families, f) for f in os.listdir(families)} | {
+        os.path.join(pb_tiny.REPO, pb_tiny._load(
+            os.path.join(pb_tiny.REPO, conf["file"]))["reference"])
+        for conf in cells.load_benchmark()["configs"]}
     files = [os.path.join(root, f)
              for root, _, fs in os.walk(pb_tiny.BENCH) for f in fs
              if f.endswith(".py")] + [pb_tiny.__file__]

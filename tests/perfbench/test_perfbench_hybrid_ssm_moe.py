@@ -262,16 +262,19 @@ def test_the_attention_readers_share_on_a_synthetic_trace():
     assert reader(_ctx([p[0] for p in pairs], [], []), **kw) is None
 
 
-def test_the_new_metric_is_declared_with_the_cell():
-    bench = cells.load_benchmark()
+@pytest.mark.parametrize("checkout", pb_tiny.CHECKOUTS)
+def test_the_new_metric_is_declared_with_the_cell(checkout, tmp_path):
+    repo = pb_tiny.checkout(checkout, tmp_path)
+    bench = cells.load_benchmark(repo)
     by_name = {m["name"]: m for m in bench["per_layer"]}
     m = by_name["ssm_state_update_roofline"]
-    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"],
-            m["workloads"]) == ("%", "higher", "device_trace", "kernel tier",
-                                "serve_tokens_per_s", [CELL])
-    assert bench["per_layer"][-1] is m and bench["workloads"][-1]["name"] \
-        == CELL and bench["configs"][-1]["name"] == NAME
-    reports = {m["name"] for m in cells.Cell(CELL).per_layer}
+    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) \
+        == ("%", "higher", "device_trace", "kernel tier", "serve_tokens_per_s")
+    assert CELL in m["workloads"]
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["config"] == NAME
+    assert NAME in [c["name"] for c in bench["configs"]]
+    reports = {m["name"] for m in cells.Cell(CELL, repo=repo).per_layer}
     assert {"routed_experts_roofline", "moe_pairs_per_step",
             "moe_load_max_over_mean", "decode_step_roofline",
             "decode_step_mfu", "decode_device_idle_share",
@@ -279,16 +282,30 @@ def test_the_new_metric_is_declared_with_the_cell():
             "decode_compiles_in_window", "decode_step_device_ms",
             "paged_attn_decode_roofline", "ssm_state_update_roofline"} \
         == reports
-    assert by_name["paged_attn_decode_roofline"]["workloads"] == [
-        "gpt2m-serve-decode", CELL]
+    assert {"gpt2m-serve-decode", CELL} <= set(
+        by_name["paged_attn_decode_roofline"]["workloads"])
     # what the three older serving cells report is what it was
-    older = {"gpt2m-serve-decode": 10, "gigachat3-serve-decode": 11,
-             "mellum2-serve-decode-mixed": 12}
-    for name, n in older.items():
-        assert len(cells.Cell(name).per_layer) == n, name
-    assert [w["name"] for w in bench["workloads"]] == [
-        "gpt2s-train", *older, CELL]
-    assert all(w["chips"] == 1 for w in bench["workloads"])
+    decode = {"decode_batch_mean", "decode_compiles_in_window",
+              "decode_step_device_ms", "decode_step_roofline",
+              "decode_step_mfu", "decode_device_idle_share",
+              "decode_peak_hbm_gb"}
+    moe = {"routed_experts_roofline", "moe_pairs_per_step",
+           "moe_load_max_over_mean"}
+    older = {
+        "gpt2m-serve-decode": decode | {
+            "decode_tick_host_ms", "decode_fetch_wait_ms",
+            "paged_attn_decode_roofline"},
+        "gigachat3-serve-decode": decode | moe | {
+            "latent_attn_decode_roofline"},
+        "mellum2-serve-decode-mixed": decode | moe | {
+            "mixed_attn_decode_roofline", "window_rows_read_share"}}
+    for name, metrics in older.items():
+        assert {m["name"] for m in cells.Cell(name, repo=repo).per_layer} \
+            == metrics, name
+    names = ["gpt2s-train", *older, CELL]
+    assert [w["name"] for w in bench["workloads"][:len(names)]] == names
+    assert all(w["chips"] == 1 for w in bench["workloads"]
+               if w["name"] in names)
 
 
 def test_the_reference_imports_nothing_of_the_program():
