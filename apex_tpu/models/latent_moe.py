@@ -22,11 +22,26 @@ FFN of a leading dense layer, or the shared expert); a routed block adds
 :class:`~apex_tpu.parallel.routed_experts.RoutedExperts`, told which
 experts this device holds.
 
+**Residual streams.** With ``hc_mult`` n > 1 the residual is n streams
+(a manifold-constrained hyper-connection, ``kernels/hyper_connection.py``):
+a token's state is one float32 row of ``n * hidden``, the embedding is
+copied into every stream (:meth:`LatentMoeModel.to_streams`) and the
+streams are summed before the final norm
+(:meth:`LatentMoeModel.from_streams`); each sublayer (attention with its
+``ln1``, the FFN with its ``ln2``) is handed ``h = sum_i H_pre[i] X[i]``
+and its output enters the streams through ``X' = H_res X + H_post y``,
+with ``(H_pre, H_post, H_res)`` computed from ``X`` by the sublayer's
+own :class:`HyperConnection`.  With ``hc_mult`` 1 there is one stream,
+the plain residual, and no parameter or operation of this is built.
+
 A block follows the serve engine's layer protocol (``serve/kernels.py``):
 ``cache_rows``, ``chunk_rows``, ``read_decode``, ``read_chunk``,
-``finish``.  ``abstract=True`` builds the model with parameters that
-have shapes and no values (weights are published into it afterwards):
-the full-size model cannot be drawn in float32 first.
+``finish``.  With streams the queries a block hands the reader and the
+output it gets back carry the attention sublayer's ``mix`` beside them
+(the block's own pair), so the mapping is computed once a sublayer.
+``abstract=True`` builds the model with parameters that have shapes and
+no values (weights are published into it afterwards): the full-size
+model cannot be drawn in float32 first.
 """
 from __future__ import annotations
 
@@ -36,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..kernels import hyper_connection as _hc
 from ..kernels.latent_attention import latent_attend, latent_decode_attention
 from ..kernels.paged_attention import gather_kv
 from ..nn.parameter import Parameter, abstract_parameter
@@ -85,6 +101,38 @@ class _Table(nn.Module):
     def __init__(self, weight: Parameter):
         super().__init__()
         self.weight = weight
+
+
+class HyperConnection(nn.Module):
+    """The mapping of one sublayer's hyper-connection over ``n`` streams
+    of ``hidden`` (``kernels/hyper_connection.py`` has the equations):
+    ``phi (2n + n*n, n*hidden)``, ``alpha`` = ``(a_pre, a_post, a_res)``,
+    ``bias (2n + n*n,)``."""
+
+    def __init__(self, n, hidden, iters, eps, clamp, init):
+        super().__init__()
+        self.n, self.iters, self.eps, self.clamp = n, iters, eps, clamp
+        k = 2 * n + n * n
+        self.phi = init((k, n * hidden), n * hidden)
+        self.alpha = init((3,), None)
+        self.bias = init((k,), None)
+
+    def pre(self, ctx, x):
+        """``x (..., n*hidden)`` -> ``(h (..., hidden), mix (..., 128))``."""
+        lead = x.shape[:-1]
+        h, mix = _hc.hyper_conn_pre(
+            x.reshape(-1, x.shape[-1]), ctx.value(self.phi),
+            ctx.value(self.alpha), ctx.value(self.bias), n=self.n,
+            iters=self.iters, eps=self.eps, clamp=self.clamp)
+        return h.reshape(lead + h.shape[-1:]), \
+            mix.reshape(lead + mix.shape[-1:])
+
+    def post(self, x, y, mix):
+        """The streams ``x`` with the sublayer's output ``y`` mixed in."""
+        out = _hc.hyper_conn_post(
+            x.reshape(-1, x.shape[-1]), y.reshape(-1, y.shape[-1]),
+            mix.reshape(-1, mix.shape[-1]), n=self.n)
+        return out.reshape(x.shape)
 
 
 class LatentAttention(nn.Module):
@@ -177,10 +225,12 @@ class LatentAttention(nn.Module):
 
 class LatentMoeBlock(nn.Module):
     """RMSNorm -> latent attention -> residual, RMSNorm -> dense gated
-    FFN (+ routed experts' held part) -> residual."""
+    FFN (+ routed experts' held part) -> residual; with ``hc``, ``(n,
+    iters, eps, clamp)``, each residual is a hyper-connection over ``n``
+    streams."""
 
     def __init__(self, hidden, attn: LatentAttention, dense_intermediate,
-                 experts, eps, init):
+                 experts, eps, init, hc=None):
         super().__init__()
         self.ln1 = FusedRMSNorm(hidden, eps=eps)
         self.attn = attn
@@ -188,6 +238,13 @@ class LatentMoeBlock(nn.Module):
         self.w_in = init((hidden, 2 * dense_intermediate), hidden)
         self.w_out = init((dense_intermediate, hidden), dense_intermediate)
         self.experts = experts
+        self.hc_attn = self.hc_ffn = None
+        if hc is not None:
+            n, iters, hc_eps, clamp = hc
+            self.hc_attn = HyperConnection(n, hidden, iters, hc_eps, clamp,
+                                           init)
+            self.hc_ffn = HyperConnection(n, hidden, iters, hc_eps, clamp,
+                                          init)
 
     def _ffn(self, ctx, h, live=None):
         """-> ``(y, pairs)``: ``pairs`` the token-expert pairs each held
@@ -202,8 +259,20 @@ class LatentMoeBlock(nn.Module):
         return y + routed.reshape(h.shape), pairs
 
     def forward(self, ctx, x, positions):
-        x = x + self.attn.forward(ctx, self.ln1.forward(ctx, x), positions)
-        return x + self._ffn(ctx, self.ln2.forward(ctx, x))[0]
+        if self.hc_attn is None:
+            x = x + self.attn.forward(ctx, self.ln1.forward(ctx, x),
+                                      positions)
+            return x + self._ffn(ctx, self.ln2.forward(ctx, x))[0]
+        h, mix = self.hc_attn.pre(ctx, x)
+        x = self.hc_attn.post(
+            x, self.attn.forward(ctx, self.ln1.forward(ctx, h), positions),
+            mix)
+        return self._ffn_streams(ctx, x)[0]
+
+    def _ffn_streams(self, ctx, x, live=None):
+        h, mix = self.hc_ffn.pre(ctx, x)
+        y, pairs = self._ffn(ctx, self.ln2.forward(ctx, h), live)
+        return self.hc_ffn.post(x, y, mix), pairs
 
     # -- the serve engine's layer protocol (serve/kernels.py) --------------
 
@@ -213,37 +282,55 @@ class LatentMoeBlock(nn.Module):
         return 1, 1, self.attn.row_width
 
     def chunk_rows(self, ctx, x, positions):
-        q, row = self.attn.absorbed(ctx, self.ln1.forward(ctx, x), positions)
-        return q, (row,)
+        if self.hc_attn is None:
+            q, row = self.attn.absorbed(ctx, self.ln1.forward(ctx, x),
+                                        positions)
+            return q, (row,)
+        h, mix = self.hc_attn.pre(ctx, x)
+        q, row = self.attn.absorbed(ctx, self.ln1.forward(ctx, h), positions)
+        return (q, mix), (row,)
 
     def read_decode(self, q, pool, layer, tables, positions, window):
-        return latent_decode_attention(
+        q, mix = q if self.hc_attn is not None else (q, None)
+        o = latent_decode_attention(
             q[:, :, 0], pool, layer, tables, positions, self.attn.scaling,
             self.attn.kv_rank, window)[:, None]
+        return o if mix is None else (o, mix)
 
     def read_chunk(self, q, pool, layer, tables, positions, window):
+        q, mix = q if self.hc_attn is not None else (q, None)
         rows, = gather_kv(pool, layer, tables)
-        return latent_attend(q, rows, positions, self.attn.scaling,
-                             self.attn.kv_rank, window)
+        o = latent_attend(q, rows, positions, self.attn.scaling,
+                          self.attn.kv_rank, window)
+        return o if mix is None else (o, mix)
 
     def finish(self, ctx, x, o, live):
-        x = x + self.attn.output(ctx, o)
-        y, pairs = self._ffn(ctx, self.ln2.forward(ctx, x), live)
-        return x + y, pairs
+        if self.hc_attn is None:
+            x = x + self.attn.output(ctx, o)
+            y, pairs = self._ffn(ctx, self.ln2.forward(ctx, x), live)
+            return x + y, pairs
+        o, mix = o
+        x = self.hc_attn.post(x, self.attn.output(ctx, o), mix)
+        return self._ffn_streams(ctx, x, live)
 
 
 class LatentMoeModel(nn.Module):
     """Token embedding -> ``first_dense`` dense blocks, then routed
     blocks -> RMSNorm -> untied head.  ``forward(ids (B, S)) -> logits
     (B, S, V)``.  ``experts_held``: the routed experts this device holds
-    in every routed layer (ids; default all of them)."""
+    in every routed layer (ids; default all of them).  ``hc_mult`` > 1:
+    the residual is that many streams, each sublayer's mapping taking
+    ``hc_sinkhorn_iters`` Sinkhorn iterations with ``hc_eps`` and its
+    logits clamped to ``+-hc_clamp`` (module docstring)."""
 
     def __init__(self, vocab_size, hidden, layers, heads, *, q_rank,
                  kv_rank, nope_dim, rope_dim, v_dim, dense_intermediate,
                  expert_intermediate, n_experts, top_k, n_group=1,
                  topk_group=1, n_shared=1, route_scale=1.0, norm_topk=True,
                  first_dense=1, experts_held=None, rope=None,
-                 max_positions=4096, eps=1e-6, dtype=_f32, abstract=False):
+                 max_positions=4096, eps=1e-6, hc_mult=1,
+                 hc_sinkhorn_iters=20, hc_eps=1e-6, hc_clamp=30.0,
+                 dtype=_f32, abstract=False):
         super().__init__()
         rope = rope or dict(rope_theta=10000.0, factor=1.0,
                             original_max_position_embeddings=max_positions,
@@ -260,6 +347,9 @@ class LatentMoeModel(nn.Module):
 
         self.vocab_size, self.hidden = vocab_size, hidden
         self.max_positions = max_positions
+        self.hc_mult = int(hc_mult)
+        hc = (self.hc_mult, int(hc_sinkhorn_iters), float(hc_eps),
+              float(hc_clamp)) if self.hc_mult > 1 else None
         self.tok_emb = _Table(init((vocab_size, hidden), hidden))
         blocks = []
         for i in range(layers):
@@ -274,7 +364,7 @@ class LatentMoeModel(nn.Module):
                 LatentAttention(hidden, heads, q_rank, kv_rank, nope_dim,
                                 rope_dim, v_dim, rope, eps, init),
                 n_shared * expert_intermediate if routed
-                else dense_intermediate, experts, eps, init))
+                else dense_intermediate, experts, eps, init, hc))
         self.blocks = nn.ModuleList(blocks)
         self.ln_f = _NormTo(hidden, eps=eps)
         self.lm_head = _Table(init((vocab_size, hidden), hidden))
@@ -285,11 +375,34 @@ class LatentMoeModel(nn.Module):
             raise ValueError(f"sequence length {s} exceeds max_positions "
                              f"{self.max_positions}")
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-        x = ctx.value(self.tok_emb.weight)[input_ids]
+        x = self.to_streams(ctx.value(self.tok_emb.weight)[input_ids])
         for blk in self.blocks:
             x = blk.forward(ctx, x, pos)
-        x = self.ln_f.forward(ctx, x)
+        x = self.ln_f.forward(ctx, self.from_streams(x))
         return jnp.matmul(x, ctx.value(self.lm_head.weight).T)
+
+    # -- the serve engine's stream hooks (serve/kernels.py) ----------------
+
+    def to_streams(self, x):
+        """The embedding ``(..., hidden)`` copied into each of the
+        ``hc_mult`` float32 streams ``(..., hc_mult * hidden)``; one
+        stream is the embedding as it is."""
+        if self.hc_mult == 1:
+            return x
+        return jnp.tile(x.astype(_f32),
+                        (1,) * (x.ndim - 1) + (self.hc_mult,))
+
+    def from_streams(self, x):
+        """The streams summed: what the final norm is handed."""
+        if self.hc_mult == 1:
+            return x
+        return jnp.sum(
+            x.reshape(x.shape[:-1] + (self.hc_mult, self.hidden)), axis=-2)
+
+    def stream_sublayers(self):
+        """The sublayers a row passes through a mapping and a mix, or
+        None with one stream."""
+        return None if self.hc_mult == 1 else 2 * len(self.blocks)
 
     def _mask_pad_logits(self, logits):
         return logits               # the vocabulary is not padded

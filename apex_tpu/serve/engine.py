@@ -158,6 +158,10 @@ class ServeEngine:
         self._state_layers = sum(n for n, _ in kept)
         self._state_bytes = sum(n * size for n, size in kept) \
             // max(self._state_layers, 1)
+        # a residual of several streams: the sublayers that mix them --
+        # the tick record's hc_* fields (_count_streams); None for one
+        counts = getattr(model, "stream_sublayers", None)
+        self._streams = counts() if counts is not None else None
         self._windowed = any(g.window is not None for g in self.groups)
         # (window, layers without one, layers with one): the tick
         # record's kv_* fields (_count_rows)
@@ -601,6 +605,8 @@ class ServeEngine:
                         self._count_rows(tick, ds)
                     if self._state_layers:
                         self._count_state(tick, ds)
+                    if self._streams:
+                        self._count_streams(tick, ds)
                     self._decode_tick(ds)
         _obs.gauge("serve.queue_depth").set(len(self.scheduler.queue))
         _obs.gauge("serve.active_sessions").set(
@@ -868,6 +874,14 @@ class ServeEngine:
         tick["ssm_sessions"] = len(sessions)
         tick["ssm_layers"] = self._state_layers
         tick["ssm_state_bytes"] = 2 * len(sessions) * self._state_bytes
+
+    def _count_streams(self, tick: dict, sessions: List[Session]) -> None:
+        """What the residual's mixes move in this tick's decode dispatch,
+        on the tick's ``serve.step`` record (docs/observability.md): each
+        live session's row goes through a mapping and a mix at every
+        sublayer."""
+        tick["hc_rows"] = len(sessions)
+        tick["hc_sublayers"] = self._streams
 
     def _publish_moe(self, tick: dict) -> None:
         """What the tick's programs counted in their routed layers —

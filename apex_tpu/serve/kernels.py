@@ -39,7 +39,11 @@ rotates by the positions and keeps one latent row a token, read by all
 heads alike; a grouped-query block (``models/gqa_moe.py``) rotates by
 its own kind's tables and keeps a K and a V row of its stored heads; a
 state-space block (``models/hybrid_ssm_moe.py``) keeps a float32 state
-and its convolution's last inputs a session.
+and its convolution's last inputs a session.  A model whose residual is
+several streams makes them from the embedding and sums them before the
+final norm through two hooks of its own (``to_streams``,
+``from_streams``: :func:`_embed`, :func:`_logits`); its layers are
+handed the streams where another model's are handed ``x``.
 What is here is the index plumbing, and it keeps each pool where it lies
 (``serve/pool.py``: ``(layers of the group, streams, num_blocks,
 block_size, width)``, row-major on the device):
@@ -238,13 +242,15 @@ def _embed(ctx, model, toks, positions):
     positions reach its layers instead); ``positions`` clip to the table
     (pad rows only — real positions are range-checked at admission,
     where the bound is a host decision, not here where a clamp would
-    silently corrupt)."""
+    silently corrupt).  A model whose residual is several streams makes
+    them here (``model.to_streams``: what the layers are handed)."""
     x = gather_rows(ctx, model.tok_emb.weight, toks)
     pos_emb = getattr(model, "pos_emb", None)
-    if pos_emb is None:
-        return x
-    pos = jnp.clip(positions, 0, pos_emb.weight.shape[0] - 1)
-    return x + gather_rows(ctx, pos_emb.weight, pos)
+    if pos_emb is not None:
+        pos = jnp.clip(positions, 0, pos_emb.weight.shape[0] - 1)
+        x = x + gather_rows(ctx, pos_emb.weight, pos)
+    to_streams = getattr(model, "to_streams", None)
+    return x if to_streams is None else to_streams(x)
 
 
 def _head(ctx, model, x):
@@ -252,6 +258,16 @@ def _head(ctx, model, x):
     table = getattr(model, "lm_head", model.tok_emb).weight
     return model._mask_pad_logits(jnp.matmul(
         x, jnp.swapaxes(ctx.value(table), 0, 1).astype(x.dtype)))
+
+
+def _logits(ctx, model, x):
+    """What the last layer left -> logits: the streams summed where the
+    model has several (``model.from_streams``), the final norm, the
+    head."""
+    from_streams = getattr(model, "from_streams", None)
+    if from_streams is not None:
+        x = from_streams(x)
+    return _head(ctx, model, model.ln_f.forward(ctx, x))
 
 
 def _num_blocks(pool) -> int:
@@ -388,8 +404,7 @@ def build_decode_fn(model, params, block_size, num_blocks, window=None):
         x, pool, counted, *states = _through_blocks(
             ctx, model, pool, x, positions[:, None], positions[:, None] >= 0,
             tables, block_size, window, decode=True, **state)
-        x = model.ln_f.forward(ctx, x)
-        logits = _head(ctx, model, x)[:, 0]               # (B, V)
+        logits = _logits(ctx, model, x)[:, 0]             # (B, V)
         nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
         return (nxt, logits, pool, counted, *states)
 
@@ -433,8 +448,7 @@ def build_prefill_fn(model, params, block_size, num_blocks,
         x, pool, counted, *states = _through_blocks(
             ctx, model, pool, x, pos, (rows < n_real)[None, :], table,
             block_size, window, decode=False, **state)
-        x = model.ln_f.forward(ctx, x)
-        logits = _head(ctx, model, x)                  # (1, chunk, V)
+        logits = _logits(ctx, model, x)                # (1, chunk, V)
         last = jax.lax.dynamic_index_in_dim(
             logits, jnp.clip(n_real - 1, 0), axis=1, keepdims=False)
         return (last, pool, counted, *states)
@@ -504,8 +518,7 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
                 d_ctx, draft, d_pool, x, pos_j[:, None], pos_j[:, None] >= 0,
                 d_tables, block_size, None, decode=True)
             if j < k:                  # step k only writes its KV row
-                x = draft.ln_f.forward(d_ctx, x)
-                logits = _head(d_ctx, draft, x)[:, 0]
+                logits = _logits(d_ctx, draft, x)[:, 0]
                 tok = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
                 chunk_toks.append(tok)
         chunk = jnp.stack(chunk_toks, axis=1)       # (B, kp1)
@@ -517,8 +530,7 @@ def build_spec_verify_fn(target, t_params, draft, d_params, block_size,
         x, t_pool, _ = _through_blocks(
             t_ctx, target, t_pool, x, q_pos, q_live, t_tables, block_size,
             None, decode=False)
-        x = target.ln_f.forward(t_ctx, x)
-        logits = _head(t_ctx, target, x)                # (B, kp1, V)
+        logits = _logits(t_ctx, target, x)              # (B, kp1, V)
         emitted = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
         agree = chunk[:, 1:] == emitted[:, :k]
         stop = jnp.concatenate(

@@ -869,3 +869,112 @@ def test_hybrid_serve_programs_take_pool_and_state_where_they_lie(
         == (6 if which == "decode" else 0), calls
     assert sum("paged_attention_decode" in c for c in calls) \
         == (2 if which == "decode" else 0), calls
+
+
+# -- the serve programs of the benchmark's cell with a residual of streams ------
+# (perfbench/configs/xing4.0-29b-a4b-l5.json at its published widths: 8.10
+# GB of bf16 weights as abstract parameters, four float32 streams a row
+# mixed at 10 sublayers, a latent pool of 32769 blocks of 16 rows of 640)
+
+
+@pytest.fixture(scope="module")
+def streams():
+    """The cell's model with parameters that have shapes and no values,
+    and its engine settings."""
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    bench = os.path.join(here, "..", "perfbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from pb import cells
+    with open(os.path.join(bench, "configs", "xing4.0-29b-a4b-l5.json")) as f:
+        cfg = json.load(f)
+    model = cells.family_module(cfg["builder"]).model(cfg)
+    model.eval()
+    return cfg, model
+
+
+@pytest.mark.parametrize("which,batch", [("decode", 256), ("prefill", 1)],
+                         ids=["decode_b256_nb128", "prefill_chunk512"])
+def test_stream_serve_programs_keep_no_streams_a_layer(chip, streams, which,
+                                                       batch):
+    """Both programs take the pool where it lies, mix the streams with
+    the two mHC kernels at every sublayer (each mapping's ``mix`` and
+    ``h`` are a row's few, the streams one float32 row of 14336), and
+    keep no copy of the streams a sublayer: the decode program's
+    temporaries (37 MiB) stay under three rows of streams (14 MiB each,
+    where one a sublayer would be 140), the prefill program's (0.16 GiB)
+    are its attention's float32 scores of a chunk, as every family's."""
+    from apex_tpu.serve import kernels as serve_kernels
+    from apex_tpu.serve.pool import init_pool_buffer
+    cfg, model = streams
+    sv = cfg["serve"]
+    params = list(model.parameters())
+    vals = [_sds(p.shape, jnp.bfloat16, chip) for p in params]
+    streams_, heads, head_dim = model.blocks[0].cache_rows
+    pool = _on(chip, jax.eval_shape(lambda: init_pool_buffer(
+        len(model.blocks), heads, head_dim, sv["num_blocks"],
+        sv["block_size"], jnp.dtype(sv["cache_dtype"]), streams=streams_)))
+    nb = cfg["max_position_embeddings"] // sv["block_size"]
+    assert (sv["max_batch"], nb) == (256, 128)
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, chip)
+    build = {"decode": serve_kernels.build_decode_fn,
+             "prefill": serve_kernels.build_prefill_fn}[which]
+    fn = build(model, params, sv["block_size"], sv["num_blocks"])
+    args = (i32(batch), i32(batch), i32(batch, nb)) if which == "decode" \
+        else (i32(1, sv["prefill_chunk"]), i32(1, nb), i32(), i32())
+    with force_mode("compiled"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            vals, pool, *args).compile()
+    ma = _check(compiled, 0)
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert ma.alias_size_in_bytes >= pool_bytes         # updated in place
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    rows = batch if which == "decode" else sv["prefill_chunk"]
+    stream_bytes = rows * cfg["hc_mult"] * cfg["hidden_size"] * 4
+    print(f"\n[{which}] arguments {ma.argument_size_in_bytes / 2**30:.3f} "
+          f"temporaries {ma.temp_size_in_bytes / 2**30:.3f} total "
+          f"{total / 2**30:.3f} GiB; pool {pool_bytes / 2**30:.3f}; streams "
+          f"{stream_bytes / 2**20:.1f} MiB")
+    assert total < USABLE_BYTES, total / 2 ** 30
+    sublayers = 2 * len(model.blocks)
+    limit = 3 * stream_bytes if which == "decode" else 2 ** 30
+    assert ma.temp_size_in_bytes < limit, ma.temp_size_in_bytes / 2 ** 20
+    text = compiled.as_text()
+    for op, ln in _pool_sized_results(compiled, pool):
+        assert op in ("parameter", "bitcast", "scatter", "fusion"), ln[:300]
+        if op == "fusion":
+            assert " scatter(" in _called_computation(text, ln), ln[:300]
+    calls = _kernel_calls(compiled)
+    assert sum("hyper_conn_pre" in c for c in calls) == sublayers, calls
+    assert sum("hyper_conn_post" in c for c in calls) == sublayers, calls
+    assert sum("latent_attention_decode" in c for c in calls) \
+        == (len(model.blocks) if which == "decode" else 0), calls
+    # no float32 view of a stream per sublayer: the streams' full-width
+    # rows are the post kernel's aliased operand and result alone
+    width = f"f32[{rows},{cfg['hc_mult'] * cfg['hidden_size']}]"
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and width in ln.split(" copy(")[0]]
+    assert not copies, copies[:2]
+    if which == "decode":
+        # both kernels take and give the decode tick's streams in the
+        # core's own memory (S(1)) at every sublayer: the premise of
+        # hyper_conn_roofline's count (perfbench/families/latent_moe_hc.py
+        # prices no HBM bytes of the streams)
+        shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ",
+                                 text, re.M))
+        spaces = []
+        for ln in text.splitlines():
+            name = ln.split("=")[0].strip()
+            if "hyper_conn" not in name or " custom-call(" not in ln:
+                continue
+            ops = re.search(r"custom-call\(([^)]*)\)", ln).group(1)
+            spaces += [shapes[o] for o in ops.split(", ")
+                       if shapes.get(o, "").startswith(width)]
+            if "hyper_conn_post" in name:
+                spaces.append(shapes[name])
+        assert len(spaces) == 3 * sublayers, spaces
+        assert all(s.endswith("S(1)}") for s in spaces), spaces
