@@ -110,6 +110,12 @@ CATALOG: Dict[str, Dict[str, str]] = {
         "description": "Copy-on-write forks of shared blocks (a "
                        "full-chain hit re-ingests its final token into "
                        "an exclusive copy)."},
+    "serve.prefix.blocks_chained": {
+        "kind": "counter", "unit": "blocks",
+        "description": "Full blocks a decoding or prefilling session "
+                       "newly chain-keyed and offered to the hash index "
+                       "(first writer wins: an offer of a key already "
+                       "mapped leaves the index as it was)."},
     "serve.cache.evictions": {
         "kind": "counter", "unit": "blocks",
         "description": "Cached-tier blocks evicted under allocation "

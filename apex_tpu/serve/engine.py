@@ -446,7 +446,9 @@ class ServeEngine:
         decodes under mixed weights; hashing its blocks would poison
         the index with bytes no current-epoch chain can reproduce)."""
         if s.weight_epoch == self.weight_epochs["target"]:
-            self.scheduler.note_commit(s)
+            n = self.scheduler.note_commit(s)
+            if n:
+                _obs.counter("serve.prefix.blocks_chained").inc(n)
 
     # -- intake ------------------------------------------------------------
 

@@ -176,6 +176,22 @@ class Session:
             return self.request.prompt + tuple(self.out[:-1])
         return self.request.prompt
 
+    @property
+    def n_fed(self) -> int:
+        """``len(fed_tokens)``, counted without building the tuple."""
+        return len(self.request.prompt) + max(len(self.out) - 1, 0)
+
+    def fed_slice(self, a: int, b: int) -> Tuple[int, ...]:
+        """``fed_tokens[a:b]`` for ``0 <= a <= b``, read from the prompt
+        and the output in place: it costs ``b - a``, not the session's
+        depth."""
+        prompt = self.request.prompt
+        b = min(b, self.n_fed)
+        if b <= len(prompt):
+            return prompt[a:b]
+        return prompt[a:] + tuple(self.out[max(a - len(prompt), 0):
+                                           b - len(prompt)])
+
     def finished(self) -> bool:
         r = self.request
         return len(self.out) >= r.max_new_tokens or \
@@ -487,17 +503,17 @@ class Scheduler:
         and register each block (first writer wins — a chain another
         session committed already just leaves ours unhashed).  Called
         by the engine after every position advance; returns the number
-        of blocks newly chained."""
+        of blocks newly chained.  Only the newly full blocks' tokens are
+        read: a call that fills no block costs the same at any depth."""
         if not self.prefix_cache or not s.cacheable:
             return 0
         bs = self.pool.block_size
-        toks = s.fed_tokens
-        full = min(s.position // bs, len(s.table), len(toks) // bs)
+        full = min(s.position // bs, len(s.table), s.n_fed // bs)
         n = 0
         while s.committed_blocks < full:
             i = s.committed_blocks
             prev = s.hash_chain[i - 1] if i else ""
-            key = chain_key(prev, toks[i * bs:(i + 1) * bs],
+            key = chain_key(prev, s.fed_slice(i * bs, (i + 1) * bs),
                             self.cache_tag)
             s.hash_chain.append(key)
             b = s.table[i]

@@ -19,7 +19,8 @@ from apex_tpu.models.gpt import GptModel
 from apex_tpu.observe import registry as obs
 from apex_tpu.serve import Request, ServeEngine
 from apex_tpu.serve.disagg import DisaggregatedEngine
-from apex_tpu.serve.pool import BlockPool, chain_key, chain_keys
+from apex_tpu.serve.pool import BlockPool, NULL_BLOCK, chain_key, chain_keys
+from apex_tpu.serve.scheduler import DECODE, Session
 
 pytestmark = pytest.mark.serve
 
@@ -257,6 +258,165 @@ def test_decode_stays_recompile_free_with_cache(model):
     # buckets {1,2,4} x table buckets — prefix hits change which rows
     # are warm, never the program shapes
     assert stats["compiles"] <= 6
+    eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the commit reads only the blocks that just filled
+# ---------------------------------------------------------------------------
+
+
+def _commits_through_fed_tokens(sched, s):
+    """What one commit of ``s`` should do, computed the long way: over
+    the whole ``fed_tokens`` tuple.  Returns the session's chain after
+    it and the ``(block id, key)`` pairs it offers the pool, in order."""
+    chain = list(s.hash_chain)
+    if not sched.prefix_cache or not s.cacheable:
+        return chain, []
+    bs = sched.pool.block_size
+    toks = s.fed_tokens
+    full = min(s.position // bs, len(s.table), len(toks) // bs)
+    offered = []
+    for i in range(s.committed_blocks, full):
+        chain.append(chain_key(chain[i - 1] if i else "",
+                               toks[i * bs:(i + 1) * bs], sched.cache_tag))
+        if s.table[i] != NULL_BLOCK and not any(
+                idx == i for idx, _src, _dst in s.cow_pending):
+            offered.append((s.table[i], chain[-1]))
+    return chain, offered
+
+
+def _serve_checked(eng, reqs):
+    """Serve ``reqs`` tick by tick, holding every commit to the long
+    way's chain and offers, and every live session's chain to
+    ``chain_keys(fed_tokens)`` after each tick.  Returns the results
+    and, for each commit of a decoding session, the blocks it chained."""
+    sched = eng.scheduler
+    bs, offers, decode_chained = sched.pool.block_size, [], []
+    commit, note_commit = sched.pool.commit, sched.note_commit
+
+    def spy_commit(block_id, key):
+        offers.append((block_id, key))
+        return commit(block_id, key)
+
+    def checked(s):
+        chain, want = _commits_through_fed_tokens(sched, s)
+        start = len(offers)
+        n = note_commit(s)
+        assert offers[start:] == want
+        assert s.hash_chain == chain and s.committed_blocks == len(chain)
+        if s.state == DECODE:
+            decode_chained.append(n)
+        return n
+
+    sched.pool.commit, sched.note_commit = spy_commit, checked
+    for r in reqs:
+        eng.submit(r)
+    while True:
+        more = eng.step()
+        for s in sched.sessions:
+            keys = chain_keys(s.fed_tokens, bs, sched.cache_tag)
+            assert len(s.hash_chain) == s.committed_blocks
+            assert s.hash_chain == keys[:s.committed_blocks]
+            if s.state == DECODE:
+                assert s.committed_blocks == len(keys)
+        if not more:
+            return dict(eng.results), decode_chained
+
+
+@pytest.mark.parametrize("case", ["unaligned", "speculative", "recompute"])
+def test_commit_matches_the_fed_tokens_chain(model, case):
+    """``unaligned``: prompts of 5-7 tokens on blocks of 4, so the first
+    block a session chains in decode straddles the prompt's end and its
+    output.  ``speculative``: a tick advances up to ``spec_k + 1``
+    positions, so on blocks of 2 one commit chains two blocks.
+    ``recompute``: a pool too small for the live set preempts, and a
+    re-admitted session re-prefills ``prompt + out[:-1]`` and chains on
+    from its output."""
+    obs.get_registry().reset()
+    kw = dict(max_batch=4, prefill_chunk=4)
+    if case == "unaligned":
+        kw.update(num_blocks=64, block_size=4)
+        reqs = [Request(f"u{i}", SHARED[:5 + i % 3], 11) for i in range(5)]
+    elif case == "speculative":
+        kw.update(num_blocks=96, block_size=2, spec_k=3)
+        reqs = [Request(f"s{i}", SHARED[:3 + 2 * i], 9) for i in range(3)]
+    else:
+        kw.update(num_blocks=10, block_size=4)
+        reqs = [Request(f"p{i}", [3 + i, 5, 7, 9, 11 + i], 8)
+                for i in range(6)]
+    draft = make_self_draft(model) if case == "speculative" else None
+    eng = ServeEngine(model, draft=draft, **kw)
+    out, decode_chained = _serve_checked(eng, reqs)
+    assert sum(decode_chained) > 0
+    if case == "unaligned":
+        assert eng.metrics()["prefix_cache"]["prefill_tokens_saved"] > 0
+    elif case == "speculative":
+        assert max(decode_chained) >= 2
+    else:
+        admissions = [e["rid"] for e in obs.events("serve.request")
+                      if e["phase"] == "prefill"]
+        readmitted = {r for r in admissions if admissions.count(r) > 1}
+        assert obs.counter("serve.preemptions").value > 0 and readmitted
+        assert readmitted <= set(out)
+    eng.close()
+    cold = ServeEngine(model, draft=draft, prefix_cache=False, **kw)
+    assert out == cold.run(reqs)
+    cold.close()
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+def test_decode_commit_never_builds_fed_tokens(model, monkeypatch, spec):
+    """The tick's commit reads no ``Session.fed_tokens``: that tuple is
+    the session's whole depth, rebuilt for every session on every tick."""
+    calls = []
+    fed_tokens = Session.fed_tokens
+
+    def counted(s):
+        calls.append(s.rid)
+        return fed_tokens.fget(s)
+    monkeypatch.setattr(Session, "fed_tokens", property(counted))
+    kw = dict(draft=make_self_draft(model), spec_k=3) if spec else {}
+    eng = ServeEngine(model, num_blocks=64, block_size=4, max_batch=4,
+                      prefill_chunk=4, **kw)
+    name = "_spec_tick" if spec else "_decode_tick"
+    tick, in_ticks = getattr(eng, name), []
+
+    def watched(sessions):
+        was = len(calls)
+        tick(sessions)
+        in_ticks.append(len(calls) - was)
+    setattr(eng, name, watched)
+    chained = obs.counter("serve.prefix.blocks_chained")
+    was = chained.value
+    eng.run([Request(f"f{i}", SHARED[:5 + 3 * i], 12) for i in range(4)])
+    assert in_ticks and sum(in_ticks) == 0
+    assert chained.value > was              # the ticks did chain blocks
+    Session(Request("x", [1, 2], 3), 0).fed_tokens
+    assert calls                            # and a read would be seen
+    eng.close()
+
+
+def test_blocks_chained_counts_what_the_index_received(model):
+    """``serve.prefix.blocks_chained`` is the number of blocks the
+    commits offered the pool's hash index over the run; on this trace
+    (shared prefixes adopted at admission, distinct outputs) the index
+    took every one."""
+    eng = ServeEngine(model, num_blocks=64, block_size=8, max_batch=4,
+                      prefill_chunk=4)
+    pool, received = eng.block_pool, []
+    commit = pool.commit
+
+    def spy(block_id, key):
+        took = commit(block_id, key)
+        received.append(took)
+        return took
+    pool.commit = spy
+    chained = obs.counter("serve.prefix.blocks_chained")
+    was = chained.value
+    reqs, arrivals = _trace()
+    eng.run(reqs, arrivals=arrivals)
+    assert chained.value - was == len(received) == sum(received) > 0
     eng.close()
 
 
